@@ -6,7 +6,7 @@ ISSUE 3 names — each maps to a recovery path the chaos tests
 (tests/test_resilience.py) drive end to end on the virtual CPU mesh:
 
 - :func:`flaky` / :class:`FlakyCallable` — fails N times then succeeds
-  (the transient-tunnel shape; exercises RetryPolicy).
+  (the transient-I/O shape; exercises RetryPolicy).
 - :func:`truncate_avro_block` / :func:`corrupt_avro_block` /
   :func:`break_avro_sync` — in-place container damage (exercises the
   quarantine readers in io/avro.py).

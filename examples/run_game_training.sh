@@ -1,7 +1,18 @@
 #!/usr/bin/env bash
 # Full GAME training + scoring workflow on synthetic recommender data
 # (the analogue of the reference's examples/run_photon_ml_driver.sh).
+#
+#   CPU:   JAX_PLATFORMS=cpu bash examples/run_game_training.sh
+#   chip:  chiprun -- bash examples/run_game_training.sh
+#
+# Runs from a clean checkout: nothing is installed, so the repo root goes on
+# PYTHONPATH here. The three steps are separate processes run one after the
+# other, so each has the chip to itself.
 set -euo pipefail
+
+ROOT=$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)
+export PYTHONPATH="$ROOT${PYTHONPATH:+:$PYTHONPATH}"
+cd "$ROOT"
 
 DATA=${DATA:-/tmp/photon-tpu-recsys}
 OUT=${OUT:-/tmp/photon-tpu-out}

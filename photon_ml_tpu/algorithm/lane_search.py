@@ -200,7 +200,8 @@ def _jitted_lane_solve(objective, use_owlqn, history, max_iter,
                        bounds=None):
     """Module-level jit: one compiled tournament program per
     (objective, optimizer statics) pair; the batch and every per-lane
-    config vector enter as ARGUMENTS (the 413 landmine — lint check 9).
+    config vector enter as ARGUMENTS (a closed-over batch would be baked
+    into the program as a constant — lint check 9).
     Mirrors estimators._jitted_grid_solve with per-lane tolerance, warm
     starts and (optionally) per-lane [L, d] boxes vmapped in; the
     objective stays use_pallas=False because these lanes are vmapped."""

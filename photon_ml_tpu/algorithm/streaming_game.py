@@ -30,10 +30,10 @@ Design (ISSUE 11):
   offsets overlay the chunk offsets host-side and the solve runs
   ``StreamingGLMObjective`` in host-loop mode — exact chunked epochs,
   decode double-buffered behind accumulation.
-- **The 413 rule, mechanized.** Every chunk-consuming jit lives at module
+- **The batch-as-argument rule, mechanized.** Every chunk-consuming jit lives at module
   scope with the chunk pytree in its ARGUMENT list (``batch``); dev/
-  lint_parity.py check 9 covers this module so the landmine stays
-  structural on the GAME path too.
+  lint_parity.py check 9 covers this module so a closed-over chunk (a new
+  program, and a compile, per chunk) stays impossible on the GAME path too.
 - **DuHL schedule (opt-in).** ``DuHLChunkSchedule`` keeps a fixed budget
   of gap-hottest chunks pinned (their decoded batches cached — FE epochs
   and RE solves hit the cache instead of the decoder), streams the cold

@@ -37,7 +37,7 @@ from photon_ml_tpu.telemetry import RunJournal, SolverTelemetry, default_registr
 from photon_ml_tpu.telemetry.layout import reset_layout_metrics
 from photon_ml_tpu.telemetry.resilience_counters import reset_resilience_metrics
 from photon_ml_tpu.telemetry.stream_counters import reset_stream_metrics
-from photon_ml_tpu.telemetry.probes import CompileMonitor
+from photon_ml_tpu.telemetry.probes import CompileMonitor, runtime_stamp
 from photon_ml_tpu.telemetry.solver_trace import reset_solver_metrics
 from photon_ml_tpu.types import TaskType
 from photon_ml_tpu.util import (
@@ -191,7 +191,8 @@ def _read_batch(path: str, fmt: str, shard_cfg, index_maps=None,
         offsets=ds.offsets,
         weights=ds.weights,
     )
-    return batch, result.index_maps, result.intercept_indices.get("features")
+    return (batch, result.index_maps,
+            result.intercept_indices.get("features"), result.decode_path)
 
 
 def _check_streaming_supported(params: "GLMDriverParams") -> None:
@@ -356,8 +357,8 @@ def run(params: GLMDriverParams) -> GLMDriverResult:
         journal.record("config", **config_summary)
     compiles = CompileMonitor()
     # crash-safe recovery (resilience/recovery.py — today GAME-only, now
-    # here too): a classified-transient failure (dropped tunnel, device
-    # loss/preemption) restarts the stages up to --max-restarts times; with
+    # here too): a classified-transient failure (device loss/preemption,
+    # flaky filesystem) restarts the stages up to --max-restarts times; with
     # --checkpoint-dir the streaming solve resumes from the latest intact
     # epoch-boundary snapshot instead of from scratch
     checkpointer = None
@@ -512,6 +513,8 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
     stage = DriverStage.INIT
     shard_cfg = {"features": FeatureShardConfiguration(feature_bags=("features",))}
     streaming = params.streaming_chunks > 0
+    # the stream reader decodes container blocks in Python
+    decode_paths = {"train": "avro-python"} if streaming else {}
 
     with PhotonLogger(os.path.join(params.output_dir, "driver.log")) as job_log:
         # PREPROCESS
@@ -522,9 +525,11 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
                     _prepare_streaming(params, shard_cfg)
                 )
             else:
-                batch, index_maps, intercept_index = _read_batch(
-                    params.input_data_path, params.input_format, shard_cfg,
-                    on_corrupt=params.on_corrupt,
+                batch, index_maps, intercept_index, decode_paths["train"] = (
+                    _read_batch(
+                        params.input_data_path, params.input_format,
+                        shard_cfg, on_corrupt=params.on_corrupt,
+                    )
                 )
                 validate_arrays(
                     labels=np.asarray(batch.labels),
@@ -599,7 +604,7 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
 
                 # the validation batch doubles as the tournament metric
                 # input; read it here (VALIDATE below reuses it)
-                val_batch, _, _ = _read_batch(
+                val_batch, _, _, decode_paths["validation"] = _read_batch(
                     params.validation_data_path, params.input_format,
                     shard_cfg, index_maps, on_corrupt=params.on_corrupt,
                 )
@@ -664,7 +669,7 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
         if params.validation_data_path:
             with Timed("glm validate"):
                 if val_batch is None:
-                    val_batch, _, _ = _read_batch(
+                    val_batch, _, _, decode_paths["validation"] = _read_batch(
                         params.validation_data_path, params.input_format,
                         shard_cfg, index_maps, on_corrupt=params.on_corrupt,
                     )
@@ -722,6 +727,8 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
         "validation_metrics": {
             str(k): v for k, v in validation_metrics.items()
         },
+        "runtime": runtime_stamp(),
+        "decode_paths": decode_paths,
     }
     if search_outcome is not None:
         summary["search"] = {
@@ -744,6 +751,9 @@ def _run_stages(params: GLMDriverParams, telemetry: SolverTelemetry,
 
 def main(argv: Sequence[str] | None = None) -> GLMDriverResult:
     logging.basicConfig(level=logging.INFO)
+    from photon_ml_tpu.util.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     p = argparse.ArgumentParser(prog="glm_driver", description=__doc__.split("\n")[0])
     p.add_argument("--input-data-path", required=True)
     p.add_argument("--validation-data-path")

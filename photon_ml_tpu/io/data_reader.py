@@ -216,6 +216,11 @@ class ReadResult:
     dataset: GameDataset
     index_maps: dict[str, IndexMap]
     intercept_indices: dict[str, int]
+    #: which decoder produced the rows — "avro-native", "avro-python",
+    #: "libsvm-native" or "libsvm-python" ("" for datasets assembled from
+    #: in-memory records). Drivers stamp it into their run summaries: the
+    #: Python readers are the ~13x slower fallback.
+    decode_path: str = ""
 
 
 def _scatter_dense(
@@ -456,8 +461,8 @@ def read_merged(
                 on_corrupt=on_corrupt,
             )
         except _AvroNativeFallback as e:
-            logger.info("native avro path unavailable (%s); using the "
-                        "Python reader", e)
+            logger.warning("native avro path unavailable (%s); using the "
+                           "~13x slower Python reader", e)
 
     if result is None:
         def records():
@@ -485,6 +490,7 @@ def read_merged(
             entity_vocabs=entity_vocabs,
             dtype=dtype,
         )
+        result.decode_path = "avro-python"
     return result
 
 
@@ -786,6 +792,7 @@ def _read_merged_avro_native(
         dataset=dataset,
         index_maps=dict(index_maps),
         intercept_indices=intercept_indices,
+        decode_path="avro-native",
     )
 
 
@@ -925,4 +932,5 @@ def _read_merged_libsvm(
         dataset=dataset,
         index_maps=dict(index_maps),
         intercept_indices=intercept_indices,
+        decode_path="libsvm-native" if data.native else "libsvm-python",
     )

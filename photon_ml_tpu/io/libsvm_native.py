@@ -12,11 +12,14 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import logging
 import os
 
 import numpy as np
 
 from photon_ml_tpu.native.build import libsvm_native_available, load_libsvm_library
+
+logger = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -33,6 +36,8 @@ class LibSVMData:
     row_offsets: np.ndarray
     cols: np.ndarray
     vals: np.ndarray
+    #: False when any part came through the Python tokenizer
+    native: bool = True
 
     @property
     def num_rows(self) -> int:
@@ -130,6 +135,7 @@ def _parse_python(path: str, zero_based: bool) -> LibSVMData:
         row_offsets=np.asarray(offsets, dtype=np.uint64),
         cols=np.asarray(cols, dtype=np.uint32),
         vals=np.asarray(vals, dtype=np.float64),
+        native=False,
     )
 
 
@@ -140,8 +146,11 @@ def parse_libsvm(
     path = str(path)
     if os.path.isdir(path):
         raise IsADirectoryError(f"expected a LibSVM file, got directory: {path}")
-    if not force_python and libsvm_native_available():
-        return _parse_native(path, zero_based)
+    if not force_python:
+        if libsvm_native_available():
+            return _parse_native(path, zero_based)
+        logger.warning("native LibSVM tokenizer unavailable (no C++ compiler "
+                       "or a failed build); using the slower Python parser")
     return _parse_python(path, zero_based)
 
 
@@ -158,4 +167,5 @@ def concat_libsvm(parts: list[LibSVMData]) -> LibSVMData:
     for p in parts:
         offsets.append(p.row_offsets[1:] + base)
         base = base + p.row_offsets[-1]
-    return LibSVMData(labels, np.concatenate(offsets), cols, vals)
+    return LibSVMData(labels, np.concatenate(offsets), cols, vals,
+                      native=all(p.native for p in parts))

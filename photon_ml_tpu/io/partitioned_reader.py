@@ -449,21 +449,16 @@ def read_partitioned(
         base = int(sum(local_rows[:rank]))
         if base:
             ds = result.dataset
-            result = ReadResult(
+            result = dataclasses.replace(
+                result,
                 dataset=dataclasses.replace(
                     ds, unique_ids=np.asarray(ds.unique_ids) + base
                 ),
-                index_maps=result.index_maps,
-                intercept_indices=result.intercept_indices,
             )
 
     # ---- pad the local block to the agreed common length
     padded, _ = pad_game_dataset_to(result.dataset, block_rows)
-    result = ReadResult(
-        dataset=padded,
-        index_maps=result.index_maps,
-        intercept_indices=result.intercept_indices,
-    )
+    result = dataclasses.replace(result, dataset=padded)
 
     partition = PartitionInfo(rank, num_ranks, local_rows, block_rows)
     logger.info(
@@ -508,12 +503,14 @@ def _read_local_records(
     evaluation_id_columns, entity_vocabs, dtype,
 ) -> ReadResult:
     maps = index_maps or build_index_maps(records, shard_configs)
-    return records_to_game_dataset(
+    result = records_to_game_dataset(
         records, shard_configs, maps,
         random_effect_id_columns=random_effect_id_columns,
         evaluation_id_columns=evaluation_id_columns,
         entity_vocabs=entity_vocabs, dtype=dtype,
     )
+    result.decode_path = "avro-python"  # block-range records decode in Python
+    return result
 
 
 def _remap_to_global_maps(
@@ -546,7 +543,8 @@ def _remap_to_global_maps(
             ii = gmap.get_index(INTERCEPT_KEY)
             if ii >= 0:
                 intercepts[shard] = ii
-    return ReadResult(
+    return dataclasses.replace(
+        local,
         dataset=dataclasses.replace(
             ds, feature_shards=new_shards, host_cache=host_cache
         ),
@@ -731,10 +729,8 @@ def _resolve_global_sparse_layout(
             flat_block_nnz=int(flat),
             _device=None, _hybrid_cache=None,
         )
-    return ReadResult(
-        dataset=dataclasses.replace(ds, feature_shards=new_shards),
-        index_maps=local.index_maps,
-        intercept_indices=local.intercept_indices,
+    return dataclasses.replace(
+        local, dataset=dataclasses.replace(ds, feature_shards=new_shards)
     )
 
 
@@ -797,13 +793,12 @@ def _remap_to_global_vocabs(
             host_cache[f"entity_idx/{t}"] = remapped
             new_vocabs[t] = global_vocab
     return (
-        ReadResult(
+        dataclasses.replace(
+            local,
             dataset=dataclasses.replace(
                 ds, entity_idx=new_idx, entity_vocabs=new_vocabs,
                 host_cache=host_cache,
             ),
-            index_maps=local.index_maps,
-            intercept_indices=local.intercept_indices,
         ),
         presence,
     )

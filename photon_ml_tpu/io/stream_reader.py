@@ -17,9 +17,9 @@ Design rules (all enforced somewhere):
   (zero-weight rows — the framework padding contract), and sparse chunks
   share one ELL width / flat-entry length / hot-column count, so the
   device accumulator compiles ONCE and every chunk rides the same jit
-  signature as an ARGUMENT (never a closed-over constant — the measured
-  HTTP-413 landmine; dev/lint_parity.py check 9 statically bans nested
-  jit in the streaming modules).
+  signature as an ARGUMENT (never a closed-over constant, which would make
+  every chunk its own program; dev/lint_parity.py check 9 statically bans
+  nested jit in the streaming modules).
 - **Prefetch is bounded and hang-free.** The producer thread and the
   consumer exchange through a depth-bounded queue with timeouts both
   ways plus a bounded join on close — a wedged side surfaces as a typed

@@ -4,15 +4,23 @@ No reference analogue: the reference shipped JVM bytecode and leaned on
 PalDB/off-heap JNI jars; this build's native components compile from
 vendored C++ at first use instead.
 
-Each .so is built once from its .cpp with the system g++ and cached next to
-the source (rebuilt when the source changes, keyed by mtime+size).
-Everything degrades gracefully: the ``*_available()`` probes return False
-when no compiler exists, and callers fall back to pure-Python paths.
+Each library is built with the system g++ into ``_build/`` beside this
+file (git-ignored) under a name keyed by a hash of the source BYTES and
+the compiler command, so a checkout, a copy or a touch never changes the
+name, an edited source or flag always does, and a library whose key does
+not match the source on disk is never loaded. A build for a new key
+removes that source's older builds.
+
+A missing compiler or a failed build raises from the ``load_*`` functions;
+the ``*_available()`` probes turn that into False and a WARNING naming the
+cause, and callers then take their (much slower) pure-Python paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import shutil
@@ -23,36 +31,43 @@ from typing import Callable
 
 logger = logging.getLogger(__name__)
 
-_DIR = os.path.dirname(__file__)
+_DIR = os.path.dirname(os.path.abspath(__file__))
+BUILD_DIR = os.path.join(_DIR, "_build")
 _LOCK = threading.Lock()
 _LIBS: dict[str, ctypes.CDLL] = {}
-_FAILED: set[str] = set()
+_FAILED: dict[str, str] = {}
 
-
-def _lib_path(source: str) -> str:
-    src_stat = os.stat(source)
-    tag = f"{src_stat.st_mtime_ns}-{src_stat.st_size}"
-    stem = os.path.splitext(os.path.basename(source))[0]
-    return os.path.join(_DIR, f"_{stem}-{tag}.so")
-
-
+_CXX_FLAGS = ["-O2", "-std=c++17", "-shared", "-fPIC"]
 #: per-source extra link flags (only the Avro decoder needs zlib; coupling
 #: every native build to libz would let a missing dev link silently degrade
 #: the others to their Python fallbacks)
 _LINK_FLAGS = {"avro_decoder.cpp": ["-lz"]}
 
 
+def _link_flags(source: str) -> list[str]:
+    return _LINK_FLAGS.get(os.path.basename(source), [])
+
+
+def _lib_path(source: str) -> str:
+    digest = hashlib.sha256()
+    with open(source, "rb") as f:
+        digest.update(f.read())
+    digest.update("\0".join(_CXX_FLAGS + _link_flags(source)).encode())
+    stem = os.path.splitext(os.path.basename(source))[0]
+    return os.path.join(BUILD_DIR, f"{stem}-{digest.hexdigest()[:16]}.so")
+
+
 def _compile(source: str, out_path: str) -> None:
     gxx = shutil.which("g++") or shutil.which("c++")
     if gxx is None:
         raise RuntimeError("no C++ compiler found")
+    os.makedirs(BUILD_DIR, exist_ok=True)
     # build into a temp file then atomically rename (concurrent test workers)
-    fd, tmp = tempfile.mkstemp(suffix=".so", dir=os.path.dirname(out_path))
+    fd, tmp = tempfile.mkstemp(suffix=".so.tmp", dir=BUILD_DIR)
     os.close(fd)
     try:
         subprocess.run(
-            [gxx, "-O2", "-std=c++17", "-shared", "-fPIC", source, "-o", tmp]
-            + _LINK_FLAGS.get(os.path.basename(source), []),
+            [gxx, *_CXX_FLAGS, source, "-o", tmp, *_link_flags(source)],
             check=True,
             capture_output=True,
             text=True,
@@ -65,6 +80,10 @@ def _compile(source: str, out_path: str) -> None:
         if os.path.exists(tmp):
             os.unlink(tmp)
         raise
+    stem = os.path.splitext(os.path.basename(source))[0]
+    for stale in glob.glob(os.path.join(BUILD_DIR, f"{stem}-*.so")):
+        if stale != out_path:
+            os.unlink(stale)
 
 
 def load_native_library(
@@ -81,7 +100,8 @@ def load_native_library(
             return _LIBS[source_basename]
         if source_basename in _FAILED:
             raise RuntimeError(
-                f"native library {source_basename} previously failed to load"
+                f"native library {source_basename} previously failed to "
+                f"load: {_FAILED[source_basename]}"
             )
         try:
             path = _lib_path(source)
@@ -89,12 +109,24 @@ def load_native_library(
                 logger.info("compiling native library %s", source_basename)
                 _compile(source, path)
             lib = ctypes.CDLL(path)
-            configure(lib)
-            _LIBS[source_basename] = lib
-            return lib
-        except Exception:
-            _FAILED.add(source_basename)
+        except (RuntimeError, OSError) as e:
+            _FAILED[source_basename] = str(e)
+            logger.warning(
+                "native library %s unavailable (%s); its callers fall back "
+                "to pure-Python paths", source_basename, e,
+            )
             raise
+        configure(lib)
+        _LIBS[source_basename] = lib
+        return lib
+
+
+def _available(load: Callable[[], ctypes.CDLL]) -> bool:
+    try:
+        load()
+        return True
+    except (RuntimeError, OSError):
+        return False
 
 
 def _configure_offheap(lib: ctypes.CDLL) -> None:
@@ -127,11 +159,7 @@ def load_offheap_library() -> ctypes.CDLL:
 
 
 def native_available() -> bool:
-    try:
-        load_offheap_library()
-        return True
-    except Exception:
-        return False
+    return _available(load_offheap_library)
 
 
 def _configure_libsvm(lib: ctypes.CDLL) -> None:
@@ -162,11 +190,7 @@ def load_libsvm_library() -> ctypes.CDLL:
 
 
 def libsvm_native_available() -> bool:
-    try:
-        load_libsvm_library()
-        return True
-    except Exception:
-        return False
+    return _available(load_libsvm_library)
 
 
 def _configure_avro(lib: ctypes.CDLL) -> None:
@@ -213,8 +237,4 @@ def load_avro_library() -> ctypes.CDLL:
 
 
 def avro_native_available() -> bool:
-    try:
-        load_avro_library()
-        return True
-    except Exception:
-        return False
+    return _available(load_avro_library)

@@ -37,30 +37,15 @@ from photon_ml_tpu.ops.normalization import NormalizationContext, no_normalizati
 
 Array = jax.Array
 
-try:
-    from jax._src.interpreters.batching import BatchTracer as _BatchTracer
-except ImportError:  # pragma: no cover - jax internals moved
-    _BatchTracer = None
-    # Loud, once, at import: the fail-safe below silently downgrades EVERY
-    # auto-mode solve to the 2-pass autodiff path (~0.5x the one-pass
-    # kernel). tests/test_pallas_glm.py carries the matching canary test.
-    import logging as _logging
-
-    _logging.getLogger(__name__).warning(
-        "jax private BatchTracer import broke (jax internals moved): "
-        "vmap detection disabled, the single-pass Pallas GLM kernel is OFF "
-        "for all auto-mode solves — update _under_vmap in %s", __name__,
-    )
+# Private, because jax has no public "is this value vmapped" query. A jax
+# that moves it fails this import — a broken build, not a quiet loss of the
+# one-pass kernel (tests/test_pallas_glm.py pins that it still discriminates).
+from jax._src.interpreters.batching import BatchTracer as _BatchTracer
 
 
 def _under_vmap(*arrays) -> bool:
     """True when any input is a vmap batch tracer (the Pallas kernel has no
-    batching rule worth using; vmapped lanes stay on the autodiff path).
-    Fails SAFE: if the private BatchTracer type is unavailable (jax
-    internals moved), report "vmapped" so the kernel never silently bakes
-    into a vmapped loop (the serial per-lane regression)."""
-    if _BatchTracer is None:
-        return True
+    batching rule worth using; vmapped lanes stay on the autodiff path)."""
     return any(isinstance(a, _BatchTracer) for a in arrays)
 
 
@@ -156,8 +141,17 @@ class GLMObjective:
             # vmapped lanes (λ-grid, per-entity RE solves) share X reads
             # across lanes in one XLA matmul — the kernel has no lane axis
             return False
+        from photon_ml_tpu.ops.pallas_glm import MAX_KERNEL_DIM, kernel_supports
+
+        supported = kernel_supports(batch.features.shape[-1])
         if self.use_pallas is None:
-            return jax.default_backend() == "tpu"
+            return supported and jax.default_backend() == "tpu"
+        if not supported:
+            raise ValueError(
+                f"use_pallas=True on a {batch.features.shape[-1]}-wide dense "
+                f"block: the kernel compiles up to {MAX_KERNEL_DIM} lane-padded "
+                "columns; leave use_pallas=None (auto) for wider blocks"
+            )
         return True
 
     def value_and_gradient(

@@ -3,10 +3,11 @@
 No reference analogue: the reference solves every per-entity random-effect
 subproblem with the iterative LBFGS/TRON family (RandomEffectOptimizationProblem
 + Optimizer.scala template loop), which is the right call on a JVM executor.
-On TPU the r5 sweep decomposition (experiments/sweep_decompose_r5.py,
-BASELINE.md) showed those vmapped iterative solves are OP-COUNT-bound, not
-bandwidth-bound: ~2 ms per RE coordinate per L-BFGS iteration on a
-[2000, 128, 16] bucket whose data could stream in ~50 µs — the two-loop
+On TPU those vmapped iterative solves are OP-COUNT-bound, not
+bandwidth-bound (an r5 decomposition of the fused sweep put ~2 ms per RE
+coordinate per L-BFGS iteration on a [2000, 128, 16] bucket whose data could
+stream in ~50 µs; r5 numbers throughout this file predate this round's
+toolchain and have not been re-measured on it — PERF.md) — the two-loop
 recursion plus a Wolfe line search whose batched while_loop runs every lane
 until the WORST lane satisfies the conditions, tens of tiny [e, d] ops per
 iteration.
@@ -15,8 +16,8 @@ For the small dense dimensions where per-entity solves live (d ≲ a few
 hundred), Newton's method is the op-minimal shape: one Hessian pass
 (a batched [e, cap, d]ᵀ[e, cap, d] MXU contraction), one d-step
 Gauss-Jordan solve (NOT an XLA cholesky — batched small decompositions
-serialize per matrix on TPU, measured 3.4 ms vs 0.09 ms hand-rolled at
-[2000, 16, 16], newton_piece_probe_r5.log), one fixed 4-point step-shrink
+serialize per matrix on TPU, 3.4 ms vs 0.09 ms hand-rolled at
+[2000, 16, 16] in r5), one fixed 4-point step-shrink
 (a vmapped value evaluation that shares the feature read across the 4
 candidates — no divergent line-search loop), one gradient pass. ~15 fused
 ops per iteration regardless of entity count. For the squared loss one
@@ -60,9 +61,8 @@ def _solve_pd(h: Array, g: Array) -> Array:
 
     XLA's native decompositions are the wrong tool for BATCHED small
     systems on TPU: on [2000, 16, 16] this measured 0.088 ms vs 3.39 ms
-    for cholesky+cho_solve and 8.97 ms for jnp.linalg.solve
-    (experiments/newton_piece_probe_r5.log — their row-sequential inner
-    loops serialize per matrix). PD systems need no pivoting (every pivot
+    for cholesky+cho_solve and 8.97 ms for jnp.linalg.solve in r5 (their
+    row-sequential inner loops serialize per matrix). PD systems need no pivoting (every pivot
     is a positive Schur complement diagonal; the caller's Levenberg jitter
     keeps them away from zero under f32)."""
     d = h.shape[-1]

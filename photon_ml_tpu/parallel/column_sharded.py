@@ -37,7 +37,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.parallel.mesh import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -1798,6 +1798,30 @@ def _host_scores(scores: Array, n: int) -> np.ndarray:
     return np.asarray(jax.device_get(scores))[:n]
 
 
+def _record_shard_spread(group: str, tree) -> None:
+    """``mesh/<group>/devices`` = the fewest distinct local devices any
+    placed array of the group has a shard on; ``.../max_shard_fraction`` =
+    the largest share of a non-replicated array one device holds. Metadata
+    only (no transfer, no sync): what tells a four-chip run that used four
+    chips from one that put everything on device 0."""
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    leaves = [x for x in jax.tree_util.tree_leaves(tree)
+              if isinstance(x, jax.Array) and x.size]
+    if not leaves:
+        return
+    reg = default_registry()
+    reg.gauge(f"mesh/{group}/devices").set(min(
+        len({s.device.id for s in x.addressable_shards}) for x in leaves
+    ))
+    fractions = [
+        max(s.data.size for s in x.addressable_shards) / x.size
+        for x in leaves if not x.sharding.is_fully_replicated
+    ]
+    if fractions:
+        reg.gauge(f"mesh/{group}/max_shard_fraction").set(max(fractions))
+
+
 def train_distributed(
     program: GameTrainProgram,
     dataset: GameDataset,
@@ -2037,6 +2061,8 @@ def train_distributed(
             mesh, data, buckets, state, fe_feature_sharded=fe_feature_sharded,
             put_fn=put_fn,
         )
+        _record_shard_spread("sample_arrays", data)
+        _record_shard_spread("entity_arrays", buckets)
         if val_data is not None:
             val_data = program.shard_scoring_inputs(
                 mesh, val_data, fe_feature_sharded=fe_feature_sharded,

@@ -30,25 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_ml_tpu.data.batch import LabeledPointBatch
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat ``shard_map``: new jax exposes ``jax.shard_map`` with
-    ``check_vma``; older installs only have
-    ``jax.experimental.shard_map.shard_map`` with the equivalent knob named
-    ``check_rep``. Every shard_map in this package routes through here so
-    the multi-chip paths work on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def make_mesh(
     data: int | None = None,
     model: int = 1,

@@ -24,7 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.data.game_data import GameDataset, pad_game_dataset

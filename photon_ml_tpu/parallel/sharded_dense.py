@@ -25,7 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from photon_ml_tpu.parallel.mesh import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from photon_ml_tpu.data.batch import LabeledPointBatch

@@ -25,7 +25,6 @@ from photon_ml_tpu.resilience.errors import (
 )
 from photon_ml_tpu.resilience.policy import (
     RetryPolicy,
-    default_dispatch_policy,
     default_io_policy,
     default_kv_policy,
 )
@@ -45,7 +44,6 @@ __all__ = [
     "is_preemption",
     "is_transient",
     "RetryPolicy",
-    "default_dispatch_policy",
     "default_io_policy",
     "default_kv_policy",
     "run_with_recovery",

@@ -9,7 +9,7 @@ mid-sweep failure that is either
 
 - a :class:`~photon_ml_tpu.io.checkpoint.DivergenceError` (non-finite
   coordinate update) with an intact checkpoint to fall back to, or
-- a classified-transient error (dropped tunnel, flaky filesystem —
+- a classified-transient error (lost device, flaky filesystem —
   resilience/errors.classify_exception)
 
 restarts the attempt instead of aborting: the re-created estimator

@@ -174,9 +174,22 @@ class TestEndToEnd:
         assert (out / "best" / "random-effect" / "per-user" / "id-info").exists()
         assert (out / "models" / "0").is_dir() and (out / "models" / "1").is_dir()
         assert (out / "index-maps" / "global.keys").exists()
-        assert (out / "training-summary.json").exists()
         assert (out / "driver.log").exists()
         assert (out / "feature-stats" / "global" / "part-00000.avro").exists()
+        # the summary says what ran the job and which decoders fed it
+        import jax
+
+        on_disk = json.loads((out / "training-summary.json").read_text())
+        runtime = on_disk["runtime"]
+        assert runtime["platform"] == "cpu"
+        assert runtime["device_count"] == jax.device_count()
+        assert runtime["devices_used"] == 1  # no --distributed
+        assert runtime["jax_version"] == jax.__version__
+        assert [m["id"] for m in runtime["device_memory"]] == [
+            d.id for d in jax.local_devices()]
+        assert set(on_disk["decode_paths"]) == {"train", "validation"}
+        assert all(v in ("avro-native", "avro-python")
+                   for v in on_disk["decode_paths"].values())
 
         score_out = tmp_path / "scores"
         s = game_scoring_driver.main(
@@ -189,6 +202,7 @@ class TestEndToEnd:
         )
         assert s["num_scored"] == 300
         assert s["evaluations"]["RMSE"] == pytest.approx(summary["best_metric"], rel=0.2)
+        assert s["runtime"]["platform"] == "cpu" and "score" in s["decode_paths"]
         from photon_ml_tpu.io.model_io import read_scores
 
         scores = read_scores(score_out / "scores")

@@ -2,6 +2,8 @@
 calibration, bootstrap quantifies stability, fitting curves move the right
 way, importance ranks signal features first, reports render)."""
 
+import json
+
 import numpy as np
 import pytest
 
@@ -237,4 +239,6 @@ class TestGLMDriver:
         assert "Hosmer-Lemeshow" in html_text
         assert "Bootstrap analysis" in html_text
         assert (out / "models-text" / "0.1.txt").exists()
-        assert (out / "glm-summary.json").exists()
+        glm_summary = json.loads((out / "glm-summary.json").read_text())
+        assert glm_summary["runtime"]["platform"] == "cpu"
+        assert set(glm_summary["decode_paths"]) == {"train", "validation"}

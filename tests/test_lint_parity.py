@@ -317,7 +317,7 @@ def test_lint_catches_dead_end_flag_rejections(tmp_path):
 
 def test_lint_catches_streaming_jit_closures(tmp_path):
     """Check 9 fires: in the streaming modules, a jit built inside a
-    function (closure risk over chunk-sized arrays — the HTTP-413
+    function (closure risk over chunk-sized arrays — the closed-over-batch
     landmine) is reported, as is a module-level jit whose signature lacks
     the chunk 'batch' argument; the sanctioned module-scope
     decorator-with-batch form passes, and non-streaming modules are not
@@ -370,7 +370,7 @@ def test_lint_catches_streaming_jit_closures(tmp_path):
 
 def test_lint_covers_streaming_game_module(tmp_path):
     """Check 9 scans algorithm/streaming_game.py (the ISSUE 11 streamed
-    GAME path): a nested jit there is reported — the 413 landmine stays
+    GAME path): a nested jit there is reported — the closure ban stays
     structural on the new path — while the sanctioned module-scope
     decorator-with-batch form passes."""
     sys.path.insert(0, str(REPO_ROOT / "dev"))
@@ -404,7 +404,7 @@ def test_lint_covers_streaming_game_module(tmp_path):
 def test_lint_catches_serving_jit_closures(tmp_path):
     """Check 9 covers photon_ml_tpu/serving/: a jit built inside a
     serving-module function (closure risk over the resident model's device
-    arrays — the same HTTP-413 landmine as chunks) is reported; the
+    arrays — the same closure mistake as chunks) is reported; the
     reviewed JIT_CLOSURE_ALLOWED construction site
     (ResidentScorer.__init__, params enter as arguments) passes, and a
     same-named method on another class does NOT inherit the exemption."""

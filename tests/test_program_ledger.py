@@ -233,8 +233,7 @@ class TestAnalysis:
         f(np.ones((8, 8), np.float32))
         (row,) = _program_rows(ledger, "program_compile")
         # CPU backend implements HLO cost analysis: flops present; memory
-        # is None because analyze_memory defaults OFF (the AOT compile it
-        # needs is a real second backend compile)
+        # is None because analyze_memory defaults OFF
         assert row["cost"] is not None and row["cost"]["flops"] > 0
         assert row["memory"] is None
 

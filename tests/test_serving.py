@@ -363,6 +363,13 @@ class TestServeDriver:
         assert s["compiled_signatures"] <= 2
         assert s["replay_compiles"] == 0  # warm() built every signature
         assert os.path.exists(out / "serving-summary.json")
+        assert s["runtime"]["platform"] == "cpu" and "requests" in s["decode_paths"]
+        # the replay's answers are written out like the scoring driver's
+        from photon_ml_tpu.io.model_io import read_scores
+
+        served = read_scores(out / "scores")
+        assert len(served) == 120
+        assert all(np.isfinite(r["predictionScore"]) for r in served)
         journal_dir = out / "telemetry"
         files = os.listdir(journal_dir)
         assert any(f.endswith(".jsonl") for f in files)
