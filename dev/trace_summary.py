@@ -15,8 +15,9 @@ written by ``telemetry/tracing.py``. Output, to stdout:
    wait — everyone else's wait is caused by it) or never arrived at all
    (wedged/crashed). This is WHO the wall-clock went to.
 
-Span times are host wall-clock only (BASELINE.md "Trace methodology
-r12"): compare fractions within one trace, never absolutes across runs.
+These files are the device-free per-rank timeline on the host's
+``perf_counter``; the same spans stand in the profiler's trace
+(``--profile-dir``) beside the device's operations, on the device's clock.
 
     python dev/trace_summary.py /path/to/trace-dir [--top 15]
 """
@@ -70,9 +71,11 @@ def find_trace_files(paths: "list[str]") -> list[str]:
 
 def self_times(events: "list[dict]") -> dict[str, dict]:
     """Per span name: {"total_s", "self_s", "count"} — self time excludes
-    directly nested spans on the same (pid, tid) lane (stack sweep over
-    start-ordered intervals; a child subtracts from its immediate parent
-    only)."""
+    directly nested spans on the same (pid, tid) lane; a child subtracts
+    from its immediate parent only. A lane whose events record their
+    ``parent`` (``args.parent`` / ``args.parent_ts``, written since the span
+    seam keeps a stack of open spans) is resolved by it; a lane of an older
+    file by containment (stack sweep over start-ordered intervals)."""
     lanes: dict[tuple, list[dict]] = defaultdict(list)
     for ev in events:
         lanes[(ev["pid"], ev["tid"])].append(ev)
@@ -80,16 +83,23 @@ def self_times(events: "list[dict]") -> dict[str, dict]:
         lambda: {"total_s": 0.0, "self_s": 0.0, "count": 0}
     )
     for lane in lanes.values():
-        lane.sort(key=lambda e: (e["ts"], -e["dur"]))
-        stack: list[dict] = []
-        selfs: dict[int, float] = {}
-        for ev in lane:
-            while stack and stack[-1]["end"] <= ev["ts"]:
-                stack.pop()
-            if stack:
-                selfs[id(stack[-1])] -= ev["dur"]
-            selfs[id(ev)] = ev["dur"]
-            stack.append(ev)
+        selfs: dict[int, float] = {id(ev): ev["dur"] for ev in lane}
+        if any("parent" in (ev.get("args") or {}) for ev in lane):
+            at = {(ev["name"], ev["ts"]): ev for ev in lane}
+            for ev in lane:
+                args = ev.get("args") or {}
+                parent = at.get((args.get("parent"), args.get("parent_ts")))
+                if parent is not None:
+                    selfs[id(parent)] -= ev["dur"]
+        else:
+            lane.sort(key=lambda e: (e["ts"], -e["dur"]))
+            stack: list[dict] = []
+            for ev in lane:
+                while stack and stack[-1]["end"] <= ev["ts"]:
+                    stack.pop()
+                if stack:
+                    selfs[id(stack[-1])] -= ev["dur"]
+                stack.append(ev)
         for ev in lane:
             row = stats[ev["name"]]
             row["total_s"] += ev["dur"] / 1e6

@@ -582,7 +582,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="write per-rank Chrome-trace span timelines "
                         "(trace-{rank:05d}.json, open in Perfetto) + a "
                         "rank-merged straggler report here; flushed on "
-                        "success and failure")
+                        "success and failure, spans still open included "
+                        "(the device-free timeline: a jax.profiler "
+                        "session holds the same spans beside the device)")
     return p
 
 

@@ -1793,7 +1793,11 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-resume", action="store_true",
                    help="ignore existing checkpoints (fresh run)")
     p.add_argument("--profile-dir",
-                   help="write a jax.profiler (TensorBoard) trace here")
+                   help="write a jax.profiler (TensorBoard / xplane) trace "
+                        "here: the device's operations and, on the same "
+                        "clock, the program's own spans as photon:<name> "
+                        "host events (train/fit, train/sweep, "
+                        "train/loss_wait, dispatch/<label>, ...)")
     p.add_argument("--telemetry-dir",
                    help="write a rank-0 JSONL run journal (config, phase "
                         "timings, per-coordinate convergence rows, compile/"
@@ -1802,7 +1806,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
                    help="write per-rank Chrome-trace span timelines "
                         "(trace-{rank:05d}.json, open in Perfetto) + a "
                         "rank-merged straggler report here; flushed on "
-                        "success and failure")
+                        "success and failure, spans still open included. "
+                        "The device-free timeline of the same spans "
+                        "--profile-dir puts beside the device's work")
     p.add_argument("--compact-random-effect-threshold", type=int,
                    default=DEFAULT_COMPACT_RE_THRESHOLD,
                    help="warm-start RE models over this feature-space size "

@@ -788,7 +788,10 @@ def main(argv: Sequence[str] | None = None) -> GLMDriverResult:
     p.add_argument("--trace-dir",
                    help="write a Chrome-trace span timeline "
                         "(trace-00000.json, open in Perfetto) + straggler "
-                        "report here; flushed on success and failure")
+                        "report here; flushed on success and failure, "
+                        "spans still open included (the device-free "
+                        "timeline: a jax.profiler session holds the same "
+                        "spans beside the device)")
     p.add_argument("--on-corrupt", default="raise",
                    choices=["raise", "quarantine"],
                    help="corrupt Avro blocks: 'raise' (strict, default) "

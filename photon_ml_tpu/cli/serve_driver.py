@@ -608,7 +608,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "— on the failure path too")
     p.add_argument("--trace-dir",
                    help="write Chrome-trace span timelines here (serve/ "
-                        "spans observe the loop; open in Perfetto)")
+                        "spans observe the loop; open in Perfetto): the "
+                        "device-free timeline; a jax.profiler session "
+                        "holds the same spans beside the device")
     return p
 
 

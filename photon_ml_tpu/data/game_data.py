@@ -27,6 +27,7 @@ TPU-native redesign (SURVEY.md §7):
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Mapping, Sequence
 
 import jax
@@ -40,6 +41,8 @@ from photon_ml_tpu.projector.projectors import (
     RandomProjectionMatrix,
 )
 from photon_ml_tpu.sampling.down_sampler import stable_uniform
+from photon_ml_tpu.telemetry.tracing import span
+from photon_ml_tpu.util.timed import Timed
 
 Array = jax.Array
 
@@ -478,35 +481,45 @@ def group_entities_into_buckets(
     per-entity reservoir cap (stable-id keyed, reference
     RandomEffectDataSet.scala:354-420) and the lower-bound filter (:320-341).
     Shared by random-effect and matrix-factorization bucketing.
+
+    Timed at set-up grain (always on, read without a tracer):
+    ``pack/entity_counts`` is the sort of the rows by entity and the run
+    boundaries (numpy), ``pack/group_entities`` the Python loop over the
+    entities.
     """
-    valid = entity_idx >= 0
-    order = np.argsort(entity_idx[valid], kind="stable")
-    rows = np.nonzero(valid)[0][order]
-    ents = entity_idx[rows]
-    per_bucket: dict[int, list[tuple[int, np.ndarray]]] = {c: [] for c in bucket_sizes}
-    if len(ents) == 0:
-        return per_bucket
-    boundaries = np.concatenate(
-        [[0], np.nonzero(ents[1:] != ents[:-1])[0] + 1, [len(ents)]]
-    )
+    with Timed("pack/entity_counts", logging.DEBUG):
+        valid = entity_idx >= 0
+        order = np.argsort(entity_idx[valid], kind="stable")
+        rows = np.nonzero(valid)[0][order]
+        ents = entity_idx[rows]
+        per_bucket: dict[int, list[tuple[int, np.ndarray]]] = {
+            c: [] for c in bucket_sizes}
+        if len(ents) == 0:
+            return per_bucket
+        boundaries = np.concatenate(
+            [[0], np.nonzero(ents[1:] != ents[:-1])[0] + 1, [len(ents)]]
+        )
     max_bucket = max(bucket_sizes)
-    for start, end in zip(boundaries[:-1], boundaries[1:]):
-        entity = int(ents[start])
-        sample_rows = rows[start:end]
-        count = len(sample_rows)
-        if active_data_lower_bound is not None and count < active_data_lower_bound:
-            continue
-        # The largest bucket is an implicit cap: sampling (not head-truncation)
-        # applies either way, so the kept subset is unbiased.
-        cap = min(active_data_upper_bound or max_bucket, max_bucket)
-        if count > cap:
-            # stable reservoir: keep the `cap` samples with smallest priority
-            prio = _stable_priorities(unique_ids[sample_rows], seed)
-            keep = np.argsort(prio, kind="stable")[:cap]
-            sample_rows = sample_rows[np.sort(keep)]
-            count = cap
-        bucket_cap = next(c for c in bucket_sizes if c >= count)
-        per_bucket[bucket_cap].append((entity, sample_rows))
+    with Timed("pack/group_entities", logging.DEBUG):
+        for start, end in zip(boundaries[:-1], boundaries[1:]):
+            entity = int(ents[start])
+            sample_rows = rows[start:end]
+            count = len(sample_rows)
+            if active_data_lower_bound is not None and count < active_data_lower_bound:
+                continue
+            # The largest bucket is an implicit cap: sampling (not
+            # head-truncation) applies either way, so the kept subset is
+            # unbiased.
+            cap = min(active_data_upper_bound or max_bucket, max_bucket)
+            if count > cap:
+                # stable reservoir: keep the `cap` samples with smallest
+                # priority
+                prio = _stable_priorities(unique_ids[sample_rows], seed)
+                keep = np.argsort(prio, kind="stable")[:cap]
+                sample_rows = sample_rows[np.sort(keep)]
+                count = cap
+            bucket_cap = next(c for c in bucket_sizes if c >= count)
+            per_bucket[bucket_cap].append((entity, sample_rows))
     return per_bucket
 
 
@@ -711,121 +724,124 @@ def build_random_effect_dataset(
                 "features_to_samples_ratio (Pearson selection) is not "
                 "supported on sparse random-effect shards"
             )
-        return _build_sparse_random_effect_dataset(
-            dataset, re_type, shard_id, shard,
+        with Timed("pack/dataset", re_type=re_type):
+            return _build_sparse_random_effect_dataset(
+                dataset, re_type, shard_id, shard,
+                active_data_upper_bound=active_data_upper_bound,
+                active_data_lower_bound=active_data_lower_bound,
+                bucket_sizes=bucket_sizes,
+                seed=seed,
+                normalization=normalization,
+            )
+
+    with Timed("pack/dataset", re_type=re_type):
+        entity_idx = dataset.host_array(f"entity_idx/{re_type}")
+        features = dataset.host_array(f"shard/{shard_id}")
+        labels = dataset.host_array("labels")
+        weights = dataset.host_array("weights")
+        unique_ids = np.asarray(dataset.unique_ids)
+        dim = features.shape[1]
+        num_entities = len(dataset.entity_vocabs[re_type])
+
+        projection = None
+        if projector_type == ProjectorType.RANDOM:
+            if projected_dim is None:
+                raise ValueError("RANDOM projection requires projected_dim")
+            projection = RandomProjectionMatrix.create(dim, projected_dim, seed)
+            if normalization is not None:
+                # normalize BEFORE sketching: x' = (x - shift)*factor, then
+                # project — exact, unlike the reference's projection OF the
+                # context (ProjectionMatrixBroadcast.projectNormalizationContext
+                # maps factor/shift vectors through the Gaussian sketch, which
+                # does not commute with per-feature scaling). Solves then run
+                # plain; the back-projected [E, d] tables are normalized-space
+                # coefficients and convert through the standard context algebra.
+                from photon_ml_tpu.ops.normalization import (
+                    host_factors,
+                    host_shifts,
+                )
+
+                features = np.asarray(features)
+                shifts = host_shifts(normalization)
+                if shifts is not None:
+                    features = features - shifts.astype(features.dtype)
+                factors = host_factors(normalization)
+                if factors is not None:
+                    features = features * factors.astype(features.dtype)
+            features = projection.project_features(features).astype(features.dtype)
+
+        per_bucket = group_entities_into_buckets(
+            entity_idx,
+            unique_ids,
+            bucket_sizes=bucket_sizes,
             active_data_upper_bound=active_data_upper_bound,
             active_data_lower_bound=active_data_lower_bound,
-            bucket_sizes=bucket_sizes,
             seed=seed,
-            normalization=normalization,
         )
 
-    entity_idx = dataset.host_array(f"entity_idx/{re_type}")
-    features = dataset.host_array(f"shard/{shard_id}")
-    labels = dataset.host_array("labels")
-    weights = dataset.host_array("weights")
-    unique_ids = np.asarray(dataset.unique_ids)
-    dim = features.shape[1]
-    num_entities = len(dataset.entity_vocabs[re_type])
-
-    projection = None
-    if projector_type == ProjectorType.RANDOM:
-        if projected_dim is None:
-            raise ValueError("RANDOM projection requires projected_dim")
-        projection = RandomProjectionMatrix.create(dim, projected_dim, seed)
-        if normalization is not None:
-            # normalize BEFORE sketching: x' = (x - shift)*factor, then
-            # project — exact, unlike the reference's projection OF the
-            # context (ProjectionMatrixBroadcast.projectNormalizationContext
-            # maps factor/shift vectors through the Gaussian sketch, which
-            # does not commute with per-feature scaling). Solves then run
-            # plain; the back-projected [E, d] tables are normalized-space
-            # coefficients and convert through the standard context algebra.
-            from photon_ml_tpu.ops.normalization import (
-                host_factors,
-                host_shifts,
+        if features_to_samples_ratio is not None and projector_type == ProjectorType.RANDOM:
+            raise ValueError(
+                "features_to_samples_ratio (Pearson selection) operates on "
+                "original feature columns and cannot combine with RANDOM "
+                "projection; use IDENTITY or INDEX_MAP"
             )
 
-            features = np.asarray(features)
-            shifts = host_shifts(normalization)
-            if shifts is not None:
-                features = features - shifts.astype(features.dtype)
-            factors = host_factors(normalization)
-            if factors is not None:
-                features = features * factors.astype(features.dtype)
-        features = projection.project_features(features).astype(features.dtype)
+        index_projected = projector_type == ProjectorType.INDEX_MAP
+        buckets: list[EntityBucket] = []
+        for cap, members in per_bucket.items():
+            if not members:
+                continue
+            with span("pack/bucket", cap=cap, entities=len(members)):
+                e = len(members)
+                be, rows_concat, lane, slot = pack_bucket_lanes(members)
+                bl = np.zeros((e, cap), dtype=labels.dtype)
+                bw = np.zeros((e, cap), dtype=weights.dtype)
+                bs = np.full((e, cap), -1, dtype=np.int32)
+                bl[lane, slot] = labels[rows_concat]
+                bw[lane, slot] = weights[rows_concat]
+                bs[lane, slot] = rows_concat
 
-    per_bucket = group_entities_into_buckets(
-        entity_idx,
-        unique_ids,
-        bucket_sizes=bucket_sizes,
-        active_data_upper_bound=active_data_upper_bound,
-        active_data_lower_bound=active_data_lower_bound,
-        seed=seed,
-    )
+                # one gather of the bucket's samples; every per-entity computation
+                # below (Pearson masks, active columns) is a vectorized grouped
+                # reduction over `lane` — no Python loop over entities
+                x = features[rows_concat]
+                if features_to_samples_ratio is not None:
+                    keep = _pearson_keep_masks_grouped(
+                        x, labels[rows_concat], lane, e, features_to_samples_ratio
+                    )
+                    x = x * keep[lane]
 
-    if features_to_samples_ratio is not None and projector_type == ProjectorType.RANDOM:
-        raise ValueError(
-            "features_to_samples_ratio (Pearson selection) operates on "
-            "original feature columns and cannot combine with RANDOM "
-            "projection; use IDENTITY or INDEX_MAP"
-        )
-
-    index_projected = projector_type == ProjectorType.INDEX_MAP
-    buckets: list[EntityBucket] = []
-    for cap, members in per_bucket.items():
-        if not members:
-            continue
-        e = len(members)
-        be, rows_concat, lane, slot = pack_bucket_lanes(members)
-        bl = np.zeros((e, cap), dtype=labels.dtype)
-        bw = np.zeros((e, cap), dtype=weights.dtype)
-        bs = np.full((e, cap), -1, dtype=np.int32)
-        bl[lane, slot] = labels[rows_concat]
-        bw[lane, slot] = weights[rows_concat]
-        bs[lane, slot] = rows_concat
-
-        # one gather of the bucket's samples; every per-entity computation
-        # below (Pearson masks, active columns) is a vectorized grouped
-        # reduction over `lane` — no Python loop over entities
-        x = features[rows_concat]
-        if features_to_samples_ratio is not None:
-            keep = _pearson_keep_masks_grouped(
-                x, labels[rows_concat], lane, e, features_to_samples_ratio
-            )
-            x = x * keep[lane]
-
-        bc = None
-        if index_projected:
-            bf, bc = _pack_index_projected(x, lane, slot, e, cap, dim)
-            if normalization is not None:
-                bf = _normalize_projected_block(
-                    bf, bc, bs, normalization, dim
+                bc = None
+                if index_projected:
+                    bf, bc = _pack_index_projected(x, lane, slot, e, cap, dim)
+                    if normalization is not None:
+                        bf = _normalize_projected_block(
+                            bf, bc, bs, normalization, dim
+                        )
+                else:
+                    bf = np.zeros((e, cap, x.shape[1]), dtype=features.dtype)
+                    bf[lane, slot] = x
+                buckets.append(
+                    EntityBucket(
+                        features=jnp.asarray(bf),
+                        labels=jnp.asarray(bl),
+                        weights=jnp.asarray(bw),
+                        entity_rows=jnp.asarray(be),
+                        sample_rows=jnp.asarray(bs),
+                        col_index=None if bc is None else jnp.asarray(bc),
+                    )
                 )
-        else:
-            bf = np.zeros((e, cap, x.shape[1]), dtype=features.dtype)
-            bf[lane, slot] = x
-        buckets.append(
-            EntityBucket(
-                features=jnp.asarray(bf),
-                labels=jnp.asarray(bl),
-                weights=jnp.asarray(bw),
-                entity_rows=jnp.asarray(be),
-                sample_rows=jnp.asarray(bs),
-                col_index=None if bc is None else jnp.asarray(bc),
-            )
-        )
 
-    return RandomEffectDataset(
-        random_effect_type=re_type,
-        feature_shard_id=shard_id,
-        buckets=buckets,
-        num_entities=num_entities,
-        dim=dim,
-        projector_type=projector_type,
-        projection=projection,
-        pre_normalized=normalization is not None,
-    )
+        return RandomEffectDataset(
+            random_effect_type=re_type,
+            feature_shard_id=shard_id,
+            buckets=buckets,
+            num_entities=num_entities,
+            dim=dim,
+            projector_type=projector_type,
+            projection=projection,
+            pre_normalized=normalization is not None,
+        )
 
 
 def build_random_effect_dataset_partitioned(

@@ -56,6 +56,8 @@ from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, solve
 from photon_ml_tpu.telemetry.program_ledger import ledger_jit
+from photon_ml_tpu.telemetry.registry import default_registry
+from photon_ml_tpu.telemetry.tracing import span
 from photon_ml_tpu.types import TaskType
 
 Array = jax.Array
@@ -711,9 +713,11 @@ class GameTrainProgram:
         put = put_fn if put_fn is not None else jax.device_put
         rep = NamedSharding(mesh, P())
         data_axis = int(mesh.shape["data"])
-        data = self._shard_data(
-            mesh, data, fe_feature_sharded=fe_feature_sharded, put_fn=put_fn
-        )
+        with span("train/shard/data"):
+            data = self._shard_data(
+                mesh, data, fe_feature_sharded=fe_feature_sharded,
+                put_fn=put_fn,
+            )
 
         ent3 = NamedSharding(mesh, P("data", None, None))
         ent2 = NamedSharding(mesh, P("data", None))
@@ -758,24 +762,26 @@ class GameTrainProgram:
                 out["col_index"] = put(b["col_index"], ent2)
             return out
 
-        sharded_buckets: dict = {
-            k: [put_bucket(b) for b in bs]
-            for k, bs in buckets.items()
-            if k not in ("__mf__", "__projections__")
-        }
-        if "__projections__" in buckets:
-            sharded_buckets["__projections__"] = {
-                k: put(v, rep)
-                for k, v in buckets["__projections__"].items()
+        with span("train/shard/buckets"):
+            sharded_buckets: dict = {
+                k: [put_bucket(b) for b in bs]
+                for k, bs in buckets.items()
+                if k not in ("__mf__", "__projections__")
             }
-        if "__mf__" in buckets:
-            sharded_buckets["__mf__"] = {
-                name: {
-                    side: [put_bucket(b) for b in side_buckets]
-                    for side, side_buckets in sides.items()
+            if "__projections__" in buckets:
+                sharded_buckets["__projections__"] = {
+                    k: put(v, rep)
+                    for k, v in buckets["__projections__"].items()
                 }
-                for name, sides in buckets["__mf__"].items()
-            }
+            if "__mf__" in buckets:
+                sharded_buckets["__mf__"] = {
+                    name: {
+                        side: [put_bucket(b) for b in side_buckets]
+                        for side, side_buckets in sides.items()
+                    }
+                    for name, sides in buckets["__mf__"].items()
+                }
+
         def put_table(v):
             # entity axis padded to a mesh multiple; padded rows are never
             # read (entity indices stay < E) nor written (scatter targets
@@ -786,14 +792,17 @@ class GameTrainProgram:
             return put(v, ent2)
 
         fe_sharding = NamedSharding(mesh, P("model")) if fe_feature_sharded else rep
-        state = GameTrainState(
-            fe_coefficients=put(state.fe_coefficients, fe_sharding),
-            re_tables={k: put_table(v) for k, v in state.re_tables.items()},
-            mf_rows={k: put_table(v) for k, v in state.mf_rows.items()},
-            mf_cols={k: put_table(v) for k, v in state.mf_cols.items()},
-            # extra FE vectors replicate (only the primary may feature-shard)
-            extra_fe={k: put(v, rep) for k, v in state.extra_fe.items()},
-        )
+        with span("train/shard/state"):
+            state = GameTrainState(
+                fe_coefficients=put(state.fe_coefficients, fe_sharding),
+                re_tables={k: put_table(v)
+                           for k, v in state.re_tables.items()},
+                mf_rows={k: put_table(v) for k, v in state.mf_rows.items()},
+                mf_cols={k: put_table(v) for k, v in state.mf_cols.items()},
+                # extra FE vectors replicate (only the primary may
+                # feature-shard)
+                extra_fe={k: put(v, rep) for k, v in state.extra_fe.items()},
+            )
         return data, sharded_buckets, state
 
     # -- the fused step ------------------------------------------------------
@@ -1804,8 +1813,6 @@ def _record_shard_spread(group: str, tree) -> None:
     the largest share of a non-replicated array one device holds. Metadata
     only (no transfer, no sync): what tells a four-chip run that used four
     chips from one that put everything on device 0."""
-    from photon_ml_tpu.telemetry.registry import default_registry
-
     leaves = [x for x in jax.tree_util.tree_leaves(tree)
               if isinstance(x, jax.Array) and x.size]
     if not leaves:
@@ -1820,6 +1827,86 @@ def _record_shard_spread(group: str, tree) -> None:
     ]
     if fractions:
         reg.gauge(f"mesh/{group}/max_shard_fraction").set(max(fractions))
+
+
+def _record_placed_bytes(*trees) -> None:
+    """``train/placed_bytes`` += the bytes of every array ``shard_inputs``
+    laid out over the mesh (metadata only: no transfer, no sync)."""
+    default_registry().counter("train/placed_bytes").inc(sum(
+        int(x.nbytes) for x in jax.tree_util.tree_leaves(trees)
+        if isinstance(x, jax.Array)
+    ))
+
+
+# -- the spans and counters of a fit ------------------------------------------
+# train_distributed and train_partitioned run the same loop; these helpers
+# are where its span names and counters live, so the two cannot drift. Every
+# span is host-side only (telemetry/tracing.py): nothing here enters a
+# compiled program, and none adds, skips or reorders a collective.
+
+
+def _fit_span(num_iterations: int, mesh: Mesh | None):
+    """``train/fit`` round one whole fit; bumps ``train/fits``, whose new
+    value is the ``fit`` identifier every span inside inherits."""
+    fits = default_registry().counter("train/fits")
+    fits.inc()
+    shape = "none" if mesh is None else "x".join(
+        str(size) for size in mesh.shape.values())
+    return span("train/fit", fit=fits.value, sweeps=num_iterations, mesh=shape)
+
+
+def _step_and_wait(program: GameTrainProgram, data, buckets,
+                   state: GameTrainState, *, sweep: int, num_iterations: int,
+                   schedulers, rows: int, check_finite: bool, checkpointer,
+                   what: str):
+    """One sweep through the fused program, then the host's wait for its
+    loss: ``train/step`` ends when the work is ENQUEUED, ``train/loss_wait``
+    is the host blocked on the device. Bumps ``train/sweeps`` and
+    ``train/rows`` (rows trained, the work as a count). Returns
+    (state, loss as a float); a non-finite loss raises before any
+    checkpoint could overwrite the last finite state with NaNs (CD-path
+    DivergenceError contract, coordinate_descent.py)."""
+    with span("train/step"):
+        if schedulers:
+            state, loss = program.step_scheduled(
+                data, buckets, state, schedulers=schedulers,
+                final_sweep=(sweep + 1 == num_iterations),
+            )
+        else:
+            state, loss = program.step(data, buckets, state)
+    with span("train/loss_wait"):
+        loss = float(loss)
+    if check_finite and not np.isfinite(loss):
+        from photon_ml_tpu.io.checkpoint import DivergenceError
+
+        raise DivergenceError(
+            f"{what} training step produced non-finite loss "
+            f"{loss} at sweep {sweep}"
+            + (
+                f"; last good checkpoint: step "
+                f"{checkpointer.latest_step()} in {checkpointer.directory}"
+                if checkpointer is not None else ""
+            )
+        )
+    registry = default_registry()
+    registry.counter("train/sweeps").inc()
+    registry.counter("train/rows").inc(rows)
+    return state, loss
+
+
+def _checkpoint_if_due(checkpointer, checkpoint_every: int, sweep: int,
+                       num_iterations: int, commit) -> None:
+    """Run ``commit()`` under ``train/checkpoint`` after every
+    ``checkpoint_every``-th sweep and after the last. Every process calls
+    it: ``commit`` holds the gathers (collectives) and the commit helper's
+    barriers; only process 0 writes (lint check 10)."""
+    if checkpointer is None or not (
+        (sweep + 1) % max(1, checkpoint_every) == 0
+        or sweep + 1 == num_iterations
+    ):
+        return
+    with span("train/checkpoint"):
+        commit()
 
 
 def train_distributed(
@@ -1879,306 +1966,253 @@ def train_distributed(
     Returns a :class:`DistributedTrainResult` (unpacks as
     ``(final_state, losses)``).
     """
-    start_sweep = 0
-    prior_losses: list[float] = []
-    best_state: GameTrainState | None = None
-    best_metric = float("nan")
-    history: list[dict] = []
-    # An explicit caller-supplied state takes precedence over resume: passing
-    # both a warm start and a stale checkpoint must not silently ignore the
-    # warm start.
-    if checkpointer is not None and resume and state is None:
-        ckpt = checkpointer.restore()
-        if ckpt is not None:
-            if "fe_coefficients" not in ckpt.arrays:
-                # e.g. a CD-path checkpoint (model/... keys) in the same dir
-                raise ValueError(
-                    f"checkpoint at {checkpointer.directory} is not a "
-                    "distributed-training checkpoint (no 'fe_coefficients' "
-                    f"array; found keys like {sorted(ckpt.arrays)[:3]}). Pass "
-                    "resume=False or use a fresh checkpoint directory."
+    with _fit_span(num_iterations, mesh):
+        start_sweep = 0
+        prior_losses: list[float] = []
+        best_state: GameTrainState | None = None
+        best_metric = float("nan")
+        history: list[dict] = []
+        # An explicit caller-supplied state takes precedence over resume: passing
+        # both a warm start and a stale checkpoint must not silently ignore the
+        # warm start.
+        if checkpointer is not None and resume and state is None:
+            with span("train/restore"):
+                ckpt = checkpointer.restore()
+                if ckpt is not None:
+                    if "fe_coefficients" not in ckpt.arrays:
+                        # e.g. a CD-path checkpoint (model/... keys) in the same dir
+                        raise ValueError(
+                            f"checkpoint at {checkpointer.directory} is not a "
+                            "distributed-training checkpoint (no 'fe_coefficients' "
+                            f"array; found keys like {sorted(ckpt.arrays)[:3]}). Pass "
+                            "resume=False or use a fresh checkpoint directory."
+                        )
+                    def by_prefix(prefix, arrays=None):
+                        arrays = ckpt.arrays if arrays is None else arrays
+                        return {
+                            k[len(prefix):]: jnp.asarray(v)
+                            for k, v in arrays.items()
+                            if k.startswith(prefix) and "/" not in k[len(prefix):]
+                        }
+                    state = GameTrainState(
+                        fe_coefficients=jnp.asarray(ckpt.arrays["fe_coefficients"]),
+                        re_tables=by_prefix("re_tables/"),
+                        mf_rows=by_prefix("mf_rows/"),
+                        mf_cols=by_prefix("mf_cols/"),
+                        extra_fe=by_prefix("extra_fe/"),
+                    )
+                    expected = {
+                        "re_tables": {s.re_type for s in program.re_specs},
+                        "mf_rows": {m.name for m in program.mf_specs},
+                        "mf_cols": {m.name for m in program.mf_specs},
+                        "extra_fe": {s.feature_shard_id for s in program.extra_fes},
+                    }
+                    found = {
+                        "re_tables": set(state.re_tables),
+                        "mf_rows": set(state.mf_rows),
+                        "mf_cols": set(state.mf_cols),
+                        "extra_fe": set(state.extra_fe),
+                    }
+                    if expected != found:
+                        raise ValueError(
+                            f"checkpoint at {checkpointer.directory} is incompatible "
+                            f"with the program's coordinate specs: checkpoint has "
+                            f"{found}, program expects {expected}. Pass resume=False "
+                            "or use a fresh checkpoint directory."
+                        )
+                    if "best/fe_coefficients" in ckpt.arrays:
+                        best_state = GameTrainState(
+                            fe_coefficients=jnp.asarray(ckpt.arrays["best/fe_coefficients"]),
+                            re_tables=by_prefix("best/re_tables/"),
+                            mf_rows=by_prefix("best/mf_rows/"),
+                            mf_cols=by_prefix("best/mf_cols/"),
+                            extra_fe=by_prefix("best/extra_fe/"),
+                        )
+                    best_metric = float(ckpt.meta.get("best_metric", float("nan")))
+                    # journaled restore evidence (resilience/checkpoint_restores)
+                    from photon_ml_tpu.telemetry import resilience_counters
+
+                    resilience_counters.record_checkpoint_restore()
+                    start_sweep = min(int(ckpt.step), num_iterations)
+                    prior_losses = [float(x) for x in ckpt.meta.get("losses", [])][:start_sweep]
+                    history = [
+                        h for h in ckpt.meta.get("metric_history", [])
+                        if int(h.get("iteration", 0)) < start_sweep
+                    ]
+
+        n_train = dataset.num_samples
+        n_val = validation_dataset.num_samples if validation_dataset is not None else 0
+        with span("train/pad"):
+            if mesh is not None:
+                from photon_ml_tpu.data.game_data import pad_game_dataset
+
+                data_axis = int(mesh.shape["data"])
+                # buckets reference sample rows by index, which appending
+                # zero-weight rows leaves intact — pad AFTER the caller built
+                # re_datasets
+                dataset, n_train = pad_game_dataset(dataset, data_axis)
+                if validation_dataset is not None:
+                    validation_dataset, n_val = pad_game_dataset(
+                        validation_dataset, data_axis
+                    )
+
+        with span("train/prepare_inputs"):
+            data, buckets = program.prepare_inputs(
+                dataset, re_datasets, mf_datasets)
+        with span("train/init_state"):
+            if state is None:
+                state = program.init_state(dataset, re_datasets, mf_datasets)
+
+        # probe/rescue lane scheduling (algorithm/lane_scheduler.py): opt-in per
+        # RE spec via OptimizerConfig.scheduler. Multi-process runs use the
+        # collective-safe SPMD mode (rank-local compaction into a fixed
+        # [num_ranks * R] rescue-block signature, per-lane flags through tiled
+        # allgathers — collectives on every rank); single-process keeps the
+        # host mode unchanged. No more multi-process fallback.
+        schedulers = None
+        scheduled_specs = [
+            s for s in program.re_specs if s.optimizer.scheduler is not None
+        ]
+        if scheduled_specs:
+            if jax.process_count() > 1 and mesh is None:
+                logger.warning(
+                    "lane scheduler configured on %s but this multi-process run "
+                    "has no mesh — falling back to the unscheduled fused step; "
+                    "pass mesh= (the SPMD scheduler assembles rescue blocks "
+                    "over it)",
+                    [s.re_type for s in scheduled_specs],
                 )
-            def by_prefix(prefix, arrays=None):
-                arrays = ckpt.arrays if arrays is None else arrays
-                return {
-                    k[len(prefix):]: jnp.asarray(v)
-                    for k, v in arrays.items()
-                    if k.startswith(prefix) and "/" not in k[len(prefix):]
-                }
-            state = GameTrainState(
-                fe_coefficients=jnp.asarray(ckpt.arrays["fe_coefficients"]),
-                re_tables=by_prefix("re_tables/"),
-                mf_rows=by_prefix("mf_rows/"),
-                mf_cols=by_prefix("mf_cols/"),
-                extra_fe=by_prefix("extra_fe/"),
-            )
-            expected = {
-                "re_tables": {s.re_type for s in program.re_specs},
-                "mf_rows": {m.name for m in program.mf_specs},
-                "mf_cols": {m.name for m in program.mf_specs},
-                "extra_fe": {s.feature_shard_id for s in program.extra_fes},
-            }
-            found = {
-                "re_tables": set(state.re_tables),
-                "mf_rows": set(state.mf_rows),
-                "mf_cols": set(state.mf_cols),
-                "extra_fe": set(state.extra_fe),
-            }
-            if expected != found:
-                raise ValueError(
-                    f"checkpoint at {checkpointer.directory} is incompatible "
-                    f"with the program's coordinate specs: checkpoint has "
-                    f"{found}, program expects {expected}. Pass resume=False "
-                    "or use a fresh checkpoint directory."
-                )
-            if "best/fe_coefficients" in ckpt.arrays:
-                best_state = GameTrainState(
-                    fe_coefficients=jnp.asarray(ckpt.arrays["best/fe_coefficients"]),
-                    re_tables=by_prefix("best/re_tables/"),
-                    mf_rows=by_prefix("best/mf_rows/"),
-                    mf_cols=by_prefix("best/mf_cols/"),
-                    extra_fe=by_prefix("best/extra_fe/"),
-                )
-            best_metric = float(ckpt.meta.get("best_metric", float("nan")))
-            # journaled restore evidence (resilience/checkpoint_restores)
-            from photon_ml_tpu.telemetry import resilience_counters
-
-            resilience_counters.record_checkpoint_restore()
-            start_sweep = min(int(ckpt.step), num_iterations)
-            prior_losses = [float(x) for x in ckpt.meta.get("losses", [])][:start_sweep]
-            history = [
-                h for h in ckpt.meta.get("metric_history", [])
-                if int(h.get("iteration", 0)) < start_sweep
-            ]
-
-    n_train = dataset.num_samples
-    n_val = validation_dataset.num_samples if validation_dataset is not None else 0
-    if mesh is not None:
-        from photon_ml_tpu.data.game_data import pad_game_dataset
-
-        data_axis = int(mesh.shape["data"])
-        # buckets reference sample rows by index, which appending zero-weight
-        # rows leaves intact — pad AFTER the caller built re_datasets
-        dataset, n_train = pad_game_dataset(dataset, data_axis)
-        if validation_dataset is not None:
-            validation_dataset, n_val = pad_game_dataset(
-                validation_dataset, data_axis
-            )
-
-    data, buckets = program.prepare_inputs(dataset, re_datasets, mf_datasets)
-    if state is None:
-        state = program.init_state(dataset, re_datasets, mf_datasets)
-
-    # probe/rescue lane scheduling (algorithm/lane_scheduler.py): opt-in per
-    # RE spec via OptimizerConfig.scheduler. Multi-process runs use the
-    # collective-safe SPMD mode (rank-local compaction into a fixed
-    # [num_ranks * R] rescue-block signature, per-lane flags through tiled
-    # allgathers — collectives on every rank); single-process keeps the
-    # host mode unchanged. No more multi-process fallback.
-    schedulers = None
-    scheduled_specs = [
-        s for s in program.re_specs if s.optimizer.scheduler is not None
-    ]
-    if scheduled_specs:
-        if jax.process_count() > 1 and mesh is None:
-            logger.warning(
-                "lane scheduler configured on %s but this multi-process run "
-                "has no mesh — falling back to the unscheduled fused step; "
-                "pass mesh= (the SPMD scheduler assembles rescue blocks "
-                "over it)",
-                [s.re_type for s in scheduled_specs],
-            )
-        else:
-            from photon_ml_tpu.algorithm.lane_scheduler import make_schedulers
-
-            schedulers = make_schedulers(scheduled_specs, mesh=mesh)
-
-    # per-sweep FE down-sampling multipliers (stable-id splitmix64, identical
-    # to the CD path's FixedEffectCoordinate seed rotation); keyed per FE
-    # coordinate ("" = primary)
-    samplers: dict[str, object] = {}
-    from photon_ml_tpu.sampling import down_sampler_for_task
-
-    for key, fe_spec in [("", program.fe)] + [
-        (s.feature_shard_id, s) for s in program.extra_fes
-    ]:
-        if fe_spec.down_sampling_rate < 1.0:
-            samplers[key] = down_sampler_for_task(
-                program.task, fe_spec.down_sampling_rate
-            )
-    if samplers:
-        samp_labels = dataset.host_array("labels")
-        samp_weights = dataset.host_array("weights")
-        samp_uids = np.asarray(dataset.unique_ids)
-        samp_dtype = np.asarray(samp_weights).dtype
-
-    def sweep_multiplier(sampler, sweep: int):
-        new_w = sampler.down_sample_weights(
-            samp_labels, samp_weights, samp_uids,
-            seed=down_sampling_seed + sweep,
-        )
-        mult = np.where(
-            samp_weights > 0, new_w / np.where(samp_weights > 0, samp_weights, 1.0), 0.0
-        ).astype(samp_dtype)
-        if mesh is not None:
-            put = put_fn if put_fn is not None else jax.device_put
-            return put(jnp.asarray(mult), NamedSharding(mesh, P("data")))
-        return jnp.asarray(mult)
-
-    val_data = None
-    evaluators = list(validation_evaluators)
-    if validation_dataset is not None and evaluators and validation_eval_data is not None:
-        val_data = program.prepare_scoring_inputs(
-            validation_dataset, re_datasets
-        )
-
-    # true entity counts, to slice off any mesh-padding rows on the way out
-    table_sizes = {
-        "re_tables": {s.re_type: re_datasets[s.re_type].num_entities
-                      for s in program.re_specs},
-        "mf_rows": {m.name: (mf_datasets or {})[m.name].num_row_entities
-                    for m in program.mf_specs},
-        "mf_cols": {m.name: (mf_datasets or {})[m.name].num_col_entities
-                    for m in program.mf_specs},
-    }
-
-    def unpadded(state_: GameTrainState) -> GameTrainState:
-        def trim(tables, sizes):
-            return {k: v[: sizes[k]] for k, v in tables.items()}
-        return GameTrainState(
-            fe_coefficients=state_.fe_coefficients,
-            re_tables=trim(state_.re_tables, table_sizes["re_tables"]),
-            mf_rows=trim(state_.mf_rows, table_sizes["mf_rows"]),
-            mf_cols=trim(state_.mf_cols, table_sizes["mf_cols"]),
-            extra_fe=dict(state_.extra_fe),
-        )
-    if mesh is not None:
-        if put_fn is None:
-            from photon_ml_tpu.parallel.multihost import default_put
-
-            put_fn = default_put()
-        data, buckets, state = program.shard_inputs(
-            mesh, data, buckets, state, fe_feature_sharded=fe_feature_sharded,
-            put_fn=put_fn,
-        )
-        _record_shard_spread("sample_arrays", data)
-        _record_shard_spread("entity_arrays", buckets)
-        if val_data is not None:
-            val_data = program.shard_scoring_inputs(
-                mesh, val_data, fe_feature_sharded=fe_feature_sharded,
-                put_fn=put_fn,
-            )
-
-    if val_data is not None and mesh is not None:
-        # device twins of the evaluators (evaluation/sharded.py): consts
-        # (labels/weights/query codes) are padded to the mesh length and
-        # placed sharded over "data" alongside the scores they reduce with.
-        # Prepared AFTER put_fn resolution so multi-process runs place
-        # through global_put like every other sharded input. mesh=None runs
-        # keep the exact host evaluators — there is no giant-n funnel to
-        # avoid, and the device AUC is a histogram approximation.
-        from photon_ml_tpu.evaluation.sharded import (
-            mesh_data_placer,
-            prepare_device_evaluators,
-        )
-
-        device_evals = prepare_device_evaluators(
-            evaluators, validation_eval_data,
-            n_pad=validation_dataset.num_samples,
-            place=mesh_data_placer(mesh, put_fn),
-        )
-    else:
-        device_evals = [None] * len(evaluators)
-
-    def to_host(v):
-        """Host copy of a (possibly multi-process sharded) array. The
-        allgather is a COLLECTIVE — every process must call it, even those
-        that discard the result (rank-0-only writes)."""
-        if jax.process_count() > 1:
-            from jax.experimental import multihost_utils
-
-            return np.asarray(multihost_utils.process_allgather(v, tiled=True))
-        return jax.device_get(v)
-
-    def state_arrays(state_: GameTrainState, prefix: str = "") -> dict:
-        clean = unpadded(state_)
-        arrays = {prefix + "fe_coefficients": to_host(clean.fe_coefficients)}
-        for sub, tables in (
-            ("re_tables/", clean.re_tables),
-            ("mf_rows/", clean.mf_rows),
-            ("mf_cols/", clean.mf_cols),
-            ("extra_fe/", clean.extra_fe),
-        ):
-            for k, v in tables.items():
-                arrays[prefix + sub + k] = to_host(v)
-        return arrays
-
-    losses = list(prior_losses)
-    for sweep in range(start_sweep, num_iterations):
-        for key, sampler in samplers.items():
-            mult = sweep_multiplier(sampler, sweep)
-            if key == "":
-                data["fe_weight_multiplier"] = mult
             else:
-                data.setdefault("extra_fe_weight_multipliers", {})[key] = mult
-        if schedulers is not None:
-            state, loss = program.step_scheduled(
-                data, buckets, state, schedulers=schedulers,
-                final_sweep=(sweep + 1 == num_iterations),
-            )
-        else:
-            state, loss = program.step(data, buckets, state)
-        losses.append(float(loss))
-        if check_finite and not np.isfinite(losses[-1]):
-            # raise BEFORE the checkpoint save below would overwrite the
-            # last finite state with NaNs (CD-path DivergenceError contract,
-            # coordinate_descent.py)
-            from photon_ml_tpu.io.checkpoint import DivergenceError
+                from photon_ml_tpu.algorithm.lane_scheduler import make_schedulers
 
-            raise DivergenceError(
-                f"fused training step produced non-finite loss "
-                f"{losses[-1]} at sweep {sweep}"
-                + (
-                    f"; last good checkpoint: step "
-                    f"{checkpointer.latest_step()} in {checkpointer.directory}"
-                    if checkpointer is not None else ""
+                schedulers = make_schedulers(scheduled_specs, mesh=mesh)
+
+        # per-sweep FE down-sampling multipliers (stable-id splitmix64, identical
+        # to the CD path's FixedEffectCoordinate seed rotation); keyed per FE
+        # coordinate ("" = primary)
+        samplers: dict[str, object] = {}
+        from photon_ml_tpu.sampling import down_sampler_for_task
+
+        for key, fe_spec in [("", program.fe)] + [
+            (s.feature_shard_id, s) for s in program.extra_fes
+        ]:
+            if fe_spec.down_sampling_rate < 1.0:
+                samplers[key] = down_sampler_for_task(
+                    program.task, fe_spec.down_sampling_rate
                 )
+        if samplers:
+            samp_labels = dataset.host_array("labels")
+            samp_weights = dataset.host_array("weights")
+            samp_uids = np.asarray(dataset.unique_ids)
+            samp_dtype = np.asarray(samp_weights).dtype
+
+        def sweep_multiplier(sampler, sweep: int):
+            new_w = sampler.down_sample_weights(
+                samp_labels, samp_weights, samp_uids,
+                seed=down_sampling_seed + sweep,
+            )
+            mult = np.where(
+                samp_weights > 0, new_w / np.where(samp_weights > 0, samp_weights, 1.0), 0.0
+            ).astype(samp_dtype)
+            if mesh is not None:
+                put = put_fn if put_fn is not None else jax.device_put
+                return put(jnp.asarray(mult), NamedSharding(mesh, P("data")))
+            return jnp.asarray(mult)
+
+        val_data = None
+        evaluators = list(validation_evaluators)
+        if validation_dataset is not None and evaluators and validation_eval_data is not None:
+            with span("train/prepare_validation"):
+                val_data = program.prepare_scoring_inputs(
+                    validation_dataset, re_datasets
+                )
+
+        # true entity counts, to slice off any mesh-padding rows on the way out
+        table_sizes = {
+            "re_tables": {s.re_type: re_datasets[s.re_type].num_entities
+                          for s in program.re_specs},
+            "mf_rows": {m.name: (mf_datasets or {})[m.name].num_row_entities
+                        for m in program.mf_specs},
+            "mf_cols": {m.name: (mf_datasets or {})[m.name].num_col_entities
+                        for m in program.mf_specs},
+        }
+
+        def unpadded(state_: GameTrainState) -> GameTrainState:
+            def trim(tables, sizes):
+                return {k: v[: sizes[k]] for k, v in tables.items()}
+            return GameTrainState(
+                fe_coefficients=state_.fe_coefficients,
+                re_tables=trim(state_.re_tables, table_sizes["re_tables"]),
+                mf_rows=trim(state_.mf_rows, table_sizes["mf_rows"]),
+                mf_cols=trim(state_.mf_cols, table_sizes["mf_cols"]),
+                extra_fe=dict(state_.extra_fe),
+            )
+        if mesh is not None:
+            if put_fn is None:
+                from photon_ml_tpu.parallel.multihost import default_put
+
+                put_fn = default_put()
+            with span("train/shard_inputs"):
+                data, buckets, state = program.shard_inputs(
+                    mesh, data, buckets, state,
+                    fe_feature_sharded=fe_feature_sharded, put_fn=put_fn,
+                )
+            _record_shard_spread("sample_arrays", data)
+            _record_shard_spread("entity_arrays", buckets)
+            _record_placed_bytes(data, buckets, state)
+            if val_data is not None:
+                with span("train/shard_validation"):
+                    val_data = program.shard_scoring_inputs(
+                        mesh, val_data, fe_feature_sharded=fe_feature_sharded,
+                        put_fn=put_fn,
+                    )
+
+        if val_data is not None and mesh is not None:
+            # device twins of the evaluators (evaluation/sharded.py): consts
+            # (labels/weights/query codes) are padded to the mesh length and
+            # placed sharded over "data" alongside the scores they reduce with.
+            # Prepared AFTER put_fn resolution so multi-process runs place
+            # through global_put like every other sharded input. mesh=None runs
+            # keep the exact host evaluators — there is no giant-n funnel to
+            # avoid, and the device AUC is a histogram approximation.
+            from photon_ml_tpu.evaluation.sharded import (
+                mesh_data_placer,
+                prepare_device_evaluators,
             )
 
-        metrics: dict[str, float] = {}
-        if training_evaluator is not None and training_eval_data is not None:
-            train_scores = _host_scores(program.score(data, state), n_train)
-            metrics[f"train:{training_evaluator.name}"] = float(
-                training_evaluator.evaluate(train_scores, training_eval_data)
-            )
-        if val_data is not None:
-            # device-side evaluation (evaluation/sharded.py): on a mesh,
-            # metrics reduce ON it from the still-sharded score vector;
-            # only scalars cross to the host — the giant-n validation pass
-            # never funnels [n] rows through one core (the reference's
-            # executor-side Evaluator/MultiEvaluator, Evaluator.scala:39-49).
-            # Evaluators without a device form (custom types), and every
-            # evaluator on mesh=None runs, take the single host gather.
-            from photon_ml_tpu.evaluation.sharded import evaluate_prepared
+            with span("train/device_evaluators"):
+                device_evals = prepare_device_evaluators(
+                    evaluators, validation_eval_data,
+                    n_pad=validation_dataset.num_samples,
+                    place=mesh_data_placer(mesh, put_fn),
+                )
+        else:
+            device_evals = [None] * len(evaluators)
 
-            val_scores = program.score(val_data, state)
-            values = evaluate_prepared(
-                evaluators, device_evals, val_scores, validation_eval_data,
-                lambda: _host_scores(val_scores, n_val),
-            )
-            for i, (ev, v) in enumerate(zip(evaluators, values)):
-                metrics[f"validate:{ev.name}"] = v
-                if i == 0 and (
-                    best_state is None or ev.better_than(v, best_metric)
-                ):
-                    best_state, best_metric = state, v
-        if metrics:
-            history.append({"iteration": sweep, "coordinate": "fused_sweep",
-                            **metrics})
+        def to_host(v):
+            """Host copy of a (possibly multi-process sharded) array. The
+            allgather is a COLLECTIVE — every process must call it, even those
+            that discard the result (rank-0-only writes)."""
+            if jax.process_count() > 1:
+                from jax.experimental import multihost_utils
 
-        if checkpointer is not None and (
-            (sweep + 1) % max(1, checkpoint_every) == 0 or sweep + 1 == num_iterations
-        ):
+                return np.asarray(multihost_utils.process_allgather(v, tiled=True))
+            return jax.device_get(v)
+
+        def state_arrays(state_: GameTrainState, prefix: str = "") -> dict:
+            clean = unpadded(state_)
+            arrays = {prefix + "fe_coefficients": to_host(clean.fe_coefficients)}
+            for sub, tables in (
+                ("re_tables/", clean.re_tables),
+                ("mf_rows/", clean.mf_rows),
+                ("mf_cols/", clean.mf_cols),
+                ("extra_fe/", clean.extra_fe),
+            ):
+                for k, v in tables.items():
+                    arrays[prefix + sub + k] = to_host(v)
+            return arrays
+
+        def commit():
             # every process participates in the gathers (collectives); the
             # commit helper gates the write to process 0 (the shared
             # checkpoint directory convention; lint check 10)
@@ -2193,41 +2227,111 @@ def train_distributed(
                  "best_metric": best_metric},
             )
 
-        if on_sweep is not None:
-            on_sweep(sweep + 1, num_iterations,
-                     losses[-1] if losses else None)
+        losses = list(prior_losses)
+        for sweep in range(start_sweep, num_iterations):
+            # the sweep's number as on_sweep reports it (1-based)
+            with span("train/sweep", sweep=sweep + 1):
+                if samplers:
+                    with span("train/down_sample"):
+                        for key, sampler in samplers.items():
+                            mult = sweep_multiplier(sampler, sweep)
+                            if key == "":
+                                data["fe_weight_multiplier"] = mult
+                            else:
+                                data.setdefault(
+                                    "extra_fe_weight_multipliers", {}
+                                )[key] = mult
+                state, loss = _step_and_wait(
+                    program, data, buckets, state, sweep=sweep,
+                    num_iterations=num_iterations, schedulers=schedulers,
+                    rows=n_train, check_finite=check_finite,
+                    checkpointer=checkpointer, what="fused",
+                )
+                losses.append(loss)
 
-    def result_state(state_: GameTrainState) -> GameTrainState:
-        clean = unpadded(state_)
-        if jax.process_count() > 1:
-            # downstream (model conversion, Avro persistence) materializes
-            # host arrays; a multi-process sharded state is not addressable,
-            # so hand back fully-gathered host-backed arrays
-            clean = GameTrainState(
-                fe_coefficients=jnp.asarray(to_host(clean.fe_coefficients)),
-                re_tables={k: jnp.asarray(to_host(v))
-                           for k, v in clean.re_tables.items()},
-                mf_rows={k: jnp.asarray(to_host(v))
-                         for k, v in clean.mf_rows.items()},
-                mf_cols={k: jnp.asarray(to_host(v))
-                         for k, v in clean.mf_cols.items()},
-                extra_fe={k: jnp.asarray(to_host(v))
-                          for k, v in clean.extra_fe.items()},
+                metrics: dict[str, float] = {}
+                if training_evaluator is not None and training_eval_data is not None:
+                    with span("train/train_metric"):
+                        train_scores = _host_scores(
+                            program.score(data, state), n_train)
+                        metrics[f"train:{training_evaluator.name}"] = float(
+                            training_evaluator.evaluate(
+                                train_scores, training_eval_data)
+                        )
+                if val_data is not None:
+                    # device-side evaluation (evaluation/sharded.py): on a
+                    # mesh, metrics reduce ON it from the still-sharded score
+                    # vector; only scalars cross to the host — the giant-n
+                    # validation pass never funnels [n] rows through one core
+                    # (the reference's executor-side Evaluator/MultiEvaluator,
+                    # Evaluator.scala:39-49). Evaluators without a device form
+                    # (custom types), and every evaluator on mesh=None runs,
+                    # take the single host gather.
+                    from photon_ml_tpu.evaluation.sharded import (
+                        evaluate_prepared,
+                    )
+
+                    with span("train/validate"):
+                        with span("train/validate/score"):
+                            val_scores = program.score(val_data, state)
+                        # blocks on the scalars: the host waiting for the
+                        # device, like train/loss_wait
+                        with span("train/validate/evaluate"):
+                            values = evaluate_prepared(
+                                evaluators, device_evals, val_scores,
+                                validation_eval_data,
+                                lambda: _host_scores(val_scores, n_val),
+                            )
+                    for i, (ev, v) in enumerate(zip(evaluators, values)):
+                        metrics[f"validate:{ev.name}"] = v
+                        if i == 0 and (
+                            best_state is None or ev.better_than(v, best_metric)
+                        ):
+                            best_state, best_metric = state, v
+                if metrics:
+                    history.append({"iteration": sweep,
+                                    "coordinate": "fused_sweep", **metrics})
+
+                _checkpoint_if_due(checkpointer, checkpoint_every, sweep,
+                                   num_iterations, commit)
+
+                if on_sweep is not None:
+                    # time in the caller's observer is not the program's
+                    with span("train/on_sweep"):
+                        on_sweep(sweep + 1, num_iterations, loss)
+
+        def result_state(state_: GameTrainState) -> GameTrainState:
+            clean = unpadded(state_)
+            if jax.process_count() > 1:
+                # downstream (model conversion, Avro persistence) materializes
+                # host arrays; a multi-process sharded state is not addressable,
+                # so hand back fully-gathered host-backed arrays
+                clean = GameTrainState(
+                    fe_coefficients=jnp.asarray(to_host(clean.fe_coefficients)),
+                    re_tables={k: jnp.asarray(to_host(v))
+                               for k, v in clean.re_tables.items()},
+                    mf_rows={k: jnp.asarray(to_host(v))
+                             for k, v in clean.mf_rows.items()},
+                    mf_cols={k: jnp.asarray(to_host(v))
+                             for k, v in clean.mf_cols.items()},
+                    extra_fe={k: jnp.asarray(to_host(v))
+                              for k, v in clean.extra_fe.items()},
+                )
+            return clean
+
+        with span("train/result_state"):
+            return DistributedTrainResult(
+                state=result_state(state),
+                losses=losses,
+                # best == final collapses to None ("treat final as best") so
+                # callers never convert/variance-compute the same state twice
+                best_state=(
+                    None if best_state is None or best_state is state
+                    else result_state(best_state)
+                ),
+                best_metric=best_metric,
+                metric_history=history,
             )
-        return clean
-
-    return DistributedTrainResult(
-        state=result_state(state),
-        losses=losses,
-        # best == final collapses to None ("treat final as best") so callers
-        # never convert/variance-compute the same state twice
-        best_state=(
-            None if best_state is None or best_state is state
-            else result_state(best_state)
-        ),
-        best_metric=best_metric,
-        metric_history=history,
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -2580,139 +2684,117 @@ def train_partitioned(
     step fails fast instead of silently resolving to a different one; 0
     means "restart from scratch" (the rollback found no checkpoint).
     None (default) keeps the newest-intact-step behavior."""
-    fingerprint = None
-    start_sweep = 0
-    prior_losses: list[float] = []
-    if resume_step == 0:
-        resume = False
-    if checkpointer is not None:
-        freezing = sorted(
-            k for k, sch in (schedulers or {}).items()
-            if getattr(getattr(sch, "config", None), "freezes", False)
-        )
-        if freezing:
-            # cross-sweep active sets (frozen_rows + carried values) are
-            # scheduler-internal state the checkpoint does not capture: a
-            # restart would re-probe every lane and diverge from the
-            # uninterrupted run, breaking the resume-exactness contract
-            raise ValueError(
-                "partitioned checkpointing cannot yet resume cross-sweep "
-                f"active-set state (freeze tolerances set on {freezing}); "
-                "drop scheduler.freeze.tolerance/scheduler.freeze.gradient "
-                "(probe/rescue scheduling resumes exactly) or disable "
-                "checkpointing for this run"
+    with _fit_span(num_iterations, mesh):
+        fingerprint = None
+        start_sweep = 0
+        prior_losses: list[float] = []
+        if resume_step == 0:
+            resume = False
+        if checkpointer is not None:
+            freezing = sorted(
+                k for k, sch in (schedulers or {}).items()
+                if getattr(getattr(sch, "config", None), "freezes", False)
             )
-        fingerprint = _partition_fingerprint(program, parts, num_ranks)
-        if resume and state is None:
-            ckpt = checkpointer.restore(
-                step=resume_step if resume_step else None
-            )
-            if ckpt is not None:
-                from photon_ml_tpu.io.checkpoint import fingerprint_mismatch
-
-                mismatch = fingerprint_mismatch(
-                    ckpt.meta.get("partition_fingerprint"), fingerprint
+            if freezing:
+                # cross-sweep active sets (frozen_rows + carried values) are
+                # scheduler-internal state the checkpoint does not capture: a
+                # restart would re-probe every lane and diverge from the
+                # uninterrupted run, breaking the resume-exactness contract
+                raise ValueError(
+                    "partitioned checkpointing cannot yet resume cross-sweep "
+                    f"active-set state (freeze tolerances set on {freezing}); "
+                    "drop scheduler.freeze.tolerance/scheduler.freeze.gradient "
+                    "(probe/rescue scheduling resumes exactly) or disable "
+                    "checkpointing for this run"
                 )
-                if mismatch is not None:
-                    raise ValueError(
-                        f"partitioned checkpoint at {checkpointer.directory}"
-                        f" was written under a different partition "
-                        f"fingerprint ({mismatch}) — a restored table row "
-                        "would map onto a different rank block / sparse "
-                        "layout; resume with the original rank count and "
-                        "layout agreement, or use a fresh checkpoint "
-                        "directory"
+            fingerprint = _partition_fingerprint(program, parts, num_ranks)
+            if resume and state is None:
+                with span("train/restore"):
+                    ckpt = checkpointer.restore(
+                        step=resume_step if resume_step else None
                     )
-                if int(ckpt.step) > num_iterations:
-                    # never silently relabel an over-trained state as an
-                    # N-sweep result: a shrunken num_iterations must fail
-                    # fast, not return the sweep-{step} model
-                    raise ValueError(
-                        f"partitioned checkpoint at {checkpointer.directory}"
-                        f" is at sweep {int(ckpt.step)}, beyond this run's "
-                        f"num_iterations={num_iterations}; raise "
-                        "num_iterations to continue training, or use a "
-                        "fresh checkpoint directory"
+                if ckpt is not None:
+                    from photon_ml_tpu.io.checkpoint import fingerprint_mismatch
+
+                    mismatch = fingerprint_mismatch(
+                        ckpt.meta.get("partition_fingerprint"), fingerprint
+                    )
+                    if mismatch is not None:
+                        raise ValueError(
+                            f"partitioned checkpoint at {checkpointer.directory}"
+                            f" was written under a different partition "
+                            f"fingerprint ({mismatch}) — a restored table row "
+                            "would map onto a different rank block / sparse "
+                            "layout; resume with the original rank count and "
+                            "layout agreement, or use a fresh checkpoint "
+                            "directory"
+                        )
+                    if int(ckpt.step) > num_iterations:
+                        # never silently relabel an over-trained state as an
+                        # N-sweep result: a shrunken num_iterations must fail
+                        # fast, not return the sweep-{step} model
+                        raise ValueError(
+                            f"partitioned checkpoint at {checkpointer.directory}"
+                            f" is at sweep {int(ckpt.step)}, beyond this run's "
+                            f"num_iterations={num_iterations}; raise "
+                            "num_iterations to continue training, or use a "
+                            "fresh checkpoint directory"
+                        )
+
+                    def by_prefix(prefix):
+                        return {
+                            k[len(prefix):]: np.asarray(v)
+                            for k, v in ckpt.arrays.items()
+                            if k.startswith(prefix) and "/" not in k[len(prefix):]
+                        }
+
+                    # host arrays; prepare_partitioned_inputs re-places them
+                    # over the mesh exactly like a warm start (tables were
+                    # saved UNSLICED, so shapes — and the jit signature —
+                    # match the interrupted run's)
+                    state = GameTrainState(
+                        fe_coefficients=np.asarray(ckpt.arrays["fe_coefficients"]),
+                        re_tables=by_prefix("re_tables/"),
+                        mf_rows={},
+                        mf_cols={},
+                        extra_fe=by_prefix("extra_fe/"),
+                    )
+                    start_sweep = int(ckpt.step)
+                    prior_losses = [
+                        float(x) for x in ckpt.meta.get("losses", [])
+                    ][:start_sweep]
+                    from photon_ml_tpu.telemetry import resilience_counters
+
+                    resilience_counters.record_checkpoint_restore()
+                    # resumed sweeps are the fused path's epochs-not-redone
+                    resilience_counters.record_epochs_resumed(start_sweep)
+                    logger.info(
+                        "resuming partitioned training from checkpoint sweep "
+                        "%d/%d", start_sweep, num_iterations,
                     )
 
-                def by_prefix(prefix):
-                    return {
-                        k[len(prefix):]: np.asarray(v)
-                        for k, v in ckpt.arrays.items()
-                        if k.startswith(prefix) and "/" not in k[len(prefix):]
-                    }
-
-                # host arrays; prepare_partitioned_inputs re-places them
-                # over the mesh exactly like a warm start (tables were
-                # saved UNSLICED, so shapes — and the jit signature —
-                # match the interrupted run's)
-                state = GameTrainState(
-                    fe_coefficients=np.asarray(ckpt.arrays["fe_coefficients"]),
-                    re_tables=by_prefix("re_tables/"),
-                    mf_rows={},
-                    mf_cols={},
-                    extra_fe=by_prefix("extra_fe/"),
-                )
-                start_sweep = int(ckpt.step)
-                prior_losses = [
-                    float(x) for x in ckpt.meta.get("losses", [])
-                ][:start_sweep]
-                from photon_ml_tpu.telemetry import resilience_counters
-
-                resilience_counters.record_checkpoint_restore()
-                # resumed sweeps are the fused path's epochs-not-redone
-                resilience_counters.record_epochs_resumed(start_sweep)
-                logger.info(
-                    "resuming partitioned training from checkpoint sweep "
-                    "%d/%d", start_sweep, num_iterations,
-                )
-
-    data, buckets, st = prepare_partitioned_inputs(
-        program, parts, mesh, num_ranks,
-        fe_feature_sharded=fe_feature_sharded, state=state,
-    )
-    r0 = sorted(parts)[0]
-    table_sizes = {
-        s.re_type: parts[r0][1][s.re_type].num_entities
-        for s in program.re_specs
-    }
-
-    def to_host(v):
-        """Model-sized arrays only (coefficients/tables) — every process
-        joins the gather (collective), unlike the O(n) score funnel the
-        partitioned path exists to remove."""
-        if jax.process_count() > 1:
-            from jax.experimental import multihost_utils
-
-            return np.asarray(multihost_utils.process_allgather(v, tiled=True))
-        return jax.device_get(v)
-
-    losses: list[float] = list(prior_losses)
-    for sweep in range(start_sweep, num_iterations):
-        if schedulers:
-            st, loss = program.step_scheduled(
-                data, buckets, st, schedulers=schedulers,
-                final_sweep=(sweep + 1 == num_iterations),
+        with span("train/prepare_inputs"):
+            data, buckets, st = prepare_partitioned_inputs(
+                program, parts, mesh, num_ranks,
+                fe_feature_sharded=fe_feature_sharded, state=state,
             )
-        else:
-            st, loss = program.step(data, buckets, st)
-        losses.append(float(loss))
-        if check_finite and not np.isfinite(losses[-1]):
-            from photon_ml_tpu.io.checkpoint import DivergenceError
+        r0 = sorted(parts)[0]
+        table_sizes = {
+            s.re_type: parts[r0][1][s.re_type].num_entities
+            for s in program.re_specs
+        }
 
-            raise DivergenceError(
-                f"partitioned training step produced non-finite loss "
-                f"{losses[-1]} at sweep {sweep}"
-                + (
-                    f"; last good checkpoint: step "
-                    f"{checkpointer.latest_step()} in {checkpointer.directory}"
-                    if checkpointer is not None else ""
-                )
-            )
-        if checkpointer is not None and (
-            (sweep + 1) % max(1, checkpoint_every) == 0
-            or sweep + 1 == num_iterations
-        ):
+        def to_host(v):
+            """Model-sized arrays only (coefficients/tables) — every process
+            joins the gather (collective), unlike the O(n) score funnel the
+            partitioned path exists to remove."""
+            if jax.process_count() > 1:
+                from jax.experimental import multihost_utils
+
+                return np.asarray(multihost_utils.process_allgather(v, tiled=True))
+            return jax.device_get(v)
+
+        def commit():
             # every rank gathers (collectives) and calls the commit helper
             # (its barriers are exchange calls every rank must make); only
             # rank 0 writes the shared directory
@@ -2731,14 +2813,31 @@ def train_partitioned(
                 exchange=exchange,
             )
 
-    final = GameTrainState(
-        fe_coefficients=jnp.asarray(to_host(st.fe_coefficients)),
-        re_tables={
-            k: jnp.asarray(to_host(v))[: table_sizes[k]]
-            for k, v in st.re_tables.items()
-        },
-        mf_rows={},
-        mf_cols={},
-        extra_fe={k: jnp.asarray(to_host(v)) for k, v in st.extra_fe.items()},
-    )
-    return DistributedTrainResult(state=final, losses=losses)
+        # the assembled sample axis: every rank's block, padding rows included
+        rows = int(data["labels"].shape[0])
+        losses: list[float] = list(prior_losses)
+        for sweep in range(start_sweep, num_iterations):
+            with span("train/sweep", sweep=sweep + 1):
+                st, loss = _step_and_wait(
+                    program, data, buckets, st, sweep=sweep,
+                    num_iterations=num_iterations, schedulers=schedulers,
+                    rows=rows, check_finite=check_finite,
+                    checkpointer=checkpointer, what="partitioned",
+                )
+                losses.append(loss)
+                _checkpoint_if_due(checkpointer, checkpoint_every, sweep,
+                                   num_iterations, commit)
+
+        with span("train/result_state"):
+            final = GameTrainState(
+                fe_coefficients=jnp.asarray(to_host(st.fe_coefficients)),
+                re_tables={
+                    k: jnp.asarray(to_host(v))[: table_sizes[k]]
+                    for k, v in st.re_tables.items()
+                },
+                mf_rows={},
+                mf_cols={},
+                extra_fe={k: jnp.asarray(to_host(v))
+                          for k, v in st.extra_fe.items()},
+            )
+        return DistributedTrainResult(state=final, losses=losses)

@@ -13,7 +13,10 @@ live here as a library instead of inside ``bench.py``:
   its hot loop as a fraction of what this chip streamed in this process.
 - ``install_compile_listener`` / ``CompileMonitor``: jax.monitoring hook
   counting backend compiles (recompilation storms are a classic silent
-  perf pathology under vmap/jit churn).
+  perf pathology under vmap/jit churn) and, beside them, what a program
+  cost before it could run: seconds tracing, seconds lowering, seconds
+  loading executables from the persistent cache, and that cache's hits and
+  misses.
 - ``device_memory_stats`` / ``live_buffer_bytes`` /
   ``device_memory_report``: allocator statistics per device. The ``cpu``
   platform reports none (``memory_stats()`` is None there); on ``tpu`` a
@@ -182,28 +185,76 @@ COMPILE_COUNT_METRIC = "jax/backend_compile_count"
 COMPILE_SECONDS_METRIC = "jax/backend_compile_seconds"
 _COMPILE_COUNTER = COMPILE_COUNT_METRIC
 _COMPILE_SECONDS = COMPILE_SECONDS_METRIC
+#: histograms of seconds (``.total`` is the process's sum so far): tracing
+#: Python into jaxprs, lowering jaxprs to MLIR modules, and reading +
+#: deserialising executables from the persistent compile cache
+TRACE_SECONDS_METRIC = "jax/trace_seconds"
+LOWER_SECONDS_METRIC = "jax/lower_seconds"
+CACHE_LOAD_SECONDS_METRIC = "jax/cache_load_seconds"
+#: counters of persistent-cache look-ups that were answered / that compiled
+#: and wrote an entry
+CACHE_HITS_METRIC = "jax/cache_hits"
+CACHE_MISSES_METRIC = "jax/cache_misses"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_DURATION_EVENTS = {
+    _TRACE_EVENT: TRACE_SECONDS_METRIC,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER_SECONDS_METRIC,
+    "/jax/core/compile/backend_compile_duration": COMPILE_SECONDS_METRIC,
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        CACHE_LOAD_SECONDS_METRIC,
+}
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": CACHE_HITS_METRIC,
+    "/jax/compilation_cache/cache_misses": CACHE_MISSES_METRIC,
+}
 #: registries that already have a listener feeding them (the listener holds
 #: a strong reference, so the id() stays unique for the registry's lifetime)
 _installed_registry_ids: set[int] = set()
 
 
 def install_compile_listener(registry=None) -> None:
-    """Idempotently (per registry) install a jax.monitoring duration
-    listener that counts backend compiles into the metrics registry.
-    jax.monitoring has no targeted unregister, so each listener installs
-    once per (process, registry) and stays."""
+    """Idempotently (per registry) install the jax.monitoring listeners
+    that file JAX's compile-path events in the metrics registry: backend
+    compiles (count and seconds), trace / lower / cache-load seconds, cache
+    hits and misses. jax.monitoring has no targeted unregister, so each
+    listener installs once per (process, registry) and stays.
+
+    JAX reports a trace's seconds when the trace ends, and a function
+    traced INSIDE another trace (a nested jit, every ``jnp`` primitive
+    wrapper) reports its own event too, inside the outer one's time: only
+    events that arrive with no trace in flight (the outermost) are added,
+    so ``jax/trace_seconds`` counts no second twice. Lowering, compiling
+    and cache look-ups happen once per dispatched program and do not nest."""
     reg = registry or default_registry()
     if id(reg) in _installed_registry_ids:
         return
+    import jax.core
     import jax.monitoring
 
     def _on_duration(name: str, secs: float, **kw) -> None:
-        if "backend_compile" in name:
+        metric = _DURATION_EVENTS.get(name)
+        if metric is None or (
+            name == _TRACE_EVENT and not jax.core.trace_ctx.is_top_level()
+        ):
+            return
+        reg.histogram(metric).observe(secs)
+        if metric == COMPILE_SECONDS_METRIC:
             reg.counter(_COMPILE_COUNTER).inc()
-            reg.histogram(_COMPILE_SECONDS).observe(secs)
+
+    def _on_event(name: str, **kw) -> None:
+        metric = _COUNT_EVENTS.get(name)
+        if metric is not None:
+            reg.counter(metric).inc()
 
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
     _installed_registry_ids.add(id(reg))
+    # every metric exists from here on: a reader tells "none yet" (0) from
+    # "no listener in this program" (absent)
+    for metric in _DURATION_EVENTS.values():
+        reg.histogram(metric)
+    for metric in (_COMPILE_COUNTER, *_COUNT_EVENTS.values()):
+        reg.counter(metric)
 
 
 def compile_count(registry=None) -> int:

@@ -56,6 +56,15 @@ Calls made while a jax trace is in flight bypass the ledger entirely: an
 inner jitted step invoked during an outer trace inlines into the outer
 program — it is not a separately dispatched program, and observing it
 would double-count.
+
+Every top-level call of a labelled program is also a span
+``dispatch/<label>`` on the one span seam (telemetry/tracing.py), ledger or
+no ledger: the host's side of a dispatch (signature look-up, a trace and
+compile on the first call, the enqueue) in the profiler's trace beside the
+device's work. And the first ``ledger_jit`` of a process installs the
+compile listener (probes.install_compile_listener) on the default
+registry, so a library user has trace / lower / cache-load / compile
+seconds and the cache's hits and misses without a driver.
 """
 
 from __future__ import annotations
@@ -64,6 +73,8 @@ import dataclasses
 import functools
 import logging
 import threading
+
+from photon_ml_tpu.telemetry.tracing import span
 
 logger = logging.getLogger(__name__)
 
@@ -625,18 +636,26 @@ def ledger_jit(fn=None, *, label: str, **jit_kwargs):
         return functools.partial(ledger_jit, label=label, **jit_kwargs)
     import jax
 
+    from photon_ml_tpu.telemetry import probes
+
+    probes.install_compile_listener()
     jitted = jax.jit(fn, **jit_kwargs)
     static_argnums = _as_tuple(jit_kwargs.get("static_argnums"))
     static_argnames = _as_tuple(jit_kwargs.get("static_argnames"))
+    dispatch = "dispatch/" + label
+    is_top_level = jax.core.trace_ctx.is_top_level
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        ledger = _LEDGER
-        if ledger is None or not jax.core.trace_ctx.is_top_level():
+        if not is_top_level():
             return jitted(*args, **kwargs)
-        return ledger.observed_call(
-            jitted, label, args, kwargs, static_argnums, static_argnames
-        )
+        with span(dispatch):
+            ledger = _LEDGER
+            if ledger is None:
+                return jitted(*args, **kwargs)
+            return ledger.observed_call(
+                jitted, label, args, kwargs, static_argnums, static_argnames
+            )
 
     wrapper.label = label
     wrapper.jitted = jitted

@@ -8,21 +8,34 @@ driver-local aggregates, while a composed multi-rank run here needs to know
 *where the wall-clock went* (decode vs exchange wait vs device dispatch vs
 checkpoint barrier) and *which rank* is the straggler. This module provides:
 
-- ``span(name, **attrs)`` — a context manager over ``time.perf_counter``
-  recording (name, category, start, duration, attrs) into a per-thread
-  ring buffer. Inert by default: with no tracer installed it returns a
-  shared null object (one dict build + one attribute read — no locks, no
-  allocation on the buffer side), the ``EventEmitter.has_listeners``
-  discipline. Spans OBSERVE, never gate: instrumentation wraps existing
-  calls with a timer and must never add, skip, reorder, or retry a
-  collective (the PR 3 rule — one rank retrying an exchange desyncs SPMD).
+- ``span(name, **attrs)`` — THE seam: the only way the program marks
+  time. One call feeds two sinks. (1) The profiler's trace: while a
+  ``jax.profiler`` session is active (``--profile-dir``, or a benchmark
+  that started one) the span is a ``TraceAnnotation`` named
+  ``photon:<name>`` carrying ``attrs`` as the event's stats, so it lands in
+  the xplane's ``/host:CPU`` plane beside the device's ``XLA Ops``, on ONE
+  clock, whoever started the profiler. (2) The ring: with a ``Tracer``
+  installed (``--trace-dir``) the span is also recorded as (name, category,
+  start, duration, attrs, parent) into a per-thread ring buffer over
+  ``time.perf_counter``. A per-thread stack of open spans gives every ring
+  event its ``parent`` (the enclosing span's name and start) and the
+  identifiers it inherits (``fit``, ``sweep``). Off (no tracer, no session)
+  it returns a shared null object: one global read and one call of the
+  profiler's own ``is_enabled`` test — no lock, nothing allocated that is
+  kept (cost per call of the three states: PERF.md 3). Spans OBSERVE, never
+  gate: instrumentation wraps existing calls with a timer and must never
+  add, skip, reorder, or retry a collective (the PR 3 rule — one rank
+  retrying an exchange desyncs SPMD). Attribute values reach the profiler
+  as ``key=value`` text: keep ``#``, ``,`` and ``=`` out of them.
 - Chrome-trace/Perfetto export: ``publish_trace`` writes
   ``trace-{rank:05d}.json`` (catapult event format: complete ``"X"``
   events, ``pid`` = rank, ``tid`` = thread) atomically into the trace dir
   under the multi-process rules — rank 0 mkdir, barrier, per-rank write
-  (the ``io/score_writer.py`` carve-out). On the FAILURE path the barrier
-  is deadline-bounded and a timeout falls back to an unbarriered write so
-  a crash still leaves a readable timeline.
+  (the ``io/score_writer.py`` carve-out). ``args`` carries the attrs plus
+  ``parent`` / ``parent_ts``. On the FAILURE path the barrier is
+  deadline-bounded and a timeout falls back to an unbarriered write, and
+  the spans still OPEN are written as begin (``"B"``) events, so a run that
+  dies inside ``train/shard/buckets`` shows it.
 - Straggler attribution: every exchange op (``parallel/multihost.py``)
   records its blocking wait as a span carrying ``tag`` + ``rank``;
   ``exchange_wait_tables`` aggregates per-rank per-tag wait totals and
@@ -33,9 +46,13 @@ checkpoint barrier) and *which rank* is the straggler. This module provides:
   ``gather_straggler_report`` merges the per-rank tables on every rank
   through the existing ``MetadataExchange`` at run end.
 
-Span durations are host wall-clock only — device time stays with
-``MarginalTimer`` (BASELINE.md "Trace methodology r12"): never compare
-absolute span times across runs; compare fractions within one trace.
+Which clock to read: inside a profiler session a span's ``photon:`` event
+shares the device's clock, so span time may be read against device time
+there (the device's idle stretches by program span, host time not spent
+waiting for the device: benchmark/program_trace.py). The ring's times are
+host ``perf_counter`` differences on a clock of its own — the device-free
+per-rank timeline; hold them against other ring spans, not against the
+xplane.
 """
 
 from __future__ import annotations
@@ -49,7 +66,16 @@ import threading
 import time
 from typing import Iterator, Mapping, NamedTuple
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 logger = logging.getLogger(__name__)
+
+#: a span's name in the profiler's trace is this + its name: what the
+#: benchmark's reader selects the program's spans by
+ANNOTATION_PREFIX = "photon:"
+#: attrs a span hands down to every span opened inside it (ring events and
+#: the Chrome export carry them): which fit, which sweep
+INHERITED_IDS = ("fit", "sweep")
 
 TRACE_FILE_FORMAT = "trace-{rank:05d}.json"
 
@@ -88,6 +114,8 @@ class TraceEvent(NamedTuple):
     thread_id: int
     thread_name: str
     attrs: dict | None
+    #: (name, start) of the span this one was opened inside, on its thread
+    parent: tuple | None = None
 
 
 class _Ring:
@@ -134,29 +162,29 @@ class Tracer:
         # module is a perf_counter difference)
         self.wall_t0 = time.time()
         self._local = threading.local()
-        self._threads: list[tuple[int, str, _Ring]] = []
-        self._lock = threading.Lock()  # buffer registration + export only
+        #: (lane index, thread name, ring, stack of open-span frames)
+        self._threads: list[tuple[int, str, _Ring, list]] = []
+        self._lock = threading.Lock()  # lane registration + export only
 
     # -- recording (hot path: no locks) --------------------------------------
 
-    def _buffer(self) -> _Ring:
-        buf = getattr(self._local, "buf", None)
-        if buf is None:
-            buf = _Ring(self.capacity)
-            self._local.buf = buf
+    def _lane(self) -> tuple[_Ring, list]:
+        lane = getattr(self._local, "lane", None)
+        if lane is None:
+            lane = self._local.lane = (_Ring(self.capacity), [])
             t = threading.current_thread()
             with self._lock:
                 # key by registration index, not thread ident: the OS
                 # reuses idents, and two short-lived threads must not
                 # merge into one timeline lane
-                self._threads.append((len(self._threads), t.name, buf))
-        return buf
+                self._threads.append((len(self._threads), t.name, *lane))
+        return lane
 
     def record(self, name: str, cat: str, t_start: float, dur: float,
-               attrs: dict | None) -> None:
+               attrs: dict | None, parent: tuple | None = None) -> None:
         """t_start: absolute ``perf_counter`` reading at span entry."""
-        self._buffer().append(
-            (name, cat, t_start - self._t0_perf, dur, attrs)
+        self._lane()[0].append(
+            (name, cat, t_start - self._t0_perf, dur, attrs, parent)
         )
 
     # -- reading --------------------------------------------------------------
@@ -164,13 +192,28 @@ class Tracer:
     def events(self) -> Iterator[TraceEvent]:
         with self._lock:
             threads = list(self._threads)
-        for tid, tname, ring in threads:
-            for name, cat, start, dur, attrs in ring.snapshot():
-                yield TraceEvent(name, cat, start, dur, tid, tname, attrs)
+        for tid, tname, ring, _ in threads:
+            for name, cat, start, dur, attrs, parent in ring.snapshot():
+                yield TraceEvent(name, cat, start, dur, tid, tname, attrs,
+                                 parent)
+
+    def open_spans(self) -> list[TraceEvent]:
+        """The spans entered and not yet left, outermost first per thread
+        (``dur`` = how long each has been open): what a run that died or
+        hangs was inside."""
+        now = time.perf_counter() - self._t0_perf
+        with self._lock:
+            threads = list(self._threads)
+        return [
+            TraceEvent(f.name, f.cat, f.start, now - f.start, tid, tname,
+                       f.merged_attrs(), f.parent)
+            for tid, tname, _, stack in threads
+            for f in list(stack)  # the owning thread may push/pop meanwhile
+        ]
 
     def dropped_events(self) -> int:
         with self._lock:
-            return sum(ring.dropped for _, _, ring in self._threads)
+            return sum(ring.dropped for _, _, ring, _ in self._threads)
 
     # -- Chrome-trace export ---------------------------------------------------
 
@@ -185,26 +228,32 @@ class Tracer:
         pids: set[int] = {self.rank}
         with self._lock:
             threads = list(self._threads)
-        for tid, tname, _ in threads:
+        for tid, tname, _, _ in threads:
             events.append({
                 "ph": "M", "name": "thread_name", "pid": self.rank,
                 "tid": tid, "args": {"name": tname},
             })
-        for ev in self.events():
+
+        def exported(ev: TraceEvent, ph: str) -> dict:
             pid = self.rank
             if ev.attrs and "rank" in ev.attrs:
                 pid = int(ev.attrs["rank"])
                 pids.add(pid)
-            events.append({
-                "ph": "X",
-                "name": ev.name,
-                "cat": ev.cat,
-                "ts": ev.start * 1e6,
-                "dur": ev.dur * 1e6,
-                "pid": pid,
-                "tid": ev.thread_id,
-                "args": json_safe(ev.attrs or {}),
-            })
+            args = dict(ev.attrs or {})
+            if ev.parent is not None:
+                args["parent"], args["parent_ts"] = (
+                    ev.parent[0], ev.parent[1] * 1e6)
+            out = {"ph": ph, "name": ev.name, "cat": ev.cat,
+                   "ts": ev.start * 1e6, "pid": pid, "tid": ev.thread_id,
+                   "args": json_safe(args)}
+            if ph == "X":
+                out["dur"] = ev.dur * 1e6
+            return out
+
+        events += [exported(ev, "X") for ev in self.events()]
+        # still open (the failure path, or a snapshot mid-run): begin events
+        # with no end — the viewer draws them to the end of the trace
+        events += [exported(ev, "B") for ev in self.open_spans()]
         meta = [
             {"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
              "args": {"name": f"rank {pid}"}}
@@ -246,40 +295,81 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_attrs", "_t0")
+    """A span with a tracer installed: a frame on its thread's stack of
+    open spans while it is open, a ring event when it closes, and the
+    profiler's annotation as well while a session is active."""
+
+    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "start", "parent",
+                 "ids", "_note", "_stack")
 
     def __init__(self, tracer: Tracer, name: str, cat: str, attrs: dict):
         self._tracer = tracer
-        self._name = name
-        self._cat = cat
-        self._attrs = attrs
+        self.name = name
+        self.cat = cat
+        self.attrs = attrs
+
+    def merged_attrs(self) -> dict | None:
+        """Own attrs over the identifiers inherited from enclosing spans."""
+        if not self.ids:
+            return self.attrs or None
+        return {**self.ids, **self.attrs}
 
     def __enter__(self):
+        tracer = self._tracer
+        _, stack = tracer._lane()
+        attrs = self.attrs
+        if stack:
+            outer = stack[-1]
+            self.parent = (outer.name, outer.start)
+            ids = outer.ids
+        else:
+            self.parent = None
+            ids = None
+        own = {k: attrs[k] for k in INHERITED_IDS if k in attrs}
+        self.ids = {**ids, **own} if ids and own else (own or ids)
+        self._stack = stack
+        self._note = None
+        if _Annotation.is_enabled():
+            self._note = _Annotation(ANNOTATION_PREFIX + self.name, **attrs)
+            self._note.__enter__()
         self._t0 = time.perf_counter()
+        self.start = self._t0 - tracer._t0_perf
+        stack.append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
-        attrs = self._attrs
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # left out of order (a generator's span)
+            stack.remove(self)
+        attrs = self.merged_attrs()
         if exc_type is not None:
             # the span records even when the traced call raises — an
             # ExchangeTimeout's wait leading up to the deadline is exactly
             # the straggler evidence
             attrs = dict(attrs) if attrs else {}
             attrs["error"] = exc_type.__name__
-        self._tracer.record(self._name, self._cat, self._t0, dur,
-                            attrs or None)
+        self._tracer.record(self.name, self.cat, self._t0, dur, attrs,
+                            self.parent)
         return False
 
 
 def span(name: str, *, cat: str = "span", **attrs):
-    """``with span("io/decode_chunk", chunk=3): ...`` — records a complete
-    event into the installed tracer; a shared null object when tracing is
-    off (the default)."""
+    """``with span("io/decode_chunk", chunk=3): ...`` — the program's one
+    way to mark time. With a tracer installed: a ring event (and the
+    profiler's annotation while a session is active). With only a profiler
+    session: the ``photon:<name>`` annotation itself. With neither (the
+    default): a shared null object."""
     tracer = _TRACER
-    if tracer is None:
-        return _NULL_SPAN
-    return _Span(tracer, name, cat, attrs)
+    if tracer is not None:
+        return _Span(tracer, name, cat, attrs)
+    if _Annotation.is_enabled():
+        return _Annotation(ANNOTATION_PREFIX + name, **attrs)
+    return _NULL_SPAN
 
 
 def tracing_active() -> bool:

@@ -5,11 +5,15 @@ logs the duration of the block; used pervasively by the drivers and the
 coordinate-descent loop. Here a context manager / decorator; durations feed
 the process-wide metrics registry (telemetry/registry.py histograms under
 ``timing/<name>``) so drivers can print a phase summary with distribution
-stats, and each block emits a jax.profiler StepTraceAnnotation so phases
-line up with device traces in TensorBoard. Each block also records a
-``phase/<name>`` span into the run tracer when one is installed
-(telemetry/tracing.py — inert by default), so driver phases frame the
-finer seam spans in the exported timeline.
+stats. The histogram and the log line are always on; the block's place in a
+timeline comes from the one span seam (telemetry/tracing.py ``span``): one
+event per block, in the profiler's trace while a session is active and in
+the run tracer's ring when one is installed, so driver phases frame the
+finer seam spans. A bare phase label (``"read training data"``) is filed as
+the span ``phase/<label>``; a name that already stands in a namespace
+(``pack/group_entities``) keeps it. ``begin <name>`` is logged at DEBUG
+when a block opens, so that the last line of a hung run names the open
+phase.
 """
 
 from __future__ import annotations
@@ -29,23 +33,19 @@ _TIMING_PREFIX = "timing/"
 
 
 class Timed(contextlib.AbstractContextManager):
-    """``with Timed("read training data"): ...`` — logs and records."""
+    """``with Timed("read training data"): ...`` — logs and records.
+    ``attrs`` go to the block's span."""
 
-    def __init__(self, name: str, log_level: int = logging.INFO):
+    def __init__(self, name: str, log_level: int = logging.INFO, **attrs):
         self.name = name
         self.log_level = log_level
+        self.attrs = attrs
         self.duration: float | None = None
 
     def __enter__(self):
-        self._annotation = None
-        try:
-            import jax.profiler
-
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
-            self._annotation.__enter__()
-        except Exception:  # profiler unavailable: timing still works
-            self._annotation = None
-        self._span = tracing.span("phase/" + self.name, cat="phase")
+        logger.debug("begin %s", self.name)
+        name = self.name if "/" in self.name else "phase/" + self.name
+        self._span = tracing.span(name, cat="phase", **self.attrs)
         self._span.__enter__()
         self._start = time.perf_counter()
         return self
@@ -53,8 +53,6 @@ class Timed(contextlib.AbstractContextManager):
     def __exit__(self, exc_type, exc, tb):
         self.duration = time.perf_counter() - self._start
         self._span.__exit__(exc_type, exc, tb)
-        if self._annotation is not None:
-            self._annotation.__exit__(exc_type, exc, tb)
         default_registry().histogram(_TIMING_PREFIX + self.name).observe(
             self.duration
         )

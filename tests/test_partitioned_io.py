@@ -367,11 +367,26 @@ def test_partitioned_training_matches_full_read(tmp_path):
     # entity-clustered input: no entity spans ranks
     assert int(np.max(parts[0].entity_rank_presence["userId"])) == 1
 
-    res = train_partitioned(
-        make_program(),
-        {r: (parts[r].result.dataset, re_parts[r]) for r in range(2)},
-        mesh, 2, num_iterations=2,
+    from photon_ml_tpu.telemetry.tracing import (
+        Tracer,
+        install_tracer,
+        uninstall_tracer,
     )
+
+    tracer = install_tracer(Tracer(rank=0))
+    try:
+        res = train_partitioned(
+            make_program(),
+            {r: (parts[r].result.dataset, re_parts[r]) for r in range(2)},
+            mesh, 2, num_iterations=2,
+        )
+    finally:
+        uninstall_tracer()
+    # the same loop marks the same spans as train_distributed's
+    names = [e.name for e in tracer.events() if e.name.startswith("train/")]
+    assert sorted(names) == sorted(
+        ["train/fit", "train/prepare_inputs", "train/result_state"]
+        + 2 * ["train/sweep", "train/step", "train/loss_wait"])
     np.testing.assert_allclose(res.losses, ref.losses, rtol=1e-12)
     np.testing.assert_allclose(
         np.asarray(res.state.fe_coefficients),
