@@ -53,7 +53,7 @@ from photon_ml_tpu.ops.variance import (
     resolve_variance_mode_for,
     validate_variance_mode,
 )
-from photon_ml_tpu.optim.common import LaneTrace, LaneTraces
+from photon_ml_tpu.optim.common import LaneTrace, LaneTraces, lane_trace_of
 from photon_ml_tpu.telemetry.program_ledger import ledger_jit
 from photon_ml_tpu.optim.optimizer import (
     OptimizerConfig,
@@ -721,22 +721,16 @@ def _solve_bucket_entities(
     """vmapped per-entity solves: ([e, k] solved coefficients, [e] trace).
 
     The trace carries each lane's final iteration count / convergence reason
-    / value — tiny extra outputs XLA computes anyway; consumers that only
-    want the table drop it (DCE removes the cost)."""
+    / value and its line-search work (optim/common.lane_solver_counts) —
+    tiny extra outputs XLA computes anyway; consumers that only want the
+    table drop it (DCE removes the cost)."""
 
     def solve_one(f, l, o, w, w0):
         batch = LabeledPointBatch(features=f, labels=l, offsets=o, weights=w)
-        result = solve(opt, objective.bind(batch), w0)
-        trace = LaneTrace(
-            iterations=result.iterations,
-            reason=result.reason,
-            value=result.value,
-            gradient_norm=result.gradient_norm,
-            valid=jnp.asarray(True),
-        )
-        return result.coefficients, trace
+        return solve(opt, objective.bind(batch), w0)
 
-    return jax.vmap(solve_one)(features, labels, offsets, weights, w0s)
+    result = jax.vmap(solve_one)(features, labels, offsets, weights, w0s)
+    return result.coefficients, lane_trace_of(result)
 
 
 def _mask_padding_lanes(trace: LaneTrace, entity_rows: Array, num_rows: int) -> LaneTrace:
@@ -746,7 +740,7 @@ def _mask_padding_lanes(trace: LaneTrace, entity_rows: Array, num_rows: int) -> 
     return trace.replace(valid=(entity_rows >= 0) & (entity_rows < num_rows))
 
 
-def solve_entity_bucket(
+def solve_entity_bucket_traced(
     objective: GLMObjective,
     opt: OptimizerConfig,
     features: Array,  # [e, cap, d]
@@ -756,34 +750,16 @@ def solve_entity_bucket(
     entity_rows: Array,  # [e]
     full_offsets: Array,  # [n]
     table: Array,  # [E, d]
-) -> Array:
-    """Solve every entity in a bucket and scatter results into the table.
+) -> tuple[Array, LaneTrace]:
+    """Solve every entity in a bucket and scatter results into the table;
+    also returns the per-lane convergence trace (padding lanes masked
+    invalid): the CD path hands it to telemetry, the fused step reduces it
+    to its line-search counts.
 
     Pure/traceable: reused by the single-chip jit wrapper below and by the
     mesh-sharded full-GAME train step (parallel/distributed.py), where the
     entity axis shards over the mesh's "data" axis.
     """
-    table, _trace = solve_entity_bucket_traced(
-        objective, opt, features, labels, weights, sample_rows, entity_rows,
-        full_offsets, table,
-    )
-    return table
-
-
-def solve_entity_bucket_traced(
-    objective: GLMObjective,
-    opt: OptimizerConfig,
-    features: Array,
-    labels: Array,
-    weights: Array,
-    sample_rows: Array,
-    entity_rows: Array,
-    full_offsets: Array,
-    table: Array,
-) -> tuple[Array, LaneTrace]:
-    """:func:`solve_entity_bucket` + per-lane convergence trace (padding
-    lanes masked invalid). The fused mesh path keeps using the untraced
-    variant; the CD path returns the trace to telemetry."""
     offsets = _bucket_offsets(sample_rows, full_offsets)
     solved, trace = _solve_bucket_entities(
         objective, opt, features, labels, weights, offsets, table[entity_rows]
@@ -889,7 +865,7 @@ def _jitted_re_bucket_variances(
     var_table: Array,  # [E, d] accumulator
 ):
     """Per-entity diag(H⁻¹) at the solved coefficients, scattered into
-    var_table with the same index semantics as solve_entity_bucket."""
+    var_table with the same index semantics as solve_entity_bucket_traced."""
     offsets = _bucket_offsets(sample_rows, full_offsets)
 
     def one(f, l, o, wt, w):
@@ -978,7 +954,7 @@ def _jitted_re_bucket_variances_indexmap_diagonal(
     return var_ext.at[entity_rows[:, None], col_index].set(vs)
 
 
-def solve_entity_bucket_indexmap(
+def solve_entity_bucket_indexmap_traced(
     objective: GLMObjective,
     opt: OptimizerConfig,
     features: Array,  # [e, cap, k]
@@ -989,35 +965,16 @@ def solve_entity_bucket_indexmap(
     col_index: Array,  # [e, k], padding slots hold d (the scratch column)
     full_offsets: Array,
     table_ext: Array,  # [E, d+1]
-) -> Array:
+) -> tuple[Array, LaneTrace]:
     """Index-map-projected bucket solve: gather each entity's active columns
     as its warm start, solve in the projected space, scatter back. Padding
     slots read/write the scratch column, which is re-zeroed afterwards.
+    Returns the table and the per-lane convergence trace.
 
     Pure/traceable (reference IndexMapProjectorRDD.scala:218-257 semantics):
     used by the single-chip jit wrapper below and by the mesh-sharded
     fused step (parallel/distributed.py), where the entity axis shards
     over "data"."""
-    table_ext, _trace = solve_entity_bucket_indexmap_traced(
-        objective, opt, features, labels, weights, sample_rows, entity_rows,
-        col_index, full_offsets, table_ext,
-    )
-    return table_ext
-
-
-def solve_entity_bucket_indexmap_traced(
-    objective: GLMObjective,
-    opt: OptimizerConfig,
-    features: Array,
-    labels: Array,
-    weights: Array,
-    sample_rows: Array,
-    entity_rows: Array,
-    col_index: Array,
-    full_offsets: Array,
-    table_ext: Array,
-) -> tuple[Array, LaneTrace]:
-    """:func:`solve_entity_bucket_indexmap` + per-lane convergence trace."""
     offsets = _bucket_offsets(sample_rows, full_offsets)
     w0s = table_ext[entity_rows[:, None], col_index]
     solved, trace = _solve_bucket_entities(
@@ -1117,7 +1074,7 @@ def _jitted_re_bucket_variances_random_diagonal(
     return var_table.at[entity_rows].set(vs)
 
 
-def solve_entity_bucket_random(
+def solve_entity_bucket_random_traced(
     objective: GLMObjective,
     opt: OptimizerConfig,
     features: Array,  # [e, cap, k] (already projected)
@@ -1128,30 +1085,11 @@ def solve_entity_bucket_random(
     matrix: Array,  # [d, k]
     full_offsets: Array,
     table: Array,  # [E, d]
-) -> Array:
+) -> tuple[Array, LaneTrace]:
     """Random-projected bucket solve: warm start Pᵀw (the adjoint projection,
     ≈ the projected coefficients since E[PᵀP]=I), back-project P w_k.
-    Pure/traceable, shared with the fused step like its index-map twin."""
-    table, _trace = solve_entity_bucket_random_traced(
-        objective, opt, features, labels, weights, sample_rows, entity_rows,
-        matrix, full_offsets, table,
-    )
-    return table
-
-
-def solve_entity_bucket_random_traced(
-    objective: GLMObjective,
-    opt: OptimizerConfig,
-    features: Array,
-    labels: Array,
-    weights: Array,
-    sample_rows: Array,
-    entity_rows: Array,
-    matrix: Array,
-    full_offsets: Array,
-    table: Array,
-) -> tuple[Array, LaneTrace]:
-    """:func:`solve_entity_bucket_random` + per-lane convergence trace."""
+    Returns the table and the per-lane convergence trace. Pure/traceable,
+    shared with the fused step like its index-map twin."""
     offsets = _bucket_offsets(sample_rows, full_offsets)
     w0s = table[entity_rows] @ matrix
     solved, trace = _solve_bucket_entities(

@@ -86,6 +86,12 @@ class SolverResult:
     ``value_history`` / ``grad_norm_history`` are fixed-size [max_iter + 1]
     arrays padded with NaN past ``iterations`` — the jittable analogue of
     OptimizationStatesTracker's bounded state queue.
+
+    ``line_search_trials[i]`` is the number of trial points iteration ``i``'s
+    line search evaluated (int32 [max_iter + 1], slot 0 and the slots past
+    ``iterations`` hold 0); ``floor_exits`` counts the searches that ended
+    at the float's floor (:data:`LINE_SEARCH_FLOOR_K`). Solvers with no
+    line search of that kind report zeros (:func:`no_line_search_counts`).
     """
 
     coefficients: Array
@@ -95,6 +101,8 @@ class SolverResult:
     reason: Array  # int32 scalar, ConvergenceReason code
     value_history: Array
     grad_norm_history: Array
+    line_search_trials: Array  # int32 [max_iter + 1]
+    floor_exits: Array  # int32 scalar
 
     @property
     def converged(self) -> Array:
@@ -118,6 +126,15 @@ class SolverResult:
         reason = ConvergenceReason(int(self.reason)).name
         lines.append(f"converged after {n} iterations: {reason}")
         return "\n".join(lines)
+
+
+def no_line_search_counts(max_iter: int) -> dict[str, Array]:
+    """The ``line_search_trials`` / ``floor_exits`` fields of a
+    :class:`SolverResult` whose solver runs no counted line search."""
+    return {
+        "line_search_trials": jnp.zeros((max_iter + 1,), jnp.int32),
+        "floor_exits": jnp.int32(0),
+    }
 
 
 @flax.struct.dataclass
@@ -146,6 +163,13 @@ class LaneTrace:
     #: solver/lane_iters histogram, so telemetry consumers must not count
     #: them again (static metadata, not a pytree leaf)
     scheduled: bool = flax.struct.field(pytree_node=False, default=False)
+    #: line-search work, filled by :func:`lane_trace_of` and read by
+    #: :func:`lane_solver_counts`; None on traces built elsewhere
+    line_search_trials: Array | None = None  # [lanes] int32, a lane's own trials
+    floor_exits: Array | None = None  # [lanes] int32
+    #: int32 scalar: sum over iterations of the max over ALL lanes (padding
+    #: lanes too) — the trips the vmapped search loop actually ran
+    lockstep_trials: Array | None = None
 
 
 class LaneTraces:
@@ -166,17 +190,52 @@ class LaneTraces:
 
 def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace:
     """Build a LaneTrace from a (vmapped) SolverResult, dropping the
-    per-iteration histories that padding lanes would make meaningless."""
+    per-iteration histories that padding lanes would make meaningless (the
+    line-search history is reduced to each lane's total and the bucket's
+    lock-step total)."""
     iterations = jnp.atleast_1d(result.iterations)
     if valid is None:
         valid = jnp.ones(iterations.shape, dtype=bool)
+    trials = jnp.atleast_2d(result.line_search_trials)  # [lanes, max_iter + 1]
     return LaneTrace(
         iterations=iterations,
         reason=jnp.atleast_1d(result.reason),
         value=jnp.atleast_1d(result.value),
         gradient_norm=jnp.atleast_1d(result.gradient_norm),
         valid=jnp.atleast_1d(valid),
+        line_search_trials=jnp.sum(trials, axis=1, dtype=jnp.int32),
+        floor_exits=jnp.atleast_1d(result.floor_exits),
+        # iteration by iteration the vmapped search loop runs until its
+        # slowest lane is done: the sum of those maxima is what the device ran
+        lockstep_trials=jnp.sum(jnp.max(trials, axis=0), dtype=jnp.int32),
     )
+
+
+#: the registry counters (``solver/<name>``) a fused sweep reports: the
+#: four of :func:`lane_solver_counts`, random-effect coordinates summed, and
+#: the fixed-effect solves' own trials and floor exits
+SOLVER_COUNT_NAMES = (
+    "lockstep_trials", "lane_trials", "floor_exits", "line_searches",
+    "fe_trials", "fe_floor_exits",
+)
+
+
+def lane_solver_counts(trace: LaneTrace) -> dict[str, Array]:
+    """A bucket's line-search work as int32 device scalars:
+    ``lockstep_trials`` (what the device ran: every lane waits for the
+    slowest search of each iteration), ``lane_trials`` (what the valid lanes
+    needed, each by itself), ``floor_exits`` and ``line_searches`` (valid
+    lanes; one search an iteration)."""
+
+    def over_valid(per_lane):
+        return jnp.sum(jnp.where(trace.valid, per_lane, 0), dtype=jnp.int32)
+
+    return {
+        "lockstep_trials": trace.lockstep_trials,
+        "lane_trials": over_valid(trace.line_search_trials),
+        "floor_exits": over_valid(trace.floor_exits),
+        "line_searches": over_valid(trace.iterations),
+    }
 
 
 def check_convergence(
@@ -226,6 +285,16 @@ class LineSearchResult:
     value: Array
     gradient: Array
     success: Array  # bool
+    trials: Array  # int32: trial points evaluated
+    floor_exit: Array  # bool: ended by the float's floor, not by the tests
+
+
+#: A search that has failed Armijo at step ``t`` is over once the decrease it
+#: could still claim, ``|t * dg0|``, is no larger than this many ulps of the
+#: function's own value: ``t`` only shrinks from there, so no later trial can
+#: show a decrease that ``f0``'s floating-point value resolves. One constant,
+#: not an option (why this value: PERF.md §6, PR 25).
+LINE_SEARCH_FLOOR_K = 1.0
 
 
 def wolfe_line_search(
@@ -239,8 +308,16 @@ def wolfe_line_search(
     c2: float = 0.9,
     max_steps: int = 25,
     host_loop: bool = False,
+    active: Array | bool = True,
 ) -> LineSearchResult:
     """Weak-Wolfe bisection line search, fully jittable.
+
+    ``active`` (False = the caller has no use for this search): the loop
+    condition is false from the first trip, so under ``vmap`` a lane whose
+    solve has already stopped adds no trial to the bucket's lock-step loop.
+    The loop also ends at the float's floor (:data:`LINE_SEARCH_FLOOR_K`),
+    returning what running out of ``max_steps`` returns: the best Armijo
+    point if one was seen, else failure.
 
     ``host_loop=True`` drives the same trial-step body from Python (see
     :func:`run_while`) so a host-level chunked ``value_and_grad_fn`` can be
@@ -255,14 +332,17 @@ def wolfe_line_search(
     (optimization/LBFGS.scala:97-107).
     """
     dg0 = jnp.vdot(g0, direction)
+    finfo = jnp.finfo(f0.dtype)
+    floor = LINE_SEARCH_FLOOR_K * finfo.eps * jnp.maximum(jnp.abs(f0), finfo.tiny)
 
     def body(state):
-        i, t, lo, hi, t_best, f_best, g_best, has_best, _done = state
+        i, t, lo, hi, t_best, f_best, g_best, has_best, _done, _floored = state
         f_t, g_t = value_and_grad_fn(w + t * direction)
         bad = jnp.isnan(f_t) | jnp.isinf(f_t)
         armijo = (f_t <= f0 + c1 * t * dg0) & ~bad
         curv = jnp.vdot(g_t, direction) >= c2 * dg0
         done = armijo & curv
+        floored = ~armijo & (jnp.abs(t * dg0) <= floor)
         # Remember the best Armijo-satisfying point seen so far: if curvature
         # never holds within max_steps, we still return a genuine decrease
         # step instead of reporting a spurious line-search failure.
@@ -284,11 +364,12 @@ def wolfe_line_search(
                 t,
             ),
         )
-        return (i + 1, new_t, new_lo, new_hi, t_best, f_best, g_best, has_best, done)
+        return (i + 1, new_t, new_lo, new_hi, t_best, f_best, g_best, has_best,
+                done, floored)
 
     def cond(state):
-        i, *_rest, done = state
-        return (i < max_steps) & ~done
+        i, *_rest, done, floored = state
+        return (i < max_steps) & ~done & ~floored & active
 
     inf = jnp.asarray(jnp.inf, dtype=f0.dtype)
     zero = jnp.zeros((), dtype=f0.dtype)
@@ -302,9 +383,13 @@ def wolfe_line_search(
         g0,
         jnp.asarray(False),
         jnp.asarray(False),
+        jnp.asarray(False),
     )
-    _, _, _, _, t_best, f_best, g_best, has_best, _done = run_while(
+    trials, _, _, _, t_best, f_best, g_best, has_best, _done, floored = run_while(
         cond, body, init, host=host_loop
     )
     success = has_best & (f_best < f0)
-    return LineSearchResult(step=t_best, value=f_best, gradient=g_best, success=success)
+    return LineSearchResult(
+        step=t_best, value=f_best, gradient=g_best, success=success,
+        trials=trials, floor_exit=floored,
+    )

@@ -93,6 +93,8 @@ class _LBFGSState:
     g0_norm: Array
     value_history: Array
     grad_norm_history: Array
+    line_search_trials: Array  # int32 [max_iter + 1]: trials of iteration i's search
+    floor_exits: Array  # int32: searches the float's floor ended
 
 
 def minimize_lbfgs(
@@ -184,6 +186,8 @@ def minimize_lbfgs(
             g0_norm=g0_norm,
             value_history=nan_hist.at[0].set(f0),
             grad_norm_history=nan_hist.at[0].set(g0_norm),
+            line_search_trials=jnp.zeros((max_iter + 1,), jnp.int32),
+            floor_exits=jnp.int32(0),
         )
 
         # Already stationary at the initial point?
@@ -201,6 +205,10 @@ def minimize_lbfgs(
         )
 
     def body(state: _LBFGSState):
+        # False only under vmap, where a stopped lane's body still runs (and
+        # is thrown away): its line search must not hold the bucket's
+        # lock-step loop open. Un-vmapped, ``cond`` guarantees it.
+        live = state.reason == ConvergenceReason.NOT_CONVERGED
         direction = two_loop_direction(
             state.g, state.s_hist, state.y_hist, state.rho, state.count, state.head
         )
@@ -249,15 +257,16 @@ def minimize_lbfgs(
 
             def ls_cond(s):
                 i, _t, _w, _f, _g, ok = s
-                return (i < max_line_search_steps) & ~ok
+                return (i < max_line_search_steps) & ~ok & live
 
-            _, _, w_new, f_new, g_new, ls_ok = run_while(
+            ls_trials, _, w_new, f_new, g_new, ls_ok = run_while(
                 ls_cond,
                 ls_body,
                 (jnp.int32(0), t_init, state.w, state.f, state.g, jnp.asarray(False)),
                 host=host_loop,
             )
             ls_success = ls_ok
+            ls_floor_exit = jnp.asarray(False)
         else:
             ls = wolfe_line_search(
                 value_and_grad_fn,
@@ -268,10 +277,12 @@ def minimize_lbfgs(
                 t_init,
                 max_steps=max_line_search_steps,
                 host_loop=host_loop,
+                active=live,
             )
             w_new = state.w + ls.step * direction
             f_new, g_new = ls.value, ls.gradient
             ls_success = ls.success
+            ls_trials, ls_floor_exit = ls.trials, ls.floor_exit
 
         s = w_new - state.w
         y = g_new - state.g
@@ -325,6 +336,8 @@ def minimize_lbfgs(
             g0_norm=state.g0_norm,
             value_history=state.value_history.at[it].set(jnp.where(ls_success, f_new, state.f)),
             grad_norm_history=state.grad_norm_history.at[it].set(gnorm),
+            line_search_trials=state.line_search_trials.at[it].set(ls_trials),
+            floor_exits=state.floor_exits + ls_floor_exit.astype(jnp.int32),
         )
 
     final = run_while(cond, body, init, host=host_loop, observer=state_observer)
@@ -341,4 +354,6 @@ def minimize_lbfgs(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
+        line_search_trials=final.line_search_trials,
+        floor_exits=final.floor_exits,
     )

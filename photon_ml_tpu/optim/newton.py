@@ -42,7 +42,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_ml_tpu.optim.common import ConvergenceReason, SolverResult
+from photon_ml_tpu.optim.common import (
+    ConvergenceReason,
+    SolverResult,
+    no_line_search_counts,
+)
 
 Array = jax.Array
 
@@ -256,4 +260,5 @@ def minimize_newton(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
+        **no_line_search_counts(max_iter),
     )

@@ -22,6 +22,7 @@ from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     SolverResult,
     check_convergence,
+    no_line_search_counts,
     run_while,
 )
 from photon_ml_tpu.optim.lbfgs import two_loop_direction
@@ -252,4 +253,5 @@ def minimize_owlqn(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
+        **no_line_search_counts(max_iter),
     )

@@ -23,7 +23,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_ml_tpu.optim.common import ConvergenceReason, SolverResult, run_while
+from photon_ml_tpu.optim.common import (
+    ConvergenceReason,
+    SolverResult,
+    no_line_search_counts,
+    run_while,
+)
 
 Array = jax.Array
 
@@ -269,4 +274,5 @@ def minimize_tron(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
+        **no_line_search_counts(max_iter),
     )

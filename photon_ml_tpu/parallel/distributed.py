@@ -39,9 +39,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.algorithm.coordinates import (
-    solve_entity_bucket,
-    solve_entity_bucket_indexmap,
-    solve_entity_bucket_random,
+    solve_entity_bucket_indexmap_traced,
+    solve_entity_bucket_random_traced,
+    solve_entity_bucket_traced,
 )
 from photon_ml_tpu.algorithm.mf_coordinate import solve_mf_side_bucket
 from photon_ml_tpu.models.matrix_factorization import score_matrix_factorization
@@ -54,6 +54,11 @@ from photon_ml_tpu.projector.projectors import ProjectorType
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.optim.common import (
+    SOLVER_COUNT_NAMES,
+    SolverResult,
+    lane_solver_counts,
+)
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, solve
 from photon_ml_tpu.telemetry.program_ledger import ledger_jit
 from photon_ml_tpu.telemetry.registry import default_registry
@@ -266,6 +271,20 @@ def _buckets_pytree(
     return out
 
 
+def _add_counts(total: dict, counts: Mapping) -> None:
+    """``total[name] += counts[name]`` over the solver counts of one solve."""
+    for name, value in counts.items():
+        total[name] = total.get(name, 0) + value
+
+
+def _fe_solved(result: SolverResult) -> tuple[Array, dict[str, Array]]:
+    """A fixed-effect solve's (coefficients, ``fe_*`` line-search counts)."""
+    return result.coefficients, {
+        "fe_trials": jnp.sum(result.line_search_trials, dtype=jnp.int32),
+        "fe_floor_exits": result.floor_exits,
+    }
+
+
 class GameTrainProgram:
     """A compiled full-GAME training step bound to static specs.
 
@@ -476,6 +495,7 @@ class GameTrainProgram:
         # CD sweep and the validation score, the two hottest signatures of
         # a training run
         self._step = ledger_jit(self._step_impl, label="train/step")
+        self._solver_counts = None  # of the last fused sweep, on the device
         self._score = ledger_jit(self._score_impl, label="train/score")
 
     def fe_coefficients_model_space(self, state: GameTrainState,
@@ -808,8 +828,23 @@ class GameTrainProgram:
     # -- the fused step ------------------------------------------------------
 
     def step(self, data, buckets, state: GameTrainState):
-        """One full CD sweep. Returns (new_state, training_loss)."""
-        return self._step(data, buckets, state)
+        """One full CD sweep. Returns (new_state, training_loss).
+
+        The sweep's line-search counts (optim/common.SOLVER_COUNT_NAMES)
+        stay on the program as one unread device array:
+        :meth:`take_solver_counts` reads it."""
+        state, loss, self._solver_counts = self._step(data, buckets, state)
+        return state, loss
+
+    def take_solver_counts(self) -> "dict[str, int] | None":
+        """The line-search counts of the sweep :meth:`step` last ran, as host
+        ints (a device-to-host read: call it once the loss has been waited
+        for), or None when no fused sweep left any; each sweep's counts are
+        handed out once."""
+        counts, self._solver_counts = self._solver_counts, None
+        if counts is None:
+            return None
+        return dict(zip(SOLVER_COUNT_NAMES, np.asarray(counts).tolist()))
 
     def _weighted_loss(self, labels, weights, total_margin):
         losses = self._loss.loss(total_margin, labels)
@@ -900,10 +935,10 @@ class GameTrainProgram:
             kind = self._kind[name]
             off = jits["offsets"](base, scores, name)
             if kind == "fe":
-                fe_w = jits["fe_solve"](data, off, weights, fe_w)
+                fe_w, _counts = jits["fe_solve"](data, off, weights, fe_w)
                 scores[name] = jits["fe_margin"](data, fe_w)
             elif kind == "extra_fe":
-                extra_fe[name] = jits["extra_fe_solve"](
+                extra_fe[name], _counts = jits["extra_fe_solve"](
                     data, name, off, labels, weights, extra_fe[name]
                 )
                 scores[name] = jits["extra_fe_margin"](data, name, extra_fe[name])
@@ -911,7 +946,7 @@ class GameTrainProgram:
                 spec = self._re_by_name[name]
                 scheduler = schedulers.get(name)
                 if scheduler is None:
-                    tables[name] = jits["re_solve"](
+                    tables[name], _counts = jits["re_solve"](
                         data, buckets, name, off, tables[name]
                     )
                 else:
@@ -1077,24 +1112,28 @@ class GameTrainProgram:
         tables = dict(state.re_tables)
         mf_rows = dict(state.mf_rows)
         mf_cols = dict(state.mf_cols)
+        solver_counts = dict.fromkeys(SOLVER_COUNT_NAMES, jnp.int32(0))
 
         for name in self.update_order:
             kind = self._kind[name]
             if kind == "fe":
-                fe_w = self._solve_primary_fe(
+                fe_w, counts = self._solve_primary_fe(
                     data, offsets_excluding(name), weights, fe_w
                 )
+                _add_counts(solver_counts, counts)
                 scores[name] = self._fe_margin_score(data, fe_w)
             elif kind == "extra_fe":
-                extra_fe[name] = self._solve_extra_fe(
+                extra_fe[name], counts = self._solve_extra_fe(
                     data, name, offsets_excluding(name), labels, weights,
                     extra_fe[name],
                 )
+                _add_counts(solver_counts, counts)
                 scores[name] = self._extra_fe_margin(data, name, extra_fe[name])
             elif kind == "re":
-                tables[name] = self._solve_re(
+                tables[name], counts = self._solve_re(
                     data, buckets, name, offsets_excluding(name), tables[name]
                 )
+                _add_counts(solver_counts, counts)
                 scores[name] = self._re_coordinate_score(
                     data, name, tables[name],
                     self._re_by_name[name].feature_shard_id,
@@ -1111,7 +1150,9 @@ class GameTrainProgram:
             fe_coefficients=fe_w, re_tables=tables,
             mf_rows=mf_rows, mf_cols=mf_cols, extra_fe=extra_fe,
         )
-        return new_state, train_loss
+        # one small array: one device-to-host read a sweep, not six
+        return new_state, train_loss, jnp.stack(
+            [solver_counts[name] for name in SOLVER_COUNT_NAMES])
 
     def _solve_primary_fe(self, data, fe_offsets, weights, fe_w0):
         """Primary fixed-effect solve (samples sharded; grads psum over the
@@ -1123,7 +1164,7 @@ class GameTrainProgram:
         The returned vector lives in normalized space (warm starts stay
         there across steps); callers score through the same effective-
         coefficient algebra the objective uses, so residuals stay in data
-        space.
+        space. Returns (coefficients, the solve's ``fe_*`` counts).
         """
         fe_sparse = data.get("fe_sparse_batch")
         fe_mult = data.get("fe_weight_multiplier")
@@ -1145,9 +1186,9 @@ class GameTrainProgram:
                 if self._fe_sharded_objective is not None
                 else self._fe_objective
             )
-        return solve(
-            self.fe.optimizer, fe_objective.bind(fe_batch), fe_w0
-        ).coefficients
+        return _fe_solved(
+            solve(self.fe.optimizer, fe_objective.bind(fe_batch), fe_w0)
+        )
 
     def _solve_extra_fe(self, data, name, full_offsets, labels, weights, w0):
         """A non-primary FE coordinate: dense replicated solve, same
@@ -1161,14 +1202,17 @@ class GameTrainProgram:
             weights=fe_weights,
         )
         spec = self._extra_fe_by_name[name]
-        return solve(
-            spec.optimizer, self._extra_fe_objectives[name].bind(batch), w0
-        ).coefficients
+        return _fe_solved(
+            solve(spec.optimizer, self._extra_fe_objectives[name].bind(batch), w0)
+        )
 
     def _solve_re(self, data, buckets, k, full_offsets, table):
-        """One random-effect coordinate (entities sharded, vmapped solves)."""
+        """One random-effect coordinate (entities sharded, vmapped solves).
+        Returns (table, the coordinate's line-search counts: its buckets'
+        optim/common.lane_solver_counts summed)."""
         spec = self._re_by_name[k]
         objective = self._re_solve_objectives[k]
+        counts: dict = {}
         if spec.projector == ProjectorType.INDEX_MAP:
             # scratch-column solve in each entity's observed columns
             # (ports algorithm/coordinates.py's single-chip path into
@@ -1178,25 +1222,27 @@ class GameTrainProgram:
                 axis=1,
             )
             for b in buckets[k]:
-                table_ext = solve_entity_bucket_indexmap(
+                table_ext, trace = solve_entity_bucket_indexmap_traced(
                     objective, spec.optimizer,
                     b["features"], b["labels"], b["weights"],
                     b["sample_rows"], b["entity_rows"], b["col_index"],
                     full_offsets, table_ext,
                 )
-            return table_ext[:, :-1]
+                _add_counts(counts, lane_solver_counts(trace))
+            return table_ext[:, :-1], counts
         if spec.projector == ProjectorType.RANDOM:
             matrix = buckets["__projections__"][k]
             for b in buckets[k]:
-                table = solve_entity_bucket_random(
+                table, trace = solve_entity_bucket_random_traced(
                     objective, spec.optimizer,
                     b["features"], b["labels"], b["weights"],
                     b["sample_rows"], b["entity_rows"], matrix,
                     full_offsets, table,
                 )
-            return table
+                _add_counts(counts, lane_solver_counts(trace))
+            return table, counts
         for b in buckets[k]:
-            table = solve_entity_bucket(
+            table, trace = solve_entity_bucket_traced(
                 objective,
                 spec.optimizer,
                 b["features"],
@@ -1207,7 +1253,8 @@ class GameTrainProgram:
                 full_offsets,
                 table,
             )
-        return table
+            _add_counts(counts, lane_solver_counts(trace))
+        return table, counts
 
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):
         """One matrix-factorization coordinate (alternating vmapped solves).
@@ -1862,7 +1909,10 @@ def _step_and_wait(program: GameTrainProgram, data, buckets,
     """One sweep through the fused program, then the host's wait for its
     loss: ``train/step`` ends when the work is ENQUEUED, ``train/loss_wait``
     is the host blocked on the device. Bumps ``train/sweeps`` and
-    ``train/rows`` (rows trained, the work as a count). Returns
+    ``train/rows`` (rows trained, the work as a count), and the
+    ``solver/<name>`` counters from the fused sweep's line-search counts,
+    read under ``train/solver_counts`` once the loss has arrived (the
+    device is done by then: no second wait). Returns
     (state, loss as a float); a non-finite loss raises before any
     checkpoint could overwrite the last finite state with NaNs (CD-path
     DivergenceError contract, coordinate_descent.py)."""
@@ -1891,6 +1941,10 @@ def _step_and_wait(program: GameTrainProgram, data, buckets,
     registry = default_registry()
     registry.counter("train/sweeps").inc()
     registry.counter("train/rows").inc(rows)
+    with span("train/solver_counts"):
+        counts = program.take_solver_counts() or {}
+    for name, value in counts.items():
+        registry.counter(f"solver/{name}").inc(value)
     return state, loss
 
 

@@ -386,7 +386,8 @@ def test_partitioned_training_matches_full_read(tmp_path):
     names = [e.name for e in tracer.events() if e.name.startswith("train/")]
     assert sorted(names) == sorted(
         ["train/fit", "train/prepare_inputs", "train/result_state"]
-        + 2 * ["train/sweep", "train/step", "train/loss_wait"])
+        + 2 * ["train/sweep", "train/step", "train/loss_wait",
+               "train/solver_counts"])
     np.testing.assert_allclose(res.losses, ref.losses, rtol=1e-12)
     np.testing.assert_allclose(
         np.asarray(res.state.fe_coefficients),

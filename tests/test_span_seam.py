@@ -244,12 +244,12 @@ ONCE_PER_FIT = [
     "train/device_evaluators", "train/result_state",
 ]
 ONCE_PER_SWEEP = [
-    "train/sweep", "train/step", "train/loss_wait", "train/train_metric",
-    "train/validate", "train/validate/score", "train/validate/evaluate",
+    "train/sweep", "train/step", "train/loss_wait", "train/solver_counts",
+    "train/train_metric", "train/validate", "train/validate/score", "train/validate/evaluate",
     "train/on_sweep", "dispatch/train/step",
 ]
-SWEEP_CHILDREN = ["train/step", "train/loss_wait", "train/train_metric",
-                  "train/validate", "train/on_sweep"]
+SWEEP_CHILDREN = ["train/step", "train/loss_wait", "train/solver_counts",
+                  "train/train_metric", "train/validate", "train/on_sweep"]
 
 
 @pytest.fixture(scope="module")
