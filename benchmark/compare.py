@@ -4,6 +4,8 @@ limits stand in the configuration file, with their readings in PERF.md)."""
 
 from __future__ import annotations
 
+import sys
+
 import numpy as np
 
 
@@ -29,6 +31,14 @@ def rel_l2(produced: np.ndarray, expected: np.ndarray) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
+def max_gap_over_rms(produced, expected) -> float:
+    """Widest gap between two sets of margins, against the rms of the
+    expected ones (float64)."""
+    ref = np.asarray(expected, np.float64)
+    gap = np.abs(np.asarray(produced, np.float64) - ref)
+    return float(gap.max() / max(np.sqrt(np.mean(ref * ref)), 1e-30))
+
+
 def own_coefficient_comparisons(produced: dict, evaluated: dict, limits: dict) -> list:
     """What does not turn on how far a solver got: the loss the program
     reported after its last sweep, and the validation margins its scoring
@@ -36,13 +46,11 @@ def own_coefficient_comparisons(produced: dict, evaluated: dict, limits: dict) -
     float64 from the generator's float32 rows AT THE PROGRAM'S OWN final
     coefficients. Sound float32 arithmetic sits at its rounding floor here;
     features held in a lower precision do not."""
-    ref = np.asarray(evaluated["val_margin"], np.float64)
-    gap = np.abs(np.asarray(produced["val_margin"], np.float64) - ref)
     return [
         ("loss_own_coef_rel_gap", rel_gap(produced["losses"][-1], evaluated["loss"]),
          limits["loss_own_coef_rel_gap"]),
         ("val_margin_own_coef_max_gap",
-         float(gap.max() / max(np.sqrt(np.mean(ref * ref)), 1e-30)),
+         max_gap_over_rms(produced["val_margin"], evaluated["val_margin"]),
          limits["val_margin_own_coef_max_gap"]),
     ]
 
@@ -70,11 +78,65 @@ def glmix_comparisons(produced: dict, expected: dict, limits: dict) -> list:
 
 
 def judge(comparisons: list) -> bool:
-    """Prints every number beside its limit; True when all are inside."""
+    """Prints every number beside its limit, on standard error (a run's last
+    lines there); True when all are inside."""
     ok = True
     for name, value, limit in comparisons:
         inside = bool(np.isfinite(value) and value <= limit)
         ok &= inside
         print(f"compare[{'ok' if inside else 'FAIL'}] {name}: {value:.6g} "
-              f"(limit {limit:g})", flush=True)
+              f"(limit {limit:g})", file=sys.stderr, flush=True)
     return ok
+
+
+def _limit(limits: dict, kind: str, lam) -> float:
+    """A kind's limit: one number for every λ, or one for each λ."""
+    limit = limits[kind]
+    return float(limit[f"{lam:g}"] if isinstance(limit, dict) else limit)
+
+
+def path_own_coefficient_comparisons(produced: dict, evaluated: dict,
+                                     limits: dict) -> list:
+    """A λ-path's numbers that do not turn on how far a solver got, for each
+    λ: the objective value and the gradient norm the solve reported and the
+    validation margins the episode's scoring read, against float64 AT THE
+    PROGRAM'S OWN coefficients."""
+    out = []
+    for k, lam in enumerate(produced["lambdas"]):
+        out += [
+            (f"lambda{lam:g}_loss_own_coef_rel_gap",
+             rel_gap(produced["values"][k], evaluated["value"][k]),
+             _limit(limits, "loss_own_coef_rel_gap", lam)),
+            (f"lambda{lam:g}_grad_norm_own_coef_rel_gap",
+             rel_gap(produced["gradient_norms"][k], evaluated["grad_norm"][k]),
+             _limit(limits, "grad_norm_own_coef_rel_gap", lam)),
+            (f"lambda{lam:g}_val_margin_own_coef_max_gap",
+             max_gap_over_rms(produced["val_margin"][k], evaluated["val_margin"][k]),
+             _limit(limits, "val_margin_own_coef_max_gap", lam)),
+        ]
+    return out
+
+
+def path_comparisons(produced: dict, expected: dict, val_labels: np.ndarray,
+                     limits: dict) -> list:
+    """Against the reference's exact minimizers, for each λ: the coefficient
+    vector, the objective value reached (the reference's taken in float64 at
+    its minimizer), the validation AUC; and that every λ of the path was
+    fitted (a solve that returns its start unchanged reads 1 in the first)."""
+    if len(produced["coefficients"]) != len(expected["coefficients"]):
+        return [("lambdas_missing", 1.0, 0.0)]
+    out = []
+    for k, lam in enumerate(produced["lambdas"]):
+        out += [
+            (f"lambda{lam:g}_coef_rel_l2",
+             rel_l2(produced["coefficients"][k], expected["coefficients"][k]),
+             _limit(limits, "coef_rel_l2", lam)),
+            (f"lambda{lam:g}_loss_rel_gap",
+             rel_gap(produced["values"][k], expected["value"][k]),
+             _limit(limits, "loss_rel_gap", lam)),
+            (f"lambda{lam:g}_val_auc_gap",
+             abs(auc(produced["val_margin"][k], val_labels)
+                 - auc(expected["val_margin"][k], val_labels)),
+             _limit(limits, "val_auc_gap", lam)),
+        ]
+    return out

@@ -68,8 +68,18 @@ def metrics_of(manifest: dict, group: str, workload: str, reported: set) -> list
     return out
 
 
+def reader_file(name: str, here: str = HERE) -> str:
+    """The file that reads a per-layer metric: ``layer_metrics/<name>.py``,
+    or for a name ``<quantity>.<suffix>`` the quantity's. An entry names ONE
+    end-to-end metric it moves, so a quantity read in cells that report
+    different ones is an entry for each (``device_idle_pct`` moves
+    ``train_rows_per_s``, ``device_idle_pct.fit`` moves ``fit_s``): entries,
+    not files."""
+    return os.path.join(here, "layer_metrics", name.split(".", 1)[0] + ".py")
+
+
 def layer_metric_reader(name: str, here: str = HERE):
-    return load_module(os.path.join(here, "layer_metrics", name + ".py")).read
+    return load_module(reader_file(name, here)).read
 
 
 def check_manifest(manifest: dict, root: str = ROOT) -> list[str]:
@@ -187,7 +197,7 @@ def check_manifest(manifest: dict, root: str = ROOT) -> list[str]:
         if m["name"] in seen:
             faults.append(f"metric {m['name']} twice")
         seen.add(m["name"])
-        reader = os.path.join(root, paths[0], "layer_metrics", m["name"] + ".py")
+        reader = reader_file(m["name"], os.path.join(root, paths[0]))
         if not os.path.isfile(reader):
             faults.append(f"per_layer {m['name']}: no reader {reader}")
     for cell in cells:

@@ -10,7 +10,9 @@ episodes until ``--seconds`` have passed and at least the traffic's
 ``min_episodes`` are done; then, outside the window, holds what the LAST
 timed episode produced against the configuration's plain reference. The
 last line of standard output is one JSON object (``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``).
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: every number compared beside its limit, which are also the
+run's last lines on standard error).
 
 A driver (``drivers/<kind>.py``, named by the traffic file's ``kind``) gives
 ``Cell(config, traffic, seed, devices, spans)`` with ``episode()`` (the timed
@@ -32,7 +34,9 @@ _PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -59,11 +63,21 @@ def configure_jax():
     return jax
 
 
+#: seconds inside ``jax.devices()``: the TPU runtime's own start. Taken OUT of
+#: ``setup_s`` and printed beside it: 6 s on a fresh machine, up to 15 s after
+#: processes that used the chip, none of it this repository's code (PERF.md
+#: 2), where a dense cell's whole set-up is 9 s.
+_chip_start_s = 0.0
+
+
 def accelerator(chips: int):
     """The devices of the run, or None when the cell's chips are not there."""
+    global _chip_start_s
     import jax
 
+    t0 = time.perf_counter()
     devices = jax.devices()
+    _chip_start_s = time.perf_counter() - t0
     if devices[0].platform != "tpu" or len(devices) < chips:
         return None
     return devices[:chips]
@@ -116,6 +130,18 @@ def measure(cell, spans, seconds: float, min_episodes: int):
     return times, wall
 
 
+def setup_layer_metrics(manifest: dict, workload: str, spans) -> dict:
+    """The cell's per-layer metrics that move ``setup_s``, read where set-up
+    ends: a program that traces or loads inside the window too (a fit that
+    builds its solves anew on every call) would add the window's seconds to
+    a reading taken after it."""
+    from benchmark.manifest import layer_metric_reader, metrics_of
+
+    return {m["name"]: layer_metric_reader(m["name"])({"spans": spans, "counters": {}})
+            for m in metrics_of(manifest, "per_layer", workload, {"setup_s"})
+            if m["moves"] == "setup_s"}
+
+
 def run_cell(found: dict, manifest: dict, seed: int, seconds: float, trace: bool,
              devices) -> dict:
     """Everything of a run but the look for a chip; returns the result line."""
@@ -133,7 +159,8 @@ def run_cell(found: dict, manifest: dict, seed: int, seconds: float, trace: bool
     with spans.span("warm"):
         cell.episode()
     cell.read_counters = trace  # counters that cost a host read: traced runs only
-    setup_s = time.perf_counter() - _PROCESS_START
+    setup_s = time.perf_counter() - _PROCESS_START - _chip_start_s
+    setup_metrics = setup_layer_metrics(manifest, workload, spans) if trace else {}
     compiles_before, requests_before = compiles.count, compiles.requests
     trace_dir = os.path.join(WORK_DIR, "trace-" + workload)
     if trace:
@@ -158,9 +185,10 @@ def run_cell(found: dict, manifest: dict, seed: int, seconds: float, trace: bool
     e2e = {name: {"value": value, "unit": unit}
            for name, (value, unit) in cell.end_to_end(times, wall).items()}
     e2e["setup_s"] = {"value": setup_s, "unit": "s"}
+    warm_start = next(s for n, s, _ in spans.closed if n == "warm")
     print("set-up spans: " + " ".join(
         f"{n}={e - s:.2f}" for n, s, e in spans.closed
-        if n in ("generate", "assemble", "pack", "warm")), flush=True)
+        if n == "warm" or s < warm_start), flush=True)
     print(f"compiled in the window: {compiles.count - compiles_before} "
           f"(cache look-ups {compiles.requests - requests_before})", flush=True)
     print(f"episodes {len(times)} in {wall:.4f} s: "
@@ -188,7 +216,8 @@ def run_cell(found: dict, manifest: dict, seed: int, seconds: float, trace: bool
         }
         metrics = {}
         for m in metrics_of(manifest, "per_layer", workload, set(e2e)):
-            value = layer_metric_reader(m["name"])(context)
+            value = (setup_metrics[m["name"]] if m["name"] in setup_metrics
+                     else layer_metric_reader(m["name"])(context))
             if value is not None:
                 metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
         result["metrics"] = metrics
@@ -205,10 +234,17 @@ def run_cell(found: dict, manifest: dict, seed: int, seconds: float, trace: bool
     reference = load_module(found["reference"])
     t0 = time.perf_counter()
     comparisons = cell.verify(reference, produced)
-    print(f"reference and comparison: {time.perf_counter() - t0:.1f} s", flush=True)
+    print(f"reference and comparison: {time.perf_counter() - t0:.1f} s; host peak "
+          f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20:.2f} GiB",
+          flush=True)
     result["correct"] = compare.judge(comparisons)
+    # every number compared beside its limit, last in the line
+    result["compared"] = {
+        name: {"value": float(value) if math.isfinite(value) else None,  # JSON has no NaN
+               "limit": limit}
+        for name, value, limit in comparisons}
     return {k: result[k] for k in ("correct", "attempted", "failed", "metrics",
-                                   "device", "breakdown") if k in result}
+                                   "device", "breakdown", "compared") if k in result}
 
 
 def main(argv=None) -> int:
@@ -230,6 +266,9 @@ def main(argv=None) -> int:
               f"{found['cell']['chips']} TPU chip(s); refusing to measure",
               file=sys.stderr)
         return EXIT_NO_CHIP
+    # what no cell can shorten: interpreter and imports (in setup_s), the chip
+    print(f"process start to devices: {time.perf_counter() - _PROCESS_START:.2f} s, "
+          f"of which the chip's start, not in setup_s: {_chip_start_s:.2f}", flush=True)
     result = run_cell(found, manifest, args.seed, args.seconds, bool(args.trace),
                       devices)
     print(json.dumps(result), flush=True)

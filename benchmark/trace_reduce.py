@@ -68,11 +68,20 @@ def instruction(text: str) -> str:
     return re.sub(r"(\.\d+)+$", "", re.sub(r"\(\d+\)$", "", name))
 
 
+def _shape(m) -> "tuple[int, int, int] | None":
+    return (int(m.group(2)), int(m.group(3)), _ITEMSIZE[m.group(1)]) if m else None
+
+
 def kernel_operand(text: str) -> "tuple[int, int, int] | None":
     """(n_pad, d_pad, itemsize) of the X a GLM kernel call was made with:
     the first 2-D operand of its custom call."""
-    m = _OPERAND.search(text.split("custom-call(", 1)[-1])
-    return (int(m.group(2)), int(m.group(3)), _ITEMSIZE[m.group(1)]) if m else None
+    return _shape(_OPERAND.search(text.split("custom-call(", 1)[-1]))
+
+
+def result_shape(text: str) -> "tuple[int, int, int] | None":
+    """(rows, columns, itemsize) of the 2-D array an instruction produces;
+    None for any other result (a tuple, a vector, a scalar)."""
+    return _shape(_OPERAND.match(text.split(" = ", 1)[-1]))
 
 
 def union_intervals(events) -> list[tuple[float, float]]:
@@ -114,7 +123,7 @@ def reduce_trace(trace: dict) -> dict:
     if not devices:
         raise ValueError("no operation ran on a device inside the trace")
     n = len(devices)
-    busy = kernel = 0.0
+    busy = kernel = feed = 0.0
     kernel_calls = op_events = 0
     k_bytes = k_flops = 0
     by_op: dict[str, float] = {}
@@ -127,6 +136,7 @@ def reduce_trace(trace: dict) -> dict:
         busy += sum(b - a for a, b in merged)
         leaves = [e for e in ops if e[0] not in CONTAINERS]
         op_events += len(leaves)
+        fed = set()  # the padded X shapes the kernel was called with
         for name, _, dur, text in leaves:
             by_op[name] = by_op.get(name, 0.0) + dur
             if KERNEL.search(name):
@@ -134,8 +144,15 @@ def reduce_trace(trace: dict) -> dict:
                 kernel_calls += 1
                 shape = kernel_operand(text)
                 if shape is not None:
+                    fed.add(shape)
                     k_bytes += kernel_bytes(*shape)
                     k_flops += kernel_flops(shape[0], shape[1])
+        # what makes the kernel's X: any other instruction whose result is an
+        # array of exactly a shape the kernel was called with (the pad of X
+        # to the kernel's tiles, a copy of it)
+        if fed:
+            feed += sum(dur for name, _, dur, text in leaves
+                        if not KERNEL.search(name) and result_shape(text) in fed)
         for text, _, dur in _clip(dev["modules"], lo, hi):
             key = instruction(text)
             by_module[key] = by_module.get(key, 0.0) + dur
@@ -160,6 +177,7 @@ def reduce_trace(trace: dict) -> dict:
         "kernel_calls": kernel_calls / n,
         "kernel_bytes": k_bytes / n,
         "kernel_flops": k_flops / n,
+        "kernel_feed_s": feed / ns,
         "op_events": op_events / n,
         "device_ops": ranked(by_op),
         "device_modules": ranked(by_module),

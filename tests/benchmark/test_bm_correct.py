@@ -2,30 +2,25 @@
 program at tiny size, the bfloat16-features control does not, and a run
 whose timed path is broken underneath comes out not correct."""
 
-import jax
 import numpy as np
 import pytest
 
 from benchmark import compare, run
 from benchmark.spans import Spans
-from bm_helpers import driver_and_reference, tiny_cell
+from bm_helpers import fit_and_compare, run_with_the_timed_path_broken, tiny_cell
 
 WORKLOAD = "glmix-ml20m.sweeps"
 
 
 def _fit_and_compare(seed: int, dtype: str = "float32"):
-    found = tiny_cell(WORKLOAD, feature_dtype=dtype)
-    driver, reference = driver_and_reference(found)
-    cell = driver.Cell(found["config"], found["traffic"], seed, jax.devices()[:1],
-                       Spans())
-    return cell.verify(reference, cell.episode())
+    return fit_and_compare(WORKLOAD, seed, dtype)
 
 
 @pytest.mark.parametrize("seed", [21, 2147483999])
 def test_reference_agrees_with_the_program(seed, capsys):
     sound = _fit_and_compare(seed)
     assert compare.judge(sound), sound
-    assert capsys.readouterr().out.count("compare[") == len(sound)  # each beside its limit
+    assert capsys.readouterr().err.count("compare[") == len(sound)  # each beside its limit
     assert [n for n, _, _ in sound][:2] == [
         "loss_own_coef_rel_gap", "val_margin_own_coef_max_gap"]
 
@@ -83,28 +78,15 @@ def _misreport_the_loss(produced):
     _misreport_the_loss,  # inside every limit but the one at own coefficients
 ])
 def test_a_run_with_the_timed_path_broken_is_not_correct(break_it, monkeypatch):
-    """Everything of a run but the look for a chip, on the CPU."""
-    from benchmark.manifest import load_manifest, load_module
-
-    found = tiny_cell(WORKLOAD)
-    driver = load_module(found["driver"])
-    if break_it is not None:
-        sound_episode = driver.Cell.episode
-
-        def broken(self):
-            self.last = break_it(sound_episode(self))
-            return self.last
-
-        monkeypatch.setattr(driver.Cell, "episode", broken)
-        import benchmark.manifest
-
-        monkeypatch.setattr(benchmark.manifest, "load_module", lambda path: (
-            driver if path == found["driver"] else load_module(path)))
-    result = run.run_cell(found, load_manifest(), seed=33, seconds=0.0, trace=False,
-                          devices=jax.devices()[:1])
+    found, result = run_with_the_timed_path_broken(WORKLOAD, break_it, monkeypatch, 33)
     assert result["correct"] is (break_it is None)
     assert result["attempted"] == found["traffic"]["min_episodes"]
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device",
+                            "compared"]  # every number beside its limit, last
+    assert all(set(v) == {"value", "limit"} for v in result["compared"].values())
+    assert result["correct"] is all(
+        v["value"] is not None and v["value"] <= v["limit"]
+        for v in result["compared"].values())
     assert all(v["value"] > 0 for v in result["metrics"].values())
 
 
@@ -116,6 +98,29 @@ def test_the_measured_path_refuses_to_run_without_a_chip(monkeypatch, capsys):
     assert code == run.EXIT_NO_CHIP != 0
     assert "{" not in captured.out  # no result line
     assert "no accelerator" in captured.err
+
+
+def test_the_chips_own_start_is_taken_out_of_setup(monkeypatch):
+    """``setup_s`` is process start to the first timed episode LESS the
+    seconds inside ``jax.devices()``: the TPU runtime's start, which grows
+    with the processes a machine has run and is none of the repository's."""
+    import time
+
+    import jax
+
+    real = jax.devices
+
+    def slow_devices(*args):
+        time.sleep(0.3)
+        return real(*args)
+
+    monkeypatch.setattr(jax, "devices", slow_devices)
+    monkeypatch.setattr(run, "_chip_start_s", 0.0)
+    assert run.accelerator(1) is None  # the CPU is no accelerator
+    assert 0.3 <= run._chip_start_s < 0.4
+    monkeypatch.setattr(run, "_chip_start_s", 1e6)
+    _, result = run_with_the_timed_path_broken(WORKLOAD, None, monkeypatch, 5)
+    assert result["metrics"]["setup_s"]["value"] < -1e5  # start to here, less 1e6
 
 
 class _StallingCell:

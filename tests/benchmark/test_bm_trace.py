@@ -83,7 +83,6 @@ def test_layer_metric_readers_on_the_reduced_trace(reduced):
            "device": {"kind": "TPU v5 lite", "memory_peak_bytes": 3 * 2**30},
            "counters": {"compiles_in_window": 0}}
     read = lambda name: layer_metric_reader(name)(ctx)  # noqa: E731
-    assert read("sweep_s") == pytest.approx(1.25)  # the warm sweep left out
     assert read("pack_s") == 3.0
     assert read("sweeps_solver_evals") == 1.0  # 2 calls over 2 sweeps
     assert read("episode_s") == pytest.approx(2.5)  # the median: a stall is not in it
@@ -92,7 +91,8 @@ def test_layer_metric_readers_on_the_reduced_trace(reduced):
     assert read("peak_hbm_GiB") == 3.0 and read("compiles_in_window") == 0
     least = 2 * roofline.kernel_bytes(4999168, 256, 4) / 819e9
     assert read("sweeps_glm_kernel_roofline") == pytest.approx(100 * least / 800e-9)
-    assert read("place_s") is None  # nothing to read: left out of the line
+    spans.closed[:] = [s for s in spans.closed if s[0] != "episode"]
+    assert read("episode_s") is None  # nothing to read: left out of the line
 
 
 def test_a_trace_without_device_work_or_window_is_refused(trace):
