@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 from photon_ml_tpu.data.batch import LabeledPointBatch
 from photon_ml_tpu.data.sparse_batch import SparseLabeledPointBatch, SparseShard
 from photon_ml_tpu.projector.projectors import (
@@ -161,18 +163,18 @@ def _pad_game_dataset_rows(dataset: GameDataset, pad: int) -> GameDataset:
     if pad == 0:
         return dataset
 
-    def padded_vec(name: str) -> tuple[np.ndarray, Array]:
+    # The padded fields are HOST arrays (and their own host cache): whoever
+    # consumes them places them — shard by shard over a mesh
+    # (parallel/mesh.place), so the padded copy of a data set that fills
+    # several chips is never whole on one.
+    def padded_vec(name: str) -> np.ndarray:
         arr = dataset.host_array(name)
-        out = np.concatenate([arr, np.zeros(pad, dtype=arr.dtype)])
-        return out, jnp.asarray(out)
+        return np.concatenate([arr, np.zeros(pad, dtype=arr.dtype)])
 
-    labels_h, labels_d = padded_vec("labels")
-    offsets_h, offsets_d = padded_vec("offsets")
     # weights pad with zeros — the whole point
-    weights_h, weights_d = padded_vec("weights")
-
+    host_cache = {name: padded_vec(name)
+                  for name in ("labels", "offsets", "weights")}
     shards: dict[str, object] = {}
-    host_cache = {"labels": labels_h, "offsets": offsets_h, "weights": weights_h}
     for k, v in dataset.feature_shards.items():
         if isinstance(v, SparseShard):
             # _coalesced survives (entries unchanged) but the hybrid split
@@ -182,20 +184,19 @@ def _pad_game_dataset_rows(dataset: GameDataset, pad: int) -> GameDataset:
                 _hybrid_cache=None,
             )
         else:
-            arr = np.asarray(v)
+            arr = dataset.host_array(f"shard/{k}")
             arr = np.concatenate(
                 [arr, np.zeros((pad, arr.shape[1]), dtype=arr.dtype)]
             )
-            shards[k] = jnp.asarray(arr)
-            host_cache[f"shard/{k}"] = arr
+            shards[k] = host_cache[f"shard/{k}"] = arr
 
-    entity_idx: dict[str, Array] = {}
-    for t, idx in dataset.entity_idx.items():
+    entity_idx: dict[str, np.ndarray] = {}
+    for t in dataset.entity_idx:
         arr = np.concatenate(
-            [np.asarray(idx), np.full(pad, -1, dtype=np.int32)]
+            [dataset.host_array(f"entity_idx/{t}"),
+             np.full(pad, -1, dtype=np.int32)]
         ).astype(np.int32)
-        entity_idx[t] = jnp.asarray(arr)
-        host_cache[f"entity_idx/{t}"] = arr
+        entity_idx[t] = host_cache[f"entity_idx/{t}"] = arr
 
     ids = {
         k: np.concatenate([np.asarray(v), np.zeros(pad, np.asarray(v).dtype)])
@@ -208,9 +209,9 @@ def _pad_game_dataset_rows(dataset: GameDataset, pad: int) -> GameDataset:
     return dataclasses.replace(
         dataset,
         unique_ids=unique_ids,
-        labels=labels_d,
-        offsets=offsets_d,
-        weights=weights_d,
+        labels=host_cache["labels"],
+        offsets=host_cache["offsets"],
+        weights=host_cache["weights"],
         feature_shards=shards,
         entity_idx=entity_idx,
         ids=ids,
@@ -663,9 +664,21 @@ def build_random_effect_dataset(
     projected_dim: int | None = None,
     features_to_samples_ratio: float | None = None,
     normalization=None,
+    mesh=None,
 ) -> RandomEffectDataset:
     """Group samples by entity into padded, size-bucketed blocks.
 
+    - mesh: the ("data", "model") mesh the blocks will be solved on. The
+      blocks are then packed FOR it: every bucket's lane count is a multiple
+      of the mesh's "data" axis (padding lanes inert: weight 0, sample rows
+      -1, an out-of-range entity row, the convention of
+      ``GameTrainProgram.shard_inputs``) and every block goes from the host
+      straight to the chips, each chip its own lanes — so ``shard_inputs``
+      finds them laid out and neither pads nor moves anything, and no chip
+      ever holds a whole block. Every row of an entity stays in ONE lane on
+      ONE chip: the exact fit, not the rank-local one of
+      :func:`build_random_effect_dataset_partitioned`. Without a mesh the
+      blocks go to the default device, as they always did.
     - upper bound: per-entity reservoir cap (stable-id keyed sampling),
       reference RandomEffectDataSet.scala:354-420 / MinHeapWithFixedCapacity.
     - lower bound: entities with fewer samples are excluded from training
@@ -787,13 +800,27 @@ def build_random_effect_dataset(
             )
 
         index_projected = projector_type == ProjectorType.INDEX_MAP
+        lane_multiple = 1 if mesh is None else int(mesh.shape["data"])
+        if mesh is not None:
+            from photon_ml_tpu.parallel.mesh import place  # imports this package
+
+        def resident(block: np.ndarray) -> Array:
+            if mesh is None:
+                return jnp.asarray(block)
+            spec = P("data", *([None] * (block.ndim - 1)))
+            return place(block, NamedSharding(mesh, spec), group="buckets")
+
         buckets: list[EntityBucket] = []
         for cap, members in per_bucket.items():
             if not members:
                 continue
             with span("pack/bucket", cap=cap, entities=len(members)):
-                e = len(members)
                 be, rows_concat, lane, slot = pack_bucket_lanes(members)
+                # lanes past the members are padding: their entity row is out
+                # of every table's range (gathers clamp, scatters drop)
+                e = -(-len(members) // lane_multiple) * lane_multiple
+                be = np.concatenate([be, np.full(
+                    e - len(members), np.iinfo(np.int32).max, np.int32)])
                 bl = np.zeros((e, cap), dtype=labels.dtype)
                 bw = np.zeros((e, cap), dtype=weights.dtype)
                 bs = np.full((e, cap), -1, dtype=np.int32)
@@ -807,7 +834,8 @@ def build_random_effect_dataset(
                 x = features[rows_concat]
                 if features_to_samples_ratio is not None:
                     keep = _pearson_keep_masks_grouped(
-                        x, labels[rows_concat], lane, e, features_to_samples_ratio
+                        x, labels[rows_concat], lane, len(members),
+                        features_to_samples_ratio,
                     )
                     x = x * keep[lane]
 
@@ -823,12 +851,12 @@ def build_random_effect_dataset(
                     bf[lane, slot] = x
                 buckets.append(
                     EntityBucket(
-                        features=jnp.asarray(bf),
-                        labels=jnp.asarray(bl),
-                        weights=jnp.asarray(bw),
-                        entity_rows=jnp.asarray(be),
-                        sample_rows=jnp.asarray(bs),
-                        col_index=None if bc is None else jnp.asarray(bc),
+                        features=resident(bf),
+                        labels=resident(bl),
+                        weights=resident(bw),
+                        entity_rows=resident(be),
+                        sample_rows=resident(bs),
+                        col_index=None if bc is None else resident(bc),
                     )
                 )
 

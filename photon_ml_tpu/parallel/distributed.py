@@ -60,6 +60,7 @@ from photon_ml_tpu.optim.common import (
     lane_solver_counts,
 )
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, solve
+from photon_ml_tpu.parallel.mesh import place
 from photon_ml_tpu.telemetry.program_ledger import ledger_jit
 from photon_ml_tpu.telemetry.registry import default_registry
 from photon_ml_tpu.telemetry.tracing import span
@@ -154,6 +155,24 @@ class MatrixFactorizationStepSpec:
     seed: int = 0
 
 
+def _input_leaf(x):
+    """A data-set field as the step's input. A host array stays on the host:
+    ``shard_inputs`` cuts it there and sends every shard straight to its
+    device (``train_distributed`` without a mesh commits it to the default
+    device once), so that nothing of global size is assembled on one chip.
+    An array that is laid out already keeps its layout."""
+    return x if isinstance(x, np.ndarray) else jnp.asarray(x)
+
+
+def _pad_leading(x, pad: int, fill=0):
+    """``x`` with ``pad`` more entries of ``fill`` along its first axis: on
+    the host where ``x`` is a host array, so that the padded copy is never
+    whole on one device."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    return xp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                  constant_values=fill)
+
+
 def _data_pytree(dataset: GameDataset, re_specs: Sequence[RandomEffectStepSpec],
                  fe_shard: str,
                  mf_specs: Sequence[MatrixFactorizationStepSpec] = (),
@@ -184,28 +203,27 @@ def _data_pytree(dataset: GameDataset, re_specs: Sequence[RandomEffectStepSpec],
                     "shard (additional fixed effects are dense-only; make "
                     "the sparse one the primary)"
                 )
-    labels = jnp.asarray(dataset.labels)
-    weights = jnp.asarray(dataset.weights)
     data = {
-        "labels": labels,
-        "offsets": jnp.asarray(dataset.offsets),
-        "weights": weights,
+        "labels": _input_leaf(dataset.labels),
+        "offsets": _input_leaf(dataset.offsets),
+        "weights": _input_leaf(dataset.weights),
         "features": {
-            k: jnp.asarray(dataset.feature_shards[k])
+            k: _input_leaf(dataset.feature_shards[k])
             for k in shards
             if not isinstance(dataset.feature_shards[k], SparseShard)
         },
         "entity_idx": {
-            t: jnp.asarray(dataset.entity_idx[t]) for t in sorted(id_types)
+            t: _input_leaf(dataset.entity_idx[t]) for t in sorted(id_types)
         },
     }
     if fe_sparse:
         # flat-COO FE batch: offsets filled per step (residual scores);
         # the static `dim` rides the pytree treedef, so sparse-vs-dense is
         # a compile-time branch in the step
+        labels = jnp.asarray(dataset.labels)
         data["fe_sparse_batch"] = SparseLabeledPointBatch.from_shard(
             dataset.feature_shards[fe_shard], labels,
-            jnp.zeros_like(labels), weights,
+            jnp.zeros_like(labels), jnp.asarray(dataset.weights),
         )
     return data
 
@@ -637,11 +655,12 @@ class GameTrainProgram:
         return data, buckets
 
     def _shard_data(self, mesh: Mesh, data, *, fe_feature_sharded: bool = False,
-                    put_fn=None):
+                    put_fn=None, group: str = "data"):
         """Lay a data pytree (training or scoring) out over the mesh:
         sample-axis arrays over "data", the FE feature axis over "model"
-        when requested."""
-        put = put_fn if put_fn is not None else jax.device_put
+        when requested. Through :func:`parallel.mesh.place`: what is laid
+        out already stays, host arrays go shard by shard."""
+        put = partial(place, put=put_fn or jax.device_put, group=group)
         vec = NamedSharding(mesh, P("data"))
         data_axis = int(mesh.shape["data"])
         fe_fspec = P("data", "model") if fe_feature_sharded else P("data", None)
@@ -730,7 +749,8 @@ class GameTrainProgram:
         put_fn: placement function (array, sharding) -> Array. Defaults to
         jax.device_put; pass parallel.multihost.global_put when the mesh
         spans multiple processes (each feeds its addressable shards)."""
-        put = put_fn if put_fn is not None else jax.device_put
+        put = partial(place, put=put_fn or jax.device_put, group="buckets")
+        put_state = partial(place, put=put_fn or jax.device_put, group="state")
         rep = NamedSharding(mesh, P())
         data_axis = int(mesh.shape["data"])
         with span("train/shard/data"):
@@ -744,7 +764,9 @@ class GameTrainProgram:
         ent1 = NamedSharding(mesh, P("data"))
 
         def put_bucket(b: dict) -> dict:
-            # Pad the entity axis to a multiple of the mesh "data" axis.
+            # Pad the entity axis to a multiple of the mesh "data" axis
+            # (nothing to do for buckets packed for this mesh:
+            # build_random_effect_dataset(mesh=...) pads the lanes itself).
             # Padding lanes carry weight 0 and an out-of-range entity row:
             # JAX clamps out-of-bounds gathers (warm-start reads are junk but
             # harmless) and DROPS out-of-bounds scatter updates, so padded
@@ -752,24 +774,12 @@ class GameTrainProgram:
             e = int(b["entity_rows"].shape[0])
             pad = (-e) % data_axis
             if pad:
-                b = dict(b)
-                b["labels"] = jnp.pad(b["labels"], ((0, pad), (0, 0)))
-                b["weights"] = jnp.pad(b["weights"], ((0, pad), (0, 0)))
-                b["sample_rows"] = jnp.pad(
-                    b["sample_rows"], ((0, pad), (0, 0)), constant_values=-1
-                )
-                b["entity_rows"] = jnp.pad(
-                    b["entity_rows"], (0, pad),
-                    constant_values=jnp.iinfo(jnp.int32).max,
-                )
-                if "features" in b:
-                    b["features"] = jnp.pad(
-                        b["features"], ((0, pad), (0, 0), (0, 0))
-                    )
-                if "col_index" in b:
-                    # padded lanes' entity_rows are OOB, so the whole 2-D
-                    # scatter row drops regardless of these column values
-                    b["col_index"] = jnp.pad(b["col_index"], ((0, pad), (0, 0)))
+                # padded lanes' entity_rows are OOB, so the whole 2-D
+                # scatter row of col_index drops whatever its column values
+                fills = {"sample_rows": -1,
+                         "entity_rows": np.iinfo(np.int32).max}
+                b = {k: _pad_leading(v, pad, fills.get(k, 0))
+                     for k, v in b.items()}
             out = {
                 "labels": put(b["labels"], ent2),
                 "weights": put(b["weights"], ent2),
@@ -808,20 +818,21 @@ class GameTrainProgram:
             # are real rows), and are sliced off again on exit
             pad = (-int(v.shape[0])) % data_axis
             if pad:
-                v = jnp.pad(v, ((0, pad), (0, 0)))
-            return put(v, ent2)
+                v = _pad_leading(v, pad)
+            return put_state(v, ent2)
 
         fe_sharding = NamedSharding(mesh, P("model")) if fe_feature_sharded else rep
         with span("train/shard/state"):
             state = GameTrainState(
-                fe_coefficients=put(state.fe_coefficients, fe_sharding),
+                fe_coefficients=put_state(state.fe_coefficients, fe_sharding),
                 re_tables={k: put_table(v)
                            for k, v in state.re_tables.items()},
                 mf_rows={k: put_table(v) for k, v in state.mf_rows.items()},
                 mf_cols={k: put_table(v) for k, v in state.mf_cols.items()},
                 # extra FE vectors replicate (only the primary may
                 # feature-shard)
-                extra_fe={k: put(v, rep) for k, v in state.extra_fe.items()},
+                extra_fe={k: put_state(v, rep)
+                          for k, v in state.extra_fe.items()},
             )
         return data, sharded_buckets, state
 
@@ -992,7 +1003,8 @@ class GameTrainProgram:
     def shard_scoring_inputs(self, mesh: Mesh, data, *,
                              fe_feature_sharded: bool = False, put_fn=None):
         return self._shard_data(
-            mesh, data, fe_feature_sharded=fe_feature_sharded, put_fn=put_fn
+            mesh, data, fe_feature_sharded=fe_feature_sharded, put_fn=put_fn,
+            group="validation",
         )
 
     def score(self, data, state: GameTrainState) -> Array:
@@ -1878,11 +1890,23 @@ def _record_shard_spread(group: str, tree) -> None:
 
 def _record_placed_bytes(*trees) -> None:
     """``train/placed_bytes`` += the bytes of every array ``shard_inputs``
-    laid out over the mesh (metadata only: no transfer, no sync)."""
-    default_registry().counter("train/placed_bytes").inc(sum(
-        int(x.nbytes) for x in jax.tree_util.tree_leaves(trees)
-        if isinstance(x, jax.Array)
-    ))
+    laid out over the mesh; ``mesh/placed_bytes/max_device`` and
+    ``.../min_device`` = the most and the fewest of those bytes any one
+    local device was handed (equal when the chips share every array; the
+    whole of it on one and nothing on the rest when they do not). Metadata
+    only: no transfer, no sync."""
+    leaves = [x for x in jax.tree_util.tree_leaves(trees)
+              if isinstance(x, jax.Array)]
+    reg = default_registry()
+    reg.counter("train/placed_bytes").inc(sum(int(x.nbytes) for x in leaves))
+    held: dict[int, int] = {}
+    for x in leaves:
+        share = int(np.prod(x.sharding.shard_shape(x.shape))) * x.dtype.itemsize
+        for device in x.sharding.addressable_devices:
+            held[device.id] = held.get(device.id, 0) + share
+    if held:
+        reg.gauge("mesh/placed_bytes/max_device").set(max(held.values()))
+        reg.gauge("mesh/placed_bytes/min_device").set(min(held.values()))
 
 
 # -- the spans and counters of a fit ------------------------------------------
@@ -2220,6 +2244,10 @@ def train_distributed(
                         mesh, val_data, fe_feature_sharded=fe_feature_sharded,
                         put_fn=put_fn,
                     )
+        else:
+            # no mesh to lay host arrays out over: commit them to the default
+            # device once, not on every sweep's call of the step
+            data, val_data = jax.device_put((data, val_data))
 
         if val_data is not None and mesh is not None:
             # device twins of the evaluators (evaluation/sharded.py): consts

@@ -28,6 +28,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.data.batch import LabeledPointBatch
+from photon_ml_tpu.telemetry.tracing import span
 
 
 def make_mesh(
@@ -44,6 +45,24 @@ def make_mesh(
         devices = devices[: data * model]
     grid = np.array(devices).reshape(data, model)
     return Mesh(grid, axis_names=("data", "model"))
+
+
+def place(x, sharding, *, put=jax.device_put, group: str = "data"):
+    """``x`` laid out as ``sharding``; the one way inputs reach a mesh.
+
+    An array that already has the layout comes back untouched, so placing
+    resident inputs moves nothing. A HOST array is cut on the host and every
+    shard goes straight to its own device (``jax.device_put`` of a numpy
+    array does that; ``multihost.global_put`` across processes), under the
+    span ``train/shard/assemble`` {group, bytes}: no device ever holds more
+    than its share of it. Anything else (a device array in another layout)
+    is ``put`` as it always was."""
+    if isinstance(x, jax.Array) and x.sharding == sharding:
+        return x
+    if isinstance(x, np.ndarray):
+        with span("train/shard/assemble", group=group, bytes=int(x.nbytes)):
+            return put(x, sharding)
+    return put(x, sharding)
 
 
 def replicate(tree, mesh: Mesh):
@@ -74,7 +93,9 @@ def shard_batch(batch: LabeledPointBatch, mesh: Mesh, *, feature_sharded: bool =
 def shard_game_dataset(dataset, mesh: Mesh):
     """Shard a GameDataset's sample-axis arrays over "data". Entity-bucket
     tensors shard their entity axis over "data" when solved (the vmapped
-    solver's batch dimension)."""
+    solver's batch dimension). Fields that are host arrays go shard by shard
+    (:func:`place`): a data set larger than one chip is laid out over four
+    without ever being whole on one."""
     vspec = NamedSharding(mesh, P("data"))
 
     n = dataset.num_samples
@@ -86,15 +107,15 @@ def shard_game_dataset(dataset, mesh: Mesh):
         )
     dataset = dataclasses.replace(
         dataset,
-        labels=jax.device_put(dataset.labels, vspec),
-        offsets=jax.device_put(dataset.offsets, vspec),
-        weights=jax.device_put(dataset.weights, vspec),
+        labels=place(dataset.labels, vspec),
+        offsets=place(dataset.offsets, vspec),
+        weights=place(dataset.weights, vspec),
         feature_shards={
-            k: jax.device_put(v, NamedSharding(mesh, P("data", None)))
+            k: place(v, NamedSharding(mesh, P("data", None)))
             for k, v in dataset.feature_shards.items()
         },
         entity_idx={
-            k: jax.device_put(v, vspec) for k, v in dataset.entity_idx.items()
+            k: place(v, vspec) for k, v in dataset.entity_idx.items()
         },
     )
     return dataset
