@@ -1531,10 +1531,9 @@ def train_glm_streaming(
             )
         resume_state = None
         if li == start_index and resume_state_arrays is not None:
-            cls = solver_state_class(opt)
-            resume_state = cls(**{
-                k: jnp.asarray(v) for k, v in resume_state_arrays.items()
-            })
+            resume_state = checkpointer.solver_state(
+                solver_state_class(opt), resume_state_arrays
+            )
             objective.epochs = resume_epochs_lambda
         observers = []
         if telemetry is not None:

@@ -48,6 +48,7 @@ import zipfile
 from typing import Any, Mapping
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 logger = logging.getLogger(__name__)
@@ -654,3 +655,23 @@ class SolverCheckpointer:
             completed=completed,
             state_arrays=state_arrays,
         )
+
+    def solver_state(self, cls, state_arrays: dict):
+        """The solver state class ``cls`` rebuilt from a restored snapshot's
+        ``state_arrays``. Raises ValueError naming the differing fields when
+        the snapshot holds another version's state (one written before the
+        L-BFGS history was kept in order has ``head``): a field list that
+        differs means a layout that differs, and no snapshot is converted
+        or reused under another layout."""
+        saved = set(state_arrays)
+        expected = {f.name for f in dataclasses.fields(cls)}
+        if saved != expected:
+            raise ValueError(
+                f"streaming checkpoint at {self.directory} holds a solver "
+                f"state whose fields are not {cls.__name__}'s (only in the "
+                f"checkpoint: {sorted(saved - expected)}, only in this "
+                f"version: {sorted(expected - saved)}): it was written by "
+                "another version of the solver; use a fresh checkpoint "
+                "directory"
+            )
+        return cls(**{k: jnp.asarray(v) for k, v in state_arrays.items()})

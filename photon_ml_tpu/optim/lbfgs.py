@@ -6,11 +6,17 @@ projection after each step, LBFGS.scala:70-76).
 
 TPU-native design: the whole solve — two-loop recursion, line search,
 convergence tests — is one ``lax.while_loop`` inside one XLA program. State
-is a pytree with fixed shapes (circular [m, d] history buffers), so the
-solver jits once, reuses the compiled program across coordinate-descent
-iterations and λ-grid points, and vmaps over entities for random-effect
-coordinates (replacing RandomEffectCoordinate.scala:104-153's per-entity
-breeze solves).
+is a pytree with fixed shapes, so the solver jits once, reuses the compiled
+program across coordinate-descent iterations and λ-grid points, and vmaps
+over entities for random-effect coordinates (replacing
+RandomEffectCoordinate.scala:104-153's per-entity breeze solves).
+
+The [m, d] pair history is kept IN ORDER, newest pair at slot 0: every slot
+the two-loop recursion reads or writes is its loop counter, the same for
+every lane of a vmapped solve, so a read is one slice of all the lanes'
+histories and never a gather with an index a lane (a circular buffer's
+newest slot differs lane by lane; PERF.md §6, PR 28). Keeping a pair costs
+one dense shift of the history: 2·m·d floats read and written an iteration.
 """
 
 from __future__ import annotations
@@ -38,43 +44,73 @@ DEFAULT_TOLERANCE = 1e-7
 
 
 def two_loop_direction(
-    g: Array, s_hist: Array, y_hist: Array, rho: Array, count: Array, head: Array
+    g: Array, s_hist: Array, y_hist: Array, rho: Array, count: Array
 ) -> Array:
-    """L-BFGS two-loop recursion over a circular history buffer.
+    """L-BFGS two-loop recursion over an ordered history.
 
-    s_hist/y_hist: [m, d]; rho: [m] (1/sᵀy); count: number of valid pairs;
-    head: slot of the most recent pair. Invalid slots are masked by zeroing
-    their alpha/beta contributions, keeping shapes static for jit.
+    s_hist/y_hist: [m, d]; rho: [m] (1/sᵀy); slot 0 holds the newest pair,
+    slot k the pair k steps back; count: number of valid pairs. Slots from
+    ``count`` on are masked by zeroing their alpha/beta contributions, keeping
+    shapes static for jit. Every slot index is a loop counter: under ``vmap``
+    it is the same for all lanes, a slice of the lanes' histories.
     """
     m = s_hist.shape[0]
 
-    def backward(i, carry):
+    def slot(x, k):
+        return lax.dynamic_index_in_dim(x, k, keepdims=False)
+
+    def backward(k, carry):
+        # newest to oldest
         q, alphas = carry
-        idx = (head - i) % m
-        valid = i < count
-        alpha = jnp.where(valid, rho[idx] * jnp.vdot(s_hist[idx], q), 0.0)
-        q = q - alpha * y_hist[idx]
-        return q, alphas.at[idx].set(alpha)
+        alpha = jnp.where(k < count, slot(rho, k) * jnp.vdot(slot(s_hist, k), q), 0.0)
+        q = q - alpha * slot(y_hist, k)
+        return q, lax.dynamic_update_index_in_dim(alphas, alpha, k, 0)
 
     q, alphas = lax.fori_loop(0, m, backward, (g, jnp.zeros((m,), dtype=g.dtype)))
 
     gamma = jnp.where(
         count > 0,
-        jnp.vdot(s_hist[head], y_hist[head])
-        / jnp.maximum(jnp.vdot(y_hist[head], y_hist[head]), 1e-30),
+        jnp.vdot(s_hist[0], y_hist[0])
+        / jnp.maximum(jnp.vdot(y_hist[0], y_hist[0]), 1e-30),
         1.0,
     )
     r = gamma * q
 
     def forward(i, r):
-        # oldest-to-newest among valid entries
-        idx = (head - (count - 1 - i)) % m
-        valid = i < count
-        beta = rho[idx] * jnp.vdot(y_hist[idx], r)
-        return r + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_hist[idx]
+        # oldest to newest: the empty slots come first and add nothing
+        k = m - 1 - i
+        beta = slot(rho, k) * jnp.vdot(slot(y_hist, k), r)
+        return r + jnp.where(k < count, slot(alphas, k) - beta, 0.0) * slot(s_hist, k)
 
     r = lax.fori_loop(0, m, forward, r)
     return -r
+
+
+def push_pair(
+    s_hist: Array,
+    y_hist: Array,
+    rho: Array,
+    count: Array,
+    s: Array,
+    y: Array,
+    accepted: Array,
+) -> tuple[Array, Array, Array, Array]:
+    """The history after a step (s, y): where the step was accepted and its
+    curvature sᵀy is positive, every pair moves one slot back (the oldest
+    falls off) and the new one takes slot 0; else the history stays."""
+    m = s_hist.shape[0]
+    sy = jnp.vdot(s, y)
+    keep_pair = accepted & (sy > 1e-10)
+
+    def pushed(hist, new):
+        return jnp.where(keep_pair, jnp.concatenate([new[None], hist[:-1]]), hist)
+
+    return (
+        pushed(s_hist, s),
+        pushed(y_hist, y),
+        pushed(rho, 1.0 / jnp.maximum(sy, 1e-30)),
+        jnp.where(keep_pair, jnp.minimum(count + 1, m), count),
+    )
 
 
 @flax.struct.dataclass
@@ -86,7 +122,6 @@ class _LBFGSState:
     y_hist: Array
     rho: Array
     count: Array
-    head: Array
     iteration: Array
     reason: Array
     prev_f: Array
@@ -179,7 +214,6 @@ def minimize_lbfgs(
             y_hist=jnp.zeros((m, d), dtype),
             rho=jnp.zeros((m,), dtype),
             count=jnp.int32(0),
-            head=jnp.int32(0),
             iteration=jnp.int32(0),
             reason=jnp.int32(ConvergenceReason.NOT_CONVERGED),
             prev_f=jnp.asarray(jnp.inf, dtype),
@@ -210,7 +244,7 @@ def minimize_lbfgs(
         # lock-step loop open. Un-vmapped, ``cond`` guarantees it.
         live = state.reason == ConvergenceReason.NOT_CONVERGED
         direction = two_loop_direction(
-            state.g, state.s_hist, state.y_hist, state.rho, state.count, state.head
+            state.g, state.s_hist, state.y_hist, state.rho, state.count
         )
         if has_box:
             # Active-set masking: don't push into an active bound
@@ -284,27 +318,15 @@ def minimize_lbfgs(
             ls_success = ls.success
             ls_trials, ls_floor_exit = ls.trials, ls.floor_exit
 
-        s = w_new - state.w
-        y = g_new - state.g
-        sy = jnp.vdot(s, y)
-        keep_pair = ls_success & (sy > 1e-10)
-
-        new_head = jnp.where(keep_pair, (state.head + 1) % m, state.head)
-        # count==0 means head slot 0 is where the first pair goes
-        write_head = jnp.where(state.count == 0, jnp.int32(0), new_head)
-        new_head = jnp.where(state.count == 0, jnp.int32(0), new_head)
-        s_hist = jnp.where(
-            keep_pair, state.s_hist.at[write_head].set(s), state.s_hist
-        )
-        y_hist = jnp.where(
-            keep_pair, state.y_hist.at[write_head].set(y), state.y_hist
-        )
-        rho = jnp.where(
-            keep_pair,
-            state.rho.at[write_head].set(1.0 / jnp.maximum(sy, 1e-30)),
+        s_hist, y_hist, rho, count = push_pair(
+            state.s_hist,
+            state.y_hist,
             state.rho,
+            state.count,
+            w_new - state.w,
+            g_new - state.g,
+            ls_success,
         )
-        count = jnp.where(keep_pair, jnp.minimum(state.count + 1, m), state.count)
 
         gnorm = projected_grad_norm(w_new, g_new)
         reason = jnp.where(
@@ -329,7 +351,6 @@ def minimize_lbfgs(
             y_hist=y_hist,
             rho=rho,
             count=count,
-            head=new_head,
             iteration=it,
             reason=reason,
             prev_f=state.f,

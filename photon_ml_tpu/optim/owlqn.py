@@ -5,7 +5,7 @@ wrapper; mutable l1RegularizationWeight for the elastic-net regularization
 path). The L2 part of elastic net stays in the smooth objective; this solver
 adds λ₁‖w‖₁ via the pseudo-gradient and orthant projection (Andrew & Gao 2007).
 
-Jittable: one lax.while_loop, fixed-shape circular L-BFGS history, masked
+Jittable: one lax.while_loop, fixed-shape ordered L-BFGS history, masked
 projection — vmaps over entities like the plain L-BFGS solver.
 """
 
@@ -25,7 +25,7 @@ from photon_ml_tpu.optim.common import (
     no_line_search_counts,
     run_while,
 )
-from photon_ml_tpu.optim.lbfgs import two_loop_direction
+from photon_ml_tpu.optim.lbfgs import push_pair, two_loop_direction
 
 Array = jax.Array
 
@@ -54,7 +54,6 @@ class _OWLQNState:
     y_hist: Array
     rho: Array
     count: Array
-    head: Array
     iteration: Array
     reason: Array
     g0_norm: Array
@@ -120,7 +119,6 @@ def minimize_owlqn(
             y_hist=jnp.zeros((m, d), dtype),
             rho=jnp.zeros((m,), dtype),
             count=jnp.int32(0),
-            head=jnp.int32(0),
             iteration=jnp.int32(0),
             reason=jnp.where(
                 g0_norm <= tolerance,
@@ -140,7 +138,7 @@ def minimize_owlqn(
     def body(state: _OWLQNState):
         pg = pseudo_gradient(state.w, state.g, l1)
         direction = two_loop_direction(
-            pg, state.s_hist, state.y_hist, state.rho, state.count, state.head
+            pg, state.s_hist, state.y_hist, state.rho, state.count
         )
         # Constrain direction to the descent orthant of -pg.
         direction = jnp.where(direction * (-pg) > 0.0, direction, 0.0)
@@ -187,24 +185,15 @@ def minimize_owlqn(
             host=host_loop,
         )
 
-        s = w_new - state.w
-        y = g_new - state.g  # smooth gradients, per Andrew & Gao
-        sy = jnp.vdot(s, y)
-        keep_pair = ls_ok & (sy > 1e-10)
-
-        new_head = jnp.where(
-            state.count == 0, jnp.int32(0), (state.head + 1) % m
-        )
-        new_head = jnp.where(keep_pair, new_head, state.head)
-        write_head = jnp.where(state.count == 0, jnp.int32(0), (state.head + 1) % m)
-        s_hist = jnp.where(keep_pair, state.s_hist.at[write_head].set(s), state.s_hist)
-        y_hist = jnp.where(keep_pair, state.y_hist.at[write_head].set(y), state.y_hist)
-        rho = jnp.where(
-            keep_pair,
-            state.rho.at[write_head].set(1.0 / jnp.maximum(sy, 1e-30)),
+        s_hist, y_hist, rho, count = push_pair(
+            state.s_hist,
+            state.y_hist,
             state.rho,
+            state.count,
+            w_new - state.w,
+            g_new - state.g,  # smooth gradients, per Andrew & Gao
+            ls_ok,
         )
-        count = jnp.where(keep_pair, jnp.minimum(state.count + 1, m), state.count)
 
         pg_new = pseudo_gradient(w_new, g_new, l1)
         gnorm = jnp.linalg.norm(pg_new)
@@ -230,7 +219,6 @@ def minimize_owlqn(
             y_hist=y_hist,
             rho=rho,
             count=count,
-            head=new_head,
             iteration=it,
             reason=reason,
             g0_norm=state.g0_norm,

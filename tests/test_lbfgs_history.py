@@ -1,0 +1,479 @@
+"""The L-BFGS pair history is kept in order, newest pair first (PR 28).
+
+The circular layout that ``optim/lbfgs.py`` and ``optim/owlqn.py`` had until
+then lives on HERE, copied as it stood, as the oracle: the ordered layout must
+give the same direction BIT FOR BIT from the same stream of pairs, in one
+process, un-vmapped and under ``vmap`` (where the circular layout's slots
+differed lane by lane and every read was a gather). Whole solves are held to
+what the parent commit returned, recorded below. A structural guard keeps
+gathers and scatters with an index a lane out of the vmapped recursion, and
+every gather and scatter out of the vmapped write.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from photon_ml_tpu.optim.lbfgs import minimize_lbfgs, push_pair, two_loop_direction
+from photon_ml_tpu.optim.owlqn import minimize_owlqn
+
+M = 10
+D = 16
+
+
+# -- the oracle: the circular layout, as optim/lbfgs.py had it ---------------
+
+
+def _circular_two_loop_direction(g, s_hist, y_hist, rho, count, head):
+    m = s_hist.shape[0]
+
+    def backward(i, carry):
+        q, alphas = carry
+        idx = (head - i) % m
+        valid = i < count
+        alpha = jnp.where(valid, rho[idx] * jnp.vdot(s_hist[idx], q), 0.0)
+        q = q - alpha * y_hist[idx]
+        return q, alphas.at[idx].set(alpha)
+
+    q, alphas = lax.fori_loop(0, m, backward, (g, jnp.zeros((m,), dtype=g.dtype)))
+
+    gamma = jnp.where(
+        count > 0,
+        jnp.vdot(s_hist[head], y_hist[head])
+        / jnp.maximum(jnp.vdot(y_hist[head], y_hist[head]), 1e-30),
+        1.0,
+    )
+    r = gamma * q
+
+    def forward(i, r):
+        # oldest-to-newest among valid entries
+        idx = (head - (count - 1 - i)) % m
+        valid = i < count
+        beta = rho[idx] * jnp.vdot(y_hist[idx], r)
+        return r + jnp.where(valid, (alphas[idx] - beta), 0.0) * s_hist[idx]
+
+    r = lax.fori_loop(0, m, forward, r)
+    return -r
+
+
+def _circular_write(s_hist, y_hist, rho, count, head, s, y, ls_success):
+    m = s_hist.shape[0]
+    sy = jnp.vdot(s, y)
+    keep_pair = ls_success & (sy > 1e-10)
+
+    new_head = jnp.where(keep_pair, (head + 1) % m, head)
+    # count==0 means head slot 0 is where the first pair goes
+    write_head = jnp.where(count == 0, jnp.int32(0), new_head)
+    new_head = jnp.where(count == 0, jnp.int32(0), new_head)
+    s_hist = jnp.where(keep_pair, s_hist.at[write_head].set(s), s_hist)
+    y_hist = jnp.where(keep_pair, y_hist.at[write_head].set(y), y_hist)
+    rho = jnp.where(
+        keep_pair,
+        rho.at[write_head].set(1.0 / jnp.maximum(sy, 1e-30)),
+        rho,
+    )
+    count = jnp.where(keep_pair, jnp.minimum(count + 1, m), count)
+    return s_hist, y_hist, rho, count, new_head
+
+
+# -- one stream of pairs through both layouts --------------------------------
+
+
+def _empty():
+    return (
+        jnp.zeros((M, D), jnp.float32),
+        jnp.zeros((M, D), jnp.float32),
+        jnp.zeros((M,), jnp.float32),
+        jnp.int32(0),
+    )
+
+
+def _stream(seed, n_pairs):
+    """n_pairs steps (s, y) of positive curvature and a gradient to turn after
+    each: float32, nothing exactly zero."""
+    rng = np.random.default_rng(seed)
+    s = rng.normal(size=(n_pairs, D)).astype(np.float32)
+    scale = rng.uniform(0.5, 2.0, size=(n_pairs, 1)).astype(np.float32)
+    y = scale * s + 0.1 * rng.normal(size=(n_pairs, D)).astype(np.float32)
+    g = rng.normal(size=(n_pairs + 1, D)).astype(np.float32)
+    return jnp.asarray(s), jnp.asarray(y), jnp.asarray(g)
+
+
+def _run_both(s, y, g, accepted):
+    """Feed the stream to both layouts; the direction after every step (and
+    before the first) from each, and the final counts."""
+
+    def step(carry, xs):
+        ordered, circular = carry
+        s_t, y_t, g_t, ok = xs
+        ordered = push_pair(*ordered, s_t, y_t, ok)
+        circular = _circular_write(*circular, s_t, y_t, ok)
+        return (ordered, circular), (
+            two_loop_direction(g_t, *ordered),
+            _circular_two_loop_direction(g_t, *circular),
+        )
+
+    init = (_empty(), _empty() + (jnp.int32(0),))
+    first = (
+        two_loop_direction(g[0], *init[0]),
+        _circular_two_loop_direction(g[0], *init[1]),
+    )
+    (ordered, circular), rest = lax.scan(step, init, (s, y, g[1:], accepted))
+    return first, rest, ordered[3], circular[3]
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _assert_same_bits(ordered, circular):
+    assert np.asarray(ordered).dtype == np.float32
+    assert np.isfinite(np.asarray(ordered)).all()
+    assert np.array_equal(_bits(ordered), _bits(circular))
+
+
+# count 0 (no pair at all), part full, full and wrapped, pairs skipped
+CASES = {
+    "count0": (0, ()),
+    "count1": (1, ()),
+    "count3": (3, ()),
+    "count9": (9, ()),
+    "wrapped15": (15, ()),
+    "wrapped23": (23, ()),
+    "skipped_part_full": (8, (2, 3, 6)),
+    "skipped_wrapped": (19, (0, 7, 8, 12, 18)),
+}
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_direction_is_bitwise_the_circular_layouts(name):
+    n_pairs, skipped = CASES[name]
+    s, y, g = _stream(seed=28 + n_pairs, n_pairs=n_pairs)
+    accepted = jnp.ones((n_pairs,), bool).at[jnp.asarray(skipped, jnp.int32)].set(False)
+    first, rest, count, count_circular = jax.jit(_run_both)(s, y, g, accepted)
+    _assert_same_bits(*first)
+    # no history yet: the direction is the negated gradient itself
+    assert np.array_equal(_bits(first[0]), _bits(-g[0]))
+    if n_pairs:
+        _assert_same_bits(*rest)
+    assert int(count) == int(count_circular) == min(n_pairs - len(skipped), M)
+
+
+def test_zero_curvature_pair_is_dropped_in_both_layouts():
+    s, y, g = _stream(seed=5, n_pairs=6)
+    y = y.at[2].set(-y[2]).at[4].set(0.0)  # sᵀy < 0, sᵀy = 0: neither is kept
+    first, rest, count, count_circular = jax.jit(_run_both)(
+        s, y, g, jnp.ones((6,), bool)
+    )
+    _assert_same_bits(*rest)
+    assert int(count) == int(count_circular) == 4
+
+
+LANES = 7
+
+
+def test_direction_is_bitwise_under_vmap_over_lanes_that_differ():
+    """Seven lanes, each at another count and with other steps refused: the
+    circular layout's newest slot differs lane by lane, the ordered one's
+    never does."""
+    n_pairs = 14
+    streams = [_stream(seed=100 + lane, n_pairs=n_pairs) for lane in range(LANES)]
+    s, y, g = (jnp.stack(x) for x in zip(*streams))
+    rng = np.random.default_rng(7)
+    # lane 0 refuses every step, lane 6 none
+    accepted = rng.uniform(size=(LANES, n_pairs)) < np.linspace(0.0, 1.0, LANES)[:, None]
+    first, rest, count, count_circular = jax.jit(jax.vmap(_run_both))(
+        s, y, g, jnp.asarray(accepted)
+    )
+    _assert_same_bits(*first)
+    _assert_same_bits(*rest)
+    assert np.array_equal(np.asarray(count), np.asarray(count_circular))
+    assert np.array_equal(np.asarray(count), np.minimum(accepted.sum(axis=1), M))
+    assert len(set(np.asarray(count).tolist())) >= 5  # the lanes really differ
+
+
+# -- no gather, no scatter with an index a lane -------------------------------
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _indexed(jaxpr):
+    """(primitive, shape of its indices) of every gather and scatter."""
+    return [
+        (eqn.primitive.name, eqn.invars[1].aval.shape)
+        for eqn in _eqns(jaxpr)
+        if "gather" in eqn.primitive.name or "scatter" in eqn.primitive.name
+    ]
+
+
+def _lane_indexed(jaxpr):
+    """The gathers and scatters whose INDICES have a lane axis. ``vmap`` writes
+    a ``dynamic_slice`` at the loop counter as a ``gather`` too, with the one
+    index all lanes share (shape [1]; XLA makes it a dynamic-slice again): that
+    is a slice. An index a lane (shape [LANES, 1]) is what the TPU runs lane by
+    lane."""
+    return [name for name, shape in _indexed(jaxpr) if LANES in shape]
+
+
+def _lane_history():
+    s_hist, y_hist, rho, _ = _empty()
+    tile = lambda x: jnp.broadcast_to(x, (LANES,) + x.shape)
+    return tile(s_hist), tile(y_hist), tile(rho), jnp.arange(LANES, dtype=jnp.int32)
+
+
+def test_vmapped_recursion_indexes_nothing_by_lane():
+    assert LANES not in (M, D, 1)
+    g = jnp.ones((LANES, D), jnp.float32)
+    ordered = jax.make_jaxpr(jax.vmap(two_loop_direction))(g, *_lane_history())
+    assert _lane_indexed(ordered.jaxpr) == []
+    # the guard sees what it guards against: the circular layout under vmap
+    circular = jax.make_jaxpr(jax.vmap(_circular_two_loop_direction))(
+        g, *_lane_history(), jnp.arange(LANES, dtype=jnp.int32)
+    )
+    assert {"gather", "scatter"} <= set(_lane_indexed(circular.jaxpr))
+
+
+def test_vmapped_write_has_no_gather_and_no_scatter():
+    step = jnp.ones((LANES, D), jnp.float32)
+    accepted = jnp.arange(LANES) % 2 == 0
+    ordered = jax.make_jaxpr(jax.vmap(push_pair))(*_lane_history(), step, step, accepted)
+    assert "concatenate" in {eqn.primitive.name for eqn in _eqns(ordered.jaxpr)}
+    assert _indexed(ordered.jaxpr) == []
+    circular = jax.make_jaxpr(jax.vmap(_circular_write))(
+        *_lane_history(), jnp.arange(LANES, dtype=jnp.int32), step, step, accepted
+    )
+    assert "scatter" in _lane_indexed(circular.jaxpr)
+
+
+# -- whole solves against what the parent commit returned --------------------
+
+
+def _logistic_lanes(n_lanes, n=120, d=12):
+    """Seeded logistic problems in float64, one a lane: lanes differ in their
+    rows, labels and L2 weight, so they stop at different iterations."""
+    rng = np.random.default_rng(2028)
+    x = rng.normal(size=(n_lanes, n, d))
+    w_true = rng.normal(size=(n_lanes, d))
+    p = 1.0 / (1.0 + np.exp(-np.einsum("lnd,ld->ln", x, w_true)))
+    labels = (rng.uniform(size=p.shape) < p).astype(np.float64)
+    l2 = np.geomspace(0.05, 5.0, n_lanes)
+    return jnp.asarray(x), jnp.asarray(labels), jnp.asarray(l2)
+
+
+def _value_and_grad(x, labels, l2):
+    def value(w):
+        z = x @ w
+        return jnp.sum(jnp.logaddexp(0.0, z) - labels * z) + 0.5 * l2 * jnp.vdot(w, w)
+
+    return jax.value_and_grad(value)
+
+
+def _solve(solver, x, labels, l2):
+    fn = _value_and_grad(x, labels, l2)
+    w0 = jnp.zeros((x.shape[-1],), x.dtype)
+    if solver == "lbfgs":
+        # history 4 of up to 30 iterations: the history wraps
+        return minimize_lbfgs(fn, w0, max_iter=30, history=4, tolerance=1e-9)
+    if solver == "box":
+        return minimize_lbfgs(
+            fn, w0, max_iter=30, history=4, tolerance=1e-9,
+            lower_bounds=jnp.full_like(w0, -0.25), upper_bounds=jnp.full_like(w0, 0.4),
+        )
+    return minimize_owlqn(fn, w0, l1_weight=1.5, max_iter=30, history=4, tolerance=1e-9)
+
+
+def solve_case(solver, vmapped):
+    """What a recorded case runs (also run, as it stands, on the parent commit
+    to make RECORDED)."""
+    x, labels, l2 = _logistic_lanes(5)
+    if vmapped:
+        result = jax.jit(jax.vmap(lambda *a: _solve(solver, *a)))(x, labels, l2)
+    else:
+        result = jax.jit(lambda *a: _solve(solver, *a))(x[1], labels[1], l2[1])
+    return {
+        "iterations": np.asarray(result.iterations).tolist(),
+        "reason": np.asarray(result.reason).tolist(),
+        "line_search_trials": np.asarray(result.line_search_trials).tolist(),
+        "value": np.asarray(result.value).tolist(),
+        "coefficients": np.asarray(result.coefficients).tolist(),
+    }
+
+
+# what commit f1295b6 (the circular layout) returned for solve_case, floats to 10 digits
+RECORDED = {
+    ("lbfgs", False): {
+        "iterations": (
+            15
+        ),
+        "reason": (
+            2
+        ),
+        "line_search_trials": (
+            [0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0]
+        ),
+        "value": (
+            23.32312693
+        ),
+        "coefficients": (
+            [-0.4442309378, 3.585110936, 0.7193695533, 0.1420932564, 0.8882843489, 1.411568668,
+            -2.124847279, 0.6406504515, 1.966784272, -0.2647399166, -0.5818763533, 1.576440227]
+        ),
+    },
+    ("lbfgs", True): {
+        "iterations": (
+            [13, 15, 10, 11, 9]
+        ),
+        "reason": (
+            [2, 2, 2, 2, 2]
+        ),
+        "line_search_trials": (
+            [[0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0], [0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 1, 1, 1, 1, 1, 1, 1, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0]]
+        ),
+        "value": (
+            [30.44281828, 23.32312693, 57.19552105, 37.39466765, 51.73512756]
+        ),
+        "coefficients": (
+            [[0.6041376361, -1.776887374, -0.1215241969, 1.755860469, 1.035289719, 1.719384174,
+            -1.182626382, 3.021746049, 0.3439701535, 1.255446293, -3.014626718, -0.09691391714],
+            [-0.4442309378, 3.585110936, 0.7193695533, 0.1420932564, 0.8882843489, 1.411568668,
+            -2.124847279, 0.6406504515, 1.966784272, -0.2647399166, -0.5818763533, 1.576440227],
+            [1.157317559, 0.7058404219, -0.1113305862, 0.04050028883, 0.9160379886,
+            -1.157615465, -0.2066163949, -0.2327451568, 0.1385588073, -0.2208566987,
+            0.3097706949, 0.2038803996], [1.411695303, 0.6019723477, -0.7026082262,
+            -0.8159868732, 0.6037108551, -1.528102515, -1.192109584, -0.3692539088,
+            0.6115884939, 0.5244347593, -0.2568350486, 0.2802906389], [-0.7416923232,
+            -0.2593214433, -0.004046605727, 0.1737039312, 0.2166393305, 0.4883590279,
+            0.4742750125, 0.01787436697, 0.5244681751, -0.6574004535, 0.6447987407,
+            0.9325748971]]
+        ),
+    },
+    ("box", False): {
+        "iterations": (
+            14
+        ),
+        "reason": (
+            2
+        ),
+        "line_search_trials": (
+            [0, 1, 1, 5, 5, 12, 6, 7, 8, 5, 7, 5, 7, 5, 14, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0]
+        ),
+        "value": (
+            54.12860842
+        ),
+        "coefficients": (
+            [-0.1747162087, 0.4, 0.1422345555, 0.05126737721, 0.2945044449, 0.4, -0.25,
+            0.1695029966, 0.4, -0.08253139006, -0.25, 0.4]
+        ),
+    },
+    ("box", True): {
+        "iterations": (
+            [30, 14, 13, 14, 10]
+        ),
+        "reason": (
+            [1, 2, 2, 2, 2]
+        ),
+        "line_search_trials": (
+            [[0, 1, 1, 5, 2, 5, 3, 5, 4, 5, 4, 5, 5, 5, 5, 5, 6, 5, 6, 5, 6, 5, 6, 5, 7, 5, 6,
+            5, 7, 5, 7], [0, 1, 1, 5, 5, 12, 6, 7, 8, 5, 7, 5, 7, 5, 14, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 5, 3, 5, 5, 6, 8, 10, 6, 8, 6, 5, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 5, 2, 5, 4, 5, 6, 5, 8, 6, 9, 5, 12,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 1, 1, 3, 6, 5, 6, 5, 9, 6, 10,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0]]
+        ),
+        "value": (
+            [57.10551539, 54.12860842, 66.02228135, 55.05719513, 58.57506463]
+        ),
+        "coefficients": (
+            [[0.2210007648, -0.25, -0.05006556924, 0.4, 0.4, 0.4, -0.25, 0.4, -0.01428752713,
+            0.1441454775, -0.25, -0.25], [-0.1747162087, 0.4, 0.1422345555, 0.05126737721,
+            0.2945044449, 0.4, -0.25, 0.1695029966, 0.4, -0.08253139006, -0.25, 0.4], [0.4, 0.4,
+            -0.0304851782, 0.09629035687, 0.4, -0.25, -0.1387787383, -0.1678131425,
+            -0.05675768506, 0.02730203859, 0.1768172452, 0.1929074163], [0.4, 0.2030891992,
+            -0.25, -0.25, 0.4, -0.25, -0.25, -0.2455012007, 0.4, 0.3734974631, -0.1852409561,
+            0.3263550911], [-0.25, -0.25, 0.0657941773, 0.0905697296, 0.1214392244, 0.398598218,
+            0.3414745715, -0.01141974643, 0.4, -0.25, 0.4, 0.4]]
+        ),
+    },
+    ("owlqn", False): {
+        "iterations": (
+            12
+        ),
+        "reason": (
+            2
+        ),
+        "line_search_trials": (
+            [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0]
+        ),
+        "value": (
+            38.80467021
+        ),
+        "coefficients": (
+            [-0.1384859378, 2.231668023, 0.2213493952, 0, 0.4350019785, 0.7348067381,
+            -1.33558716, 0.2068371255, 1.179715077, -0.01140307416, -0.3092588253, 0.8384910573]
+        ),
+    },
+    ("owlqn", True): {
+        "iterations": (
+            [11, 12, 10, 10, 8]
+        ),
+        "reason": (
+            [2, 2, 2, 2, 2]
+        ),
+        "line_search_trials": (
+            [[0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+            0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0]]
+        ),
+        "value": (
+            [46.81191916, 38.80467021, 63.87764191, 48.78892798, 58.63684273]
+        ),
+        "coefficients": (
+            [[0.2366406368, -0.8134898908, 0, 0.8438512838, 0.5709183485, 0.9344725559,
+            -0.6254081266, 1.648836226, 0.07179361629, 0.5033549244, -1.601630429,
+            -0.09968446865], [-0.1384859378, 2.231668023, 0.2213493952, 0, 0.4350019785,
+            0.7348067381, -1.33558716, 0.2068371255, 1.179715077, -0.01140307416, -0.3092588253,
+            0.8384910573], [0.8958599412, 0.5104864138, -0.02313192709, 0.01248186896,
+            0.7304609864, -0.8749213817, -0.09556961002, -0.105885383, 0.009132316987,
+            -0.03032677572, 0.2058785389, 0.1261248294], [1.106747032, 0.3489382852,
+            -0.4703584935, -0.6489348036, 0.4173377965, -1.181123687, -0.9323279533,
+            -0.2059132187, 0.4371639893, 0.3712567836, -0.1607433396, 0.1945615903],
+            [-0.6344267408, -0.1669343627, 0, 0.08178545727, 0.1406529615, 0.3972154923,
+            0.3659559473, 0, 0.4498434216, -0.5438777386, 0.5320530284, 0.8091640479]]
+        ),
+    },
+}
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["one_solve", "vmapped"])
+@pytest.mark.parametrize("solver", ["lbfgs", "box", "owlqn"])
+def test_whole_solve_is_the_parent_commits(solver, vmapped):
+    got = solve_case(solver, vmapped)
+    want = RECORDED[solver, vmapped]
+    for exact in ("iterations", "reason", "line_search_trials"):
+        assert got[exact] == want[exact], exact
+    np.testing.assert_allclose(got["value"], want["value"], rtol=1e-6)
+    np.testing.assert_allclose(
+        got["coefficients"], want["coefficients"], rtol=1e-6, atol=1e-12
+    )

@@ -1094,6 +1094,46 @@ class TestCrashSafeStreamingResume:
                 checkpointer=ck,
             )
 
+    def test_solver_state_of_another_layout_fails_fast_named(self, tmp_path):
+        """A mid-solve snapshot whose state fields are not the state class's
+        (one written while the L-BFGS history was circular has ``head``) is
+        refused by name: never rebuilt, never read as the ordered layout."""
+        import dataclasses
+        from typing import Any
+
+        from photon_ml_tpu.io.checkpoint import SolverCheckpointer
+
+        ck = SolverCheckpointer(tmp_path / "ck")
+        saves = []
+        save_progress = ck.save_progress
+
+        def recording_save(**kw):
+            saves.append(kw)
+            return save_progress(**kw)
+
+        ck.save_progress = recording_save
+        self._train(checkpointer=ck)
+        mid_solve = next(kw for kw in saves if kw["solver_state"] is not None)
+
+        state = mid_solve["solver_state"]
+        names = [f.name for f in dataclasses.fields(state)]
+        assert "head" not in names and {"s_hist", "y_hist", "rho", "count"} <= set(names)
+        circular = dataclasses.make_dataclass(
+            "CircularState", [(name, Any) for name in names + ["head"]]
+        )(*(getattr(state, name) for name in names), np.int32(0))
+
+        old = SolverCheckpointer(tmp_path / "old")
+        old.save_progress(**{**mid_solve, "solver_state": circular})
+        with pytest.raises(
+            ValueError,
+            match=r"only in the checkpoint: \['head'\].*fresh checkpoint directory",
+        ):
+            self._train(checkpointer=old)
+        # the same snapshot under the state class's own fields resumes
+        own = SolverCheckpointer(tmp_path / "own")
+        own.save_progress(**mid_solve)
+        assert set(self._train(checkpointer=own)) == set(self.LAMS)
+
 
 def _partitioned_fixture(num_ranks=2, n=32, d=4, seed=1):
     """In-memory dense-FE partitioned GAME fixture: ``num_ranks`` equal
