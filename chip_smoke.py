@@ -463,7 +463,7 @@ def glmix_argv(work: str, out: str, tel: str, mesh: "str | None") -> tuple:
         "--validation-data-path", os.path.join(work, "val"),
         "--root-output-dir", out, "--override-output",
         "--task-type", "LOGISTIC_REGRESSION",
-        # max.iter=10: the budget of the one GAME sweep on record (bench.py)
+        # max.iter=10: the budget the GAME sweeps of this repo's records ran at
         "--coordinate-configurations",
         "name=global,feature.shard=global,reg.weights=1,max.iter=10",
         "--coordinate-configurations",

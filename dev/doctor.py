@@ -1,22 +1,16 @@
 #!/usr/bin/env python
-"""Run doctor: one offline findings report over a run directory's evidence.
+"""Run doctor: one offline findings report over what a run left behind.
 
-Takes a directory holding any mix of driver bench artifacts
-(``BENCH_r*.json`` / ``MULTICHIP_r*.json``), the ``bench-report.json``
-sidecar, JSONL run journals (``run-journal.jsonl`` and friends — with
-``--live`` also their crash-durable ``.partial`` stage files), and per-rank
-``trace-*.json`` files, and emits ONE report:
+A reader of run journals, program-ledger rows and per-rank traces. Takes a
+directory holding any mix of JSONL run journals (``run-journal.jsonl`` and
+friends — with ``--live`` also their crash-durable ``.partial`` stage
+files) and per-rank ``trace-*.json`` files, and emits ONE report:
 
-- a verdict per bench row (telemetry/verdicts.py — the BASELINE.md same-run
-  win criteria as code), with known pathology signatures named with their
-  measured causes (negative marginals, ~40x contention blowouts,
-  ``parsed: null`` tail overruns);
-- cross-round history findings (improvements, plateaus) in each rule's
-  declared direction;
-- registry-counter cross-checks from the journal snapshot
-  (overlap_fraction ~ 0 with prefetch on, high serve pad fraction,
-  quarantined blocks, preemption restarts, exhausted restart budgets) plus
-  the last heartbeat cursor and failure rows of a crashed/in-flight run;
+- registry-counter cross-checks from each journal's snapshot
+  (telemetry/verdicts.py: overlap_fraction ~ 0 with prefetch on, high
+  serve pad fraction, quarantined blocks, preemption restarts, exhausted
+  restart budgets) plus the last heartbeat cursor and failure rows of a
+  crashed/in-flight run;
 - the per-program compiled-program ledger table (ISSUE 13): per labeled
   jit program — calls, compiles, recompiles, signatures, compile seconds,
   flops, peak bytes, with each label's LAST recompile attribution (the
@@ -29,15 +23,16 @@ sidecar, JSONL run journals (``run-journal.jsonl`` and friends — with
   restart-storm pathology naming a flapping culprit rank, and (with
   ``--live``) the last abort marker seen.
 
-Exit status: nonzero iff the CURRENT round (the sidecar when present, else
-the highest BENCH round) contains a row that LOST its registered win
-criterion — so "fold the bench results into BASELINE.md" (ROADMAP item 1)
-starts from a machine verdict, not from hand-decoding unit strings.
-Historical pathologies (the r04/r05 ``parsed: null`` captures) are
-reported but only fail under ``--strict``.
+It says nothing about speed: that is ``benchmark/run.py`` in the cells of
+``BENCHMARK.json``, with the numbers in ``PERF.md``. A directory that holds
+none of the files above (only ``BENCH_r*.json`` captures of the pre-chip
+regime, say) is reported as holding nothing the doctor reads.
 
-Run from the repo root (judges the checked-in history) or point it at a
-production run's ``--telemetry-dir``:
+Exit status: 0, unless ``--strict`` is given and a finding has status
+``pathology`` or ``warning`` (an unclosed journal, a failed run, a
+recompile storm, a restart storm, ...): then 1.
+
+Point it at a run's ``--telemetry-dir`` / ``--trace-dir``:
 
     python -m dev.doctor [RUN_DIR] [--live] [--strict] [--json]
 """
@@ -55,7 +50,7 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
-from photon_ml_tpu.telemetry import bench_history, verdicts  # noqa: E402
+from photon_ml_tpu.telemetry import verdicts  # noqa: E402
 from photon_ml_tpu.telemetry.journal import (  # noqa: E402
     JOURNAL_PARTIAL_SUFFIX as PARTIAL_SUFFIX,
     heartbeat_cursor,
@@ -231,52 +226,8 @@ def run_doctor(
 
     Importable so tests judge findings structurally; ``main`` wraps it.
     """
-    history = bench_history.load_history(directory)
     lines: list[str] = [f"run doctor: {os.path.abspath(directory)}"]
     findings: list = []
-    current_round_findings: list = []
-
-    if history.artifacts or history.sidecar is not None:
-        lines.append("")
-        lines.append("== bench verdicts ==")
-        latest = history.latest
-        for art in history.artifacts:
-            vs = verdicts.judge_artifact(art)
-            findings.extend(vs)
-            if art is latest:
-                current_round_findings.extend(vs)
-            for v in vs:
-                lines.append(v.line())
-        if history.sidecar is not None:
-            lines.append(f"-- sidecar {bench_history.SIDECAR_FILENAME} "
-                         "(preferred: never tail-truncated)")
-            vs = verdicts.judge_artifact(history.sidecar)
-            findings.extend(vs)
-            current_round_findings.extend(vs)
-            for v in vs:
-                lines.append(v.line())
-        # the CURRENT multichip round gates the exit code like the current
-        # bench round does — independently of the sidecar (which never
-        # carries multichip evidence)
-        current_multi = max(
-            (m.round for m in history.multichip if m.round is not None),
-            default=None,
-        )
-        for m in history.multichip:
-            v = verdicts.judge_multichip(m)
-            findings.append(v)
-            if m.round == current_multi:
-                current_round_findings.append(v)
-            lines.append(v.line())
-        hist = verdicts.history_findings(history)
-        if hist:
-            lines.append("")
-            lines.append("== cross-round history ==")
-            findings.extend(hist)
-            for v in hist:
-                lines.append(v.line())
-    else:
-        lines.append("(no BENCH_r*/MULTICHIP_r* artifacts or sidecar here)")
 
     journal_paths = _find_journals(directory, live)
     merged_records: list = []
@@ -322,33 +273,35 @@ def run_doctor(
         lines.append("== traces ==")
         lines.extend(trace_lines)
 
-    regressions = verdicts.regressions(current_round_findings)
-    if strict:
-        regressions = regressions + [
-            v for v in findings
-            if v.status in (verdicts.PATHOLOGY, verdicts.WARNING)
-        ]
+    if not journal_paths and not trace_lines:
+        lines.append("(no run journals or trace files here: nothing the "
+                     "doctor reads)")
+
+    gating = [
+        v for v in findings
+        if v.status in (verdicts.PATHOLOGY, verdicts.WARNING)
+    ]
     lines.append("")
-    if regressions:
-        lines.append(f"REGRESSIONS ({len(regressions)}):")
-        for v in regressions:
+    if gating:
+        lines.append(f"PATHOLOGIES/WARNINGS ({len(gating)}, "
+                     "exit 1 under --strict):")
+        for v in gating:
             lines.append(f"  {v.metric} [{v.rule}]: {v.detail}")
     else:
-        lines.append("REGRESSIONS: none")
-    return (1 if regressions else 0), findings, "\n".join(lines)
+        lines.append("PATHOLOGIES/WARNINGS: none")
+    return (1 if strict and gating else 0), findings, "\n".join(lines)
 
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("directory", nargs="?", default=".",
-                   help="run directory (bench artifacts + journals + "
-                        "traces); default: cwd")
+                   help="run directory (journals + traces); default: cwd")
     p.add_argument("--live", action="store_true",
                    help="also tail crash-durable .partial journal stages "
                         "(a wedged run's evidence before close)")
     p.add_argument("--strict", action="store_true",
-                   help="fail on pathologies/warnings too, not just "
-                        "current-round win-criterion losses")
+                   help="the gate: exit 1 iff a finding has status "
+                        "pathology or warning (without it the exit is 0)")
     p.add_argument("--json", action="store_true",
                    help="emit findings as one JSON object instead of text")
     args = p.parse_args(argv)

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Static parity-convention lints for photon_ml_tpu (CLAUDE.md conventions).
 
-Fourteen checks, all pure-AST (no jax import; runs in milliseconds):
+Thirteen checks, numbered 1-14 with 12 retired, all pure-AST (no jax import; runs in milliseconds):
 
 1. **Docstring citations** — every ``photon_ml_tpu/**/*.py`` module (except
    ``__init__.py`` re-export shims) must carry a module docstring that
@@ -104,15 +104,7 @@ Fourteen checks, all pure-AST (no jax import; runs in milliseconds):
    wall anchor — sites whose OUTPUT is an absolute timestamp, never a
    difference).
 
-12. **Bench rows without a verdict rule** — every row key
-   ``bench.sample_report()`` emits (the ``_row(...)`` metric literals,
-   including f-string prefixes like ``fe_hot_loop_hbm_gbps_{label}``) must
-   have a registered win criterion in ``telemetry/verdicts.py`` (a
-   ``@rule("<key>")`` / ``@rule("<prefix>*")`` decorator literal). A new
-   bench row whose "what does winning mean" lives only in prose is exactly
-   how BENCH_r04/r05 shipped with ``parsed: null`` unnoticed — the doctor
-   (dev/doctor.py) can only judge rows the registry covers, so the
-   coverage is enforced statically.
+12. (removed with ``bench.py``, PR 29)
 
 13. **Raw jit sites in the hot-program packages** — every jit in
    ``algorithm/``, ``serving/`` and ``parallel/`` must route through
@@ -975,102 +967,6 @@ def check_resident_param_mutations(root: pathlib.Path) -> list[str]:
     return problems
 
 
-#: where check 12 reads its two sides from (relative to the lint root)
-BENCH_MODULE = "bench.py"
-VERDICTS_MODULE = f"{PACKAGE}/telemetry/verdicts.py"
-
-
-def _bench_row_keys(tree: ast.AST) -> list[tuple[str, bool, int]]:
-    """(key, is_prefix, lineno) for every ``_row(...)`` first argument in
-    ``sample_report()`` — string literals exactly, f-strings as the leading
-    constant prefix (``fe_hot_loop_hbm_gbps_{label}`` ->
-    ``fe_hot_loop_hbm_gbps_`` + is_prefix)."""
-    keys: list[tuple[str, bool, int]] = []
-    fn = next(
-        (n for n in ast.walk(tree)
-         if isinstance(n, ast.FunctionDef) and n.name == "sample_report"),
-        None,
-    )
-    if fn is None:
-        return keys
-    for node in ast.walk(fn):
-        if not (
-            isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Name)
-            and node.func.id == "_row"
-            and node.args
-        ):
-            continue
-        arg = node.args[0]
-        if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-            keys.append((arg.value, False, node.lineno))
-        elif isinstance(arg, ast.JoinedStr):
-            prefix = ""
-            for part in arg.values:
-                if isinstance(part, ast.Constant) and isinstance(
-                    part.value, str
-                ):
-                    prefix += part.value
-                else:
-                    break
-            if prefix:
-                keys.append((prefix, True, node.lineno))
-    return keys
-
-
-def _verdict_rule_patterns(tree: ast.AST) -> set[str]:
-    """String-literal first arguments of ``@rule(...)`` decorators in
-    telemetry/verdicts.py — the statically readable registry surface."""
-    patterns: set[str] = set()
-    for node in ast.walk(tree):
-        if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            continue
-        for deco in node.decorator_list:
-            if (
-                isinstance(deco, ast.Call)
-                and isinstance(deco.func, ast.Name)
-                and deco.func.id == "rule"
-                and deco.args
-                and isinstance(deco.args[0], ast.Constant)
-                and isinstance(deco.args[0].value, str)
-            ):
-                patterns.add(deco.args[0].value)
-    return patterns
-
-
-def check_bench_verdict_rules(root: pathlib.Path) -> list[str]:
-    bench_path = root / BENCH_MODULE
-    verdicts_path = root / VERDICTS_MODULE
-    if not bench_path.exists() or not verdicts_path.exists():
-        return []  # synthetic lint roots without a bench surface
-    keys = _bench_row_keys(ast.parse(bench_path.read_text()))
-    patterns = _verdict_rule_patterns(ast.parse(verdicts_path.read_text()))
-    stems = {p[:-1] for p in patterns if p.endswith("*")}
-    problems = []
-    for key, is_prefix, lineno in keys:
-        if is_prefix:
-            # SOUND direction only: every key the f-string can generate is
-            # key+<suffix>, which matches a glob stem s iff the generated
-            # key startswith s — guaranteed for all suffixes only when the
-            # literal prefix already contains the stem. (s.startswith(key)
-            # would accept `f"fe_{x}"` against stem "fe_hot_loop_…" while
-            # rule_for("fe_other") matches nothing at runtime.)
-            matched = any(key.startswith(s) for s in stems)
-        else:
-            matched = key in patterns or any(
-                key.startswith(s) for s in stems
-            )
-        if not matched:
-            problems.append(
-                f"{BENCH_MODULE}:{lineno}: bench row {key!r}"
-                f"{' (f-string prefix)' if is_prefix else ''} has no "
-                "registered verdict rule — add @rule(...) with its win "
-                "criterion in telemetry/verdicts.py so dev/doctor.py can "
-                "judge the row (lint check 12)"
-            )
-    return problems
-
-
 def run_lints(root: pathlib.Path | str | None = None) -> list[str]:
     root = pathlib.Path(root) if root else pathlib.Path(__file__).resolve().parents[1]
     return (
@@ -1085,7 +981,6 @@ def run_lints(root: pathlib.Path | str | None = None) -> list[str]:
         + check_streaming_jit_closures(root)
         + check_checkpoint_commit_sites(root)
         + check_time_time_durations(root)
-        + check_bench_verdict_rules(root)
         + check_raw_jit_sites(root)
         + check_resident_param_mutations(root)
     )

@@ -111,7 +111,7 @@ def _make_objective(task: TaskType, cfg: CoordinateOptimizationConfig,
     (per-entity RE/MF buckets, λ-grid lanes): `lax.while_loop` bodies trace
     with UNBATCHED tracers, so runtime batch-tracer detection cannot see the
     vmap — a Pallas call baked into the loop body then gets batched into a
-    serial per-lane loop (~lanes× slower; the r4 bench regression). Only
+    serial per-lane loop (~lanes× slower; seen in round 4). Only
     un-vmapped solve paths (the FE coordinate) pass None (= auto/on-TPU)."""
     if sparse:
         return SparseGLMObjective(

@@ -37,9 +37,9 @@ single-jit path bitwise-identical (tests/test_lane_scheduler.py pins it).
 Scheduled solves trade the one-jit sweep for a few extra dispatches and
 small host reads per bucket (each read is a sync point: the host waits
 for the device, then the device for the host) — worth it exactly when the
-saved lane iterations outweigh those stalls (compare the same-run
-``fused_game_sweep_scheduled_ms`` vs ``fused_game_sweep_ms`` bench rows,
-never cross-run absolutes).
+saved lane iterations outweigh those stalls. No benchmark cell runs a
+scheduled sweep yet, so the scheduler has no speed claim (ROADMAP R8 is
+the cell it waits for).
 
 use_pallas MUST stay False in every objective this module receives — the
 solves are vmapped, and a baked-in pallas_call would batch into a serial

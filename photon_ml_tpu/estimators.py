@@ -1368,8 +1368,8 @@ def train_glm_streaming(
     TRON for streamed second-order solves). ``exchange``: optional
     ``parallel.multihost.MetadataExchange`` — each rank streams its own
     block assignment and the per-epoch accumulators sum in rank order.
-    ``prefetch=False`` decodes inline (the same-run OFF baseline the bench
-    row measures against).
+    ``prefetch=False`` decodes inline (the OFF side that tests compare the
+    prefetching run with, bitwise).
 
     ``checkpointer``: optional ``io.checkpoint.SolverCheckpointer`` —
     crash-safe resume for the streaming path. Every outer solver iteration

@@ -134,7 +134,7 @@ def _pad_dense_chunk(
 class ArrayChunkSource(ChunkSource):
     """Dense in-memory source: chunks a host [n, d] array by row ranges.
 
-    The reference workload for tests/bench: ``decode_hook`` (called once
+    The reference workload for tests: ``decode_hook`` (called once
     per ``load`` in whichever thread loads) injects host decode cost or
     faults — e.g. a sleep standing in for disk/decompress latency, or a
     ``dev.faultinject.flaky`` transient failure.
@@ -665,8 +665,8 @@ class GameAvroChunkSource:
         self.entity_vocabs = dict(entity_vocabs or {})
         self.on_corrupt = on_corrupt
         self.dtype = dtype
-        #: dynamic per-source decode evidence (the partitioned bench's
-        #: per-rank decoded-bytes metric; io_counters stays process-global)
+        #: dynamic per-source decode evidence (bytes this rank decoded;
+        #: io_counters stays process-global)
         self.bytes_decoded = 0
         if chunk_plan is not None:
             # a precomputed plan (plan_partitioned_game_stream's rank-local

@@ -212,10 +212,10 @@ class SparseGLMObjective:
 
         The autodiff gradient transposes the margin gather into a
         random-index scatter-add over [dim] — the dominant cost of giant-d
-        solves on TPU (BENCH_r02: 733 ms/iter at d=10⁷, ~0.1 GB/s useful
-        traffic). With the entries pre-sorted by column, each column's
-        contributions form one contiguous run, and the whole reduction
-        becomes chunked prefix sums + a boundary gather
+        solves on TPU (733 ms/iter at d=10⁷, ~0.1 GB/s useful traffic:
+        measured before PR 21, not since). With the entries pre-sorted by
+        column, each column's contributions form one contiguous run, and the
+        whole reduction becomes chunked prefix sums + a boundary gather
         (:func:`_sorted_run_sums`) — cumsum/gather only, no scatter and no
         giant-``num_segments`` segment-sum (the latter failed to compile at
         d=10⁷ on the TPU compile service, BASELINE.md r2). Full
@@ -268,7 +268,7 @@ class SparseGLMObjective:
         sparse-tail split as the gradient — forward X(f·v) rides the hot
         MXU matmul + cold tail, and the transpose assembles as the head
         matvec + k_hot scatter plus the tail scatters. This is TRON's CG
-        inner loop at giant d (the d=10⁸ bench row).
+        inner loop at giant d (d=10⁸).
         """
         norm = self.normalization
         if batch.has_hybrid_view and norm.shifts is None:

@@ -432,7 +432,7 @@ class ColumnShardedGLMObjective:
         """H v = Xᵀ diag(w_i l''_i) X v (+ λv): forward psum'd Jv, then the
         same local sorted-run transpose — TRON's CG ladder at giant d.
         With a hot head, both directions take the dense-head/sparse-tail
-        split (the hybrid CG step of the d=10⁸ bench row)."""
+        split (the hybrid CG step at d=10⁸)."""
         self._check_blocks(batch)
         n = batch.num_samples
         hot = batch.has_hot_head

@@ -21,17 +21,10 @@ from photon_ml_tpu.telemetry.layout import (
     reset_layout_metrics,
 )
 from photon_ml_tpu.telemetry.probes import (
-    GATE_REPS,
     CompileMonitor,
-    MarginalResult,
-    MarginalTimer,
     compile_count,
     install_compile_listener,
     live_buffer_bytes,
-    median_spread,
-    read_scalar,
-    scan_step_marginal,
-    stream_calibration,
 )
 from photon_ml_tpu.telemetry.program_ledger import (
     ProgramLedger,
@@ -92,17 +85,10 @@ __all__ = [
     "LAYOUT_METRIC_PREFIX",
     "record_hybrid_layout",
     "reset_layout_metrics",
-    "GATE_REPS",
     "CompileMonitor",
-    "MarginalResult",
-    "MarginalTimer",
     "compile_count",
     "install_compile_listener",
     "live_buffer_bytes",
-    "median_spread",
-    "read_scalar",
-    "scan_step_marginal",
-    "stream_calibration",
     "ProgramLedger",
     "current_ledger",
     "install_ledger",

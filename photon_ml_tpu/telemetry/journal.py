@@ -5,8 +5,8 @@ publish to the final destination on close) crossed with
 PhotonOptimizationLogEvent / OptimizationStatesTracker.scala:82-101 (the
 structured per-coordinate optimization telemetry the reference emitted to
 external listeners). Here both become one machine-parseable artifact: every
-driver/estimator/bench phase appends typed records (phase timings,
-convergence rows, calibration probes, config summaries) to a local spool
+driver/estimator phase appends typed records (phase timings,
+convergence rows, config summaries) to a local spool
 file, and ``close()`` moves it atomically to ``<dir>/run-journal.jsonl``.
 
 Multi-process discipline (CLAUDE.md): only rank 0 touches shared output
@@ -83,7 +83,7 @@ def json_safe(obj):
 
 #: fields the journal stamps onto every heartbeat row itself — everything
 #: ELSE in the row is the caller's progress cursor (dev/doctor.py and
-#: telemetry/verdicts.py both print "where was the run" from this split).
+#: verdicts.journal_findings both print "where was the run" from it).
 #: ``hbm_bytes`` and ``compiles`` are the ISSUE 13 drift snapshots: live
 #: device-buffer bytes and the backend compile count, so ``doctor --live``
 #: can show device-memory drift and mid-run compile storms on a wedged run.

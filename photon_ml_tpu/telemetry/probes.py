@@ -1,16 +1,8 @@
-"""Device/runtime probes: compile counts, HBM bytes, marginal timing.
+"""Device/runtime probes: compile counts, HBM bytes, the runtime stamp.
 
 Reference parity: no reference analogue — Photon-ML leaned on the Spark UI
-for executor/runtime attribution (SURVEY.md §5). The measurement helpers
-live here as a library instead of inside ``bench.py``:
+for executor/runtime attribution (SURVEY.md §5).
 
-- ``MarginalTimer`` / ``scan_step_marginal``: K_hi-vs-K_lo differencing of
-  K evaluations inside ONE jit, ending on a host read. The difference
-  cancels every fixed per-call cost (dispatch, launch, the host read) so
-  what remains is device time per evaluation.
-- ``stream_calibration``: a same-run one-X-read matvec probe
-  (``fe_hot_loop_stream_gbps``) as a callable, so an experiment can state
-  its hot loop as a fraction of what this chip streamed in this process.
 - ``install_compile_listener`` / ``CompileMonitor``: jax.monitoring hook
   counting backend compiles (recompilation storms are a classic silent
   perf pathology under vmap/jit churn) and, beside them, what a program
@@ -26,155 +18,12 @@ live here as a library instead of inside ``bench.py``:
   its run summary so a reader of the summary knows what ran it.
 
 Everything imports jax lazily so this module is safe to import before the
-platform is chosen (bench.py / driver startup order).
+platform is chosen (driver startup order).
 """
 
 from __future__ import annotations
 
-import dataclasses
-import statistics
-import time
-from typing import Callable
-
-import numpy as np
-
 from photon_ml_tpu.telemetry.registry import default_registry
-
-#: median-of-K reps for gate metrics: a one-chip machine shares its host's
-#: cores, so single-shot host-clock numbers spread
-GATE_REPS = 3
-
-
-def median_spread(measure_once: Callable[[], float], reps: int = GATE_REPS):
-    """Run a marginal measurement ``reps`` times; return
-    (median, [min, max]) — the spread is the error bar to quote with it."""
-    vals = [measure_once() for _ in range(reps)]
-    return statistics.median(vals), [min(vals), max(vals)]
-
-
-def read_scalar(x) -> float:
-    """Host-read synchronization point: returns float(x), which waits for
-    the device to produce it (same wait as ``block_until_ready``, plus the
-    copy of one scalar)."""
-    return float(np.asarray(x).ravel()[0])
-
-
-@dataclasses.dataclass
-class MarginalResult:
-    median: float  # marginal seconds per unit of work
-    spread: list  # [min, max] across reps
-
-
-@dataclasses.dataclass
-class MarginalTimer:
-    """K_hi-vs-K_lo marginal differencing over an arbitrary timed unit.
-
-    ``measure(timed_k)`` calls ``timed_k(k)`` — which must run ``k`` units
-    of work and return elapsed seconds, ending on a host read (use
-    :func:`read_scalar`) — and returns the per-unit marginal
-    ``(t(k_hi) - t(k_lo)) / (k_hi - k_lo)`` as a median-of-``reps`` with
-    [min, max] spread. Differencing cancels the fixed per-call cost;
-    ``k_hi - k_lo`` must be large enough that device time dwarfs the
-    call-to-call jitter of that fixed cost, or marginals can come out
-    negative."""
-
-    k_lo: int = 1
-    k_hi: int = 5
-    reps: int = GATE_REPS
-    floor: float = 1e-6
-
-    def __post_init__(self):
-        if self.k_hi <= self.k_lo:
-            raise ValueError(f"k_hi ({self.k_hi}) must exceed k_lo ({self.k_lo})")
-
-    def measure(self, timed_k: Callable[[int], float]) -> MarginalResult:
-        def once() -> float:
-            lo = timed_k(self.k_lo)
-            hi = timed_k(self.k_hi)
-            return max((hi - lo) / (self.k_hi - self.k_lo), self.floor)
-
-        median, spread = median_spread(once, self.reps)
-        return MarginalResult(median=median, spread=spread)
-
-
-def scan_step_marginal(
-    step_fn,
-    operand,
-    dim: int,
-    *,
-    k_lo: int = 16,
-    k_hi: int = 256,
-    reps: int = GATE_REPS,
-    warmups: int = 4,
-    rng=None,
-) -> tuple[float, list]:
-    """Marginal seconds per evaluation of ``step_fn(w, operand) -> (w', v)``.
-
-    K evaluations run inside ONE jit via ``lax.scan`` (so the K_hi-K_lo
-    delta is pure device time), every step consumes the carry (XLA hoists
-    loop-invariant work such as ``X @ w0`` out of the scan otherwise),
-    warm starts are perturbed per rep, and timing ends on a host read. Returns ``(median, [min, max])`` like :func:`median_spread`."""
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(7) if rng is None else rng
-
-    def timed(k: int) -> float:
-        @jax.jit
-        def run(w0, op):
-            w, vs = jax.lax.scan(
-                lambda w, _: step_fn(w, op), w0, None, length=k
-            )
-            return vs.sum() + w.sum()
-
-        float(run(jnp.zeros(dim, jnp.float32), operand))  # compile + sync
-        best = None
-        for _ in range(warmups):
-            w0 = jnp.asarray(rng.normal(size=dim).astype(np.float32)) * 0.01
-            t0 = time.perf_counter()
-            float(run(w0, operand))
-            el = time.perf_counter() - t0
-            best = el if best is None or el < best else best
-        return best
-
-    return median_spread(
-        lambda: max((timed(k_hi) - timed(k_lo)) / (k_hi - k_lo), 1e-6), reps
-    )
-
-
-def stream_calibration(
-    features,
-    *,
-    k_lo: int = 16,
-    k_hi: int = 256,
-    reps: int = GATE_REPS,
-    rng=None,
-) -> dict:
-    """Same-run calibration: achieved GB/s of one [n, d] matvec X read per
-    step, so hot-loop times can be stated as fractions of a one-pass
-    stream measured in the same process. The probe is an XLA matvec, not
-    a bandwidth ceiling: a fraction above 1.0 is possible."""
-    import jax.numpy as jnp
-
-    n, d = features.shape
-    xbytes = n * d * features.dtype.itemsize
-
-    def step(w, x):
-        return w + jnp.sum(x @ w) * 1e-30, jnp.float32(0)
-
-    marginal, spread = scan_step_marginal(
-        step, features, d, k_lo=k_lo, k_hi=k_hi, reps=reps, rng=rng
-    )
-    return {
-        "gbps": xbytes / marginal / 1e9,
-        "spread_gbps": [xbytes / s / 1e9 for s in spread[::-1]],
-        "marginal_sec": marginal,
-        "spread_sec": spread,
-        "bytes_per_eval": xbytes,
-        "n": int(n),
-        "d": int(d),
-    }
-
 
 # --- compile-event monitoring (jax.monitoring) ------------------------------
 

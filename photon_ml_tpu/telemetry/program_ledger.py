@@ -50,7 +50,7 @@ Design (ISSUE 13):
   cache's ``serve/resident_params_bytes`` gauge when fed, else the live
   device-buffer bytes probe) + the program's temp bytes, against the
   device's ``bytes_limit`` where the backend reports one —
-  telemetry/verdicts.py turns forecast > limit into a finding.
+  verdicts._ledger_findings turns forecast > limit into a finding.
 
 Calls made while a jax trace is in flight bypass the ledger entirely: an
 inner jitted step invoked during an outer trace inlines into the outer

@@ -9,7 +9,7 @@ These metrics are that count: how many lanes each refresh selected (and
 why), how many it carried over untouched, per coordinate and per run.
 
 Names are constants so producers (algorithm/refresh.py) and consumers
-(tests, journals, bench.py, cli/game_training_driver.py) cannot drift —
+(tests, journals, cli/game_training_driver.py) cannot drift —
 the same contract as telemetry/serving_counters.py.
 """
 
@@ -59,7 +59,7 @@ def record_carried_coordinate(n: int = 1) -> None:
 
 
 def selection_evidence() -> dict:
-    """The counters as a summary dict (driver summaries, bench rows)."""
+    """The counters as a summary dict (driver summaries)."""
     reg = default_registry()
     return {
         "lanes_total": int(reg.counter(LANES_TOTAL).value),

@@ -9,7 +9,7 @@ solver telemetry, compile-event probes), replacing the bare module-level
 so the JSONL run journal (telemetry/journal.py) can persist them verbatim.
 
 Thread-safe; no jax dependency — importable before the backend is chosen
-(bench.py and the drivers configure platforms after import).
+(the drivers configure platforms after import).
 """
 
 from __future__ import annotations

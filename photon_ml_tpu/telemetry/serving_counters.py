@@ -10,7 +10,7 @@ lint check 11), queue depth, request/batch/row counts, and the pad
 fraction the shape-bucket discipline costs.
 
 Names are constants so producers (serving/resident.py, serving/batching.py)
-and consumers (tests, journals, bench.py, cli/serve_driver.py) cannot
+and consumers (tests, journals, cli/serve_driver.py) cannot
 drift — the same contract as telemetry/stream_counters.py.
 """
 
@@ -21,7 +21,7 @@ from photon_ml_tpu.telemetry.registry import default_registry
 #: prefix shared by every serving metric (reset_serving_metrics)
 SERVING_METRIC_PREFIX = "serve/"
 #: submit-to-result latency per request (ms): the SLO histogram — its
-#: p50/p95 are what the serve driver reports and bench.py prices
+#: p50/p95 are what the serve driver reports
 LATENCY_MS = "serve/latency_ms"
 #: bounded request-queue depth observed at each enqueue/dequeue
 QUEUE_DEPTH = "serve/queue_depth"

@@ -9,7 +9,7 @@ AND failure paths — that host decode was actually hidden behind device
 compute instead of serialized with it.
 
 Names are constants so the producers (the chunk prefetcher / epoch runner)
-and consumers (tests, journals, bench.py) cannot drift.
+and consumers (tests, journals) cannot drift.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ between runs, so it is never derived from ``tempfile``, a pid or the time:
 - unset: ``<checkout>/.jax_cache`` (git-ignored), derived from this
   package's location.
 
-Called first thing by the four drivers' ``main()``, ``bench.py`` and
+Called first thing by the four drivers' ``main()``, ``benchmark/run.py`` and
 ``chip_smoke.py`` — before the first compile. ``tests/conftest.py`` calls
 :func:`disable_compile_cache` instead (see there for why).
 """

@@ -117,7 +117,7 @@ def test_graft_entry_contract():
 def test_dryrun_multichip_self_provisions_from_one_device():
     """Reproduce the driver's environment: ONE visible device, then ask for 8.
 
-    Round-1 gate failure (MULTICHIP_r01.json ok=false): dryrun_multichip(8)
+    Round-1 gate failure (pre-PR-21 capture, ok=false): dryrun_multichip(8)
     did jax.devices()[:8] in a 1-chip environment and crashed reshaping the
     mesh. The entry point must now self-provision a virtual 8-device CPU mesh
     in a subprocess. This test runs the whole thing from a CLEAN subprocess

@@ -469,7 +469,7 @@ class TestPrefetchOverlap:
         every decode hides entirely, so overlap is decisively nonzero and
         the prefetch-ON epoch is strictly faster than the inline OFF
         epoch — the acceptance-criterion evidence path, d=512 and
-        n >> chunk budget like the bench row."""
+        n >> chunk budget."""
         x, y, _, _ = _dense_data(n=160, d=512)
         epoch_ms = {}
         for prefetch in (True, False):

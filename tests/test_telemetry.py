@@ -18,12 +18,10 @@ import pytest
 
 from photon_ml_tpu.telemetry import (
     CompileMonitor,
-    MarginalTimer,
     MetricsRegistry,
     RunJournal,
     SolverTelemetry,
     lane_summary,
-    median_spread,
     solver_result_row,
 )
 from photon_ml_tpu.telemetry.journal import json_safe
@@ -274,44 +272,45 @@ class TestProbes:
         assert cm.count >= 1
         assert cm.seconds > 0
 
-    def test_marginal_timer_differences_out_fixed_cost(self):
-        # synthetic cost model: 10s dispatch + 1s/unit; the marginal must
-        # recover the per-unit cost, not the fixed cost
-        timer = MarginalTimer(k_lo=2, k_hi=10, reps=3)
-        result = timer.measure(lambda k: 10.0 + 1.0 * k)
-        assert result.median == pytest.approx(1.0)
-        assert result.spread == [pytest.approx(1.0), pytest.approx(1.0)]
-
-    def test_marginal_timer_floor_and_validation(self):
-        with pytest.raises(ValueError):
-            MarginalTimer(k_lo=5, k_hi=5)
-        r = MarginalTimer(k_lo=1, k_hi=2, reps=1).measure(lambda k: 1.0)
-        assert r.median == pytest.approx(1e-6)  # negative marginal floored
-
-    def test_median_spread(self):
-        vals = iter([3.0, 1.0, 2.0])
-        med, spread = median_spread(lambda: next(vals), reps=3)
-        assert med == 2.0 and spread == [1.0, 3.0]
-
-    def test_scan_step_marginal_and_stream_calibration(self):
+    @pytest.mark.parametrize("metric", [
+        "jax/trace_seconds",
+        "jax/lower_seconds",
+        "jax/backend_compile_seconds",
+    ])
+    def test_listener_histogram_rises_across_one_fresh_jit(self, metric):
+        """The three histograms the benchmark's program-cost metrics read
+        (PERF.md §3), fed by a real compile and not a replayed event."""
+        import jax
         import jax.numpy as jnp
 
-        from photon_ml_tpu.telemetry import scan_step_marginal, stream_calibration
+        from photon_ml_tpu.telemetry import install_compile_listener
 
-        x = jnp.asarray(np.random.default_rng(0).normal(size=(64, 8)),
-                        jnp.float32)
-        median, spread = scan_step_marginal(
-            lambda w, op: (w + (op @ w).sum() * 1e-30, jnp.float32(0)),
-            x, 8, k_lo=2, k_hi=8, reps=1, warmups=1,
+        registry = MetricsRegistry()
+        install_compile_listener(registry)
+        operand = jnp.ones(3)  # its own little programs, before the reading
+        histogram = registry.histogram(metric)
+        count, total = histogram.count, histogram.total
+        salt = np.random.default_rng().integers(1 << 30)
+        jax.jit(lambda x: x * 3 + int(salt))(operand).block_until_ready()
+        assert histogram.count > count
+        assert histogram.total > total
+
+    def test_compile_listener_installs_once_per_registry(self):
+        """jax.monitoring has no unregister: a second install on the same
+        registry must add no second listener, or every event counts twice."""
+        import jax.monitoring
+
+        from photon_ml_tpu.telemetry import install_compile_listener
+        from photon_ml_tpu.telemetry.probes import COMPILE_COUNT_METRIC
+
+        registry = MetricsRegistry()
+        install_compile_listener(registry)
+        install_compile_listener(registry)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 0.5
         )
-        assert spread[0] <= median <= spread[1]
-        assert median >= 1e-6  # floored, never negative
-        cal = stream_calibration(x, k_lo=2, k_hi=8, reps=1)
-        assert cal["bytes_per_eval"] == 64 * 8 * 4
-        assert cal["gbps"] > 0
-        assert cal["gbps"] == pytest.approx(
-            cal["bytes_per_eval"] / cal["marginal_sec"] / 1e9
-        )
+        assert registry.counter(COMPILE_COUNT_METRIC).value == 1
+        assert registry.histogram("jax/backend_compile_seconds").total == 0.5
 
     def test_live_buffer_bytes(self):
         import jax.numpy as jnp
