@@ -59,7 +59,7 @@ from photon_ml_tpu.ops.normalization import (
     build_normalization,
 )
 from photon_ml_tpu.data.sparse_batch import SparseLabeledPointBatch, SparseShard
-from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.ops.objective import BoundObjective, GLMObjective
 from photon_ml_tpu.ops.sparse_objective import SparseGLMObjective
 from photon_ml_tpu.ops.variance import (
     coefficient_variances,
@@ -1174,6 +1174,45 @@ def _jitted_grid_solve(objective, use_owlqn, history, max_iter, tolerance,
     return jax.vmap(solve_one)(l2v, l1v)
 
 
+class _RidgeBoundObjective(BoundObjective):
+    """A bound objective plus ``0.5·l2·w'w`` with ``l2`` a traced value: what
+    lets ONE compiled solve serve every λ of an L2 path. The objective itself
+    is built with ``l2_weight`` 0 (the kernel tests it in Python); the term is
+    added round it in ``_jitted_grid_solve``'s formula and order."""
+
+    def __init__(self, objective, batch, l2):
+        super().__init__(objective, batch)
+        self.l2 = l2
+
+    def value(self, w):
+        return super().value(w) + 0.5 * self.l2 * jnp.vdot(w, w)
+
+    def value_and_grad(self, w):
+        v, g = super().value_and_grad(w)
+        return v + 0.5 * self.l2 * jnp.vdot(w, w), g + self.l2 * w
+
+    def hessian_vector(self, w, v):
+        return super().hessian_vector(w, v) + self.l2 * v
+
+    def hessian_matrix(self, w):
+        h = super().hessian_matrix(w)
+        return h + self.l2 * jnp.eye(h.shape[0], dtype=h.dtype)
+
+
+@functools.partial(ledger_jit, label="glm/path_solve", static_argnums=(0, 1))
+def _jitted_path_solve(objective, opt, batch, w0, l2, lower_bounds, upper_bounds):
+    """Module-level jit: one compiled solve per (objective statics,
+    OptimizerConfig) pair, reused by every λ of a train_glm path and by every
+    later train_glm call on the same shapes. The batch, the warm start, the
+    L2 weight and the box are ARGUMENTS, so no feature block is baked into a
+    program; ``objective`` carries ``l2_weight`` 0. The L1 weight of an
+    elastic-net λ lives in the static ``opt``: one program per λ there."""
+    return solve(
+        opt, _RidgeBoundObjective(objective, batch, l2), w0,
+        lower_bounds=lower_bounds, upper_bounds=upper_bounds,
+    )
+
+
 def train_glm_tournament(
     batch: LabeledPointBatch,
     task: TaskType,
@@ -1258,6 +1297,13 @@ def train_glm(
     Returned models are in original feature space (warm starts stay in
     normalized space internally).
 
+    Every λ is solved by ONE cached program (``_jitted_path_solve``) whose
+    arguments are the batch, the warm start, the box and the L2 weight: an
+    L2 path compiles one program whatever its length, and a caller that
+    refits a resident batch (same shapes, same optimizer, the same
+    ``normalization`` object) pays a dispatch per λ — nothing is traced,
+    lowered or loaded again. Nothing between two λ waits on the device.
+
     telemetry: optional ``telemetry.SolverTelemetry`` — one convergence row
     (iterations, reason, value history) per λ solve.
     """
@@ -1275,41 +1321,49 @@ def train_glm(
             "(elastic_net_alpha must be 0)"
         )
     loss = loss_for_task(task)
+    if lower_bounds is not None:
+        lower_bounds = jnp.asarray(lower_bounds, batch.dtype)
+    if upper_bounds is not None:
+        upper_bounds = jnp.asarray(upper_bounds, batch.dtype)
+    # λ's L2 is an argument of the solve, so the program's objective has none
+    objective = _objective_for_batch(batch, loss, 0.0, normalization,
+                                     use_pallas=None)
+    norm = objective.normalization
     models: dict[float, GeneralizedLinearModel] = {}
     w = jnp.zeros((batch.dim,), dtype=batch.solve_dtype)
     for lam in sorted(regularization_weights):
         l1 = elastic_net_alpha * lam
         l2 = (1.0 - elastic_net_alpha) * lam
-        objective = _objective_for_batch(batch, loss, l2, normalization,
-                                         use_pallas=None)
         opt = optimizer
         if l1 > 0.0:
             opt = dataclasses.replace(
                 optimizer.with_l1(l1), optimizer_type=OptimizerType.OWLQN
             )
-        result = solve(
-            opt, objective.bind(batch), w,
-            lower_bounds=None if lower_bounds is None else jnp.asarray(lower_bounds, batch.dtype),
-            upper_bounds=None if upper_bounds is None else jnp.asarray(upper_bounds, batch.dtype),
+        result = _jitted_path_solve(
+            objective, opt, batch, w, np.asarray(l2, batch.solve_dtype),
+            lower_bounds, upper_bounds,
         )
         w = result.coefficients
         if telemetry is not None:
             telemetry.record_solve("glm", result, extra={"lambda": lam})
             telemetry.heartbeat("glm", lam=lam,
                                 n_lambdas=len(regularization_weights))
-        norm = objective.normalization
         means = norm.to_model_space(w, intercept_index)
         variances = None
         if compute_variance:
-            variances = norm.variances_to_model_space(
-                coefficient_variances(objective, w, batch, mode=variance_mode)
-            )
+            # the Hessian of THIS λ's objective: its own L2 inside
+            variances = norm.variances_to_model_space(coefficient_variances(
+                _objective_for_batch(batch, loss, l2, normalization,
+                                     use_pallas=None),
+                w, batch, mode=variance_mode,
+            ))
         models[lam] = GeneralizedLinearModel(
             Coefficients(means=means, variances=variances), task
         )
-        logger.info(
-            "trained λ=%g: value=%g iters=%d", lam, float(result.value), int(result.iterations)
-        )
+        if logger.isEnabledFor(logging.INFO):  # the reads wait for the device
+            logger.info(
+                "trained λ=%g: value=%g iters=%d", lam, float(result.value), int(result.iterations)
+            )
     return models
 
 
