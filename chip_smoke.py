@@ -4,7 +4,8 @@
 One process drives, in order, through the entry points a user would call:
 
   kernel   ops.pallas_glm.fused_value_and_gradient vs the autodiff objective
-           at d in {256, 512, 2048, 4096} x {f32, bf16}: Mosaic custom call
+           at d in {256, 512, 2048, 4096} x {f32, bf16} on whole tiles and at
+           d = 2000, 617 rows short of them (the masked body): Mosaic custom call
            present, value/gradient agree with an f64 numpy recomputation
   glmix    cli.game_training_driver.main  (TrainingExampleAvro on disk ->
            FE d=256 + per-user RE + per-item RE d=16, logistic, fused
@@ -64,6 +65,9 @@ class Sizes:
     glm_n_val: int = 16384
     kernel_widths: tuple = (256, 512, 2048, 4096)
     kernel_tiles: int = 8
+    #: (width, rows short of ``kernel_tiles`` whole tiles): neither a whole
+    #: number of lanes nor of row tiles, so Mosaic compiles the masked body
+    kernel_ragged: tuple = (2000, 617)
     serve_requests: int = 64
     serve_request_rows: int = 16
     # widths: FE 255 features + intercept, RE 15 + intercept, GLM 511 + intercept
@@ -82,6 +86,7 @@ FULL = Sizes()
 REHEARSAL = Sizes(
     n_train=4096, n_val=1024, n_users=60, n_items=40, glm_n=4096,
     glm_n_val=1024, kernel_widths=(256, 512), kernel_tiles=2,
+    kernel_ragged=(200, 617),
     auc_lift_floor=0.5,  # 4096 rows cannot pin 256 + 100 x 16 coefficients
 )
 
@@ -406,7 +411,11 @@ def leg_kernel(c: Checks, sizes: Sizes, on_tpu: bool) -> None:
     from photon_ml_tpu.data.batch import LabeledPointBatch
     from photon_ml_tpu.ops.losses import LogisticLoss
     from photon_ml_tpu.ops.objective import GLMObjective
-    from photon_ml_tpu.ops.pallas_glm import _row_tile, fused_value_and_gradient
+    from photon_ml_tpu.ops.pallas_glm import (
+        _round_up,
+        _row_tile,
+        fused_value_and_gradient,
+    )
 
     def rel(a, b):
         a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
@@ -414,10 +423,12 @@ def leg_kernel(c: Checks, sizes: Sizes, on_tpu: bool) -> None:
 
     loss = LogisticLoss()
     reference = GLMObjective(loss, l2_weight=0.5, use_pallas=False)
-    for d in sizes.kernel_widths:
+    shapes = [(d, 0) for d in sizes.kernel_widths] + [sizes.kernel_ragged]
+    for d, rows_short in shapes:
         for dtype in (jnp.float32, jnp.bfloat16):
-            name = f"kernel d={d} {jnp.dtype(dtype).name}"
-            n = sizes.kernel_tiles * _row_tile(d, jnp.dtype(dtype).itemsize)
+            tile = _row_tile(_round_up(d, 128), jnp.dtype(dtype).itemsize)
+            n = sizes.kernel_tiles * tile - rows_short
+            name = f"kernel d={d} {jnp.dtype(dtype).name} n={n}"
             rng = np.random.default_rng(d)
             w = rng.normal(size=d).astype(np.float32)
             y = (rng.random(n) < 0.5).astype(np.float32)

@@ -33,8 +33,32 @@ numpy recomputation):
 
 Accumulator outputs (value, gradient, Σr) map to the same block every grid
 step, making them sequential accumulators (TPU grids are serialized),
-initialized at step 0. Padding rows carry weight 0 and padded feature /
-coefficient columns are 0, so they contribute nothing.
+initialized at step 0.
+
+The kernel reads X ``[n, d]`` and the aux block ``[n, 3]`` AS THEY LIE
+(PR 33): the grid is ``cdiv(n, tile)`` and the X block ``(tile, d_pad)``
+over the ``d``-wide array, so the last row tile and the last lanes are
+partial blocks, whose out-of-bounds part is undefined on read (the
+interpreter fills it with NaN; 0 x NaN is NaN, so nothing there may be
+multiplied away). What the zero padding did outside is done on the tile in
+VMEM, by selects on an iota that follow from the static shape alone:
+
+- ``d % 128 != 0``: lanes at or past ``d`` of the X tile are selected to
+  zero before the margin's reduction; ``w`` arrives zero-padded to ``d_pad``
+  (a ``d``-float pad) and the gradient leaves ``d_pad`` wide.
+- ``n % tile != 0``: a second body, which only the LAST grid step runs
+  (``pl.when``), also zeroes the rows at or past ``n`` of the X tile and of
+  ``r`` and ``ws * l``; every other step runs the unmasked body.
+- whole tiles and whole lanes: no mask is emitted at all, and the kernel's
+  jaxpr is letter for letter the one the padded wrapper ran.
+
+The zeros stand exactly where the wrapper's ``jnp.pad`` put them, under the
+same tile and the same ``d_pad``-lane reduction, so value and gradient are
+the padded call's BIT FOR BIT (tests/test_pallas_glm.py in the interpreter;
+on the chip at 400,000 x 2,000, 99,999 x 4,000 and in bf16: my chip run,
+PR 33). The pad it replaces was ``f32[400384,2048] pad(f32[400000,2000])``
+inside every evaluation of the dense benchmark cell: 9.96 ms beside the
+kernel's 4.61 ms, 55 times a fit (PERF.md §6, PR 33).
 
 On the ``cpu`` platform the kernel runs in Pallas interpret mode so the
 same code path is testable there; on ``tpu`` it is always compiled by
@@ -78,6 +102,9 @@ MAX_KERNEL_DIM = 16384
 #: kernel (and never the interpreter) without anyone reading HLO
 TRACES_COMPILED = "ops/pallas_glm/traces_compiled"
 TRACES_INTERPRETED = "ops/pallas_glm/traces_interpreted"
+#: bumped once per trace of the kernel in which a masked body was emitted
+#: (rows past the last whole tile, or lanes past the last whole 128)
+TRACES_RAGGED = "ops/pallas_glm/traces_ragged"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -100,38 +127,72 @@ def _row_tile(d_pad: int, itemsize: int) -> int:
     return int(np.clip(rows // sublane * sublane, sublane, cap))
 
 
-def _kernel(loss: PointwiseLoss, x_ref, aux_ref, w_ref,
+def _kernel(loss: PointwiseLoss, n: int, d: int, x_ref, aux_ref, w_ref,
             val_ref, grad_ref, rsum_ref):
-    @pl.when(pl.program_id(0) == 0)
+    tile, d_pad = x_ref.shape
+    step = pl.program_id(0)
+
+    @pl.when(step == 0)
     def _init():
         val_ref[0, 0] = jnp.float32(0.0)
         rsum_ref[0, 0] = jnp.float32(0.0)
         grad_ref[:] = jnp.zeros_like(grad_ref)
 
-    x = x_ref[:].astype(jnp.float32)  # [tile, d_pad], streamed f32 or bf16
-    w = w_ref[:]  # [1, d_pad], f32
-    aux = aux_ref[:]  # [tile, 3]: labels | offsets | weights
-    y, o, ws = aux[:, 0:1], aux[:, 1:2], aux[:, 2:3]
-    margins = jnp.sum(x * w, axis=1, keepdims=True) + o
-    l, dz = loss.loss_and_dz(margins, y)
-    r = ws * dz  # [tile, 1] f32
-    val_ref[0, 0] += jnp.sum(ws * l)
-    # Σr feeds the normalized-space chain rule (grad shift term) for free
-    rsum_ref[0, 0] += jnp.sum(r)
-    grad_ref[:] = grad_ref[:] + jnp.sum(r * x, axis=0, keepdims=True)
+    def accumulate(rows):
+        """One tile into the accumulators. ``rows`` is None on a whole tile,
+        else how many of the tile's rows the array has: what lies past an
+        array's edge in a partial block is undefined on read (the interpreter
+        fills it with NaN), so it is selected away, never multiplied by 0."""
+        x = x_ref[:].astype(jnp.float32)  # [tile, d_pad], streamed f32 or bf16
+        keep = live = None
+        if d != d_pad:
+            keep = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < d
+        if rows is not None:
+            live = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < rows
+            keep = live if keep is None else keep & live
+        if keep is not None:
+            x = jnp.where(keep, x, 0.0)
+        w = w_ref[:]  # [1, d_pad], f32, zero past d
+        aux = aux_ref[:]  # [tile, 3]: labels | offsets | weights
+        y, o, ws = aux[:, 0:1], aux[:, 1:2], aux[:, 2:3]
+        margins = jnp.sum(x * w, axis=1, keepdims=True) + o
+        l, dz = loss.loss_and_dz(margins, y)
+
+        def alive(v):
+            return v if live is None else jnp.where(live, v, 0.0)
+
+        r = alive(ws * dz)  # [tile, 1] f32
+        val_ref[0, 0] += jnp.sum(alive(ws * l))
+        # Σr feeds the normalized-space chain rule (grad shift term) for free
+        rsum_ref[0, 0] += jnp.sum(r)
+        grad_ref[:] = grad_ref[:] + jnp.sum(r * x, axis=0, keepdims=True)
+
+    if n % tile == 0:
+        accumulate(None)
+    else:
+        last = pl.num_programs(0) - 1
+        pl.when(step != last)(lambda: accumulate(None))
+        pl.when(step == last)(lambda: accumulate(n % tile))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _fused_padded(loss: PointwiseLoss, x, aux, interpret: bool, w):
-    n_pad, d_pad = x.shape
+    """``x`` [n, d] and ``aux`` [n, 3] as they lie; ``w`` [d_pad], zero past
+    d. The name is the one the device trace knows the kernel by."""
+    n, d = x.shape
+    d_pad = w.shape[0]
     tile = _row_tile(d_pad, x.dtype.itemsize)
-    grid = (n_pad // tile,)
+    if n == 0:  # an empty grid would leave the accumulators unwritten
+        zero = jnp.float32(0.0)
+        return zero, jnp.zeros((d_pad,), jnp.float32), zero
+    if n % tile or d != d_pad:
+        default_registry().counter(TRACES_RAGGED).inc()
 
     vmem = {} if interpret else dict(memory_space=pltpu.VMEM)
     smem = {} if interpret else dict(memory_space=pltpu.SMEM)
     value, grad, rsum = pl.pallas_call(
-        functools.partial(_kernel, loss),
-        grid=grid,
+        functools.partial(_kernel, loss, n, d),
+        grid=(pl.cdiv(n, tile),),
         in_specs=[
             pl.BlockSpec((tile, d_pad), lambda i: (i, 0), **vmem),
             pl.BlockSpec((tile, 3), lambda i: (i, 0), **vmem),
@@ -187,9 +248,10 @@ def fused_value_and_gradient(
 
     bf16 feature blocks stream as bf16 (half the HBM traffic) with all
     accumulation in f32; coefficients/value/gradient stay f32 throughout.
-    Inputs of any shape are zero-padded to (tile-multiple rows, 128m cols);
-    padded rows get weight 0 and padded columns 0 coefficients,
-    contributing nothing.
+    Inputs of any shape go to the kernel as they are: no copy of
+    ``batch.features`` or of the aux block is made to reach whole tiles
+    (the kernel masks its last row tile and its last lanes itself, see the
+    module header); only the coefficients are zero-padded to 128m lanes.
     """
     if interpret is None:
         interpret = _should_interpret()
@@ -199,10 +261,8 @@ def fused_value_and_gradient(
     x = batch.features
     if x.dtype not in (jnp.float32, jnp.bfloat16):
         x = jnp.asarray(x, jnp.float32)
-    n, d = x.shape
-    tile = _row_tile(_round_up(d, _LANE), x.dtype.itemsize)
-    n_pad, d_pad = _round_up(max(n, 1), tile), _round_up(d, _LANE)
-    x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
+    d = x.shape[1]
+    d_pad = _round_up(d, _LANE)
     factors = shifts = None
     if normalization is not None:
         factors, shifts = normalization.factors, normalization.shifts
@@ -218,7 +278,6 @@ def fused_value_and_gradient(
         offsets,
         jnp.asarray(batch.weights, jnp.float32),
     ], axis=1)
-    aux = jnp.pad(aux, ((0, n_pad - n), (0, 0)))
     value, grad, rsum = _fused_padded(loss, x, aux, bool(interpret), w)
     grad = grad[:d]
     if shifts is not None:

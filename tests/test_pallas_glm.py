@@ -277,3 +277,137 @@ def test_kernel_traces_are_counted():
     batch = _batch(16, 4)
     kernel_mod.fused_value_and_gradient(SquaredLoss(), jnp.zeros(4), batch)
     assert counter.value == before + 1
+
+
+# -- X as it lies: the last row tile and the last lanes are masked in the kernel
+
+
+def _ragged_shapes(tile):
+    return [
+        pytest.param(tile + 300, 256, id="rows"),        # n % tile != 0 only
+        pytest.param(tile, 200, id="lanes"),             # d % 128 != 0 only
+        pytest.param(tile + 300, 200, id="rows+lanes"),
+        pytest.param(300, 20, id="n<tile"),
+        pytest.param(tile + 1, 130, id="n=tile+1"),
+    ]
+
+
+RAGGED = [
+    pytest.param(*shape.values, dtype, normalized,
+                 id=f"{shape.id}-{dtype}{'-normalized' if normalized else ''}")
+    for dtype, tile in (("float32", 1024), ("bfloat16", 2048))
+    for shape in _ragged_shapes(tile)
+    for normalized in (False, True)
+]
+
+
+@pytest.mark.parametrize("n,d,dtype,normalized", RAGGED)
+def test_ragged_shapes_match_autodiff_and_the_zero_padded_call(n, d, dtype, normalized):
+    """A partial block's out-of-bounds part is undefined on read (the
+    interpreter fills it with NaN). Held here: value and gradient against
+    autodiff on the same stored features, and BIT FOR BIT against the same
+    call on a batch explicitly padded to whole tiles (zero rows of weight 0,
+    zero columns with zero coefficients): what the wrapper's ``jnp.pad`` of X
+    built before, which the masks have to reproduce exactly."""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+    from photon_ml_tpu.ops.normalization import NormalizationType, build_normalization
+
+    dtype = jnp.dtype(dtype)
+    d_pad = kernel_mod._round_up(d, 128)
+    tile = kernel_mod._row_tile(d_pad, dtype.itemsize)
+    n_pad = kernel_mod._round_up(n, tile)
+    assert (n % tile, d % 128) != (0, 0)
+
+    rng = np.random.default_rng(n + d)
+    batch = _batch(n, d, seed=n, binary=True)
+    batch = batch.replace(features=batch.features.astype(dtype))
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32)) * 0.3
+    mean = rng.normal(size=d).astype(np.float32)
+    variance = rng.uniform(0.5, 4.0, size=d).astype(np.float32)
+
+    def context(width):
+        if not normalized:
+            return None
+        return build_normalization(
+            NormalizationType.STANDARDIZATION,
+            mean=jnp.pad(jnp.asarray(mean), (0, width - d)),
+            variance=jnp.pad(jnp.asarray(variance), (0, width - d),
+                             constant_values=1.0),
+            max_magnitude=jnp.ones(width), intercept_index=0)
+
+    loss = LogisticLoss()
+    v, g = fused_value_and_gradient(
+        loss, w, batch, l2_weight=0.7, normalization=context(d), interpret=True)
+
+    stored = batch.replace(features=batch.features.astype(jnp.float32))
+    objective = GLMObjective(
+        loss, l2_weight=0.7, normalization=context(d), use_pallas=False)
+    ref_v, ref_g = jax.value_and_grad(objective.value)(w, stored)
+    np.testing.assert_allclose(float(v), float(ref_v), rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(ref_g), rtol=2e-4, atol=2e-4)
+
+    rows, cols = (0, n_pad - n), (0, d_pad - d)
+    padded = LabeledPointBatch(
+        features=jnp.pad(batch.features, (rows, cols)),
+        labels=jnp.pad(batch.labels, rows), offsets=jnp.pad(batch.offsets, rows),
+        weights=jnp.pad(batch.weights, rows))
+    pv, pg = fused_value_and_gradient(
+        loss, jnp.pad(w, cols), padded, l2_weight=0.7,
+        normalization=context(d_pad), interpret=True)
+    assert float(v) == float(pv)
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(pg)[:d])
+    assert not np.any(np.asarray(pg)[d:])
+
+
+def _kernel_body(n, d):
+    """The jaxpr Mosaic is handed for an [n, d] float32 feature block."""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+
+    closed = jax.make_jaxpr(
+        lambda x, aux, w: kernel_mod._fused_padded(SquaredLoss(), x, aux, True, w))(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+        jax.ShapeDtypeStruct((kernel_mod._round_up(d, 128),), jnp.float32))
+    (call,) = [e for e in closed.jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    return str(call.params["jaxpr"])
+
+
+@pytest.mark.parametrize("n,d,bodies,iotas", [
+    pytest.param(2048, 256, 1, 0, id="whole-tiles"),  # the GLMix cells' kind
+    pytest.param(2048, 200, 1, 1, id="lanes"),  # a lane mask, on every step
+    pytest.param(2000, 256, 2, 1, id="rows"),  # a row mask, in the last step's body
+    pytest.param(2000, 200, 2, 3, id="rows+lanes"),  # lanes in both, rows in the last
+])
+def test_masks_follow_from_the_static_shape(n, d, bodies, iotas):
+    """No option decides what is masked: ``n % tile`` and ``d % 128`` do, at
+    trace time. On whole tiles the kernel holds no mask at all (it is the
+    kernel the padded wrapper ran); rows past ``n`` are masked in a second
+    body, which only the last grid step runs."""
+    body = _kernel_body(n, d)
+    assert body.count("reduce_sum[axes=(1,)") == bodies  # one margin sum a body
+    assert body.count(" iota[") == iotas  # every mask is a comparison on an iota
+
+
+@pytest.mark.parametrize("n,d,ragged", [
+    pytest.param(1024, 128, 0, id="whole-tiles"),
+    pytest.param(1000, 128, 1, id="rows"),
+    pytest.param(1024, 100, 1, id="lanes"),
+])
+def test_ragged_traces_are_counted(n, d, ragged):
+    """``traces_ragged`` rises once for every trace of the kernel in which a
+    masked body was emitted, and never on whole tiles."""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    counter = default_registry().counter(kernel_mod.TRACES_RAGGED)
+    kernel_mod._fused_padded.clear_cache()  # a cached trace emits nothing anew
+    before = counter.value
+    fused_value_and_gradient(PoissonLoss(), jnp.zeros(d), _batch(n, d), interpret=True)
+    assert counter.value == before + ragged
+
+
+def test_an_empty_batch_is_zero():
+    batch = _batch(0, 8)
+    v, g = fused_value_and_gradient(SquaredLoss(), jnp.ones(8), batch, interpret=True)
+    assert float(v) == 0.0 and not np.any(np.asarray(g))

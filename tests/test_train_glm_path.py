@@ -278,3 +278,54 @@ def test_program_takes_the_batch_as_input_and_bakes_in_none_of_it(layout):
     eager = jax.make_jaxpr(
         lambda w: solve(opt, objective.bind(batch), w))(w0)
     assert any(np.size(c) == largest for c in _constants(eager))
+
+
+# -- (e) the kernel reads the feature block as it lies ------------------------
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, but a Pallas
+    kernel's body: what happens there happens on a tile in VMEM."""
+    stack = [jaxpr]
+    while stack:
+        for eqn in stack.pop().eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (tuple, list)) else (value,):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        stack.append(inner)
+
+
+@pytest.mark.parametrize("n,d,ragged", [
+    pytest.param(1000, 200, 1, id="ragged"),  # neither whole tiles nor whole lanes
+    pytest.param(1024, 256, 0, id="whole-tiles"),
+])
+def test_no_copy_of_the_feature_block_is_made_for_the_kernel(n, d, ragged):
+    """No equation of the path's program builds an array of the feature
+    block's size or more by padding, concatenating or updating (the kernel
+    wrapper used to pad X to whole tiles inside EVERY evaluation: 3.28 GB
+    written and read again, 55 times a fit of the dense benchmark cell), and
+    the kernel's trace counts itself as ragged exactly when it masks."""
+    from photon_ml_tpu.ops import pallas_glm
+
+    batch = _batch("dense", seed=7, n=n, d=d, dtype=np.float32)
+    objective = estimators._objective_for_batch(
+        batch, loss_for_task(TASK), 0.0, None, use_pallas=True)
+    opt = OptimizerConfig(max_iterations=5)
+    counter = default_registry().counter(pallas_glm.TRACES_RAGGED)
+    before = counter.value
+    traced = estimators._jitted_path_solve.trace(
+        objective, opt, batch, jnp.zeros((d,), jnp.float32),
+        np.asarray(1.0, np.float32), None, None).jaxpr
+    assert counter.value == before + ragged
+
+    equations = list(_equations(traced.jaxpr))
+    assert any(e.primitive.name == "pallas_call" for e in equations)
+    builders = {"pad", "concatenate", "dynamic_update_slice"}
+    assert not [
+        (e.primitive.name, v.aval.shape) for e in equations
+        if e.primitive.name in builders
+        for v in e.outvars if np.prod(v.aval.shape) >= n * d]
