@@ -20,6 +20,7 @@ scatters straight back into the [E, k] factor table.
 from __future__ import annotations
 
 import dataclasses
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ from photon_ml_tpu.algorithm.coordinates import (
     CoordinateOptimizationConfig,
     _bucket_offsets,
     _make_objective,
+    _mask_padding_lanes,
     _solve_bucket_entities,
     _solve_config,
 )
@@ -44,9 +46,12 @@ from photon_ml_tpu.models.matrix_factorization import (
     init_factors,
 )
 from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.optim.common import LaneTrace
 from photon_ml_tpu.optim.optimizer import OptimizerConfig
 from photon_ml_tpu.telemetry.program_ledger import ledger_jit
+from photon_ml_tpu.telemetry.registry import default_registry
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.util.timed import Timed
 
 Array = jax.Array
 
@@ -62,12 +67,16 @@ class MFSideBucket:
     labels/weights: [e, cap] (weight 0 marks padding)
     entity_rows:    [e]      row in this side's entity vocab
     sample_rows:    [e, cap] global sample row per slot, -1 pad
+
+    The packer leaves all four on the HOST: whoever solves them places them
+    (``GameTrainProgram.shard_inputs`` shard by shard over its mesh,
+    ``MatrixFactorizationCoordinate`` once on the default device).
     """
 
-    labels: Array
-    weights: Array
-    entity_rows: Array
-    sample_rows: Array
+    labels: "np.ndarray | Array"
+    weights: "np.ndarray | Array"
+    entity_rows: "np.ndarray | Array"
+    sample_rows: "np.ndarray | Array"
 
     @property
     def num_entities(self) -> int:
@@ -84,6 +93,18 @@ class MFDataset:
     col_buckets: list[MFSideBucket]
     num_row_entities: int
     num_col_entities: int
+
+    def pad_fractions(self) -> tuple[float, float]:
+        """(row side, column side): the share of the ``[e, cap]`` slots that
+        is padding, which the gathers and the lanes pay for the ladder."""
+
+        def padding(buckets) -> float:
+            slots = sum(int(b.sample_rows.size) for b in buckets)
+            kept = sum(int(np.count_nonzero(np.asarray(b.sample_rows) >= 0))
+                       for b in buckets)
+            return 1.0 - kept / slots if slots else 0.0
+
+        return padding(self.row_buckets), padding(self.col_buckets)
 
     def trained_masks(self) -> tuple[np.ndarray, np.ndarray]:
         """Boolean [R] / [C] masks of entities that appear in any bucket.
@@ -134,14 +155,8 @@ def _build_side_buckets(
         bl[lane, slot] = labels[rows_concat]
         bw[lane, slot] = weights[rows_concat] * (other_idx[rows_concat] >= 0)
         bs[lane, slot] = rows_concat
-        buckets.append(
-            MFSideBucket(
-                labels=jnp.asarray(bl),
-                weights=jnp.asarray(bw),
-                entity_rows=jnp.asarray(be),
-                sample_rows=jnp.asarray(bs),
-            )
-        )
+        buckets.append(MFSideBucket(
+            labels=bl, weights=bw, entity_rows=be, sample_rows=bs))
     return buckets
 
 
@@ -154,27 +169,36 @@ def build_mf_dataset(
     active_data_upper_bound: int | None = None,
     seed: int = 0,
 ) -> MFDataset:
-    labels = dataset.host_array("labels")
-    weights = dataset.host_array("weights")
-    unique_ids = np.asarray(dataset.unique_ids)
-    row_idx = dataset.host_array(f"entity_idx/{row_effect_type}")
-    col_idx = dataset.host_array(f"entity_idx/{col_effect_type}")
-    return MFDataset(
-        row_effect_type=row_effect_type,
-        col_effect_type=col_effect_type,
-        row_buckets=_build_side_buckets(
-            row_idx, col_idx, labels, weights, unique_ids,
-            bucket_sizes=bucket_sizes,
-            active_data_upper_bound=active_data_upper_bound, seed=seed,
-        ),
-        col_buckets=_build_side_buckets(
-            col_idx, row_idx, labels, weights, unique_ids,
-            bucket_sizes=bucket_sizes,
-            active_data_upper_bound=active_data_upper_bound, seed=seed,
-        ),
-        num_row_entities=len(dataset.entity_vocabs[row_effect_type]),
-        num_col_entities=len(dataset.entity_vocabs[col_effect_type]),
-    )
+    """Both sides' buckets, packed on the host and left there. Timed as
+    ``pack/mf_side_buckets``; the padding the ladder costs each side is left
+    in the gauges ``mf/<row>_x_<col>/row_pad_fraction`` and ``col_pad_fraction``."""
+    with Timed("pack/mf_side_buckets", logging.DEBUG):
+        labels = dataset.host_array("labels")
+        weights = dataset.host_array("weights")
+        unique_ids = np.asarray(dataset.unique_ids)
+        row_idx = dataset.host_array(f"entity_idx/{row_effect_type}")
+        col_idx = dataset.host_array(f"entity_idx/{col_effect_type}")
+        mf = MFDataset(
+            row_effect_type=row_effect_type,
+            col_effect_type=col_effect_type,
+            row_buckets=_build_side_buckets(
+                row_idx, col_idx, labels, weights, unique_ids,
+                bucket_sizes=bucket_sizes,
+                active_data_upper_bound=active_data_upper_bound, seed=seed,
+            ),
+            col_buckets=_build_side_buckets(
+                col_idx, row_idx, labels, weights, unique_ids,
+                bucket_sizes=bucket_sizes,
+                active_data_upper_bound=active_data_upper_bound, seed=seed,
+            ),
+            num_row_entities=len(dataset.entity_vocabs[row_effect_type]),
+            num_col_entities=len(dataset.entity_vocabs[col_effect_type]),
+        )
+    for side, fraction in zip(("row", "col"), mf.pad_fractions()):
+        default_registry().gauge(
+            f"mf/{row_effect_type}_x_{col_effect_type}/{side}_pad_fraction"
+        ).set(fraction)
+    return mf
 
 
 def solve_mf_side_bucket(
@@ -188,9 +212,11 @@ def solve_mf_side_bucket(
     other_factors: Array,   # [E_other, k] the fixed side's factor table
     full_offsets: Array,    # [n] base + residual offsets
     table: Array,           # [E_this, k] this side's factor table
-) -> Array:
+) -> tuple[Array, LaneTrace]:
     """One alternating half-step over one bucket: gather the fixed side's
-    factors as features, vmap-solve every entity, scatter back.
+    factors as features, vmap-solve every entity, scatter back. Returns the
+    table and the lanes' trace (padding lanes masked invalid), as
+    ``solve_entity_bucket_traced`` does for a random-effect bucket.
 
     Pure/traceable: reused by the single-chip jit wrapper below and by the
     mesh-sharded fused GAME step (parallel/distributed.py), where the
@@ -201,10 +227,11 @@ def solve_mf_side_bucket(
     pad = sample_rows < 0
     feats = jnp.where(pad[..., None] | (oidx < 0)[..., None], 0.0, feats)
     offsets = _bucket_offsets(sample_rows, full_offsets)
-    solved, _trace = _solve_bucket_entities(
+    solved, trace = _solve_bucket_entities(
         objective, opt, feats, labels, weights, offsets, table[entity_rows]
     )
-    return table.at[entity_rows].set(solved)
+    trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
+    return table.at[entity_rows].set(solved), trace
 
 
 @partial(ledger_jit, label="coord/mf_side_solve", static_argnums=(0, 1))
@@ -223,7 +250,7 @@ def _jitted_mf_side_solve(
     return solve_mf_side_bucket(
         objective, opt, labels, weights, entity_rows, sample_rows,
         other_idx_full, other_factors, full_offsets, table,
-    )
+    )[0]
 
 
 @dataclasses.dataclass
@@ -243,6 +270,14 @@ class MatrixFactorizationCoordinate(Coordinate):
     num_latent_factors: int
     num_alternations: int = 2
     seed: int = 0
+
+    def __post_init__(self):
+        # the packer leaves the buckets on the host: commit them to the
+        # default device once, not at every half-step's call
+        self._row_buckets, self._col_buckets = (
+            [MFSideBucket(*jax.device_put(
+                (b.labels, b.weights, b.entity_rows, b.sample_rows))) for b in side]
+            for side in (self.mf_dataset.row_buckets, self.mf_dataset.col_buckets))
 
     def initial_model(self) -> MatrixFactorizationModel:
         mf = self.mf_dataset
@@ -286,12 +321,12 @@ class MatrixFactorizationCoordinate(Coordinate):
         col_idx = self.dataset.entity_idx[mf.col_effect_type]
         rows, cols = model.row_factors, model.col_factors
         for _ in range(self.num_alternations):
-            for b in mf.row_buckets:
+            for b in self._row_buckets:
                 rows = _jitted_mf_side_solve(
                     objective, opt, b.labels, b.weights, b.entity_rows,
                     b.sample_rows, col_idx, cols, full_offsets, rows,
                 )
-            for b in mf.col_buckets:
+            for b in self._col_buckets:
                 cols = _jitted_mf_side_solve(
                     objective, opt, b.labels, b.weights, b.entity_rows,
                     b.sample_rows, row_idx, rows, full_offsets, cols,

@@ -212,11 +212,14 @@ def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace
 
 
 #: the registry counters (``solver/<name>``) a fused sweep reports: the
-#: four of :func:`lane_solver_counts`, random-effect coordinates summed, and
-#: the fixed-effect solves' own trials and floor exits
+#: four of :func:`lane_solver_counts`, random-effect coordinates summed, the
+#: fixed-effect solves' own trials and floor exits, and the same four of the
+#: matrix-factorization half-steps' lanes (``mf_*``; zero without such a
+#: coordinate)
 SOLVER_COUNT_NAMES = (
     "lockstep_trials", "lane_trials", "floor_exits", "line_searches",
     "fe_trials", "fe_floor_exits",
+    "mf_lockstep_trials", "mf_lane_trials", "mf_floor_exits", "mf_line_searches",
 )
 
 

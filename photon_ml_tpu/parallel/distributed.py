@@ -638,10 +638,10 @@ class GameTrainProgram:
             m.name: {
                 side: [
                     {
-                        "labels": b.labels,
-                        "weights": b.weights,
-                        "sample_rows": b.sample_rows,
-                        "entity_rows": b.entity_rows,
+                        "labels": _input_leaf(b.labels),
+                        "weights": _input_leaf(b.weights),
+                        "sample_rows": _input_leaf(b.sample_rows),
+                        "entity_rows": _input_leaf(b.entity_rows),
                     }
                     for b in side_buckets
                 ]
@@ -972,9 +972,10 @@ class GameTrainProgram:
                     data, name, tables[name], spec.feature_shard_id
                 )
             else:  # mf
-                mf_rows[name], mf_cols[name], scores[name] = jits["mf_solve"](
-                    data, buckets, name, off, mf_rows[name], mf_cols[name]
-                )
+                mf_rows[name], mf_cols[name], scores[name], _counts = (
+                    jits["mf_solve"](
+                        data, buckets, name, off, mf_rows[name], mf_cols[name]
+                    ))
         total = jits["offsets"](base, scores, None)
         loss = jits["loss"](labels, weights, total)
         new_state = GameTrainState(
@@ -1151,10 +1152,12 @@ class GameTrainProgram:
                     self._re_by_name[name].feature_shard_id,
                 )
             else:  # mf
-                mf_rows[name], mf_cols[name], scores[name] = self._solve_mf(
-                    data, buckets, name, offsets_excluding(name),
-                    mf_rows[name], mf_cols[name],
-                )
+                mf_rows[name], mf_cols[name], scores[name], counts = (
+                    self._solve_mf(
+                        data, buckets, name, offsets_excluding(name),
+                        mf_rows[name], mf_cols[name],
+                    ))
+                _add_counts(solver_counts, counts)
 
         total_margin = offsets_excluding()
         train_loss = self._weighted_loss(labels, weights, total_margin)
@@ -1162,7 +1165,7 @@ class GameTrainProgram:
             fe_coefficients=fe_w, re_tables=tables,
             mf_rows=mf_rows, mf_cols=mf_cols, extra_fe=extra_fe,
         )
-        # one small array: one device-to-host read a sweep, not six
+        # one small array: one device-to-host read a sweep, not one a count
         return new_state, train_loss, jnp.stack(
             [solver_counts[name] for name in SOLVER_COUNT_NAMES])
 
@@ -1269,29 +1272,37 @@ class GameTrainProgram:
         return table, counts
 
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):
-        """One matrix-factorization coordinate (alternating vmapped solves).
-        Returns (rows, cols, score)."""
+        """One matrix-factorization coordinate (alternating vmapped solves),
+        every half-step under the scope ``mf/<name>/<side>`` so that its
+        device instructions carry the coordinate's name. Returns (rows, cols,
+        score, the half-steps' line-search counts: their buckets'
+        optim/common.lane_solver_counts summed, as ``mf_*``)."""
         m = self._mf_by_name[name]
         row_idx = data["entity_idx"][m.row_effect_type]
         col_idx = data["entity_idx"][m.col_effect_type]
         objective = self._mf_objectives[name]
         mf_buckets = buckets["__mf__"][name]
+        counts: dict = {}
+
+        def half_step(side, table, other_idx, other_factors):
+            with jax.named_scope(f"mf/{name}/{side}"):
+                for b in mf_buckets[side]:
+                    table, trace = solve_mf_side_bucket(
+                        objective, m.optimizer, b["labels"], b["weights"],
+                        b["entity_rows"], b["sample_rows"], other_idx,
+                        other_factors, full_offsets, table,
+                    )
+                    _add_counts(counts, {
+                        "mf_" + k: v
+                        for k, v in lane_solver_counts(trace).items()})
+            return table
+
         for _ in range(m.num_alternations):
-            for b in mf_buckets["row"]:
-                rows = solve_mf_side_bucket(
-                    objective, m.optimizer, b["labels"], b["weights"],
-                    b["entity_rows"], b["sample_rows"], col_idx, cols,
-                    full_offsets, rows,
-                )
-            for b in mf_buckets["col"]:
-                cols = solve_mf_side_bucket(
-                    objective, m.optimizer, b["labels"], b["weights"],
-                    b["entity_rows"], b["sample_rows"], row_idx, rows,
-                    full_offsets, cols,
-                )
+            rows = half_step("row", rows, col_idx, cols)
+            cols = half_step("col", cols, row_idx, rows)
         return rows, cols, score_matrix_factorization(
             rows, cols, row_idx, col_idx
-        )
+        ), counts
 
 
 def compute_state_variances(
@@ -2247,7 +2258,7 @@ def train_distributed(
         else:
             # no mesh to lay host arrays out over: commit them to the default
             # device once, not on every sweep's call of the step
-            data, val_data = jax.device_put((data, val_data))
+            data, val_data, buckets = jax.device_put((data, val_data, buckets))
 
         if val_data is not None and mesh is not None:
             # device twins of the evaluators (evaluation/sharded.py): consts

@@ -362,3 +362,74 @@ def test_mf_reservoir_cap_ignores_unusable_samples(rng):
     # all 6 usable samples must survive the cap with nonzero weight
     kept = sum(float((np.asarray(b.weights) > 0).sum()) for b in mf.row_buckets)
     assert kept == n_usable
+
+
+def test_one_fused_sweep_equals_the_coordinate_path(rng):
+    """The fused step's factorization coordinate is the
+    MatrixFactorizationCoordinate's update: from the same starting factors,
+    against the fixed effect the sweep has just solved, one alternation gives
+    the same two tables."""
+    from photon_ml_tpu.parallel.distributed import (
+        FixedEffectStepSpec,
+        GameTrainProgram,
+        MatrixFactorizationStepSpec,
+    )
+
+    rows, cols, y = _mf_problem(rng, n=500)
+    x = rng.normal(size=(500, 5))
+    labels = (y + x @ rng.normal(size=5) > 0).astype(np.float64)
+    ds = build_game_dataset(
+        labels=labels, feature_shards={"global": x},
+        entity_keys={"user": rows, "item": cols}, dtype=np.float64)
+    mf_dataset = build_mf_dataset(ds, "user", "item", bucket_sizes=(16, 64, 256))
+    opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=7)
+    program = GameTrainProgram(
+        TaskType.LOGISTIC_REGRESSION,
+        FixedEffectStepSpec("global", opt, l2_weight=0.5),
+        mf_specs=(MatrixFactorizationStepSpec(
+            "mf", "user", "item", 3, opt, l2_weight=0.7, seed=11),))
+    data, buckets = program.prepare_inputs(ds, {}, {"mf": mf_dataset})
+    state = program.init_state(ds, {}, {"mf": mf_dataset})
+    new_state, _loss = program.step(data, buckets, state)
+
+    coord = MatrixFactorizationCoordinate(
+        coordinate_id="mf", dataset=ds, mf_dataset=mf_dataset,
+        task=TaskType.LOGISTIC_REGRESSION,
+        config=CoordinateOptimizationConfig(optimizer=opt, l2_weight=0.7),
+        num_latent_factors=3, num_alternations=1, seed=11)
+    model = coord.initial_model()
+    np.testing.assert_array_equal(np.asarray(model.row_factors),
+                                  np.asarray(state.mf_rows["mf"]))
+    fe_margin = jnp.asarray(x) @ new_state.fe_coefficients
+    model, _ = coord.update_model(model, extra_offsets=fe_margin)
+    np.testing.assert_allclose(np.asarray(model.row_factors),
+                               np.asarray(new_state.mf_rows["mf"]), rtol=1e-9, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(model.col_factors),
+                               np.asarray(new_state.mf_cols["mf"]), rtol=1e-9, atol=1e-12)
+    assert np.abs(np.asarray(model.row_factors) - np.asarray(state.mf_rows["mf"])).max() > 1e-2
+
+
+def test_the_packer_stays_on_the_host_times_itself_and_records_its_padding():
+    """Seven users of 1 to 7 rows and two items of 14 on a ladder of (4, 8):
+    every block is a host array, the packing is timed, and the gauges read
+    the padding the ladder costs each side."""
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    users = np.repeat(np.arange(7), np.arange(1, 8))  # 28 rows
+    items = np.arange(28) % 2  # two items of 14 rows: past the top rung
+    ds = build_game_dataset(
+        labels=np.zeros(28), feature_shards={},
+        entity_keys={"user": users.astype(str), "item": items.astype(str)},
+        dtype=np.float64)
+    before = default_registry().histogram("timing/pack/mf_side_buckets").count
+    mf = build_mf_dataset(ds, "user", "item", bucket_sizes=(4, 8))
+    for b in mf.row_buckets + mf.col_buckets:
+        for block in (b.labels, b.weights, b.entity_rows, b.sample_rows):
+            assert isinstance(block, np.ndarray)
+    assert default_registry().histogram("timing/pack/mf_side_buckets").count == before + 1
+    # users: sizes 1..4 in cap 4 (16 slots, 10 rows), 5..7 in cap 8 (24, 18)
+    # items: two of 14 rows capped to the top rung, 8 (16 slots, 16 rows)
+    gauges = default_registry().snapshot()["gauges"]
+    assert gauges["mf/user_x_item/row_pad_fraction"] == pytest.approx(1 - 28 / 40)
+    assert gauges["mf/user_x_item/col_pad_fraction"] == 0.0
+    assert mf.pad_fractions() == (pytest.approx(0.3), 0.0)

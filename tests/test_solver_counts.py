@@ -11,6 +11,10 @@ import numpy as np
 import pytest
 
 from photon_ml_tpu.algorithm.coordinates import _bucket_offsets
+from photon_ml_tpu.algorithm.mf_coordinate import (
+    build_mf_dataset,
+    solve_mf_side_bucket,
+)
 from photon_ml_tpu.data.batch import LabeledPointBatch
 from photon_ml_tpu.data.game_data import (
     build_game_dataset,
@@ -26,6 +30,7 @@ from photon_ml_tpu.parallel.distributed import (
     FixedEffectStepSpec,
     GameTrainProgram,
     GameTrainState,
+    MatrixFactorizationStepSpec,
     RandomEffectStepSpec,
     train_distributed,
 )
@@ -145,10 +150,12 @@ def test_counters_equal_a_recount_from_whole_solver_results(float64_fit):
 
 def test_float32_fit_counts_searches_the_floor_ended():
     """Float32, four sweeps (the later ones start near their optimum): all
-    six counters are positive, and they hang together (a floor exit is a
-    search, a search has a trial)."""
+    six counters of a GLMix program are positive, and they hang together (a
+    floor exit is a search, a search has a trial); with no factorization
+    coordinate the ``mf_*`` four stay zero."""
     gained, _events = counted_fit(*glmix(np.float32), sweeps=4)
-    assert all(gained[name] > 0 for name in SOLVER_COUNT_NAMES), gained
+    assert all((gained[name] > 0) != name.startswith("mf_")
+               for name in SOLVER_COUNT_NAMES), gained
     assert gained["floor_exits"] <= gained["line_searches"] <= gained["lane_trials"]
     assert gained["lockstep_trials"] <= gained["lane_trials"]
     assert gained["fe_floor_exits"] <= 4  # at most one a fixed-effect solve
@@ -178,3 +185,68 @@ def test_counts_are_read_after_the_loss_has_arrived(float64_fit):
     for wait, read in zip(waits, reads):
         assert read.start >= wait.start + wait.dur
         assert read.parent == wait.parent  # the same sweep
+
+
+MF_COUNTS = ("mf_lockstep_trials", "mf_lane_trials", "mf_floor_exits",
+             "mf_line_searches")
+
+
+def test_mf_counts_are_the_half_steps_lane_traces_summed():
+    """A fused sweep with a factorization coordinate: its ``mf_*`` counts are
+    the lane traces of its half-steps' bucket solves summed (row side, then
+    column side against the rows just solved), apart from the random
+    effects' four."""
+    dataset, re_datasets, glmix_program = glmix(np.float64)
+    mf_datasets = {"mf": build_mf_dataset(dataset, "user", "item",
+                                          bucket_sizes=(8, 32, 128))}
+    opt = glmix_program.fe.optimizer
+    spec = MatrixFactorizationStepSpec("mf", "user", "item", 3, opt, l2_weight=1.0)
+    program = GameTrainProgram(
+        glmix_program.task, glmix_program.fe, glmix_program.re_specs,
+        mf_specs=(spec,))
+    data, buckets = program.prepare_inputs(dataset, re_datasets, mf_datasets)
+    state = program.init_state(dataset, re_datasets, mf_datasets)
+    new_state, _loss = program.step(data, buckets, state)
+    counts = program.take_solver_counts()
+    assert sorted(counts) == sorted(SOLVER_COUNT_NAMES)
+
+    # the half-steps again, outside the step, at the offsets the step gave them
+    scores = program._coordinate_scores(data, GameTrainState(
+        fe_coefficients=new_state.fe_coefficients, re_tables=new_state.re_tables,
+        mf_rows=state.mf_rows, mf_cols=state.mf_cols))
+    offsets = program._sum_scores(data["offsets"], scores, "mf")
+    expect = dict.fromkeys(MF_COUNTS, 0)
+    rows, cols = state.mf_rows["mf"], state.mf_cols["mf"]
+    sides = buckets["__mf__"]["mf"]
+    for side in ("row", "col"):
+        for b in sides[side]:
+            table, other, other_idx = (
+                (rows, cols, data["entity_idx"]["item"]) if side == "row"
+                else (cols, rows, data["entity_idx"]["user"]))
+            table, trace = solve_mf_side_bucket(
+                program._mf_objectives["mf"], opt, b["labels"], b["weights"],
+                b["entity_rows"], b["sample_rows"], other_idx, other, offsets, table)
+            rows, cols = (table, cols) if side == "row" else (rows, table)
+            assert bool(np.all(trace.valid))  # no mesh: no padding lane
+            trials = np.asarray(trace.line_search_trials)
+            expect["mf_lockstep_trials"] += int(trace.lockstep_trials)
+            expect["mf_lane_trials"] += int(trials.sum())
+            expect["mf_floor_exits"] += int(np.sum(trace.floor_exits))
+            expect["mf_line_searches"] += int(np.sum(trace.iterations))
+    assert {name: counts[name] for name in MF_COUNTS} == expect
+    assert 0 < counts["mf_lockstep_trials"] < counts["mf_lane_trials"]
+    np.testing.assert_allclose(np.asarray(new_state.mf_rows["mf"]), np.asarray(rows))
+    np.testing.assert_allclose(np.asarray(new_state.mf_cols["mf"]), np.asarray(cols))
+    # the random effects' lanes are counted beside them, under their own names
+    assert counts["lockstep_trials"] > 0 and counts["lane_trials"] > 0
+
+
+def test_without_an_mf_spec_the_mf_counts_are_zero_and_cost_nothing(float64_fit):
+    """A GLMix program: the four ``mf_*`` entries of the step's count vector
+    are constants of the program (literal zeros), not computed."""
+    dataset, re_datasets, program, gained, _events = float64_fit
+    assert [gained[name] for name in MF_COUNTS] == [0, 0, 0, 0]
+    data, buckets = program.prepare_inputs(dataset, re_datasets, None)
+    state = program.init_state(dataset, re_datasets, None)
+    jaxpr = jax.make_jaxpr(program._step_impl)(data, buckets, state)
+    assert jaxpr.out_avals[-1].shape == (len(SOLVER_COUNT_NAMES),)
