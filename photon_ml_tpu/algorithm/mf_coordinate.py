@@ -14,7 +14,9 @@ half-step gathers the fixed side's factors as features and reuses the
 vmapped per-entity solver (`coordinates._solve_bucket_entities`) over
 size-bucketed padded blocks. The gather happens *inside* jit, so a bucket's
 HLO is (embedding-lookup → vmapped LBFGS) fused by XLA, and each half-step
-scatters straight back into the [E, k] factor table.
+scatters straight back into the [E, k] factor table. Where a bucket's
+``cap`` is a whole number of the device's vectors the lanes read the gathered
+block ``[k, cap]``, the slots minor (``slots_minor``, ``_SlotsMinorObjective``).
 """
 
 from __future__ import annotations
@@ -36,6 +38,7 @@ from photon_ml_tpu.algorithm.coordinates import (
     _solve_bucket_entities,
     _solve_config,
 )
+from photon_ml_tpu.data.batch import LabeledPointBatch
 from photon_ml_tpu.data.game_data import (
     GameDataset,
     group_entities_into_buckets,
@@ -106,6 +109,19 @@ class MFDataset:
 
         return padding(self.row_buckets), padding(self.col_buckets)
 
+    def slots_minor_fractions(self) -> tuple[float, float]:
+        """(row side, column side): the share of the ``[e, cap]`` slots in
+        buckets whose half-step lays its gathered features out with the slots
+        minor (``slots_minor``, the rule ``solve_mf_side_bucket`` applies)."""
+
+        def share(buckets) -> float:
+            slots = sum(int(b.sample_rows.size) for b in buckets)
+            minor = sum(int(b.sample_rows.size) for b in buckets
+                        if slots_minor(int(b.sample_rows.shape[1])))
+            return minor / slots if slots else 0.0
+
+        return share(self.row_buckets), share(self.col_buckets)
+
     def trained_masks(self) -> tuple[np.ndarray, np.ndarray]:
         """Boolean [R] / [C] masks of entities that appear in any bucket.
         Entities outside (vocab members with zero samples) are never
@@ -171,7 +187,9 @@ def build_mf_dataset(
 ) -> MFDataset:
     """Both sides' buckets, packed on the host and left there. Timed as
     ``pack/mf_side_buckets``; the padding the ladder costs each side is left
-    in the gauges ``mf/<row>_x_<col>/row_pad_fraction`` and ``col_pad_fraction``."""
+    in the gauges ``mf/<row>_x_<col>/row_pad_fraction`` and ``col_pad_fraction``,
+    the share of each side's slots whose half-step takes the slots-minor
+    layout in ``row_slots_minor_fraction`` and ``col_slots_minor_fraction``."""
     with Timed("pack/mf_side_buckets", logging.DEBUG):
         labels = dataset.host_array("labels")
         weights = dataset.host_array("weights")
@@ -194,11 +212,67 @@ def build_mf_dataset(
             num_row_entities=len(dataset.entity_vocabs[row_effect_type]),
             num_col_entities=len(dataset.entity_vocabs[col_effect_type]),
         )
-    for side, fraction in zip(("row", "col"), mf.pad_fractions()):
-        default_registry().gauge(
-            f"mf/{row_effect_type}_x_{col_effect_type}/{side}_pad_fraction"
-        ).set(fraction)
+    for gauge, fractions in (("pad_fraction", mf.pad_fractions()),
+                             ("slots_minor_fraction", mf.slots_minor_fractions())):
+        for side, fraction in zip(("row", "col"), fractions):
+            default_registry().gauge(
+                f"mf/{row_effect_type}_x_{col_effect_type}/{side}_{gauge}"
+            ).set(fraction)
     return mf
+
+
+#: the width of the device's vectors: the minor axis of an array is stored in
+#: whole tiles of this many elements
+_VECTOR_LANES = 128
+
+
+def slots_minor(cap: int) -> bool:
+    """Whether a half-step over buckets of ``cap`` slots hands its lanes the
+    gathered features with the SLOTS minor, ``[e, k, cap]``. The gather writes
+    the k factors of a slot along the vector lanes, so at k = 32 three lanes
+    in four are padding in every pass of the solver over the block; with the
+    slots minor nothing is padded when ``cap`` is a whole number of vectors,
+    and more than before when it is not (8 slots: 16x). The one rule for the
+    half-step and for the ``*_slots_minor_fraction`` gauges."""
+    return cap % _VECTOR_LANES == 0
+
+
+class _SlotsMinorObjective(GLMObjective):
+    """``objective`` for a lane whose features lie ``[k, cap]``, the slots
+    minor. The layout has to be the block's LOGICAL shape: the TPU compiler
+    lays out what a solver's ``while`` carries from the loop body alone, last
+    axis minor from k = 32 up, and converts whatever it is handed (a block
+    transposed behind an ``optimization_barrier``, one held by a layout
+    constraint, even a program argument) back to that at the loop's entry.
+    The random effects keep the shared objective; this is the matrix-
+    factorization half-step's own."""
+
+    def __init__(self, objective: GLMObjective):
+        super().__init__(
+            objective.loss, l2_weight=objective.l2_weight,
+            normalization=objective.normalization,
+            axis_name=objective.axis_name, use_pallas=False)
+        self._slots_major = objective
+
+    def _key(self):
+        return super()._key() + ("slots_minor",)
+
+    def margins(self, coefficients: Array, batch: LabeledPointBatch) -> Array:
+        eff = self.normalization.effective_coefficients(coefficients)
+        shift = self.normalization.margin_shift(eff)
+        return eff @ batch.features - shift + batch.offsets
+
+    # the dense Hessians (the Newton solver's, a variance's) are small and no
+    # loop carries them: taken from the shared objective on the block's
+    # transposed view
+
+    def hessian_matrix(self, coefficients: Array, batch: LabeledPointBatch) -> Array:
+        return self._slots_major.hessian_matrix(
+            coefficients, batch.replace(features=batch.features.T))
+
+    def hessian_diagonal(self, coefficients: Array, batch: LabeledPointBatch) -> Array:
+        return self._slots_major.hessian_diagonal(
+            coefficients, batch.replace(features=batch.features.T))
 
 
 def solve_mf_side_bucket(
@@ -226,6 +300,11 @@ def solve_mf_side_bucket(
     feats = other_factors[jnp.maximum(oidx, 0)]            # [e, cap, k]
     pad = sample_rows < 0
     feats = jnp.where(pad[..., None] | (oidx < 0)[..., None], 0.0, feats)
+    if slots_minor(feats.shape[1]):
+        # laid out once, [e, k, cap], as a random effect's features lie on
+        # the device, and read that way by every trial of the lanes
+        feats = jnp.swapaxes(feats, 1, 2)
+        objective = _SlotsMinorObjective(objective)
     offsets = _bucket_offsets(sample_rows, full_offsets)
     solved, trace = _solve_bucket_entities(
         objective, opt, feats, labels, weights, offsets, table[entity_rows]

@@ -144,9 +144,12 @@ def test_dryrun_multichip_self_provisions_from_one_device():
     assert "GATE_OK" in proc.stdout
 
 
-def test_fused_step_with_mf_sharded_matches_single_device(rng):
+@pytest.mark.parametrize("cap", [64, 128])
+def test_fused_step_with_mf_sharded_matches_single_device(rng, cap):
     """The fused step including an MF coordinate must be sharding-invariant
-    and reduce the loss on low-rank-structured data."""
+    and reduce the loss on low-rank-structured data: with the lanes' features
+    as the gather wrote them (``cap`` 64) and with the slots minor (128: the
+    relayout keeps the entity axis, the sharded one, in place)."""
     from photon_ml_tpu.algorithm.mf_coordinate import build_mf_dataset
     from photon_ml_tpu.parallel.distributed import MatrixFactorizationStepSpec
 
@@ -167,7 +170,7 @@ def test_fused_step_with_mf_sharded_matches_single_device(rng):
         },
         dtype=np.float64,
     )
-    mf_datasets = {"mf": build_mf_dataset(dataset, "user", "item", bucket_sizes=(n,))}
+    mf_datasets = {"mf": build_mf_dataset(dataset, "user", "item", bucket_sizes=(cap,))}
     opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=8)
     program = GameTrainProgram(
         TaskType.LINEAR_REGRESSION,

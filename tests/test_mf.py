@@ -364,30 +364,22 @@ def test_mf_reservoir_cap_ignores_unusable_samples(rng):
     assert kept == n_usable
 
 
-def test_one_fused_sweep_equals_the_coordinate_path(rng):
-    """The fused step's factorization coordinate is the
-    MatrixFactorizationCoordinate's update: from the same starting factors,
-    against the fixed effect the sweep has just solved, one alternation gives
-    the same two tables."""
+def _assert_fused_sweep_equals_coordinate_path(ds, x, mf_dataset, *, iterations, seed):
+    """One sweep of the fused step (fixed effect ``global``, then the
+    factorization) against ``MatrixFactorizationCoordinate.update_model`` on
+    the fixed effect's margin: the same starting factors, the same two tables."""
     from photon_ml_tpu.parallel.distributed import (
         FixedEffectStepSpec,
         GameTrainProgram,
         MatrixFactorizationStepSpec,
     )
 
-    rows, cols, y = _mf_problem(rng, n=500)
-    x = rng.normal(size=(500, 5))
-    labels = (y + x @ rng.normal(size=5) > 0).astype(np.float64)
-    ds = build_game_dataset(
-        labels=labels, feature_shards={"global": x},
-        entity_keys={"user": rows, "item": cols}, dtype=np.float64)
-    mf_dataset = build_mf_dataset(ds, "user", "item", bucket_sizes=(16, 64, 256))
-    opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=7)
+    opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=iterations)
     program = GameTrainProgram(
         TaskType.LOGISTIC_REGRESSION,
         FixedEffectStepSpec("global", opt, l2_weight=0.5),
         mf_specs=(MatrixFactorizationStepSpec(
-            "mf", "user", "item", 3, opt, l2_weight=0.7, seed=11),))
+            "mf", "user", "item", 3, opt, l2_weight=0.7, seed=seed),))
     data, buckets = program.prepare_inputs(ds, {}, {"mf": mf_dataset})
     state = program.init_state(ds, {}, {"mf": mf_dataset})
     new_state, _loss = program.step(data, buckets, state)
@@ -396,7 +388,7 @@ def test_one_fused_sweep_equals_the_coordinate_path(rng):
         coordinate_id="mf", dataset=ds, mf_dataset=mf_dataset,
         task=TaskType.LOGISTIC_REGRESSION,
         config=CoordinateOptimizationConfig(optimizer=opt, l2_weight=0.7),
-        num_latent_factors=3, num_alternations=1, seed=11)
+        num_latent_factors=3, num_alternations=1, seed=seed)
     model = coord.initial_model()
     np.testing.assert_array_equal(np.asarray(model.row_factors),
                                   np.asarray(state.mf_rows["mf"]))
@@ -407,6 +399,21 @@ def test_one_fused_sweep_equals_the_coordinate_path(rng):
     np.testing.assert_allclose(np.asarray(model.col_factors),
                                np.asarray(new_state.mf_cols["mf"]), rtol=1e-9, atol=1e-12)
     assert np.abs(np.asarray(model.row_factors) - np.asarray(state.mf_rows["mf"])).max() > 1e-2
+
+
+def test_one_fused_sweep_equals_the_coordinate_path(rng):
+    """The fused step's factorization coordinate is the
+    MatrixFactorizationCoordinate's update: from the same starting factors,
+    against the fixed effect the sweep has just solved, one alternation gives
+    the same two tables."""
+    rows, cols, y = _mf_problem(rng, n=500)
+    x = rng.normal(size=(500, 5))
+    labels = (y + x @ rng.normal(size=5) > 0).astype(np.float64)
+    ds = build_game_dataset(
+        labels=labels, feature_shards={"global": x},
+        entity_keys={"user": rows, "item": cols}, dtype=np.float64)
+    mf_dataset = build_mf_dataset(ds, "user", "item", bucket_sizes=(16, 64, 256))
+    _assert_fused_sweep_equals_coordinate_path(ds, x, mf_dataset, iterations=7, seed=11)
 
 
 def test_the_packer_stays_on_the_host_times_itself_and_records_its_padding():
@@ -433,3 +440,225 @@ def test_the_packer_stays_on_the_host_times_itself_and_records_its_padding():
     assert gauges["mf/user_x_item/row_pad_fraction"] == pytest.approx(1 - 28 / 40)
     assert gauges["mf/user_x_item/col_pad_fraction"] == 0.0
     assert mf.pad_fractions() == (pytest.approx(0.3), 0.0)
+
+
+# -- the half-step's layout: slots minor where ``cap`` is whole vectors -------
+
+
+def _logistic_objective(l2_weight=1.0):
+    from photon_ml_tpu.ops.losses import loss_for_task
+    from photon_ml_tpu.ops.objective import GLMObjective
+
+    return GLMObjective(loss_for_task(TaskType.LOGISTIC_REGRESSION),
+                        l2_weight=l2_weight, use_pallas=False)
+
+
+def _side_bucket(rng, e, cap, k, n_other=23, n_this=40):
+    """One side's bucket as the packer would leave it, with everything a
+    half-step has to mask: padding slots (``sample_rows`` -1, weight 0),
+    slots whose other-side entity is unseen (``other_idx`` -1, weight 0) and
+    padding LANES (``entity_rows`` past the table: gathers clamp, scatters
+    drop). float32 throughout."""
+    n = e * cap
+    sample_rows = rng.permutation(n).reshape(e, cap).astype(np.int32)
+    fill = rng.integers(max(1, cap // 2), cap + 1, size=e)
+    sample_rows[np.arange(cap)[None, :] >= fill[:, None]] = -1
+    other_idx = rng.integers(0, n_other, size=n).astype(np.int32)
+    other_idx[rng.random(n) < 0.1] = -1
+    weights = ((sample_rows >= 0)
+               & (other_idx[np.maximum(sample_rows, 0)] >= 0)).astype(np.float32)
+    entity_rows = rng.permutation(n_this)[:e].astype(np.int32)
+    entity_rows[-2:] = n_this  # two padding lanes
+    return dict(
+        labels=(rng.random((e, cap)) < 0.5).astype(np.float32), weights=weights,
+        entity_rows=entity_rows, sample_rows=sample_rows, other_idx_full=other_idx,
+        other_factors=(rng.normal(size=(n_other, k)) / np.sqrt(k)).astype(np.float32),
+        full_offsets=rng.normal(size=n).astype(np.float32),
+        table=(rng.normal(size=(n_this, k)) / np.sqrt(k)).astype(np.float32))
+
+
+def _plain_half_step(objective, opt, labels, weights, entity_rows, sample_rows,
+                     other_idx_full, other_factors, full_offsets, table):
+    """The half-step written out in ``jax.numpy``: gather the other side's
+    factor rows, mask, solve every lane by the same ``solve`` on ``[cap, k]``
+    features, scatter."""
+    import jax
+
+    from photon_ml_tpu.data.batch import LabeledPointBatch
+    from photon_ml_tpu.optim.optimizer import solve
+
+    live = (sample_rows >= 0) & (other_idx_full[jnp.maximum(sample_rows, 0)] >= 0)
+    oidx = jnp.maximum(other_idx_full[jnp.maximum(sample_rows, 0)], 0)
+    feats = jnp.where(live[..., None], other_factors[oidx], 0.0)        # [e, cap, k]
+    offsets = jnp.where(sample_rows >= 0,
+                        full_offsets[jnp.maximum(sample_rows, 0)], 0.0)
+
+    def lane(f, l, o, w, w0):
+        batch = LabeledPointBatch(features=f, labels=l, offsets=o, weights=w)
+        return solve(opt, objective.bind(batch), w0)
+
+    result = jax.vmap(lane)(feats, labels, offsets, weights, table[entity_rows])
+    valid = (entity_rows >= 0) & (entity_rows < table.shape[0])
+    return table.at[entity_rows].set(result.coefficients), result, valid
+
+
+_HALF_STEP_CASES = [
+    pytest.param(cap, k, "LBFGS", dtype, id=f"cap{cap}-k{k}-LBFGS-{dtype}")
+    for cap in (8, 32, 128, 256) for k in (4, 32) for dtype in ("float32", "float64")
+] + [pytest.param(128, 4, solver, "float64", id=f"cap128-k4-{solver}-float64")
+     for solver in ("NEWTON", "TRON")]
+
+
+@pytest.mark.parametrize("cap,k,solver,dtype", _HALF_STEP_CASES)
+def test_half_step_equals_the_plain_one_at_every_cap(rng, cap, k, solver, dtype):
+    """Whatever layout the rule gives the lanes' features, the half-step is
+    the plain one, with the same lanes valid: in float64 step for step, to
+    1e-9 after six iterations; in float32, the benchmark's precision, to 5e-3
+    of the table's largest entry (a float32 fit follows its rounding, another
+    order of a sum and another trial is accepted: 1.6e-3 was read, where a
+    block read the wrong way round is off by the table's own size)."""
+    import jax
+
+    from photon_ml_tpu.algorithm.mf_coordinate import solve_mf_side_bucket
+
+    objective = _logistic_objective()
+    opt = OptimizerConfig(optimizer_type=OptimizerType[solver], max_iterations=6)
+    bucket = {
+        name: jnp.asarray(a, dtype if a.dtype == np.float32 else None)
+        for name, a in _side_bucket(rng, 9, cap, k).items()}
+    table, trace = jax.jit(solve_mf_side_bucket, static_argnums=(0, 1))(
+        objective, opt, *bucket.values())
+    want, result, valid = jax.jit(_plain_half_step, static_argnums=(0, 1))(
+        objective, opt, *bucket.values())
+    assert table.dtype == jnp.dtype(dtype)
+    exact = dtype == "float64"
+    np.testing.assert_allclose(
+        np.asarray(table), np.asarray(want), rtol=1e-9 if exact else 0.0,
+        atol=(1e-9 if exact else 5e-3) * float(np.abs(np.asarray(want)).max()))
+    np.testing.assert_array_equal(np.asarray(trace.valid), np.asarray(valid))
+    assert not np.asarray(trace.valid)[-2:].any() and np.asarray(trace.valid)[:-2].all()
+    if exact:
+        np.testing.assert_array_equal(np.asarray(trace.iterations)[:-2],
+                                      np.asarray(result.iterations)[:-2])
+    # the lanes moved: the comparison is not of two untouched tables
+    rows = np.asarray(bucket["entity_rows"])[:-2]
+    assert np.abs(np.asarray(table)[rows] - np.asarray(bucket["table"])[rows]).max() > 1e-2
+
+
+def _float_shapes_in(jaxpr, found: set) -> set:
+    """The shapes of the float arrays among the variables of a jaxpr and of
+    the jaxprs its equations hold."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        for v in list(eqn.invars) + list(eqn.outvars):
+            aval = getattr(v, "aval", None)
+            if hasattr(aval, "shape") and jnp.issubdtype(aval.dtype, jnp.floating):
+                found.add(tuple(aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _float_shapes_in(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("cap", [8, 32, 128, 256, 384, 2048])
+def test_the_lanes_read_slots_minor_exactly_where_the_rule_says(rng, cap):
+    """No chip needed: in the half-step's jaxpr the solver's loops hold the
+    ``[e, k, cap]`` block, slots last, for the buckets ``slots_minor`` names,
+    and the gathered ``[e, cap, k]`` block for the others; neither holds
+    both, and nothing but the static shape chose."""
+    import jax
+
+    from photon_ml_tpu.algorithm.mf_coordinate import slots_minor, solve_mf_side_bucket
+
+    e, k = 5, 3
+    objective = _logistic_objective()
+    opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=3)
+    bucket = _side_bucket(rng, e, cap, k)
+    jaxpr = jax.make_jaxpr(
+        lambda *arrays: solve_mf_side_bucket(objective, opt, *arrays))(*bucket.values())
+    in_loops: set = set()
+    for eqn in jaxpr.jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _float_shapes_in(sub, in_loops)
+    assert in_loops, "the solver's loops were not found"
+    assert slots_minor(cap) == (cap % 128 == 0)
+    assert ((e, k, cap) in in_loops) == slots_minor(cap)
+    assert ((e, cap, k) in in_loops) == (not slots_minor(cap))
+
+
+def test_the_packer_records_the_share_of_slots_that_go_slots_minor():
+    """Users of 1 to 4 rows (cap 4) and of 100 (cap 128), items of 130 rows
+    capped to the top rung: the gauges and ``slots_minor_fractions`` read the
+    share of each side's slots in buckets the half-step lays slots minor."""
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    users = np.concatenate([np.repeat(np.arange(4), np.arange(1, 5)),   # 10 rows
+                            np.repeat(np.arange(4, 7), 100)])           # 300 rows
+    items = np.arange(310) % 2   # two items of 155 rows: past the top rung
+    ds = build_game_dataset(
+        labels=np.zeros(310), feature_shards={},
+        entity_keys={"user": users.astype(str), "item": items.astype(str)},
+        dtype=np.float64)
+    mf = build_mf_dataset(ds, "user", "item", bucket_sizes=(4, 128))
+    # users: 4 lanes of cap 4 (16 slots), 3 lanes of cap 128 (384 slots)
+    # items: 2 lanes of cap 128 (256 slots)
+    assert mf.slots_minor_fractions() == (pytest.approx(384 / 400), 1.0)
+    gauges = default_registry().snapshot()["gauges"]
+    assert gauges["mf/user_x_item/row_slots_minor_fraction"] == pytest.approx(0.96)
+    assert gauges["mf/user_x_item/col_slots_minor_fraction"] == 1.0
+    none = build_mf_dataset(ds, "user", "item", bucket_sizes=(4, 200))
+    assert none.slots_minor_fractions() == (0.0, 0.0)
+    assert default_registry().snapshot()["gauges"][
+        "mf/user_x_item/row_slots_minor_fraction"] == 0.0
+
+
+@pytest.mark.parametrize("ladder", [(8, 128), (16, 256), (8, 32, 64)],
+                         ids=lambda ladder: "ladder" + "-".join(map(str, ladder)))
+def test_one_fused_sweep_equals_the_coordinate_path_on_whole_vector_buckets(rng, ladder):
+    """As ``test_one_fused_sweep_equals_the_coordinate_path``, on ladders whose
+    upper rungs are whole vectors (both layouts in one half-step) and on one
+    with none: the fused step's ``_solve_mf`` and ``update_model`` take the
+    layout from the same function and give the same two tables."""
+    n = 900
+    rows, cols, y = _mf_problem(rng, n=n, n_rows=14, n_cols=6, k=3)
+    # eight light users of 5 rows and three of 20, so that every rung is filled
+    rows[:40] = np.array([f"w{i}" for i in range(8)])[np.arange(40) % 8]
+    rows[40:100] = np.array([f"m{i}" for i in range(3)])[np.arange(60) % 3]
+    x = rng.normal(size=(n, 4))
+    labels = (y + x @ rng.normal(size=4) > 0).astype(np.float64)
+    ds = build_game_dataset(
+        labels=labels, feature_shards={"global": x},
+        entity_keys={"user": rows, "item": cols}, dtype=np.float64)
+    mf_dataset = build_mf_dataset(ds, "user", "item", bucket_sizes=ladder)
+    caps = {int(b.sample_rows.shape[1])
+            for b in mf_dataset.row_buckets + mf_dataset.col_buckets}
+    assert caps == set(ladder)
+    _assert_fused_sweep_equals_coordinate_path(ds, x, mf_dataset, iterations=6, seed=5)
+
+
+@pytest.mark.parametrize(
+    "method", ["value_and_gradient", "hessian_vector", "hessian_matrix", "hessian_diagonal"])
+def test_the_slots_minor_objective_is_the_shared_one_on_the_transposed_block(rng, method):
+    """Everything a solver can ask of the lanes' objective over ``[k, cap]``
+    features equals the shared objective's answer over ``[cap, k]``."""
+    import jax
+
+    from photon_ml_tpu.algorithm.mf_coordinate import _SlotsMinorObjective
+    from photon_ml_tpu.data.batch import LabeledPointBatch
+
+    cap, k = 24, 5
+    shared = _logistic_objective(0.3)
+    slots_minor = _SlotsMinorObjective(shared)
+    assert slots_minor != shared and slots_minor == _SlotsMinorObjective(shared)
+    batch = LabeledPointBatch(
+        features=jnp.asarray(rng.normal(size=(cap, k))),
+        labels=jnp.asarray((rng.random(cap) < 0.5).astype(np.float64)),
+        offsets=jnp.asarray(rng.normal(size=cap)),
+        weights=jnp.asarray(rng.random(cap)))
+    w = jnp.asarray(rng.normal(size=k))
+    args = (w, jnp.asarray(rng.normal(size=k))) if method == "hessian_vector" else (w,)
+    want = getattr(shared, method)(*args, batch)
+    got = getattr(slots_minor, method)(*args, batch.replace(features=batch.features.T))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-12, atol=1e-14)
