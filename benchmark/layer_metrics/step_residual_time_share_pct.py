@@ -1,0 +1,12 @@
+"""Share of the devices' busy seconds inside the window that the fused step
+spends in the sums over coordinates: scopes ``residual`` (the offsets every
+solve is handed, all the scores but its own) and ``loss`` (the training loss
+at the step's end); in percent. One of the seven shares of
+``benchmark/step_scopes.py``, which says how an event finds its category and
+what reads as nothing (no device plane, a program without the record, a text
+that is not the trace's program's)."""
+from benchmark import step_scopes
+
+
+def read(ctx):
+    return step_scopes.share(step_scopes.of_this_run(), "residual")

@@ -759,13 +759,22 @@ def solve_entity_bucket_traced(
     Pure/traceable: reused by the single-chip jit wrapper below and by the
     mesh-sharded full-GAME train step (parallel/distributed.py), where the
     entity axis shards over the mesh's "data" axis.
+
+    Its three phases run under the scopes ``gather``, ``solve`` and
+    ``scatter`` (``jax.named_scope``: the compiled instructions' ``op_name``,
+    which telemetry/program_ledger.compiled_scopes reads back), here and in
+    the index-map, random-projection and matrix-factorization siblings.
     """
-    offsets = _bucket_offsets(sample_rows, full_offsets)
-    solved, trace = _solve_bucket_entities(
-        objective, opt, features, labels, weights, offsets, table[entity_rows]
-    )
-    trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
-    return table.at[entity_rows].set(solved), trace
+    with jax.named_scope("gather"):
+        offsets = _bucket_offsets(sample_rows, full_offsets)
+        w0s = table[entity_rows]
+    with jax.named_scope("solve"):
+        solved, trace = _solve_bucket_entities(
+            objective, opt, features, labels, weights, offsets, w0s
+        )
+        trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
+    with jax.named_scope("scatter"):
+        return table.at[entity_rows].set(solved), trace
 
 
 @partial(ledger_jit, label="coord/re_bucket_solve", static_argnums=(0, 1))
@@ -975,14 +984,17 @@ def solve_entity_bucket_indexmap_traced(
     used by the single-chip jit wrapper below and by the mesh-sharded
     fused step (parallel/distributed.py), where the entity axis shards
     over "data"."""
-    offsets = _bucket_offsets(sample_rows, full_offsets)
-    w0s = table_ext[entity_rows[:, None], col_index]
-    solved, trace = _solve_bucket_entities(
-        objective, opt, features, labels, weights, offsets, w0s
-    )
-    trace = _mask_padding_lanes(trace, entity_rows, table_ext.shape[0])
-    table_ext = table_ext.at[entity_rows[:, None], col_index].set(solved)
-    return table_ext.at[:, -1].set(0.0), trace
+    with jax.named_scope("gather"):
+        offsets = _bucket_offsets(sample_rows, full_offsets)
+        w0s = table_ext[entity_rows[:, None], col_index]
+    with jax.named_scope("solve"):
+        solved, trace = _solve_bucket_entities(
+            objective, opt, features, labels, weights, offsets, w0s
+        )
+        trace = _mask_padding_lanes(trace, entity_rows, table_ext.shape[0])
+    with jax.named_scope("scatter"):
+        table_ext = table_ext.at[entity_rows[:, None], col_index].set(solved)
+        return table_ext.at[:, -1].set(0.0), trace
 
 
 @partial(ledger_jit, label="coord/re_bucket_variances_random", static_argnums=(0,))
@@ -1090,13 +1102,16 @@ def solve_entity_bucket_random_traced(
     ≈ the projected coefficients since E[PᵀP]=I), back-project P w_k.
     Returns the table and the per-lane convergence trace. Pure/traceable,
     shared with the fused step like its index-map twin."""
-    offsets = _bucket_offsets(sample_rows, full_offsets)
-    w0s = table[entity_rows] @ matrix
-    solved, trace = _solve_bucket_entities(
-        objective, opt, features, labels, weights, offsets, w0s
-    )
-    trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
-    return table.at[entity_rows].set(solved @ matrix.T), trace
+    with jax.named_scope("gather"):
+        offsets = _bucket_offsets(sample_rows, full_offsets)
+        w0s = table[entity_rows] @ matrix
+    with jax.named_scope("solve"):
+        solved, trace = _solve_bucket_entities(
+            objective, opt, features, labels, weights, offsets, w0s
+        )
+        trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
+    with jax.named_scope("scatter"):
+        return table.at[entity_rows].set(solved @ matrix.T), trace
 
 
 @partial(ledger_jit, label="coord/re_bucket_solve_indexmap", static_argnums=(0, 1))

@@ -294,23 +294,29 @@ def solve_mf_side_bucket(
 
     Pure/traceable: reused by the single-chip jit wrapper below and by the
     mesh-sharded fused GAME step (parallel/distributed.py), where the
-    entity axis shards over the mesh's "data" axis."""
-    safe_rows = jnp.maximum(sample_rows, 0)
-    oidx = other_idx_full[safe_rows]                       # [e, cap]
-    feats = other_factors[jnp.maximum(oidx, 0)]            # [e, cap, k]
-    pad = sample_rows < 0
-    feats = jnp.where(pad[..., None] | (oidx < 0)[..., None], 0.0, feats)
-    if slots_minor(feats.shape[1]):
-        # laid out once, [e, k, cap], as a random effect's features lie on
-        # the device, and read that way by every trial of the lanes
-        feats = jnp.swapaxes(feats, 1, 2)
-        objective = _SlotsMinorObjective(objective)
-    offsets = _bucket_offsets(sample_rows, full_offsets)
-    solved, trace = _solve_bucket_entities(
-        objective, opt, feats, labels, weights, offsets, table[entity_rows]
-    )
-    trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
-    return table.at[entity_rows].set(solved), trace
+    entity axis shards over the mesh's "data" axis. The same three scopes
+    as there: ``gather`` holds the other side's factor rows, the mask and the
+    slots-minor relayout too."""
+    with jax.named_scope("gather"):
+        safe_rows = jnp.maximum(sample_rows, 0)
+        oidx = other_idx_full[safe_rows]                       # [e, cap]
+        feats = other_factors[jnp.maximum(oidx, 0)]            # [e, cap, k]
+        pad = sample_rows < 0
+        feats = jnp.where(pad[..., None] | (oidx < 0)[..., None], 0.0, feats)
+        if slots_minor(feats.shape[1]):
+            # laid out once, [e, k, cap], as a random effect's features lie on
+            # the device, and read that way by every trial of the lanes
+            feats = jnp.swapaxes(feats, 1, 2)
+            objective = _SlotsMinorObjective(objective)
+        offsets = _bucket_offsets(sample_rows, full_offsets)
+        w0s = table[entity_rows]
+    with jax.named_scope("solve"):
+        solved, trace = _solve_bucket_entities(
+            objective, opt, feats, labels, weights, offsets, w0s
+        )
+        trace = _mask_padding_lanes(trace, entity_rows, table.shape[0])
+    with jax.named_scope("scatter"):
+        return table.at[entity_rows].set(solved), trace
 
 
 @partial(ledger_jit, label="coord/mf_side_solve", static_argnums=(0, 1))

@@ -54,36 +54,37 @@ def two_loop_direction(
     shapes static for jit. Every slot index is a loop counter: under ``vmap``
     it is the same for all lanes, a slice of the lanes' histories.
     """
-    m = s_hist.shape[0]
+    with jax.named_scope("lbfgs/direction"):
+        m = s_hist.shape[0]
 
-    def slot(x, k):
-        return lax.dynamic_index_in_dim(x, k, keepdims=False)
+        def slot(x, k):
+            return lax.dynamic_index_in_dim(x, k, keepdims=False)
 
-    def backward(k, carry):
-        # newest to oldest
-        q, alphas = carry
-        alpha = jnp.where(k < count, slot(rho, k) * jnp.vdot(slot(s_hist, k), q), 0.0)
-        q = q - alpha * slot(y_hist, k)
-        return q, lax.dynamic_update_index_in_dim(alphas, alpha, k, 0)
+        def backward(k, carry):
+            # newest to oldest
+            q, alphas = carry
+            alpha = jnp.where(k < count, slot(rho, k) * jnp.vdot(slot(s_hist, k), q), 0.0)
+            q = q - alpha * slot(y_hist, k)
+            return q, lax.dynamic_update_index_in_dim(alphas, alpha, k, 0)
 
-    q, alphas = lax.fori_loop(0, m, backward, (g, jnp.zeros((m,), dtype=g.dtype)))
+        q, alphas = lax.fori_loop(0, m, backward, (g, jnp.zeros((m,), dtype=g.dtype)))
 
-    gamma = jnp.where(
-        count > 0,
-        jnp.vdot(s_hist[0], y_hist[0])
-        / jnp.maximum(jnp.vdot(y_hist[0], y_hist[0]), 1e-30),
-        1.0,
-    )
-    r = gamma * q
+        gamma = jnp.where(
+            count > 0,
+            jnp.vdot(s_hist[0], y_hist[0])
+            / jnp.maximum(jnp.vdot(y_hist[0], y_hist[0]), 1e-30),
+            1.0,
+        )
+        r = gamma * q
 
-    def forward(i, r):
-        # oldest to newest: the empty slots come first and add nothing
-        k = m - 1 - i
-        beta = slot(rho, k) * jnp.vdot(slot(y_hist, k), r)
-        return r + jnp.where(k < count, slot(alphas, k) - beta, 0.0) * slot(s_hist, k)
+        def forward(i, r):
+            # oldest to newest: the empty slots come first and add nothing
+            k = m - 1 - i
+            beta = slot(rho, k) * jnp.vdot(slot(y_hist, k), r)
+            return r + jnp.where(k < count, slot(alphas, k) - beta, 0.0) * slot(s_hist, k)
 
-    r = lax.fori_loop(0, m, forward, r)
-    return -r
+        r = lax.fori_loop(0, m, forward, r)
+        return -r
 
 
 def push_pair(
@@ -98,19 +99,20 @@ def push_pair(
     """The history after a step (s, y): where the step was accepted and its
     curvature sᵀy is positive, every pair moves one slot back (the oldest
     falls off) and the new one takes slot 0; else the history stays."""
-    m = s_hist.shape[0]
-    sy = jnp.vdot(s, y)
-    keep_pair = accepted & (sy > 1e-10)
+    with jax.named_scope("lbfgs/history"):
+        m = s_hist.shape[0]
+        sy = jnp.vdot(s, y)
+        keep_pair = accepted & (sy > 1e-10)
 
-    def pushed(hist, new):
-        return jnp.where(keep_pair, jnp.concatenate([new[None], hist[:-1]]), hist)
+        def pushed(hist, new):
+            return jnp.where(keep_pair, jnp.concatenate([new[None], hist[:-1]]), hist)
 
-    return (
-        pushed(s_hist, s),
-        pushed(y_hist, y),
-        pushed(rho, 1.0 / jnp.maximum(sy, 1e-30)),
-        jnp.where(keep_pair, jnp.minimum(count + 1, m), count),
-    )
+        return (
+            pushed(s_hist, s),
+            pushed(y_hist, y),
+            pushed(rho, 1.0 / jnp.maximum(sy, 1e-30)),
+            jnp.where(keep_pair, jnp.minimum(count + 1, m), count),
+        )
 
 
 @flax.struct.dataclass
@@ -293,26 +295,28 @@ def minimize_lbfgs(
                 i, _t, _w, _f, _g, ok = s
                 return (i < max_line_search_steps) & ~ok & live
 
-            ls_trials, _, w_new, f_new, g_new, ls_ok = run_while(
-                ls_cond,
-                ls_body,
-                (jnp.int32(0), t_init, state.w, state.f, state.g, jnp.asarray(False)),
-                host=host_loop,
-            )
+            with jax.named_scope("lbfgs/line_search"):
+                ls_trials, _, w_new, f_new, g_new, ls_ok = run_while(
+                    ls_cond,
+                    ls_body,
+                    (jnp.int32(0), t_init, state.w, state.f, state.g, jnp.asarray(False)),
+                    host=host_loop,
+                )
             ls_success = ls_ok
             ls_floor_exit = jnp.asarray(False)
         else:
-            ls = wolfe_line_search(
-                value_and_grad_fn,
-                state.w,
-                state.f,
-                state.g,
-                direction,
-                t_init,
-                max_steps=max_line_search_steps,
-                host_loop=host_loop,
-                active=live,
-            )
+            with jax.named_scope("lbfgs/line_search"):
+                ls = wolfe_line_search(
+                    value_and_grad_fn,
+                    state.w,
+                    state.f,
+                    state.g,
+                    direction,
+                    t_init,
+                    max_steps=max_line_search_steps,
+                    host_loop=host_loop,
+                    active=live,
+                )
             w_new = state.w + ls.step * direction
             f_new, g_new = ls.value, ls.gradient
             ls_success = ls.success

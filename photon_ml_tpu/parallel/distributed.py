@@ -858,19 +858,21 @@ class GameTrainProgram:
         return dict(zip(SOLVER_COUNT_NAMES, np.asarray(counts).tolist()))
 
     def _weighted_loss(self, labels, weights, total_margin):
-        losses = self._loss.loss(total_margin, labels)
-        wsum = jnp.maximum(jnp.sum(weights), 1.0)
-        return jnp.sum(weights * losses) / wsum
+        with jax.named_scope("loss"):
+            losses = self._loss.loss(total_margin, labels)
+            wsum = jnp.maximum(jnp.sum(weights), 1.0)
+            return jnp.sum(weights * losses) / wsum
 
     def _sum_scores(self, base, scores, skip=None):
         """base + every coordinate score except ``skip`` — the residual-
         offset sum of the CD recursion, as its own jittable piece for the
         scheduled sweep."""
-        total = base
-        for k, v in scores.items():
-            if k != skip:
-                total = total + v
-        return total
+        with jax.named_scope("residual"):
+            total = base
+            for k, v in scores.items():
+                if k != skip:
+                    total = total + v
+            return total
 
     def _scheduled_jits(self):
         """Per-coordinate jitted pieces of the sweep, for step_scheduled:
@@ -1030,55 +1032,58 @@ class GameTrainProgram:
         normalized; score through the full effective-coefficient algebra
         (factor scaling, and the per-entity margin-shift term for
         standardized coordinates)."""
-        sp = data.get("re_sparse", {}).get(k)
-        if sp is not None:
-            # compact [E, K] table over per-entity active columns; when the
-            # coordinate is SCALE-normalized, both the table and the entry
-            # values (scaled in _attach_re_sparse) live in normalized space
-            # — their product is the data-space margin, no shift term
-            from photon_ml_tpu.models.game import score_random_effect_compact
+        with jax.named_scope(f"score/{k}"):
+            sp = data.get("re_sparse", {}).get(k)
+            if sp is not None:
+                # compact [E, K] table over per-entity active columns; when the
+                # coordinate is SCALE-normalized, both the table and the entry
+                # values (scaled in _attach_re_sparse) live in normalized space
+                # — their product is the data-space margin, no shift term
+                from photon_ml_tpu.models.game import score_random_effect_compact
 
-            return score_random_effect_compact(
-                table, sp["ent"], sp["pos"], sp["rows"], sp["vals"],
-                data["labels"].shape[0],
+                return score_random_effect_compact(
+                    table, sp["ent"], sp["pos"], sp["rows"], sp["vals"],
+                    data["labels"].shape[0],
+                )
+            norm = self._re_objectives[k].normalization
+            eff = norm.effective_coefficients(table)
+            scores = score_random_effect(
+                eff, data["features"][shard_id], data["entity_idx"][k]
             )
-        norm = self._re_objectives[k].normalization
-        eff = norm.effective_coefficients(table)
-        scores = score_random_effect(
-            eff, data["features"][shard_id], data["entity_idx"][k]
-        )
-        if norm.shifts is not None:
-            # per-entity margin-shift scalar: (w_e ⊙ f) · shifts
-            idx = data["entity_idx"][k]
-            ent_shift = eff @ norm.shifts
-            scores = scores - jnp.where(
-                idx >= 0, ent_shift[jnp.maximum(idx, 0)], 0.0
-            )
-        return scores
+            if norm.shifts is not None:
+                # per-entity margin-shift scalar: (w_e ⊙ f) · shifts
+                idx = data["entity_idx"][k]
+                ent_shift = eff @ norm.shifts
+                scores = scores - jnp.where(
+                    idx >= 0, ent_shift[jnp.maximum(idx, 0)], 0.0
+                )
+            return scores
 
     def _fe_margin_score(self, data, fe_w: Array) -> Array:
         """The FE coordinate's pure margin (no offsets) from normalized-space
         coefficients, dense or flat-COO."""
-        fe_sparse = data.get("fe_sparse_batch")
-        objective = (
-            self._fe_sparse_objective if fe_sparse is not None
-            else self._fe_objective
-        )
-        norm = objective.normalization
-        eff = norm.effective_coefficients(fe_w)
-        if fe_sparse is not None:
-            # fe_sparse keeps its zero offsets, so this is the pure margin
-            return sparse_margins(fe_sparse, eff) - norm.margin_shift(eff)
-        return (
-            data["features"][self.fe.feature_shard_id] @ eff
-            - norm.margin_shift(eff)
-        )
+        with jax.named_scope(f"score/{self.fe.feature_shard_id}"):
+            fe_sparse = data.get("fe_sparse_batch")
+            objective = (
+                self._fe_sparse_objective if fe_sparse is not None
+                else self._fe_objective
+            )
+            norm = objective.normalization
+            eff = norm.effective_coefficients(fe_w)
+            if fe_sparse is not None:
+                # fe_sparse keeps its zero offsets, so this is the pure margin
+                return sparse_margins(fe_sparse, eff) - norm.margin_shift(eff)
+            return (
+                data["features"][self.fe.feature_shard_id] @ eff
+                - norm.margin_shift(eff)
+            )
 
     def _extra_fe_margin(self, data, shard_id: str, w: Array) -> Array:
         """Pure margin of a non-primary (dense, replicated) FE coordinate."""
-        norm = self._extra_fe_objectives[shard_id].normalization
-        eff = norm.effective_coefficients(w)
-        return data["features"][shard_id] @ eff - norm.margin_shift(eff)
+        with jax.named_scope(f"score/{shard_id}"):
+            norm = self._extra_fe_objectives[shard_id].normalization
+            eff = norm.effective_coefficients(w)
+            return data["features"][shard_id] @ eff - norm.margin_shift(eff)
 
     def _coordinate_scores(self, data, state: GameTrainState) -> dict[str, Array]:
         """name -> score of EVERY coordinate at the state (primary FE
@@ -1098,15 +1103,25 @@ class GameTrainProgram:
                 data, s.re_type, state.re_tables[s.re_type], s.feature_shard_id
             )
         for m in self.mf_specs:
-            scores[m.name] = score_matrix_factorization(
-                state.mf_rows[m.name],
-                state.mf_cols[m.name],
-                data["entity_idx"][m.row_effect_type],
-                data["entity_idx"][m.col_effect_type],
-            )
+            with jax.named_scope(f"score/{m.name}"):
+                scores[m.name] = score_matrix_factorization(
+                    state.mf_rows[m.name],
+                    state.mf_cols[m.name],
+                    data["entity_idx"][m.row_effect_type],
+                    data["entity_idx"][m.col_effect_type],
+                )
         return scores
 
     def _step_impl(self, data, buckets, state: GameTrainState):
+        """One fused sweep. Every phase is traced under a ``jax.named_scope``,
+        entered where the phase's code lives so that the scheduled sweep and
+        the coordinate-descent path carry it too: ``score/<coordinate>``,
+        ``residual``, ``loss``, ``fe/solve``, ``extra_fe/<name>/solve``,
+        ``re/<type>`` and ``mf/<name>/<side>`` round a bucket's ``gather``,
+        ``solve`` and ``scatter``, ``lbfgs/direction|history|line_search``
+        inside a solve. A scope is metadata (the compiled instructions'
+        ``op_name``), never an instruction;
+        ``program_ledger.compiled_scopes("train/step")`` reads them back."""
         labels, weights = data["labels"], data["weights"]
         base_offsets = data["offsets"]
 
@@ -1181,95 +1196,99 @@ class GameTrainProgram:
         coefficient algebra the objective uses, so residuals stay in data
         space. Returns (coefficients, the solve's ``fe_*`` counts).
         """
-        fe_sparse = data.get("fe_sparse_batch")
-        fe_mult = data.get("fe_weight_multiplier")
-        fe_weights = weights if fe_mult is None else weights * fe_mult
-        if fe_sparse is not None:
-            fe_batch = fe_sparse.replace(offsets=fe_offsets, weights=fe_weights)
-            fe_objective = self._fe_sparse_objective
-        else:
-            fe_batch = LabeledPointBatch(
-                features=data["features"][self.fe.feature_shard_id],
-                labels=data["labels"],
-                offsets=fe_offsets,
-                weights=fe_weights,
+        with jax.named_scope("fe/solve"):
+            fe_sparse = data.get("fe_sparse_batch")
+            fe_mult = data.get("fe_weight_multiplier")
+            fe_weights = weights if fe_mult is None else weights * fe_mult
+            if fe_sparse is not None:
+                fe_batch = fe_sparse.replace(offsets=fe_offsets, weights=fe_weights)
+                fe_objective = self._fe_sparse_objective
+            else:
+                fe_batch = LabeledPointBatch(
+                    features=data["features"][self.fe.feature_shard_id],
+                    labels=data["labels"],
+                    offsets=fe_offsets,
+                    weights=fe_weights,
+                )
+                # multi-device mesh: per-device single-pass kernel + psum
+                # (parallel/sharded_dense.py) instead of the GSPMD autodiff path
+                fe_objective = (
+                    self._fe_sharded_objective
+                    if self._fe_sharded_objective is not None
+                    else self._fe_objective
+                )
+            return _fe_solved(
+                solve(self.fe.optimizer, fe_objective.bind(fe_batch), fe_w0)
             )
-            # multi-device mesh: per-device single-pass kernel + psum
-            # (parallel/sharded_dense.py) instead of the GSPMD autodiff path
-            fe_objective = (
-                self._fe_sharded_objective
-                if self._fe_sharded_objective is not None
-                else self._fe_objective
-            )
-        return _fe_solved(
-            solve(self.fe.optimizer, fe_objective.bind(fe_batch), fe_w0)
-        )
 
     def _solve_extra_fe(self, data, name, full_offsets, labels, weights, w0):
         """A non-primary FE coordinate: dense replicated solve, same
         residual + down-sampling contract as the primary."""
-        mult = data.get("extra_fe_weight_multipliers", {}).get(name)
-        fe_weights = weights if mult is None else weights * mult
-        batch = LabeledPointBatch(
-            features=data["features"][name],
-            labels=labels,
-            offsets=full_offsets,
-            weights=fe_weights,
-        )
-        spec = self._extra_fe_by_name[name]
-        return _fe_solved(
-            solve(spec.optimizer, self._extra_fe_objectives[name].bind(batch), w0)
-        )
+        with jax.named_scope(f"extra_fe/{name}/solve"):
+            mult = data.get("extra_fe_weight_multipliers", {}).get(name)
+            fe_weights = weights if mult is None else weights * mult
+            batch = LabeledPointBatch(
+                features=data["features"][name],
+                labels=labels,
+                offsets=full_offsets,
+                weights=fe_weights,
+            )
+            spec = self._extra_fe_by_name[name]
+            return _fe_solved(
+                solve(spec.optimizer, self._extra_fe_objectives[name].bind(batch), w0)
+            )
 
     def _solve_re(self, data, buckets, k, full_offsets, table):
-        """One random-effect coordinate (entities sharded, vmapped solves).
-        Returns (table, the coordinate's line-search counts: its buckets'
-        optim/common.lane_solver_counts summed)."""
+        """One random-effect coordinate (entities sharded, vmapped solves),
+        under the scope ``re/<k>``. Returns (table, the coordinate's
+        line-search counts: its buckets' optim/common.lane_solver_counts
+        summed)."""
         spec = self._re_by_name[k]
         objective = self._re_solve_objectives[k]
         counts: dict = {}
-        if spec.projector == ProjectorType.INDEX_MAP:
-            # scratch-column solve in each entity's observed columns
-            # (ports algorithm/coordinates.py's single-chip path into
-            # the SPMD program; IndexMapProjectorRDD.scala:218-257)
-            table_ext = jnp.concatenate(
-                [table, jnp.zeros((table.shape[0], 1), table.dtype)],
-                axis=1,
-            )
-            for b in buckets[k]:
-                table_ext, trace = solve_entity_bucket_indexmap_traced(
-                    objective, spec.optimizer,
-                    b["features"], b["labels"], b["weights"],
-                    b["sample_rows"], b["entity_rows"], b["col_index"],
-                    full_offsets, table_ext,
+        with jax.named_scope(f"re/{k}"):
+            if spec.projector == ProjectorType.INDEX_MAP:
+                # scratch-column solve in each entity's observed columns
+                # (ports algorithm/coordinates.py's single-chip path into
+                # the SPMD program; IndexMapProjectorRDD.scala:218-257)
+                table_ext = jnp.concatenate(
+                    [table, jnp.zeros((table.shape[0], 1), table.dtype)],
+                    axis=1,
                 )
-                _add_counts(counts, lane_solver_counts(trace))
-            return table_ext[:, :-1], counts
-        if spec.projector == ProjectorType.RANDOM:
-            matrix = buckets["__projections__"][k]
+                for b in buckets[k]:
+                    table_ext, trace = solve_entity_bucket_indexmap_traced(
+                        objective, spec.optimizer,
+                        b["features"], b["labels"], b["weights"],
+                        b["sample_rows"], b["entity_rows"], b["col_index"],
+                        full_offsets, table_ext,
+                    )
+                    _add_counts(counts, lane_solver_counts(trace))
+                return table_ext[:, :-1], counts
+            if spec.projector == ProjectorType.RANDOM:
+                matrix = buckets["__projections__"][k]
+                for b in buckets[k]:
+                    table, trace = solve_entity_bucket_random_traced(
+                        objective, spec.optimizer,
+                        b["features"], b["labels"], b["weights"],
+                        b["sample_rows"], b["entity_rows"], matrix,
+                        full_offsets, table,
+                    )
+                    _add_counts(counts, lane_solver_counts(trace))
+                return table, counts
             for b in buckets[k]:
-                table, trace = solve_entity_bucket_random_traced(
-                    objective, spec.optimizer,
-                    b["features"], b["labels"], b["weights"],
-                    b["sample_rows"], b["entity_rows"], matrix,
-                    full_offsets, table,
+                table, trace = solve_entity_bucket_traced(
+                    objective,
+                    spec.optimizer,
+                    b["features"],
+                    b["labels"],
+                    b["weights"],
+                    b["sample_rows"],
+                    b["entity_rows"],
+                    full_offsets,
+                    table,
                 )
                 _add_counts(counts, lane_solver_counts(trace))
             return table, counts
-        for b in buckets[k]:
-            table, trace = solve_entity_bucket_traced(
-                objective,
-                spec.optimizer,
-                b["features"],
-                b["labels"],
-                b["weights"],
-                b["sample_rows"],
-                b["entity_rows"],
-                full_offsets,
-                table,
-            )
-            _add_counts(counts, lane_solver_counts(trace))
-        return table, counts
 
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):
         """One matrix-factorization coordinate (alternating vmapped solves),
@@ -1300,9 +1319,9 @@ class GameTrainProgram:
         for _ in range(m.num_alternations):
             rows = half_step("row", rows, col_idx, cols)
             cols = half_step("col", cols, row_idx, rows)
-        return rows, cols, score_matrix_factorization(
-            rows, cols, row_idx, col_idx
-        ), counts
+        with jax.named_scope(f"score/{name}"):
+            score = score_matrix_factorization(rows, cols, row_idx, col_idx)
+        return rows, cols, score, counts
 
 
 def compute_state_variances(

@@ -65,6 +65,16 @@ device's work. And the first ``ledger_jit`` of a process installs the
 compile listener (probes.install_compile_listener) on the default
 registry, so a library user has trace / lower / cache-load / compile
 seconds and the cache's hits and misses without a driver.
+
+**What a scope holds** (``compiled_scopes``): a profiler's device events
+name compiled instructions and carry none of their metadata, so which
+``jax.named_scope`` an event ran under can only be read from the compiled
+program's text. ``ledger_jit``'s wrapper remembers, for its label, the
+abstract signature of the last call that TRACED (nothing on a call that
+dispatches, and never an array); ``compiled_scopes(label)`` compiles from it
+(jit's own caches or the compile cache answer) and returns every
+instruction's signature and ``op_name``. Called by whoever reads a trace,
+after the fact; an untraced run never calls it.
 """
 
 from __future__ import annotations
@@ -72,7 +82,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import re
 import threading
+import typing
+import weakref
 
 from photon_ml_tpu.telemetry.tracing import span
 
@@ -612,6 +625,131 @@ def uninstall_ledger() -> ProgramLedger | None:
     return ledger
 
 
+# ---------------------------------------------------------------------------
+# What a label compiled: instruction names, signatures and scopes
+# ---------------------------------------------------------------------------
+
+
+class CompiledScopes(typing.NamedTuple):
+    """``instructions``: {instruction name: (signature, op_name)} for every
+    instruction of a compiled program whose metadata names an ``op_name``
+    (the ``jax.named_scope``s it was traced under, then the primitive);
+    ``entry_loops``: the names among them that are ``while`` loops of the
+    ENTRY computation (each runs once an execution of the program)."""
+
+    instructions: dict
+    entry_loops: frozenset
+
+
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT )?(%[\w.\-]+ = .*?)(?:, metadata=\{[^}]*op_name="([^"]*)"|$)',
+    re.MULTILINE)
+_LAYOUT_OR_COMMENT = re.compile(r"\{[^{}]*\}|/\*.*?\*/|\s")
+_CUT_LAYOUT_OR_COMMENT = re.compile(r"(\{[^{}]*|/\*[^/]*)$")
+
+
+def parse_instruction(text: str) -> "tuple[str, str, bool]":
+    """(name, signature, whole) of an instruction's text, as a compiled
+    program's text and a profiler's device event both print it: ``%while.9 =
+    (s32[], f32[8,32]{1,0}) while(...)`` -> ``("while.9",
+    "(s32[],f32[8,32])while", True)``. The signature is the result shape and
+    the opcode; layouts, index comments and blanks go. The profiler cuts a
+    long event name short (a loop's tuple of shapes runs to thousands of
+    characters): then no ``opcode(`` follows the shape, ``whole`` is False
+    and the signature is what is left of it, a PREFIX of the whole one."""
+    name, _, rest = text.partition(" = ")
+    depth = 0
+    for end, char in enumerate(rest):  # the result shape may be a tuple
+        depth += (char == "(") - (char == ")")
+        if char == " " and depth == 0:
+            break
+    else:
+        end = len(rest)
+    opcode, whole, _ = rest[end:].lstrip().partition("(")
+    shape = _LAYOUT_OR_COMMENT.sub("", rest[:end])
+    if not opcode:  # cut short: drop the layout or comment the cut fell in
+        shape = _CUT_LAYOUT_OR_COMMENT.sub("", shape)
+    return name.strip().lstrip("%"), shape + opcode, bool(whole)
+
+
+def scopes_of_text(hlo_text: str) -> CompiledScopes:
+    """The record of one compiled program's text (``Compiled.as_text()``)."""
+
+    def with_metadata(text: str) -> dict:
+        out = {}
+        for line, op_name in _INSTRUCTION.findall(text):
+            if op_name:
+                name, sig, _ = parse_instruction(line)
+                out[name] = (sig, op_name)
+        return out
+
+    entry = hlo_text.partition("\nENTRY ")[2].partition("\n}")[0]
+    return CompiledScopes(with_metadata(hlo_text), frozenset(
+        name for name, (sig, _) in with_metadata(entry).items()
+        if sig.endswith(")while")))
+
+
+@dataclasses.dataclass
+class _TracedCall:
+    """The last call under a label that traced: the jitted function, held
+    weakly (a program that was collected reads as nothing), its arguments as
+    an abstract signature, and what it compiled to once someone asked."""
+
+    jitted: weakref.ref
+    args: tuple
+    kwargs: dict
+    scopes: "CompiledScopes | None" = None
+
+
+#: label -> its last traced call; a process holds a few dozen labels
+_TRACED: "dict[str, _TracedCall]" = {}
+
+
+def _abstract(leaf):
+    """An array leaf as its ``jax.ShapeDtypeStruct`` (with the sharding of a
+    committed ``jax.Array`` and the weak type of a Python scalar's array:
+    what jit lowers by); anything else as it is."""
+    import jax
+
+    shape, dtype = getattr(leaf, "shape", None), getattr(leaf, "dtype", None)
+    if shape is None or dtype is None:
+        return leaf
+    committed = isinstance(leaf, jax.Array) and leaf.committed
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=leaf.sharding if committed else None,
+        weak_type=getattr(leaf, "weak_type", False))
+
+
+def _remember_trace(label: str, jitted, args, kwargs) -> None:
+    import jax
+
+    def forget(ref, records=_TRACED):  # the statics do not outlive their program
+        if label in records and records[label].jitted is ref:
+            del records[label]
+
+    args, kwargs = jax.tree_util.tree_map(_abstract, (args, kwargs))
+    _TRACED[label] = _TracedCall(weakref.ref(jitted, forget), args, kwargs)
+
+
+def compiled_scopes(label: str) -> "CompiledScopes | None":
+    """What the program last traced under ``label`` compiled to: see
+    :class:`CompiledScopes`. Lowers and compiles from the remembered
+    abstract signature (in the process that ran it the lowering and the
+    executable are jit's cached ones; elsewhere the compile cache answers),
+    parses the text once and keeps the result for the process. None where
+    nothing was traced under the label, or the program that was is gone."""
+    call = _TRACED.get(label)
+    if call is None:
+        return None
+    if call.scopes is None:
+        jitted = call.jitted()
+        if jitted is None:
+            return None
+        text = jitted.lower(*call.args, **call.kwargs).compile().as_text()
+        call.scopes = scopes_of_text(text or "")
+    return call.scopes
+
+
 def _as_tuple(v) -> tuple:
     if v is None:
         return ()
@@ -639,7 +777,16 @@ def ledger_jit(fn=None, *, label: str, **jit_kwargs):
     from photon_ml_tpu.telemetry import probes
 
     probes.install_compile_listener()
-    jitted = jax.jit(fn, **jit_kwargs)
+    # .here: the function ran in this thread, i.e. jit traced it there (a
+    # trace runs in the thread of the call that misses jit's cache)
+    traced = threading.local()
+
+    @functools.wraps(fn)
+    def traced_fn(*args, **kwargs):
+        traced.here = True
+        return fn(*args, **kwargs)
+
+    jitted = jax.jit(traced_fn, **jit_kwargs)
     static_argnums = _as_tuple(jit_kwargs.get("static_argnums"))
     static_argnames = _as_tuple(jit_kwargs.get("static_argnames"))
     dispatch = "dispatch/" + label
@@ -647,15 +794,22 @@ def ledger_jit(fn=None, *, label: str, **jit_kwargs):
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        if not is_top_level():
+        if not is_top_level():  # inlined in an outer trace: not a program
             return jitted(*args, **kwargs)
+        # only a trace inside THIS call counts: not what a ``.lower()`` or an
+        # inlined call left behind in this thread
+        traced.here = False
         with span(dispatch):
-            ledger = _LEDGER
-            if ledger is None:
-                return jitted(*args, **kwargs)
-            return ledger.observed_call(
-                jitted, label, args, kwargs, static_argnums, static_argnames
-            )
+            try:
+                ledger = _LEDGER
+                if ledger is None:
+                    return jitted(*args, **kwargs)
+                return ledger.observed_call(
+                    jitted, label, args, kwargs, static_argnums, static_argnames
+                )
+            finally:
+                if traced.here:  # shapes and shardings outlive a donation
+                    _remember_trace(label, jitted, args, kwargs)
 
     wrapper.label = label
     wrapper.jitted = jitted
