@@ -70,6 +70,13 @@ Array = jax.Array
 
 logger = logging.getLogger(__name__)
 
+#: registry counter bumped once per TRACE of the exchange that brings the
+#: arrays a coordinate's buckets index to every chip of a mesh
+#: (``GameTrainProgram._whole_on_every_chip``): 0 in a one-device program, one
+#: per random-effect coordinate and two per factorization alternation in a
+#: traced step on a mesh
+EXCHANGES_TRACED = "train/exchanges_traced"
+
 
 @flax.struct.dataclass
 class GameTrainState:
@@ -417,6 +424,13 @@ class GameTrainProgram:
         # baked-in pallas_call cannot be partitioned.
         n_mesh_devices = int(mesh.devices.size) if mesh is not None else 1
         multi_device = mesh is not None and n_mesh_devices > 1
+        # where the arrays a coordinate's buckets index lie and where
+        # _whole_on_every_chip puts them; None for a program of one device,
+        # which has nothing to exchange
+        self._exchange = (
+            (NamedSharding(mesh, P("data")), NamedSharding(mesh, P()))
+            if multi_device else None
+        )
         if mesh is None and use_pallas_fe is None:
             use_pallas_fe = False  # topology unknown: keep the kernel out
         self._fe_objective = GLMObjective(
@@ -1238,15 +1252,57 @@ class GameTrainProgram:
                 solve(spec.optimizer, self._extra_fe_objectives[name].bind(batch), w0)
             )
 
+    def _whole_on_every_chip(self, *arrays):
+        """Arrays that a coordinate's buckets index by their slots (the
+        row-ordered ``[n]`` offsets by ``sample_rows``; for a factorization's
+        half-step also the fixed side's ``[n]`` entity index and its
+        ``[E, k]`` factors), whole on every chip of the program's mesh: ONE
+        all-gather an array, under the scope ``gather``, ahead of the loop
+        over the buckets. Such an array lies over the chips by its leading
+        axis (``P("data")``), a bucket's ``[e, cap]`` indices by lanes and
+        name rows anywhere; left to itself the partitioner all-gathers every
+        bucket's indices, has every chip gather ALL lanes' slots against its
+        own rows under a mask and all-reduces the results (PERF.md 6, PR 38).
+        With the operand replicated a chip gathers its own lanes' slots and
+        nobody else's. The values are the same bit for bit.
+
+        Two constraints in a traced step: the first says where the array
+        lies (``P("data")``, nothing moves) and is the instruction the
+        partitioner reshards, so the all-gather carries ITS ``op_name``,
+        ``.../gather/sharding_constraint``; constrained to replicated alone,
+        the all-gather takes the name of whatever produced the array
+        (``residual/add``) and its seconds are filed under that phase.
+
+        A program of one device, and arrays that lie on one device (the
+        post-hoc variances of a fit that was not laid over the mesh), are
+        returned as they came: nothing is emitted."""
+        if self._exchange is None or not any(
+            isinstance(a, jax.core.Tracer) or len(a.sharding.device_set) > 1
+            for a in arrays
+        ):
+            return arrays
+        default_registry().counter(EXCHANGES_TRACED).inc()
+        by_leading_axis, every_chip = self._exchange
+
+        def whole(a):
+            if isinstance(a, jax.core.Tracer):
+                a = jax.lax.with_sharding_constraint(a, by_leading_axis)
+            return jax.lax.with_sharding_constraint(a, every_chip)
+
+        with jax.named_scope("gather"):
+            return tuple(whole(a) for a in arrays)
+
     def _solve_re(self, data, buckets, k, full_offsets, table):
         """One random-effect coordinate (entities sharded, vmapped solves),
-        under the scope ``re/<k>``. Returns (table, the coordinate's
-        line-search counts: its buckets' optim/common.lane_solver_counts
-        summed)."""
+        under the scope ``re/<k>``; on a mesh the offsets are brought whole
+        to every chip once, ahead of the buckets. Returns (table, the
+        coordinate's line-search counts: its buckets'
+        optim/common.lane_solver_counts summed)."""
         spec = self._re_by_name[k]
         objective = self._re_solve_objectives[k]
         counts: dict = {}
         with jax.named_scope(f"re/{k}"):
+            (full_offsets,) = self._whole_on_every_chip(full_offsets)
             if spec.projector == ProjectorType.INDEX_MAP:
                 # scratch-column solve in each entity's observed columns
                 # (ports algorithm/coordinates.py's single-chip path into
@@ -1293,9 +1349,11 @@ class GameTrainProgram:
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):
         """One matrix-factorization coordinate (alternating vmapped solves),
         every half-step under the scope ``mf/<name>/<side>`` so that its
-        device instructions carry the coordinate's name. Returns (rows, cols,
-        score, the half-steps' line-search counts: their buckets'
-        optim/common.lane_solver_counts summed, as ``mf_*``)."""
+        device instructions carry the coordinate's name; on a mesh what its
+        buckets index (the offsets, the fixed side's entity index and
+        factors) is brought whole to every chip once a half-step. Returns
+        (rows, cols, score, the half-steps' line-search counts: their
+        buckets' optim/common.lane_solver_counts summed, as ``mf_*``)."""
         m = self._mf_by_name[name]
         row_idx = data["entity_idx"][m.row_effect_type]
         col_idx = data["entity_idx"][m.col_effect_type]
@@ -1305,11 +1363,13 @@ class GameTrainProgram:
 
         def half_step(side, table, other_idx, other_factors):
             with jax.named_scope(f"mf/{name}/{side}"):
+                offsets, other_idx, other_factors = self._whole_on_every_chip(
+                    full_offsets, other_idx, other_factors)
                 for b in mf_buckets[side]:
                     table, trace = solve_mf_side_bucket(
                         objective, m.optimizer, b["labels"], b["weights"],
                         b["entity_rows"], b["sample_rows"], other_idx,
-                        other_factors, full_offsets, table,
+                        other_factors, offsets, table,
                     )
                     _add_counts(counts, {
                         "mf_" + k: v
@@ -1442,7 +1502,8 @@ def compute_state_variances(
     for spec in selected:
         ds = re_datasets[spec.re_type]
         table = state.re_tables[spec.re_type]
-        full_offsets = offsets_excluding(skip=spec.re_type)
+        (full_offsets,) = program._whole_on_every_chip(
+            offsets_excluding(skip=spec.re_type))
         max_bucket = max((b.entity_rows.shape[0] for b in ds.buckets), default=1)
         norm = program._re_objectives[spec.re_type].normalization
         if spec.projector == ProjectorType.RANDOM:

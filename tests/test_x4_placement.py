@@ -5,6 +5,11 @@ Attempt 1 of the four-chip benchmark cell died with the whole 20 GB X
 assembled on chip 0 (PERF.md 6). The rule these tests hold the program to:
 no device ever holds more than its own shard of an array whose leading axis
 is rows, lanes or entities.
+
+Since PR 39 also: a chip gathers its own lanes' offsets and nobody else's.
+The ``[n]`` vectors a coordinate's buckets index are brought whole to every
+chip once a coordinate (``GameTrainProgram._whole_on_every_chip``), read here
+in the compiled step's text.
 """
 
 import importlib.util
@@ -15,15 +20,19 @@ import jax
 import numpy as np
 import pytest
 
+from photon_ml_tpu.algorithm.mf_coordinate import build_mf_dataset
 from photon_ml_tpu.data.game_data import GameDataset, build_random_effect_dataset
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType
 from photon_ml_tpu.parallel.distributed import (
+    EXCHANGES_TRACED,
     FixedEffectStepSpec,
     GameTrainProgram,
+    MatrixFactorizationStepSpec,
     RandomEffectStepSpec,
     train_distributed,
 )
 from photon_ml_tpu.parallel.mesh import make_mesh
+from photon_ml_tpu.telemetry.program_ledger import scopes_of_text
 from photon_ml_tpu.telemetry.registry import default_registry
 from photon_ml_tpu.telemetry.tracing import Tracer, install_tracer, uninstall_tracer
 from photon_ml_tpu.types import TaskType
@@ -33,10 +42,12 @@ ROWS, D_GLOBAL, D_ENTITY = 8192, 32, 8
 USERS, ITEMS = 96, 50
 LADDER = (8, 32, 128, 512)
 RE = (("user", "per_user"), ("item", "per_item"))
-COLLECTIVE = re.compile(
-    r"= (.*?) (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
-    r"(-start|-done)?\(")
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
+               "collective-permute")
+COLLECTIVE = re.compile(r"= (.*?) (" + "|".join(COLLECTIVES) + r")(-start|-done)?\(")
 SHAPE = re.compile(r"\w+\[([\d,]*)\]")
+TYPED_SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
+MF_RANK, MF_ALTERNATIONS = 4, 2
 
 
 def _host_data() -> dict:
@@ -277,3 +288,275 @@ def test_the_step_under_data_4_moves_no_rows_by_width_array(host, four):
     assert {"all-reduce", "all-gather"} <= {op for op, _, _ in found}
     widest = max(found, key=lambda f: f[1])
     assert widest[1] < ROWS * D_ENTITY / 4, widest
+
+
+# -- PR 39: the [n] vectors are exchanged once a coordinate -------------------
+
+
+def _mf_program(mesh) -> GameTrainProgram:
+    optimizer = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=10,
+                                rel_function_tolerance=1e-6)
+    return GameTrainProgram(
+        TaskType.LOGISTIC_REGRESSION,
+        FixedEffectStepSpec("global", optimizer, l2_weight=1.0),
+        mf_specs=(MatrixFactorizationStepSpec(
+            "mf", "user", "item", MF_RANK, optimizer, l2_weight=1.0,
+            num_alternations=MF_ALTERNATIONS),),
+        use_pallas_fe=None, mesh=mesh)
+
+
+def _without_exchange(program: GameTrainProgram) -> GameTrainProgram:
+    """The program as its parent lowered it on a mesh."""
+    program._exchange = None
+    return program
+
+
+def _mesh(devices):
+    return devices and make_mesh(devices, 1, devices=jax.devices()[:devices])
+
+
+def _packed(host, mesh) -> dict:
+    dataset = _dataset(host)
+    return {t: build_random_effect_dataset(dataset, t, s, bucket_sizes=LADDER, mesh=mesh)
+            for t, s in RE}
+
+
+def _mf_packed(host) -> dict:
+    return {"mf": build_mf_dataset(_dataset(host), "user", "item", bucket_sizes=LADDER)}
+
+
+def _placed(program, mesh, host, packed=None, mf=None):
+    """(data, buckets, state) of the program, laid over the mesh if one is given."""
+    inputs = (_dataset(host), packed or {}, mf)
+    data, buckets = program.prepare_inputs(*inputs)
+    state = program.init_state(*inputs)
+    if mesh is None:
+        return data, buckets, state
+    return program.shard_inputs(mesh, data, buckets, state)
+
+
+def _traced_exchanges(program, placed) -> int:
+    """What tracing the step once adds to the registry's counter."""
+    counter = default_registry().counter(EXCHANGES_TRACED)
+    before = counter.value
+    jax.eval_shape(program._step_impl, *placed)
+    return counter.value - before
+
+
+def _compiled(program, placed) -> dict:
+    """The optimized step's collectives as (operation, [(dtype, dims)], op_name)
+    and its scalar gathers (one element a slot) as (slots written, op_name),
+    from the program's own record of its compiled text."""
+    text = jax.jit(program._step_impl).lower(*placed).compile().as_text()
+    collectives, scalar_gathers = [], []
+    for signature, name in scopes_of_text(text).instructions.values():
+        opcode = re.search(r"[\w-]+$", signature).group()
+        shapes = [(dtype, tuple(int(s) for s in dims.split(",") if s))
+                  for dtype, dims in TYPED_SHAPE.findall(signature)]
+        if opcode.removesuffix("-start") in COLLECTIVES:  # a "-done" repeats its start
+            collectives.append((opcode.removesuffix("-start"), shapes, name))
+        elif opcode == "gather" and shapes[0][1][1:] == (1,):
+            scalar_gathers.append((shapes[0][1][0], name))
+    return {"collectives": collectives, "scalar_gathers": scalar_gathers}
+
+
+def _lanes(buckets_of_a_coordinate) -> list:
+    return [tuple(b["sample_rows"].shape) for b in buckets_of_a_coordinate]
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return make_mesh(4, 1, devices=jax.devices()[:4])
+
+
+@pytest.fixture(scope="module")
+def glmix4(host, four, mesh4):
+    program = _program(mesh4)
+    placed = _placed(program, mesh4, host, four["packed"])
+    return {"lanes": {t: _lanes(placed[1][t]) for t, _ in RE},
+            **_compiled(program, placed)}
+
+
+@pytest.fixture(scope="module")
+def mf4(host, mesh4):
+    program = _mf_program(mesh4)
+    placed = _placed(program, mesh4, host, mf=_mf_packed(host))
+    return {"lanes": {side: _lanes(placed[1]["__mf__"]["mf"][side])
+                      for side in ("row", "col")},
+            **_compiled(program, placed)}
+
+
+def _index_shapes(lanes) -> set:
+    """How a bucket's [e, cap] row indices can appear as a collective's operand."""
+    return {shape for e, cap in lanes for shape in ((e, cap), (e, cap, 1))}
+
+
+def test_without_the_exchange_the_partitioner_gathers_every_lanes_indices(host, four, mesh4):
+    """What the other tests of this section read is there to be read: the same
+    step with the exchange taken out all-gathers the buckets' ``s32[e, cap, 1]``
+    row indices and all-reduces ``f32[e, cap]`` offsets under ``gather``."""
+    program = _without_exchange(_program(mesh4))
+    placed = _placed(program, mesh4, host, four["packed"])
+    assert _traced_exchanges(program, placed) == 0
+    compiled = _compiled(program, placed)
+    lanes = [shape for t, _ in RE for shape in _lanes(placed[1][t])]
+    gathered = {dims for op, shapes, _ in compiled["collectives"] if op == "all-gather"
+                for dtype, dims in shapes if dtype == "s32"}
+    assert gathered & _index_shapes(lanes)
+    reduced = {dims for op, shapes, name in compiled["collectives"]
+               if op == "all-reduce" and "/gather/" in name for _, dims in shapes}
+    assert reduced & {(e, cap) for e, cap in lanes if cap != D_ENTITY}
+
+
+@pytest.mark.parametrize("compiled", ["glmix4", "mf4"])
+def test_no_collective_carries_a_buckets_row_indices(compiled, request):
+    compiled = request.getfixturevalue(compiled)
+    lanes = [shape for shapes in compiled["lanes"].values() for shape in shapes]
+    forbidden = _index_shapes(lanes)
+    for op, shapes, name in compiled["collectives"]:
+        for dtype, dims in shapes:
+            assert not (dtype == "s32" and dims in forbidden), (op, shapes, name)
+
+
+def test_under_gather_only_the_warm_starts_are_all_reduced(glmix4):
+    """``table[entity_rows]`` stays as it was: the table lies by entities, so a
+    bucket's ``[e, d]`` warm starts are summed over the chips. No ``[e, cap]``
+    block of offsets is."""
+    for t, _ in RE:
+        lanes = glmix4["lanes"][t]
+        reduced = [dims for op, shapes, name in glmix4["collectives"]
+                   if op == "all-reduce" and f"re/{t}/gather/" in name
+                   for _, dims in shapes]
+        assert len(reduced) <= len(lanes)
+        assert set(reduced) <= {(e, D_ENTITY) for e, _ in lanes}, reduced
+
+
+def test_one_all_gather_of_the_rows_a_random_effect_coordinate(glmix4):
+    whole = [(shapes, name) for op, shapes, name in glmix4["collectives"]
+             if op == "all-gather" and any(dims == (ROWS,) for _, dims in shapes)]
+    assert sorted(name for _, name in whole) == [
+        f"jit(_step_impl)/re/{t}/gather/sharding_constraint" for t in ("item", "user")]
+    assert all(shapes == [("f32", (ROWS,))] for shapes, _ in whole)
+
+
+def test_a_chip_gathers_its_own_lanes_offsets_and_nobody_elses(glmix4):
+    for t, _ in RE:
+        written = sorted(slots for slots, name in glmix4["scalar_gathers"]
+                         if f"re/{t}/gather/" in name)
+        assert written == sorted(e // 4 * cap for e, cap in glmix4["lanes"][t])
+
+
+def test_the_factorization_exchanges_each_rows_vector_once_a_half_step(mf4):
+    """The offsets, the fixed side's ``[n]`` entity index and its ``[E, k]``
+    factors: one all-gather each a half-step, under that half-step's scope."""
+    whole = [(shapes, name) for op, shapes, name in mf4["collectives"]
+             if op == "all-gather" and any(dims == (ROWS,) for _, dims in shapes)]
+    half_steps = sorted(["col", "row"] * MF_ALTERNATIONS)
+    for dtype in ("f32", "s32"):
+        names = sorted(name for shapes, name in whole if shapes == [(dtype, (ROWS,))])
+        assert names == [f"jit(_step_impl)/mf/mf/{side}/gather/sharding_constraint"
+                         for side in half_steps], (dtype, whole)
+    assert len(whole) == 2 * len(half_steps)
+    factors = sorted(name.split("/")[3] for op, shapes, name in mf4["collectives"]
+                     if op == "all-gather" and name.endswith("/gather/sharding_constraint")
+                     for _, dims in shapes if dims[1:] == (MF_RANK,))
+    assert factors == half_steps
+    for side in ("row", "col"):
+        written = sorted(slots for slots, name in mf4["scalar_gathers"]
+                         if f"mf/mf/{side}/gather/" in name)
+        own = [e // 4 * cap for e, cap in mf4["lanes"][side]]
+        # the offsets and the other side's index, a scalar gather each a bucket
+        assert written == sorted(own * 2 * MF_ALTERNATIONS)
+
+
+@pytest.mark.parametrize("devices, build, exchanges", [
+    (None, _program, 0), (1, _program, 0), (4, _program, len(RE)),
+    (None, _mf_program, 0), (4, _mf_program, 2 * MF_ALTERNATIONS)])
+def test_the_counter_reads_the_exchanges_a_trace_of_the_step_made(
+        host, devices, build, exchanges):
+    mesh = _mesh(devices)
+    packed, mf = (_packed(host, mesh), None) if build is _program else (None, _mf_packed(host))
+    program = build(mesh)
+    assert _traced_exchanges(program, _placed(program, mesh, host, packed, mf)) == exchanges
+
+
+@pytest.mark.parametrize("devices, constrained", [(None, False), (1, False), (4, True)])
+def test_only_a_program_of_several_devices_lowers_the_step_with_a_sharding_constraint(
+        host, devices, constrained):
+    """The one-chip cells' guarantee: nothing is emitted, the step is the
+    parent's. And the text would show one: four devices' lowering does."""
+    mesh = _mesh(devices)
+    program = _program(mesh)
+    placed = _placed(program, mesh, host, _packed(host, mesh))
+    text = jax.jit(program._step_impl).lower(*placed).as_text().lower()
+    assert ("sharding_constraint" in text or "@sharding" in text) == constrained
+
+
+def test_the_exchange_changes_no_bit_of_a_sweep(host, four, mesh4):
+    """Four devices' ``step`` with the exchange and with it taken out: the same
+    values gathered by the same indices, so tables, loss and line-search counts
+    are equal bit for bit."""
+    def sweep(exchange: bool):
+        program = _program(mesh4) if exchange else _without_exchange(_program(mesh4))
+        state, loss = program.step(*_placed(program, mesh4, host, four["packed"]))
+        return state, np.asarray(loss), program.take_solver_counts()
+
+    state, loss, counts = sweep(True)
+    plain_state, plain_loss, plain_counts = sweep(False)
+    assert counts == plain_counts and counts["line_searches"] > 0
+    assert loss.tobytes() == plain_loss.tobytes()
+    np.testing.assert_array_equal(np.asarray(state.fe_coefficients),
+                                  np.asarray(plain_state.fe_coefficients))
+    for t, _ in RE:
+        np.testing.assert_array_equal(np.asarray(state.re_tables[t]),
+                                      np.asarray(plain_state.re_tables[t]))
+
+
+def test_four_devices_count_the_line_searches_one_device_counts(host, four):
+    """One sweep from the same start on one device and on four: float32 sums
+    taken in another order end a lane's solve an iteration earlier or later
+    (measured, four against one: 1008 line searches against 1007, 1018 lanes'
+    trials against 1016, 74 in lock step against 73, the fixed effect's 4)."""
+    def counts(devices):
+        mesh = _mesh(devices)
+        program = _program(mesh)
+        packed = four["packed"] if devices == 4 else _packed(host, mesh)
+        _, loss = program.step(*_placed(program, mesh, host, packed))
+        return program.take_solver_counts(), float(loss)
+
+    (on_four, loss_four), (on_one, loss_one) = counts(4), counts(1)
+    for name in ("line_searches", "lane_trials", "lockstep_trials", "fe_trials"):
+        assert 0 < on_one[name]
+        assert abs(on_four[name] - on_one[name]) <= max(1, 0.01 * on_one[name]), name
+    assert abs(loss_four - loss_one) < 1.3e-5 * loss_one
+
+
+def test_the_variances_of_a_fit_on_the_mesh_go_through_the_same_exchange(host, four, mesh4):
+    """``compute_state_variances`` indexes the offsets by every bucket's
+    ``sample_rows`` too: once a coordinate they are brought whole to every
+    chip, and the variances are those of the program that does not."""
+    from photon_ml_tpu.parallel.distributed import compute_state_variances
+
+    def variances(exchange: bool):
+        program = _program(mesh4) if exchange else _without_exchange(_program(mesh4))
+        _, _, state = _placed(program, mesh4, host, four["packed"])
+        rng = np.random.default_rng(39)  # any coefficients: the offsets differ by row
+
+        def drawn(x):
+            return jax.device_put(
+                0.3 * rng.standard_normal(x.shape).astype(x.dtype), x.sharding)
+
+        state = state.replace(fe_coefficients=drawn(state.fe_coefficients),
+                              re_tables={t: drawn(state.re_tables[t]) for t, _ in RE})
+        counter = default_registry().counter(EXCHANGES_TRACED)
+        before = counter.value
+        _, by_type, _ = compute_state_variances(
+            program, state, _dataset(host), four["packed"])
+        return by_type, counter.value - before
+
+    exchanged, made = variances(True)
+    plain, none = variances(False)
+    assert (made, none) == (len(RE), 0)
+    for t, _ in RE:
+        assert np.isfinite(np.asarray(exchanged[t])).any()
+        np.testing.assert_array_equal(np.asarray(exchanged[t]), np.asarray(plain[t]))
