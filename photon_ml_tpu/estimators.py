@@ -1345,9 +1345,9 @@ def train_glm(
         )
         w = result.coefficients
         if telemetry is not None:
-            telemetry.record_solve("glm", result, extra={"lambda": lam})
-            telemetry.heartbeat("glm", lam=lam,
-                                n_lambdas=len(regularization_weights))
+            telemetry.record_solve("glm", result, extra={
+                "lambda": lam, "optimizer": opt.optimizer_type.name})
+            telemetry.heartbeat("glm", lam=lam, n_lambdas=len(regularization_weights))
         means = norm.to_model_space(w, intercept_index)
         variances = None
         if compute_variance:
@@ -1664,7 +1664,8 @@ def train_glm_streaming(
             telemetry.record_solve(
                 "glm_streaming", result,
                 extra={"lambda": lam, "epochs": objective.epochs,
-                       "chunks": source.num_chunks},
+                       "chunks": source.num_chunks,
+                       "optimizer": opt.optimizer_type.name},
             )
         models[lam] = GeneralizedLinearModel(
             Coefficients(means=norm.to_model_space(w, intercept_index)), task

@@ -87,11 +87,11 @@ class SolverResult:
     arrays padded with NaN past ``iterations`` — the jittable analogue of
     OptimizationStatesTracker's bounded state queue.
 
-    ``line_search_trials[i]`` is the number of trial points iteration ``i``'s
-    line search evaluated (int32 [max_iter + 1], slot 0 and the slots past
-    ``iterations`` hold 0); ``floor_exits`` counts the searches that ended
-    at the float's floor (:data:`LINE_SEARCH_FLOOR_K`). Solvers with no
-    line search of that kind report zeros (:func:`no_line_search_counts`).
+    ``line_search_trials[i]`` counts the objective-side evaluations INSIDE
+    iteration ``i`` (int32 [max_iter + 1]; slot 0 and slots past ``iterations``
+    hold 0): a line search's trial points, TRON's Hessian-vector products;
+    ``floor_exits`` the searches, or TRON's rounds, the float's floor ended
+    (:data:`LINE_SEARCH_FLOOR_K`). Others: :func:`no_line_search_counts`.
     """
 
     coefficients: Array

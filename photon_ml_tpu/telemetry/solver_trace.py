@@ -87,6 +87,24 @@ def solver_result_row(
     }
 
 
+def tron_counts(result: SolverResult) -> dict:
+    """What a scalar TRON solve's ``SolverResult`` says of its inner loop
+    (optim/tron.py fills the line-search fields with it): the Hessian-vector
+    products of all rounds, the rounds that were rejected (a round whose value
+    AND gradient-norm slots repeat the slots before them: a kept step moves
+    the gradient even where float32 leaves the value as it was), and the
+    rounds the float's floor ended."""
+    n = int(result.iterations) + 1
+    values = np.asarray(result.value_history)[:n]
+    grads = np.asarray(result.grad_norm_history)[:n]
+    return {
+        "tron_hv_products": int(np.sum(np.asarray(result.line_search_trials))),
+        "tron_rejected_rounds": int(np.sum(
+            (values[1:] == values[:-1]) & (grads[1:] == grads[:-1]))),
+        "tron_floor_exits": int(result.floor_exits),
+    }
+
+
 def _as_host_trace(trace: LaneTrace | LaneTraces | SolverResult) -> LaneTrace:
     """Normalize to one LaneTrace whose fields are host numpy arrays — ONE
     device-to-host transfer per field (per-bucket LaneTraces merge here, in
@@ -251,11 +269,20 @@ class SolverTelemetry:
         outer_iteration: int = 0,
         extra: dict | None = None,
     ) -> dict:
-        """One un-vmapped solve (FE coordinate, sequential λ step)."""
+        """One un-vmapped solve (FE coordinate, sequential λ step). A solve
+        whose ``extra`` names ``optimizer`` "TRON" also reports
+        :func:`tron_counts`, in its row and as ``solver/tron_*`` counters."""
         if not self._has_sink():
             return {}
         row = solver_result_row(result)
-        row.update(extra or {})
+        extra = extra or {}
+        if extra.get("optimizer") == "TRON":
+            counts = tron_counts(result)
+            row.update(counts)
+            if self.registry is not None:
+                for name, count in counts.items():
+                    self.registry.counter(SOLVER_METRIC_PREFIX + name).inc(count)
+        row.update(extra)
         row.update(coordinate=coordinate_id, outer_iteration=outer_iteration)
         self._journal("convergence", row)
         self._emit(coordinate_id, outer_iteration, row)
