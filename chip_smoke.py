@@ -6,7 +6,9 @@ One process drives, in order, through the entry points a user would call:
   kernel   ops.pallas_glm.fused_value_and_gradient vs the autodiff objective
            at d in {256, 512, 2048, 4096} x {f32, bf16} on whole tiles and at
            d = 2000, 617 rows short of them (the masked body): Mosaic custom call
-           present, value/gradient agree with an f64 numpy recomputation
+           present, value/gradient agree with an f64 numpy recomputation; then
+           ops.pallas_glm.fused_hessian_vector at each: its custom call, the
+           product against f64 numpy and against the jvp of the gradient
   glmix    cli.game_training_driver.main  (TrainingExampleAvro on disk ->
            FE d=256 + per-user RE + per-item RE d=16, logistic, fused
            GameTrainProgram, 2 CD sweeps, telemetry on, 0 restarts)
@@ -414,6 +416,7 @@ def leg_kernel(c: Checks, sizes: Sizes, on_tpu: bool) -> None:
     from photon_ml_tpu.ops.pallas_glm import (
         _round_up,
         _row_tile,
+        fused_hessian_vector,
         fused_value_and_gradient,
     )
 
@@ -461,6 +464,23 @@ def leg_kernel(c: Checks, sizes: Sizes, on_tpu: bool) -> None:
             c.check(f"{name}: kernel vs autodiff objective (value, grad)",
                     rel(v, rv) < band and rel(g, rg) < band,
                     f"{rel(v, rv):.2e}, {rel(g, rg):.2e} (band {band:g})")
+            # a Hessian-vector product at the same width: the kernel's sibling
+            vec = rng.normal(size=d).astype(np.float32)
+            p = 1 / (1 + np.exp(-m))
+            hv_true = x64.T @ (p * (1 - p) * (x64 @ vec.astype(np.float64))) + 0.5 * vec
+            operands = (jnp.asarray(w), jnp.asarray(vec), batch)
+            product = jax.jit(
+                lambda w_, v_, b_: fused_hessian_vector(loss, w_, v_, b_, l2_weight=0.5)
+            ).lower(*operands).compile()
+            if on_tpu:
+                c.check(f"{name}: product, Mosaic custom call in the compiled program",
+                        "tpu_custom_call" in product.as_text(), f"n={n}")
+            hv = product(*operands)
+            rhv = jax.jit(reference.hessian_vector)(*operands)
+            c.check(f"{name}: product vs f64 numpy", rel(hv, hv_true) < 1e-4,
+                    f"{rel(hv, hv_true):.2e}")
+            c.check(f"{name}: product vs the jvp of the gradient",
+                    rel(hv, rhv) < band, f"{rel(hv, rhv):.2e} (band {band:g})")
 
 
 def glmix_argv(work: str, out: str, tel: str, mesh: "str | None") -> tuple:

@@ -172,10 +172,10 @@ class GLMObjective:
     def hessian_vector(
         self, coefficients: Array, vector: Array, batch: LabeledPointBatch
     ) -> Array:
-        """H @ v, one jvp of the gradient (TRON's CG step, TRON.scala:298-300), its
-        contractions at precision "highest": float32 stays float32 whatever the
-        backend makes of them. Compiled for a v5e at 400,000 x 2,000 it is two
-        multiply-reduce passes over X (``X v``, then ``X' u``), none on the MXU."""
+        """H @ v (TRON's CG step, TRON.scala:298-300): ``_one_pass_hessian_vector``
+        (at the end of this file) where its rule admits, else a jvp of the gradient."""
+        if self._pallas_enabled(coefficients, batch) and not _under_vmap(vector):
+            return _one_pass_hessian_vector(self, coefficients, vector, batch)
         with jax.default_matmul_precision("highest"):
             return jax.jvp(lambda w: jax.grad(self.value)(w, batch),
                            (coefficients,), (vector,))[1]
@@ -256,3 +256,28 @@ class BoundObjective:
 
 ValueAndGradFn = Callable[[Array], tuple[Array, Array]]
 HessianVectorFn = Callable[[Array, Array], Array]
+
+
+def _one_pass_hessian_vector(
+    objective: GLMObjective, coefficients: Array, vector: Array, batch: LabeledPointBatch
+) -> Array:
+    """``GLMObjective.hessian_vector`` under the rule that gives
+    ``value_and_gradient`` the one-pass kernel (``_pallas_enabled``, and a
+    ``vector`` that is no vmap tracer either): the kernel's sibling,
+    ops/pallas_glm.fused_hessian_vector, one read of X a product. Everywhere
+    else the method takes one jvp of the gradient, its contractions at
+    precision "highest": float32 stays float32 whatever the backend makes of
+    them. Compiled for a v5e at 400,000 x 2,000 that fallback is two
+    multiply-reduce passes over X (``X v``, then ``X' u``) and a hoisted third
+    a round, none on the MXU.
+
+    Down here, and the method's body at the ten lines it had, because a line
+    that moves above a Python caller of the gradient kernel
+    (``BoundObjective.value_and_grad`` is one) re-keys every compiled program
+    that holds that kernel (PERF.md 6, PR 24)."""
+    from photon_ml_tpu.ops.pallas_glm import fused_hessian_vector
+
+    return fused_hessian_vector(
+        objective.loss, coefficients, vector, batch,
+        l2_weight=objective.l2_weight, normalization=objective.normalization,
+    )

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused GLM value + gradient in one pass over X.
+"""Pallas TPU kernels: fused GLM value + gradient, and H v, one pass over X each.
 
 This is the reference's hot loop (ValueAndGradientAggregator.scala:133-177 —
 per-sample margin dot product, pointwise loss, axpy accumulation, merged
@@ -6,15 +6,21 @@ tree-wise) as a single Pallas kernel: each row tile streams through VMEM
 once; the margin matvec, the pointwise loss/derivative, and the gradient
 accumulation all consume the tile while it is resident, so X crosses HBM
 once per evaluation where the autodiff/XLA path reads it twice (forward
-margin matvec + backward transpose matvec — XLA does not fuse them into one
-read, as the timings below show).
+margin matvec + backward transpose matvec — XLA does not fuse them).
 
-Measured on one TPU v5e under jax 0.9.0 (chip run of PR 21; ms per
-evaluation inside one jitted 64-step scan, gradient error against an f64
-numpy recomputation):
+Its sibling (PR 41; HessianVectorAggregator.scala's loop) is the product TRON's
+CG takes (``_hv_kernel`` / ``fused_hessian_vector``, at the end of this file):
+the same grid, tile, aux block and masks, and per tile ``m = x.w + o``,
+``d2 = ws * l''(m, y)``, ``z = x.v``, ``acc += (d2 z)' x``, where the jvp of the
+gradient reads X twice a product and once more a round. On a v5e at 400,000 x
+2,000 float32 a launch takes 4.60 ms beside the gradient kernel's 4.60 (85 % of
+819 GB/s; PERF.md 5, PR 41): two more vector operations an element, the margins
+recomputed on the tile, hide under the stream. Its gap to float64 there is 4e-7.
 
-- f32, d=512, n=262144: 0.92 ms (583 GB/s of X) vs 1.63 ms for the
-  autodiff path (two X passes) — 1.8x; d=4096, n=32768: 0.74 vs 1.45 ms.
+Measured on one TPU v5e under jax 0.9.0 (chip run of PR 21; per evaluation inside
+one jitted 64-step scan): f32, d=512, n=262144: 0.92 ms (583 GB/s of X) vs 1.63 ms
+for the autodiff path (two X passes) — 1.8x; d=4096, n=32768: 0.74 vs 1.45 ms.
+
 - Both matvecs are VPU multiply + lane/sublane reductions with f32
   products, for f32 and bf16 tiles alike. An MXU variant ([tile,d]@[d,1]
   margins, [1,tile]@[tile,d] gradient) was no faster at d >= 512 (0.92 vs
@@ -31,11 +37,10 @@ numpy recomputation):
   limit 16.00M"); the tile budget and the explicit limit below are what
   made d_pad in {2048, 4096, 12800, 16384} x {f32, bf16} compile.
 
-Accumulator outputs (value, gradient, Σr) map to the same block every grid
-step, making them sequential accumulators (TPU grids are serialized),
-initialized at step 0.
+Accumulator outputs (value, gradient, Σr) map to the same block every grid step:
+sequential accumulators (TPU grids are serialized), initialized at step 0.
 
-The kernel reads X ``[n, d]`` and the aux block ``[n, 3]`` AS THEY LIE
+The kernels read X ``[n, d]`` and the aux block ``[n, 3]`` AS THEY LIE
 (PR 33): the grid is ``cdiv(n, tile)`` and the X block ``(tile, d_pad)``
 over the ``d``-wide array, so the last row tile and the last lanes are
 partial blocks, whose out-of-bounds part is undefined on read (the
@@ -49,20 +54,15 @@ VMEM, by selects on an iota that follow from the static shape alone:
 - ``n % tile != 0``: a second body, which only the LAST grid step runs
   (``pl.when``), also zeroes the rows at or past ``n`` of the X tile and of
   ``r`` and ``ws * l``; every other step runs the unmasked body.
-- whole tiles and whole lanes: no mask is emitted at all, and the kernel's
-  jaxpr is letter for letter the one the padded wrapper ran.
+- whole tiles and whole lanes: no mask is emitted at all.
 
-The zeros stand exactly where the wrapper's ``jnp.pad`` put them, under the
-same tile and the same ``d_pad``-lane reduction, so value and gradient are
-the padded call's BIT FOR BIT (tests/test_pallas_glm.py in the interpreter;
-on the chip at 400,000 x 2,000, 99,999 x 4,000 and in bf16: my chip run,
-PR 33). The pad it replaces was ``f32[400384,2048] pad(f32[400000,2000])``
-inside every evaluation of the dense benchmark cell: 9.96 ms beside the
-kernel's 4.61 ms, 55 times a fit (PERF.md §6, PR 33).
+The zeros stand exactly where a ``jnp.pad`` of X would put them, under the same
+tile and ``d_pad``-lane reduction: results are the padded call's BIT FOR BIT
+(tests/test_pallas_glm.py, tests/test_pallas_hv.py; on the chip, PR 33). The pad
+this replaced cost 9.96 ms beside the kernel's 4.61 ms, 55 times a fit.
 
-On the ``cpu`` platform the kernel runs in Pallas interpret mode so the
-same code path is testable there; on ``tpu`` it is always compiled by
-Mosaic; any other platform is an error.
+On ``cpu`` the kernels run in Pallas interpret mode, so the same code path is
+testable there; on ``tpu`` Mosaic compiles them; any other platform is an error.
 """
 
 from __future__ import annotations
@@ -289,3 +289,170 @@ def fused_value_and_gradient(
         value = value + 0.5 * l2_weight * jnp.vdot(coefficients, coefficients)
         grad = grad + l2_weight * coefficients
     return value.astype(coefficients.dtype), grad
+
+
+# -- the Hessian-vector product: the same stream, two lane reductions a tile --
+
+#: the product kernel's own three, bumped as the gradient kernel's are
+HV_TRACES_COMPILED = "ops/pallas_glm/hv_traces_compiled"
+HV_TRACES_INTERPRETED = "ops/pallas_glm/hv_traces_interpreted"
+HV_TRACES_RAGGED = "ops/pallas_glm/hv_traces_ragged"
+
+
+def _hv_kernel(loss: PointwiseLoss, n: int, d: int, zshift_ref, x_ref, aux_ref,
+               w_ref, v_ref, acc_ref, usum_ref):
+    """``acc += X' (d2 * (X v + zshift))`` over one row tile, ``d2`` the loss's
+    second derivative at the margins ``X w + o``, which are recomputed on the
+    tile: vector work under a DMA-bound stream, and nothing is carried from
+    the round's gradient evaluation to its products. Grid, tile, aux block
+    and masks are ``_kernel``'s."""
+    tile, d_pad = x_ref.shape
+    step = pl.program_id(0)
+
+    @pl.when(step == 0)
+    def _init():
+        usum_ref[0, 0] = jnp.float32(0.0)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def accumulate(rows):
+        """``rows`` as in ``_kernel``: None on a whole tile, else the rows of
+        the tile that the array has; what lies past an edge is selected away."""
+        x = x_ref[:].astype(jnp.float32)  # [tile, d_pad], streamed f32 or bf16
+        keep = live = None
+        if d != d_pad:
+            keep = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < d
+        if rows is not None:
+            live = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < rows
+            keep = live if keep is None else keep & live
+        if keep is not None:
+            x = jnp.where(keep, x, 0.0)
+        aux = aux_ref[:]  # [tile, 3]: labels | offsets | weights
+        y, o, ws = aux[:, 0:1], aux[:, 1:2], aux[:, 2:3]
+        margins = jnp.sum(x * w_ref[:], axis=1, keepdims=True) + o
+        z = jnp.sum(x * v_ref[:], axis=1, keepdims=True) + zshift_ref[0, 0]
+        u = ws * loss.d2z(margins, y) * z  # [tile, 1] f32
+        if live is not None:
+            u = jnp.where(live, u, 0.0)
+        # Σu feeds the normalized-space chain rule, as Σr does the gradient's
+        usum_ref[0, 0] += jnp.sum(u)
+        acc_ref[:] = acc_ref[:] + jnp.sum(u * x, axis=0, keepdims=True)
+
+    if n % tile == 0:
+        accumulate(None)
+    else:
+        last = pl.num_programs(0) - 1
+        pl.when(step != last)(lambda: accumulate(None))
+        pl.when(step == last)(lambda: accumulate(n % tile))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _hv_one_pass(loss: PointwiseLoss, x, aux, interpret: bool, w, v, zshift):
+    """``x`` [n, d] and ``aux`` [n, 3] as they lie; ``w`` and ``v`` [d_pad],
+    zero past d; ``zshift`` the scalar every row's ``X v`` is moved by.
+    Returns ``(X'u [d_pad], Σu)``. The device trace knows the custom call by
+    this name, and the benchmark's reduction files a name that holds
+    ``_fused_padded`` or ``pallas`` under the gradient kernel's category
+    whatever scope it was traced under: this one must match neither."""
+    n, d = x.shape
+    d_pad = w.shape[0]
+    tile = _row_tile(d_pad, x.dtype.itemsize)
+    if n == 0:  # an empty grid would leave the accumulators unwritten
+        return jnp.zeros((d_pad,), jnp.float32), jnp.float32(0.0)
+    if n % tile or d != d_pad:
+        default_registry().counter(HV_TRACES_RAGGED).inc()
+
+    vmem = {} if interpret else dict(memory_space=pltpu.VMEM)
+    smem = {} if interpret else dict(memory_space=pltpu.SMEM)
+    row = pl.BlockSpec((1, d_pad), lambda i: (0, 0), **vmem)
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0), **smem)
+    acc, usum = pl.pallas_call(
+        functools.partial(_hv_kernel, loss, n, d),
+        grid=(pl.cdiv(n, tile),),
+        in_specs=[
+            scalar,
+            pl.BlockSpec((tile, d_pad), lambda i: (i, 0), **vmem),
+            pl.BlockSpec((tile, 3), lambda i: (i, 0), **vmem),
+            row,
+            row,
+        ],
+        out_specs=[row, scalar],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(zshift.reshape(1, 1), x, aux, w.reshape(1, d_pad), v.reshape(1, d_pad))
+    return acc[0], usum[0, 0]
+
+
+def fused_hessian_vector(
+    loss: PointwiseLoss,
+    coefficients: Array,
+    vector: Array,
+    batch: LabeledPointBatch,
+    *,
+    l2_weight: float = 0.0,
+    normalization=None,
+    interpret: bool | None = None,
+) -> Array:
+    """Fused ``H(coefficients) @ vector`` of the weighted GLM objective: the
+    sibling of :func:`fused_value_and_gradient`, one read of X a product.
+
+    Numerically equivalent to ``jax.jvp`` of ``jax.grad`` of
+    GLMObjective.value, normalization algebra included: the kernel streams X
+    once with ``eff_w = factors*w`` (and the offsets shifted, as for the
+    gradient) and ``eff_v = factors*v``; the scalar ``eff_v . shifts`` that
+    every row's ``z = x . eff_v`` is moved by is taken off ON THE TILE, before
+    ``u = d2 * z``, where it costs one add a row. Folded through afterwards
+    (``X'(d2 z) - (eff_v . shifts) X'd2``) it would want a second [d]
+    accumulator and cancels AFTER the sums: on standardized columns whose
+    means are of their deviation's size the product's gap to float64 read
+    1e-6 to 2e-5 folded where 1e-7 on the tile (interpreter, float32; 20,000
+    to 50,000 rows). Back to ``w``'s space
+    ``Hv = factors * (X'u - (Σu)*shifts) + l2*v``. Use inside jit.
+
+    Feature dtypes, shapes and what is padded are the gradient kernel's.
+    """
+    if interpret is None:
+        interpret = _should_interpret()
+    default_registry().counter(
+        HV_TRACES_INTERPRETED if interpret else HV_TRACES_COMPILED
+    ).inc()
+    x = batch.features
+    if x.dtype not in (jnp.float32, jnp.bfloat16):
+        x = jnp.asarray(x, jnp.float32)
+    d = x.shape[1]
+    d_pad = _round_up(d, _LANE)
+    factors = shifts = None
+    if normalization is not None:
+        factors, shifts = normalization.factors, normalization.shifts
+    eff_w = jnp.asarray(coefficients, jnp.float32)
+    eff_v = jnp.asarray(vector, jnp.float32)
+    if factors is not None:
+        factors = jnp.asarray(factors, jnp.float32)
+        eff_w, eff_v = eff_w * factors, eff_v * factors
+    offsets = jnp.asarray(batch.offsets, jnp.float32)
+    zshift = jnp.float32(0.0)
+    if shifts is not None:
+        shifts = jnp.asarray(shifts, jnp.float32)
+        offsets = offsets - jnp.dot(eff_w, shifts)
+        zshift = -jnp.dot(eff_v, shifts)
+    aux = jnp.stack([
+        jnp.asarray(batch.labels, jnp.float32),
+        offsets,
+        jnp.asarray(batch.weights, jnp.float32),
+    ], axis=1)
+    hv, usum = _hv_one_pass(
+        loss, x, aux, bool(interpret),
+        jnp.pad(eff_w, (0, d_pad - d)), jnp.pad(eff_v, (0, d_pad - d)), zshift)
+    hv = hv[:d]
+    if shifts is not None:
+        hv = hv - usum * shifts
+    if factors is not None:
+        hv = hv * factors
+    hv = hv.astype(coefficients.dtype)
+    if l2_weight > 0.0:
+        hv = hv + l2_weight * vector
+    return hv

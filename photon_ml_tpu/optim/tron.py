@@ -7,13 +7,13 @@ defaults maxIter=15, tolerance=1e-5, maxNumImprovementFailures — here the CG
 cap defaults to 20 like the reference (TRON.scala:257-262).
 
 TPU-native: outer loop and CG are nested lax.while_loops in one XLA program;
-each CG step is one Hessian-vector product (a jvp-of-grad). What that is on
-the chip was read from the compiled text of ``glm/path_solve`` for a v5e at
-400,000 x 2,000 float32 (PERF.md 5, PR 40): two multiply-reduce fusions that
-each read X once, ``X v`` into [n] and ``X' u`` into [d], on the vector unit in
-float32 (no ``convolution``, no MXU ``dot``, nothing rounded to bfloat16); the
-margins ``X w`` and the loss's second derivative do not change inside a round
-and the compiler hoists them out of the CG loop, one more pass over X a round.
+each CG step is one Hessian-vector product, whatever ``hessian_vector_fn`` is.
+For a dense GLM on the chip, un-vmapped, it is ONE Pallas custom call that reads
+X once (ops/pallas_glm.fused_hessian_vector, by ``GLMObjective``'s own rule:
+4.60 ms at 400,000 x 2,000 float32 on a v5e, PERF.md 5, PR 41); elsewhere a
+jvp-of-grad, which compiled for that size is two multiply-reduce fusions that
+each read X once, on the vector unit in float32, and a third the compiler
+hoists out of the CG loop, the margins ``X w``, once a round (PERF.md 6, PR 40).
 TRON needs only O(4) work vectors vs L-BFGS's 2m, which is why the reference
 positions it for high-dimensional L2 problems — the same argument holds for
 sharded 1B-coefficient vectors (SURVEY.md §7).

@@ -1,0 +1,117 @@
+"""Compiles for a DESCRIBED TPU v5e (no chip attached, nothing runs): what the
+interpreter cannot show of the Pallas kernels, and what the benchmark's trace
+reduction will find in the TRON path program.
+
+Held here: the one-pass Hessian-vector kernel compiles through Mosaic at the
+widths the auto rule admits (scoped VMEM, tiling), and ``glm/path_solve`` under
+TRON at the benchmark cell's size holds ONE custom call a product, inside the
+CG loop, under ``tron/hv``, named after its jitted wrapper by a name
+``benchmark/trace_reduce.KERNEL`` does not match, with X relaid out twice a
+solve and never inside a loop.
+
+This is the ONE file that describes a topology: the TPU library is loaded by
+the process that runs these tests, inside a fixture, never at import.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import photon_ml_tpu.ops.pallas_glm as kernel_mod
+from benchmark import trace_reduce
+from photon_ml_tpu.data.batch import LabeledPointBatch
+from photon_ml_tpu.ops.losses import LogisticLoss
+from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType
+
+ROWS, FEATURES = 400_000, 2_000  # logistic-epsilon-tron.path
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the library away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("d,rows_short", [(512, 0), (2000, 617), (4096, 0), (16384, 0)],
+                         ids=["d512", "d2000-ragged", "d4096", "d16384"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_product_kernel_compiles_for_a_v5e(one_chip, d, rows_short, dtype):
+    dtype = jnp.dtype(dtype)
+    d_pad = kernel_mod._round_up(d, 128)
+    n = 8 * kernel_mod._row_tile(d_pad, dtype.itemsize) - rows_short
+    with jax.enable_x64(False):  # the suite's x64 makes the grid's index maps int64
+        compiled = jax.jit(
+            lambda x, aux, w, v, z: kernel_mod._hv_one_pass(
+                LogisticLoss(), x, aux, False, w, v, z)
+        ).lower(_shape(one_chip, (n, d), dtype), _shape(one_chip, (n, 3)),
+                _shape(one_chip, (d_pad,)), _shape(one_chip, (d_pad,)),
+                _shape(one_chip, ())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def tron_path_text(one_chip):
+    """The optimized text of ``glm/path_solve`` under the cell's optimizer at
+    the cell's size, the objective as ``train_glm`` builds it on a TPU."""
+    from photon_ml_tpu import estimators
+
+    batch = LabeledPointBatch(
+        features=_shape(one_chip, (ROWS, FEATURES)), labels=_shape(one_chip, (ROWS,)),
+        offsets=_shape(one_chip, (ROWS,)), weights=_shape(one_chip, (ROWS,)))
+    tron = OptimizerConfig(OptimizerType.TRON, max_iterations=15, tolerance=1e-5,
+                           max_cg_iterations=20)
+    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+        # what the auto rule and the interpreter's rule ask
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return estimators._jitted_path_solve.lower(
+            GLMObjective(LogisticLoss()), tron, batch,
+            _shape(one_chip, (FEATURES,)), _shape(one_chip, ()), None, None,
+        ).compile().as_text()
+
+
+def _custom_calls(text):
+    """{instruction name: op_name} of the program's Mosaic custom calls."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text)}
+
+
+def test_a_product_is_one_custom_call_under_tron_hv_by_its_own_name(tron_path_text):
+    calls = _custom_calls(tron_path_text)
+    products = {name: op for name, op in calls.items() if "tron/hv" in op}
+    assert len(products) == 1
+    (name, op_name), = products.items()
+    assert trace_reduce.instruction(f"%{name} = ") == "_hv_one_pass"
+    assert not trace_reduce.KERNEL.search(name)
+    assert op_name.endswith("tron/cg/while/body/tron/hv/jit(_hv_one_pass)/pallas_call")
+    # the round's value and gradient: the gradient kernel, outside every scope
+    others = {trace_reduce.instruction(f"%{n} = ") for n in calls if n not in products}
+    assert others == {"_fused_padded"}
+    assert not any("tron/" in calls[n] for n in calls if n not in products)
+
+
+def test_x_is_read_by_the_kernels_alone_and_relaid_out_twice_a_solve(tron_path_text):
+    """No XLA fusion reads the [rows, features] block (the jvp's two
+    multiply-reduce passes and the hoisted third are gone), and the relayout
+    copy of X stands in ENTRY, before the first evaluation and before the
+    rounds' loop: once a solve each, never once a product."""
+    x = rf"f32\[{ROWS},{FEATURES}\]"
+    entry = tron_path_text[tron_path_text.index("\nENTRY "):]
+    copies = re.findall(rf"= {x}[^ ]* copy\(", tron_path_text)
+    assert len(copies) == 2 and len(re.findall(rf"= {x}[^ ]* copy\(", entry)) == 2
+    assert not re.search(rf"fusion\([^\n]*{x}", tron_path_text)

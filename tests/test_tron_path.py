@@ -10,7 +10,11 @@ ends at the float's floor, before ``max_iterations``, with no rejected round
 after its last accepted one; (d) the jitted path equals the eager loop; (e)
 ``H v`` against float64 numpy, and a product with bfloat16 operands beside it;
 (f) an L-BFGS solve's ``SolverResult`` has the leaves it had; (g) the scopes
-and the telemetry adapter's counts exist for a TRON solve and for no other.
+and the telemetry adapter's counts exist for a TRON solve and for no other;
+(h) with the one-pass product kernel in (interpreted) the path takes the rounds
+and products it takes on the jvp, and the kernel's call stands under
+``tron/hv`` by a name the benchmark's reduction does not take for the
+gradient kernel's.
 """
 
 import contextlib
@@ -433,6 +437,67 @@ def test_a_tron_scope_is_never_an_instruction(monkeypatch):
     bare = text_of_a_fresh_program()
     assert "tron/" not in bare
     assert stripped(bare) == stripped(scoped)
+
+
+# -- (h) the one-pass product on the path ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def kernel_and_jvp_paths():
+    """The λ path through ``glm/path_solve`` twice, the objective built with
+    the kernels forced (interpreted here) and with them off, and the record of
+    the program that holds the kernels."""
+    from photon_ml_tpu.estimators import _jitted_path_solve
+
+    x, y = _rows("logistic", 3000, 40, seed=23, dtype=np.float32)
+    batch = _batch(x, y)
+    paths = {}
+    for use_pallas in (True, False):
+        objective = GLMObjective(loss_for_task(TASKS["logistic"]),
+                                 use_pallas=use_pallas)
+        w = jnp.zeros(40, jnp.float32)
+        paths[use_pallas] = []
+        for lam in LAMBDAS:
+            result = _jitted_path_solve(objective, TRON, batch, w,
+                                        np.float32(lam), None, None)
+            w = result.coefficients
+            paths[use_pallas].append(result)
+        if use_pallas:  # the record is of the label's LAST traced program
+            record = compiled_scopes("glm/path_solve")
+    return paths, record
+
+
+@pytest.mark.parametrize("k", range(len(LAMBDAS)), ids=[f"lambda{lam:g}" for lam in LAMBDAS])
+def test_the_kernels_path_takes_the_jvps_rounds_and_products(kernel_and_jvp_paths, k):
+    paths, _ = kernel_and_jvp_paths
+    kernel, jvp = paths[True][k], paths[False][k]
+    assert int(kernel.iterations) == int(jvp.iterations)
+    assert (np.asarray(kernel.line_search_trials).tolist()
+            == np.asarray(jvp.line_search_trials).tolist())
+    assert int(kernel.reason) == int(jvp.reason)
+    w, expected = np.asarray(kernel.coefficients), np.asarray(jvp.coefficients)
+    assert np.linalg.norm(w - expected) / np.linalg.norm(expected) < 2e-5
+
+
+def test_the_products_call_stands_under_tron_hv_by_a_name_of_its_own(kernel_and_jvp_paths):
+    """``benchmark/path_scopes.py`` files an instruction whose NAME matches
+    ``trace_reduce.KERNEL`` under the gradient kernel's category before it
+    looks at the scope, and on the chip a Pallas custom call is named after
+    the jitted wrapper round it: the product's wrapper must not match."""
+    from benchmark import trace_reduce
+
+    _, record = kernel_and_jvp_paths
+    # the scopes each kernel's jitted wrapper was traced under (interpreted,
+    # the wrapper holds the grid's loop; on the chip, the one custom call)
+    scopes = {name: {op_name.partition(f"/jit({name})")[0]
+                     for _, op_name in record.instructions.values()
+                     if f"/jit({name})" in op_name}
+              for name in ("_hv_one_pass", "_fused_padded")}
+    assert scopes["_hv_one_pass"] and scopes["_fused_padded"]
+    assert all(_holds({path}, "tron/cg", "tron/hv") for path in scopes["_hv_one_pass"])
+    assert not any("tron/" in path for path in scopes["_fused_padded"])
+    assert trace_reduce.KERNEL.search("_fused_padded")
+    assert not trace_reduce.KERNEL.search("_hv_one_pass")
 
 
 def test_the_adapter_reports_tron_counts_for_a_tron_solve_only(float64_solve):
