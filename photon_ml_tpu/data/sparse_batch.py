@@ -139,6 +139,15 @@ def _check_index_ranges(rows, cols, n, dim) -> None:
             raise ValueError(f"column index >= dim={dim}")
 
 
+def _strictly_row_major(rows: np.ndarray, cols: np.ndarray) -> bool:
+    """True when (row, col) strictly increases along the entries: sorted
+    row-major and every pair once. Three passes, no sort."""
+    same_row = rows[1:] == rows[:-1]
+    if not ((rows[1:] > rows[:-1]) | same_row).all():
+        return False
+    return bool((cols[1:] > cols[:-1])[same_row].all())
+
+
 def coalesce_coo(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray):
     """Sum duplicate (row, col) entries (scipy sum_duplicates semantics).
 
@@ -148,6 +157,11 @@ def coalesce_coo(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray):
     exactly what the segment sums want.
     """
     if len(vals) == 0:
+        return rows, cols, vals
+    if _strictly_row_major(rows, cols):
+        # already sorted with no pair twice (a reader or generator that emits
+        # row-major unique entries): nothing to sum, and the lexsort it would
+        # take to find that out is 20 s at 4e7 entries
         return rows, cols, vals
     order = np.lexsort((cols, rows))
     r, c, v = rows[order], cols[order], vals[order]
@@ -902,8 +916,20 @@ def _column_sorted_arrays(
     )
 
 
-def sparse_margins(batch: SparseLabeledPointBatch, coefficients: Array) -> Array:
-    """margin_i = Σ_j x_ij w_j + offset_i without materializing X.
+def hot_head_dot(a: Array, b: Array) -> Array:
+    """A dot with the dense hot head as one operand, held to float32: a TPU's
+    DEFAULT matmul precision lets the compiler round float32 operands to
+    bfloat16 (1e-3 on every margin), so the head's three products (margins,
+    gradient, Hessian-vector) say ``HIGHEST``. (On a v5e XLA makes these
+    matrix-vector products float32 multiply-reduces on the vector unit
+    either way, PERF.md 6, PR 43: the name costs nothing and binds the next
+    compiler too.)"""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def sparse_product(batch: SparseLabeledPointBatch, coefficients: Array) -> Array:
+    """``X @ w`` without materializing X and without the offsets: what a
+    model scores a sparse block with (models/coefficients.py).
 
     ELL view: one gather + a DENSE row-sum over [n, L] — no per-row
     segment-sum at all (the segment-sum alone costs ~7.5 ns/entry on v5e,
@@ -913,44 +939,39 @@ def sparse_margins(batch: SparseLabeledPointBatch, coefficients: Array) -> Array
     scatter-add gradient (the reference's hand-written sparse axpy,
     ValueAndGradientAggregator.scala).
 
-    Hybrid view: the hot head adds ONE dense MXU matmul over the
-    [n, k_hot] block against a k_hot-sized coefficient gather — no
-    per-entry index ops at all for the covered nonzeros — and the ELL/flat
-    arrays (holding only the cold tail) contribute through the unchanged
-    paths below. Head pad columns are zero, so their clamped ids are inert.
+    Hybrid view: the hot head adds ONE dense matmul over the [n, k_hot]
+    block against a k_hot-sized coefficient gather (scope ``sparse/head``)
+    — no per-entry index ops at all for the covered nonzeros — and the
+    ELL/flat arrays (holding only the cold tail) contribute through the
+    unchanged paths (scope ``sparse/tail_margins``). Head pad columns are
+    zero, so their clamped ids are inert.
     """
+    base = None
     if batch.has_hybrid_view:
-        base = batch.hot_vals @ coefficients[batch.hot_col_ids]
+        with jax.named_scope("sparse/head"):
+            base = hot_head_dot(batch.hot_vals, coefficients[batch.hot_col_ids])
+    with jax.named_scope("sparse/tail_margins"):
         if batch.has_ell_view:
-            base = base + jnp.einsum(
+            ell = jnp.einsum(
                 "nl,nl->n", batch.ell_vals, coefficients[batch.ell_cols]
             )
-        if batch.values.shape[0]:
-            base = base + jax.ops.segment_sum(
+            base = ell if base is None else base + ell
+        # the flat triple: whatever overflowed the ELL block, or (no other
+        # view) every entry, none included
+        if batch.values.shape[0] or base is None:
+            flat = jax.ops.segment_sum(
                 batch.values * coefficients[batch.col_indices],
                 batch.row_ids,
                 num_segments=batch.num_samples, indices_are_sorted=True,
             )
-        return base + batch.offsets
-    if batch.has_ell_view:
-        base = jnp.einsum(
-            "nl,nl->n", batch.ell_vals, coefficients[batch.ell_cols]
-        )
-        if batch.values.shape[0]:
-            base = base + jax.ops.segment_sum(
-                batch.values * coefficients[batch.col_indices],
-                batch.row_ids,
-                num_segments=batch.num_samples, indices_are_sorted=True,
-            )
-        return base + batch.offsets
-    contrib = batch.values * coefficients[batch.col_indices]
-    return (
-        jax.ops.segment_sum(
-            contrib, batch.row_ids,
-            num_segments=batch.num_samples, indices_are_sorted=True,
-        )
-        + batch.offsets
-    )
+            base = flat if base is None else base + flat
+    return base
+
+
+def sparse_margins(batch: SparseLabeledPointBatch, coefficients: Array) -> Array:
+    """margin_i = Σ_j x_ij w_j + offset_i (:func:`sparse_product` plus the
+    batch's offsets)."""
+    return sparse_product(batch, coefficients) + batch.offsets
 
 
 def sparse_column_sum(

@@ -11,7 +11,13 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+from photon_ml_tpu.data.sparse_batch import SparseLabeledPointBatch, sparse_product
+
 Array = jax.Array
+
+#: a sparse block scored as ONE program, the block an ARGUMENT (every layout
+#: of the batch: the hybrid head, the ELL tail, the flat overflow)
+_sparse_score = jax.jit(sparse_product)
 
 
 @flax.struct.dataclass
@@ -23,8 +29,13 @@ class Coefficients:
     def dim(self) -> int:
         return self.means.shape[-1]
 
-    def compute_score(self, features: Array) -> Array:
-        """Dot product score (reference Coefficients.computeScore)."""
+    def compute_score(self, features) -> Array:
+        """Dot product score (reference Coefficients.computeScore):
+        ``features`` a dense [n, d] block, or a ``SparseLabeledPointBatch``
+        whose rows are scored through its own layout (``sparse_product``:
+        the batch's offsets are not part of a score)."""
+        if isinstance(features, SparseLabeledPointBatch):
+            return _sparse_score(features, self.means)
         return features @ self.means
 
     @classmethod

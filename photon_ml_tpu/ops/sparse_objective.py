@@ -27,8 +27,10 @@ import jax.numpy as jnp
 
 from photon_ml_tpu.data.sparse_batch import (
     SparseLabeledPointBatch,
+    hot_head_dot,
     sparse_column_sum,
     sparse_margins,
+    sparse_product,
 )
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import (
@@ -158,14 +160,28 @@ class SparseGLMObjective:
         derives for the ELL path, written out so the hybrid value+gradient
         shares ONE dz evaluation across head and tail (the r4 dense-kernel
         single-pass discipline)."""
-        if batch.has_ell_view:
-            contrib = dzw[:, None] * batch.ell_vals
-            g_eff = g_eff.at[batch.ell_cols.ravel()].add(contrib.ravel())
-        if batch.values.shape[0]:
-            g_eff = g_eff.at[batch.col_indices].add(
-                dzw[batch.row_ids] * batch.values
-            )
+        with jax.named_scope("sparse/tail_gradient"):
+            if batch.has_ell_view:
+                contrib = dzw[:, None] * batch.ell_vals
+                g_eff = g_eff.at[batch.ell_cols.ravel()].add(contrib.ravel())
+            if batch.values.shape[0]:
+                g_eff = g_eff.at[batch.col_indices].add(
+                    dzw[batch.row_ids] * batch.values
+                )
         return g_eff
+
+    def _head_gradient(
+        self, row_terms: Array, batch: SparseLabeledPointBatch
+    ) -> Array:
+        """The hot head's share of ``X' row_terms`` as a [dim] vector: one
+        dense [n]·[n, k_hot] product at float32 (``hot_head_dot``) and a
+        k_hot-sized scatter, under the scope ``sparse/head``."""
+        with jax.named_scope("sparse/head"):
+            # the solve's dtype: bfloat16 feature values still accumulate in float32
+            g_eff = jnp.zeros((batch.dim,), dtype=batch.solve_dtype)
+            return g_eff.at[batch.hot_col_ids].add(
+                hot_head_dot(row_terms, batch.hot_vals)
+            )
 
     def _value_and_gradient_hybrid(
         self, coefficients: Array, batch: SparseLabeledPointBatch
@@ -175,9 +191,10 @@ class SparseGLMObjective:
 
         One forward margin evaluation (hot MXU matmul + ELL/flat tail), one
         dz, then the gradient assembles as
-            head:  dzwᵀ X_hot  — a dense [n]·[n, k_hot] matvec plus a
-                   k_hot-sized scatter into [dim] (amortized over n rows;
-                   NO per-entry index ops for covered nonzeros)
+            head:  dzwᵀ X_hot  — a dense [n]·[n, k_hot] matvec (at float32:
+                   ``hot_head_dot``) plus a k_hot-sized scatter into [dim]
+                   (amortized over n rows; NO per-entry index ops for
+                   covered nonzeros)
             tail:  the existing ELL/flat transpose scatters, now over the
                    cold residual only
         with the full normalization algebra of the column-sorted path:
@@ -188,8 +205,7 @@ class SparseGLMObjective:
         losses, dz = self.loss.loss_and_dz(margins, batch.labels)
         total = jnp.sum(batch.weights * losses)
         dzw = batch.weights * dz
-        g_eff = jnp.zeros((batch.dim,), dtype=batch.values.dtype)
-        g_eff = g_eff.at[batch.hot_col_ids].add(dzw @ batch.hot_vals)
+        g_eff = self._head_gradient(dzw, batch)
         g_eff = self._tail_gradient_update(g_eff, dzw, batch)
         norm = self.normalization
         if norm.shifts is not None:
@@ -273,12 +289,11 @@ class SparseGLMObjective:
         norm = self.normalization
         if batch.has_hybrid_view and norm.shifts is None:
             eff_v = norm.effective_coefficients(vector)
-            mv = sparse_margins(batch, eff_v) - batch.offsets  # pure X @ f·v
+            mv = sparse_product(batch, eff_v)  # pure X @ f·v, no offsets
             margins = self.margins(coefficients, batch)
             d2w = self.loss.d2z(margins, batch.labels) * batch.weights
             t = d2w * mv
-            hv_eff = jnp.zeros((batch.dim,), dtype=batch.values.dtype)
-            hv_eff = hv_eff.at[batch.hot_col_ids].add(t @ batch.hot_vals)
+            hv_eff = self._head_gradient(t, batch)
             hv_eff = self._tail_gradient_update(hv_eff, t, batch)
             hv = hv_eff * norm.factors if norm.factors is not None else hv_eff
             if self.axis_name is not None:
@@ -288,7 +303,7 @@ class SparseGLMObjective:
             return hv
         if batch.has_column_sorted_view and norm.shifts is None:
             eff_v = norm.effective_coefficients(vector)
-            mv = sparse_margins(batch, eff_v) - batch.offsets  # pure X @ f·v
+            mv = sparse_product(batch, eff_v)  # pure X @ f·v, no offsets
             margins = self.margins(coefficients, batch)
             d2w = self.loss.d2z(margins, batch.labels) * batch.weights
             t = d2w * mv
