@@ -17,8 +17,9 @@ either direction.
 
 Optional faster views of the same entries (all strictly opt-in, all
 agreeing to tight tolerance — tests/test_sparse_hybrid.py): column-sorted
-(scatter-free gradient via sorted-run prefix sums), ELL (padded [n, L]
-rows, the single-chip default), and the r6 hybrid dense-head/sparse-tail
+(scatter-free gradient via sorted-run prefix sums), ELL (padded rows in
+width tiers that follow the rows' counts, the single-chip default; one
+[n, L] block at an explicit or agreed width), and the r6 hybrid dense-head/sparse-tail
 split (:class:`HybridPolicy`) — the nnz-hottest columns of a power-law
 feature space train as one dense MXU block with zero per-entry index ops,
 the cold residual rides the ELL tail.
@@ -285,6 +286,22 @@ class SparseShard:
             ))
         return self._hybrid_cache[1]
 
+    def one_ell_width(self) -> int:
+        """The ONE width of this shard's [n, L] ELL block where the layout
+        has to be a single block (a mesh shards it ``P("data", None)``):
+        the agreed ``ell_width`` where the reader exchanged one, else the
+        one-width auto rule on the counts of the rows' entries (of the cold
+        tail's, under the attached hybrid policy)."""
+        if self.ell_width is not None:
+            return int(self.ell_width)
+        if self.hybrid_policy is not None:
+            rows = self.hybrid_split(self.hybrid_policy)[2]
+        else:
+            rows = np.asarray(self.coalesced()[0])
+        n = self.num_samples
+        counts = np.bincount(rows, minlength=n) if len(rows) else np.zeros(n, np.int64)
+        return _ell_auto_width(counts, n, len(rows))
+
     def summarize(self, weights: np.ndarray | None = None) -> dict:
         rows, cols, vals = self.coalesced()
         return summarize_sparse(
@@ -311,6 +328,26 @@ class SparseShardDevice:
             contrib, self.row_ids,
             num_segments=self.num_samples, indices_are_sorted=True,
         )
+
+
+@flax.struct.dataclass
+class EllTier:
+    """One further width tier of the ELL view: entries ``W_k + 1 .. W_{k+1}``
+    of ONLY the rows that hold more than ``W_k`` (``W_k`` = the widths of the
+    tiers before it, ``ell_vals`` first). Pad slots carry value 0 / col 0.
+    The rows lie along the MINOR axis (``[width, n_k]``): a TPU keeps a
+    narrow ``[n_k, 4]`` block with its rows along the lanes anyway, and
+    flattening that row by row for the gather and the scatter-add compiled
+    into 245 MB of code and 155 s where this takes 27 (PERF.md 6, PR 44);
+    this way round a tier flattens as it lies."""
+
+    vals: Array     # [W_{k+1} - W_k, n_k]
+    cols: Array     # [W_{k+1} - W_k, n_k] int32
+    row_ids: Array  # [n_k] int32, ascending and unique (a subset of the rows)
+
+    @property
+    def width(self) -> int:
+        return self.vals.shape[0]
 
 
 @flax.struct.dataclass
@@ -350,15 +387,22 @@ class SparseLabeledPointBatch:
     #: boundary gather — no scatter and no giant-num_segments segment-sum,
     #: both of which fail to compile/perform at d ~ 10⁷ on TPU.
     col_bounds: Array | None = None
-    #: optional ELL (padded fixed-width row) view: [n, L] values / column
-    #: ids with pad slots carrying value 0 / col 0; rows wider than L spill
-    #: their overflow into the flat COO arrays (which then hold ONLY the
-    #: tail). Margins become one dense row-sum (no per-row segment-sum) and
-    #: the dz term broadcasts (no per-entry row gather) — measured 1.9x the
+    #: optional ELL (padded-row) view, tier 0: [n, W_1] values / column ids,
+    #: every row's first W_1 entries, pad slots carrying value 0 / col 0.
+    #: Margins become one dense row-sum (no per-row segment-sum) and the dz
+    #: term broadcasts (no per-entry row gather) — measured 1.9x the
     #: flat-COO iteration at d=10⁷ on v5e (BASELINE.md r3). The gradient's
     #: transpose scatter falls out of autodiff unchanged.
     ell_vals: Array | None = None
     ell_cols: Array | None = None
+    #: the ELL view's further width tiers (:class:`EllTier`), narrowest rows
+    #: first: the auto rule (``_ell_tier_widths``) picks the widths from the
+    #: histogram of the rows' counts, so that the slots follow the counts
+    #: where one [n, L] block pads every row to L. Empty at an explicit or
+    #: agreed width (``ell=<int>``, ``SparseShard.ell_width``) and where all
+    #: rows hold the same count. What lies beyond the last width spills into
+    #: the flat COO arrays (which then hold ONLY that overflow).
+    ell_tiers: tuple[EllTier, ...] = ()
     #: optional hybrid dense-head view (HybridPolicy): the hottest columns'
     #: entries live in a dense [n, k_hot] block (zero-filled, columns
     #: remapped to head slots) whose margin is X_hot @ w[hot_col_ids] — one
@@ -455,8 +499,10 @@ class SparseLabeledPointBatch:
         """Bind a shard's device COO to per-sample labels/offsets/weights.
 
         ell: "auto" (default) builds the padded-row ELL view — the fast TPU
-        margin/gradient layout (BASELINE.md r3) — with auto width; an int
-        fixes the width; False keeps pure flat COO.
+        margin/gradient layout (BASELINE.md r3) — in width tiers read off
+        the rows' counts (one [n, L] block at ``shard.ell_width`` where the
+        shard carries an agreed width); an int fixes ONE width; False keeps
+        pure flat COO.
         ``column_sorted_gradient`` selects the hand-fused column path
         instead and disables ELL (it consumes the full flat triple).
         hybrid: None (default) inherits the shard's attached
@@ -469,16 +515,27 @@ class SparseLabeledPointBatch:
             shard.hybrid_policy if hybrid is None
             else resolve_hybrid_policy(hybrid)
         )
-        if policy is not None:
-            if column_sorted_gradient:
-                raise ValueError(
-                    "hybrid and column_sorted_gradient are mutually "
-                    "exclusive layouts (the hybrid tail rides the ELL view)"
+        if policy is not None and column_sorted_gradient:
+            raise ValueError(
+                "hybrid and column_sorted_gradient are mutually "
+                "exclusive layouts (the hybrid tail rides the ELL view)"
+            )
+        if policy is not None or (ell and not column_sorted_gradient):
+            # no shard.device() here: the batch is built from fresh
+            # host-side arrays, and caching the unused flat triple would pin
+            # ~12 bytes/nnz of dead HBM on exactly the giant-d path
+            extra = {}
+            if policy is not None:
+                hot_vals, hot_ids, rows, cols, vals = shard.hybrid_split(policy)
+                extra = dict(
+                    hot_vals=jnp.asarray(hot_vals),
+                    hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
                 )
-            hot_vals, hot_ids, rows, cols, vals = shard.hybrid_split(policy)
-            ell_extra = {}
+            else:
+                rows, cols, vals = (np.asarray(a) for a in shard.coalesced())
+            tail_nnz = len(vals)
             if ell:
-                ev, ec, rows, cols, vals = _ell_arrays(
+                ell_fields, (rows, cols, vals) = _ell_view(
                     rows, cols, vals, shard.num_samples,
                     shard.ell_width if ell == "auto" or ell is True
                     else int(ell),
@@ -486,10 +543,8 @@ class SparseLabeledPointBatch:
                 rows, cols, vals = _pad_flat_tail(
                     rows, cols, vals, shard.flat_block_nnz
                 )
-                ell_extra = dict(
-                    ell_vals=jnp.asarray(ev), ell_cols=jnp.asarray(ec)
-                )
-            return cls(
+                extra.update(ell_fields)
+            batch = cls(
                 values=jnp.asarray(vals),
                 col_indices=jnp.asarray(cols, dtype=jnp.int32),
                 row_ids=jnp.asarray(rows, dtype=jnp.int32),
@@ -497,32 +552,11 @@ class SparseLabeledPointBatch:
                 offsets=jnp.asarray(offsets),
                 weights=jnp.asarray(weights),
                 dim=int(shard.feature_dim),
-                hot_vals=jnp.asarray(hot_vals),
-                hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
-                **ell_extra,
+                **extra,
             )
-        if ell and not column_sorted_gradient:
-            # no shard.device() here: the ELL batch is built from fresh
-            # host-side arrays, and caching the unused flat triple would pin
-            # ~12 bytes/nnz of dead HBM on exactly the giant-d path
-            rows, cols, vals = shard.coalesced()
-            ev, ec, tr, tc, tv = _ell_arrays(
-                np.asarray(rows), np.asarray(cols), np.asarray(vals),
-                shard.num_samples,
-                shard.ell_width if ell == "auto" or ell is True else int(ell),
-            )
-            tr, tc, tv = _pad_flat_tail(tr, tc, tv, shard.flat_block_nnz)
-            return cls(
-                values=jnp.asarray(tv),
-                col_indices=jnp.asarray(tc, dtype=jnp.int32),
-                row_ids=jnp.asarray(tr, dtype=jnp.int32),
-                labels=jnp.asarray(labels),
-                offsets=jnp.asarray(offsets),
-                weights=jnp.asarray(weights),
-                dim=int(shard.feature_dim),
-                ell_vals=jnp.asarray(ev),
-                ell_cols=jnp.asarray(ec),
-            )
+            if policy is not None:
+                _record_tail_layout(policy.label, batch, tail_nnz)
+            return batch
         dev = shard.device()
         extra = {}
         if column_sorted_gradient:
@@ -563,8 +597,9 @@ class SparseLabeledPointBatch:
         pad_nnz_to: round the entry count up (e.g. to a power of two) so
         repeated reads of growing data reuse compiled programs.
         ell: "auto" (default) builds the padded-row ELL view (fast TPU
-        layout; overflow entries stay in the flat tail, which pad_nnz_to
-        then applies to); False keeps pure flat COO;
+        layout) in width tiers read off the rows' counts; an int fixes ONE
+        [n, L] block; overflow entries stay in the flat tail, which
+        pad_nnz_to then applies to; False keeps pure flat COO;
         ``column_sorted_gradient`` disables ELL.
         hybrid: opt-in hybrid dense-head view (HybridPolicy/True); the
         hot head becomes a dense [n, k_hot] block and ELL/flat keep only
@@ -593,14 +628,12 @@ class SparseLabeledPointBatch:
                 hot_vals=jnp.asarray(hot_vals),
                 hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
             )
+        tail_nnz = len(vals)
         ell_extra = {}
         if ell and not column_sorted_gradient:
-            ev, ec, rows, cols, vals = _ell_arrays(
+            ell_extra, (rows, cols, vals) = _ell_view(
                 rows, cols, vals, n,
                 None if ell == "auto" or ell is True else int(ell),
-            )
-            ell_extra = dict(
-                ell_vals=jnp.asarray(ev), ell_cols=jnp.asarray(ec)
             )
         nnz = len(vals)
         target = max(pad_nnz_to or 0, nnz)
@@ -624,7 +657,7 @@ class SparseLabeledPointBatch:
             _column_sorted_arrays(rows, cols, vals, len(vals), dim=dim)
             if column_sorted_gradient else {}
         )
-        return cls(
+        batch = cls(
             values=jnp.asarray(vals),
             col_indices=jnp.asarray(cols, dtype=jnp.int32),
             row_ids=jnp.asarray(rows, dtype=jnp.int32),
@@ -636,44 +669,153 @@ class SparseLabeledPointBatch:
             **ell_extra,
             **hybrid_extra,
         )
+        if policy is not None:
+            _record_tail_layout(policy.label, batch, tail_nnz)
+        return batch
 
 
-#: ELL auto-width: the padded [n, L] may hold at most this multiple of the
-#: true nnz (skewed rows spill to the COO tail instead of inflating L)
+#: one-width ELL rule (an agreed width: the partitioned reader's and the
+#: mesh's): the padded [n, L] may hold at most this multiple of the true nnz
+#: (skewed rows spill to the COO tail instead of inflating L)
 _ELL_WASTE_CAP = 1.6
+
+#: the tier rule's prices, in slots (one slot = one gather of a coefficient
+#: and one scatter-add into the gradient an evaluation): a row of a further
+#: tier (its sum added at its row id, its dz gathered there), an overflow
+#: entry (a gather, a segment-sum, a row gather, a scatter-add), a tier's
+#: launches; and the most tiers a view may take
+_TIER_ROW_COST = 0.5
+_OVERFLOW_ENTRY_COST = 1.5
+_TIER_LAUNCH_COST = 2048.0
+_MAX_TIERS = 6
+#: candidate widths the rule weighs at most (the distinct counts, thinned
+#: evenly by rank beyond this many)
+_MAX_TIER_CANDIDATES = 256
+
+
+def _ell_tier_widths(freq: np.ndarray) -> tuple[int, ...]:
+    """The ELL view's cumulative tier widths ``(W_1 < W_2 < ...)`` from the
+    HISTOGRAM of the rows' entry counts (``freq[v]`` = rows that hold exactly
+    v entries, empty rows included) and from nothing else.
+
+    Tier 0 gives every row W_1 slots; tier k >= 1 gives the rows that hold
+    more than W_k another W_{k+1} - W_k; entries beyond the last width
+    overflow into the flat triple. The rule minimises what an evaluation
+    moves: the slots, ``_TIER_ROW_COST`` a row of every further tier,
+    ``_OVERFLOW_ENTRY_COST`` an overflow entry and ``_TIER_LAUNCH_COST`` a
+    tier, over at most ``_MAX_TIERS`` tiers. Between two counts that occur the
+    cost is linear in a width, so only counts that occur are candidates, and
+    a shortest-path recursion over them is exact; ties go to fewer tiers,
+    then to the narrower widths. Rows that all hold one count get one tier
+    of that width."""
+    freq = np.asarray(freq, dtype=np.int64)
+    n = int(freq.sum())
+    widths = np.flatnonzero(freq[1:]) + 1  # the counts that occur, ascending
+    if len(widths) == 0:
+        return (1,)
+    if len(widths) > _MAX_TIER_CANDIDATES:
+        keep = np.linspace(0, len(widths) - 1, _MAX_TIER_CANDIDATES)
+        widths = widths[np.unique(keep.astype(np.int64))]
+    # rows_from[v] = rows that hold v entries or more; entries_from[v] = the
+    # entries that stand at position v or beyond in their rows
+    rows_from = np.append(np.cumsum(freq[::-1])[::-1], 0)
+    entries_from = np.append(np.cumsum(rows_from[::-1])[::-1], 0)
+    lower = np.concatenate([[0], widths])  # where a tier starts
+    holders = np.concatenate([[n], rows_from[widths + 1]]).astype(np.float64)
+    span = widths[None, :] - lower[:, None]
+    # step[i, j]: the price of a tier from width lower[i] to widths[j]
+    step = holders[:, None] * span + _TIER_LAUNCH_COST
+    step[1:] += _TIER_ROW_COST * holders[1:, None]
+    step[span <= 0] = np.inf
+    overflow = _OVERFLOW_ENTRY_COST * entries_from[widths + 1]
+    reach = step[0]  # the cheapest t tiers that end at widths[j]
+    came_from, best = [], (np.inf, 0, 0)
+    for tiers in range(1, _MAX_TIERS + 1):
+        total = reach + overflow
+        j = int(np.argmin(total))
+        if total[j] < best[0]:
+            best = (float(total[j]), tiers, j)
+        if tiers < _MAX_TIERS:
+            via = reach[:, None] + step[1:]
+            came_from.append(np.argmin(via, axis=0))
+            reach = via.min(axis=0)
+    _, tiers, j = best
+    chosen = []
+    for t in range(tiers - 1, -1, -1):
+        chosen.append(int(widths[j]))
+        if t:
+            j = int(came_from[t - 1][j])
+    return tuple(reversed(chosen))
 
 
 def _ell_arrays(
     rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, n: int,
     width: int | None,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Split row-sorted COO triples into an ELL block + overflow tail.
+) -> tuple:
+    """Split row-sorted COO triples into the ELL view + overflow tail.
 
-    Returns (ell_vals [n, L], ell_cols [n, L], tail_rows, tail_cols,
-    tail_vals). Each row's first L entries land in the ELL block (pad value
-    0 / col 0); entries beyond L spill to the tail. width=None picks the
-    smallest L covering the 98th-percentile row while keeping n·L under
-    ``_ELL_WASTE_CAP``x nnz.
-    """
+    Returns (ell_vals [n, W_1], ell_cols [n, W_1], tiers, tail_rows,
+    tail_cols, tail_vals). Each row's first W_1 entries land in the first
+    block (pad value 0 / col 0); ``tiers`` holds, for every further width
+    W_{k+1} of the view, the (vals [W_{k+1} - W_k, n_k], cols, row_ids) of
+    entries W_k + 1 .. W_{k+1} of the rows that hold more than W_k (rows
+    along the minor axis, :class:`EllTier`; row ids ascending); entries
+    beyond the last width spill to the tail. width=None takes the widths
+    ``_ell_tier_widths`` reads off the rows' counts; an explicit width is one
+    block of that width and no further tier."""
     counts = np.bincount(rows, minlength=n) if len(rows) else np.zeros(n, np.int64)
     if width is None:
-        width = _ell_auto_width(counts, n, len(vals))
-    width = max(1, int(width))
+        widths = _ell_tier_widths(np.bincount(counts))
+    else:
+        widths = (max(1, int(width)),)
     starts = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(counts, out=starts[1:])
     pos = np.arange(len(rows)) - starts[rows] if len(rows) else np.zeros(0, np.int64)
-    in_ell = pos < width
-    ell_vals = np.zeros((n, width), dtype=vals.dtype)
-    ell_cols = np.zeros((n, width), dtype=np.int32)
-    ell_vals[rows[in_ell], pos[in_ell]] = vals[in_ell]
-    ell_cols[rows[in_ell], pos[in_ell]] = cols[in_ell]
-    tail = ~in_ell
-    return ell_vals, ell_cols, rows[tail], cols[tail], vals[tail]
+    blocks, lower = [], 0
+    for upper in widths:
+        here = (pos >= lower) & (pos < upper)
+        if lower == 0:
+            row_ids = None  # every row
+            shape, at = (n, upper), (rows[here], pos[here])
+        else:
+            holds = counts > lower
+            row_ids = np.flatnonzero(holds).astype(np.int32)
+            slot = np.cumsum(holds) - 1  # row -> its place in the tier
+            shape = (upper - lower, len(row_ids))
+            at = (pos[here] - lower, slot[rows[here]])
+        tier_vals = np.zeros(shape, dtype=vals.dtype)
+        tier_cols = np.zeros(shape, dtype=np.int32)
+        tier_vals[at] = vals[here]
+        tier_cols[at] = cols[here]
+        blocks.append((tier_vals, tier_cols, row_ids))
+        lower = upper
+    tail = pos >= lower
+    (ell_vals, ell_cols, _), tiers = blocks[0], tuple(blocks[1:])
+    return ell_vals, ell_cols, tiers, rows[tail], cols[tail], vals[tail]
+
+
+def _ell_view(
+    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, n: int,
+    width: int | None,
+) -> tuple[dict, tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """``_ell_arrays`` as a batch's fields (placed) and the overflow triple."""
+    ev, ec, tiers, tr, tc, tv = _ell_arrays(rows, cols, vals, n, width)
+    fields = dict(
+        ell_vals=jnp.asarray(ev),
+        ell_cols=jnp.asarray(ec),
+        ell_tiers=tuple(
+            EllTier(vals=jnp.asarray(v), cols=jnp.asarray(c),
+                    row_ids=jnp.asarray(r))
+            for v, c, r in tiers
+        ),
+    )
+    return fields, (tr, tc, tv)
 
 
 def _ell_auto_width(counts: np.ndarray, n: int, nnz: int) -> int:
-    """Smallest L covering the 98th-percentile row while keeping n·L under
-    ``_ELL_WASTE_CAP``x nnz (skewed rows spill to the COO tail)."""
+    """The ONE width of an agreed [n, L] block (a mesh's, the partitioned
+    reader's): smallest L covering the 98th-percentile row while keeping n·L
+    under ``_ELL_WASTE_CAP``x nnz (skewed rows spill to the COO tail)."""
     if nnz == 0:
         return 1
     q = int(np.quantile(counts, 0.98)) if n else 1
@@ -802,10 +944,6 @@ def _select_hot_columns(
     return hot, 0
 
 
-def _overflow_beyond(counts: np.ndarray, width: int) -> int:
-    return int(np.maximum(counts - width, 0).sum())
-
-
 def _hybrid_arrays(
     rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     n: int, dim: int, policy: HybridPolicy, *, pad: bool = True,
@@ -822,8 +960,9 @@ def _hybrid_arrays(
     id into the last hot column's block and inflate its width). The tail
     triple preserves row-major order (it is a filtered subsequence of the
     sorted input), so the downstream ELL/segment-sum sorted promises
-    survive. Records layout-decision gauges under
-    ``layout/<policy.label>/*`` (telemetry/layout.py).
+    survive. Records the head's gauges under ``layout/<policy.label>/*``
+    (telemetry/layout.py); the tail's are set from the arrays a batch
+    builder makes of the triple (``_record_tail_layout``).
     """
     from photon_ml_tpu.telemetry.layout import record_hybrid_layout
 
@@ -852,15 +991,6 @@ def _hybrid_arrays(
     tail = ~is_hot
     tr, tc, tv = rows[tail], cols[tail], vals[tail]
 
-    itemsize = vals.dtype.itemsize
-    tail_counts = (
-        np.bincount(tr, minlength=n) if len(tr) else np.zeros(n, np.int64)
-    )
-    tail_width = _ell_auto_width(tail_counts, n, len(tv))
-    full_counts = (
-        np.bincount(rows, minlength=n) if nnz else np.zeros(n, np.int64)
-    )
-    full_width = _ell_auto_width(full_counts, n, nnz)
     record_hybrid_layout(
         policy.label,
         k_hot=k,
@@ -868,18 +998,37 @@ def _hybrid_arrays(
         hot_coverage=covered / nnz if nnz else 0.0,
         hot_nnz=covered,
         tail_nnz=len(tv),
-        tail_width=tail_width,
-        hybrid_bytes=(
-            n * k_pad * itemsize
-            + n * tail_width * (itemsize + 4)
-            + _overflow_beyond(tail_counts, tail_width) * (itemsize + 8)
-        ),
-        ell_bytes=(
-            n * full_width * (itemsize + 4)
-            + _overflow_beyond(full_counts, full_width) * (itemsize + 8)
-        ),
     )
     return hot_block, hot_ids, tr, tc, tv
+
+
+def _record_tail_layout(
+    label: str, batch: SparseLabeledPointBatch, tail_nnz: int
+) -> None:
+    """The gauges of a hybrid batch's cold tail, read off the arrays that
+    were BUILT (telemetry/layout.py ``record_tail_layout``): the slots an
+    evaluation gathers and scatters (every ELL tier's, pads included, and the
+    flat triple's), the tiers' count, the view's last width, the share of the
+    slots that holds no entry, and the batch's bytes, head included."""
+    from photon_ml_tpu.telemetry.layout import record_tail_layout
+
+    widths = [batch.ell_vals.shape[1]] if batch.has_ell_view else []
+    widths += [tier.width for tier in batch.ell_tiers]
+    held = jax.tree_util.tree_leaves((
+        batch.ell_vals, batch.ell_cols, batch.ell_tiers, batch.values,
+        batch.col_indices, batch.row_ids, batch.hot_vals,
+    ))
+    slots = batch.nnz + sum(int(v.size) for v in jax.tree_util.tree_leaves(
+        (batch.ell_vals, [tier.vals for tier in batch.ell_tiers])
+    ))
+    record_tail_layout(
+        label,
+        tail_slots=slots,
+        tail_tiers=len(widths),
+        tail_width=sum(widths),
+        tail_pad_share=1.0 - tail_nnz / slots if slots else 0.0,
+        hybrid_bytes=sum(int(a.size) * a.dtype.itemsize for a in held),
+    )
 
 
 def _column_sorted_arrays(
@@ -931,13 +1080,14 @@ def sparse_product(batch: SparseLabeledPointBatch, coefficients: Array) -> Array
     """``X @ w`` without materializing X and without the offsets: what a
     model scores a sparse block with (models/coefficients.py).
 
-    ELL view: one gather + a DENSE row-sum over [n, L] — no per-row
+    ELL view: one gather + a DENSE row-sum a width tier — no per-row
     segment-sum at all (the segment-sum alone costs ~7.5 ns/entry on v5e,
-    BASELINE.md r3); only overflow-tail entries, if any, take the
-    segment-sum path. Flat COO: gather w at the nonzero columns, multiply,
-    segment-sum by row. Either way autodiff produces the transpose
-    scatter-add gradient (the reference's hand-written sparse axpy,
-    ValueAndGradientAggregator.scala).
+    BASELINE.md r3); a further tier's [n_k] sums are added into [n] at its
+    row ids (ascending and unique: no sort, no conflict); only overflow-tail
+    entries, if any, take the segment-sum path. Flat COO: gather w at the
+    nonzero columns, multiply, segment-sum by row. Either way autodiff
+    produces the transpose scatter-add gradient (the reference's
+    hand-written sparse axpy, ValueAndGradientAggregator.scala).
 
     Hybrid view: the hot head adds ONE dense matmul over the [n, k_hot]
     block against a k_hot-sized coefficient gather (scope ``sparse/head``)
@@ -955,8 +1105,13 @@ def sparse_product(batch: SparseLabeledPointBatch, coefficients: Array) -> Array
             ell = jnp.einsum(
                 "nl,nl->n", batch.ell_vals, coefficients[batch.ell_cols]
             )
+            for tier in batch.ell_tiers:
+                ell = ell.at[tier.row_ids].add(
+                    jnp.einsum("ln,ln->n", tier.vals, coefficients[tier.cols]),
+                    indices_are_sorted=True, unique_indices=True,
+                )
             base = ell if base is None else base + ell
-        # the flat triple: whatever overflowed the ELL block, or (no other
+        # the flat triple: whatever overflowed the ELL view, or (no other
         # view) every entry, none included
         if batch.values.shape[0] or base is None:
             flat = jax.ops.segment_sum(
@@ -974,28 +1129,45 @@ def sparse_margins(batch: SparseLabeledPointBatch, coefficients: Array) -> Array
     return sparse_product(batch, coefficients) + batch.offsets
 
 
+def tail_transpose_add(
+    out: Array, batch: SparseLabeledPointBatch, row_terms: Array,
+    square_values: bool = False,
+) -> Array:
+    """``out`` [dim] plus Σ_i row_terms_i · x_ij (or x_ij²) over the entries
+    of the ELL view and the flat triple: the transpose scatter-adds of
+    :func:`sparse_product`'s gathers. The row term broadcasts over a tier's
+    width (no per-entry row gather; a further tier gathers it once a row, at
+    its row ids); pad slots (value 0, col 0) add 0 to column 0."""
+    def values(v):
+        return v * v if square_values else v
+
+    if batch.has_ell_view:
+        contrib = row_terms[:, None] * values(batch.ell_vals)
+        out = out.at[batch.ell_cols.ravel()].add(contrib.ravel())
+        for tier in batch.ell_tiers:
+            contrib = row_terms[tier.row_ids][None, :] * values(tier.vals)
+            out = out.at[tier.cols.ravel()].add(contrib.ravel())
+    if batch.values.shape[0]:
+        out = out.at[batch.col_indices].add(
+            row_terms[batch.row_ids] * values(batch.values)
+        )
+    return out
+
+
 def sparse_column_sum(
     batch: SparseLabeledPointBatch, row_weights: Array,
     square_values: bool = False,
 ) -> Array:
     """Σ_i row_weights_i · x_ij (or x_ij²) per column j — the building block
-    of Hessian diagonals (row_weights = w_i·l''_i). ELL's pad slots (value
-    0, col 0) contribute 0 to every column either way; the hybrid head
+    of Hessian diagonals (row_weights = w_i·l''_i). The ELL tiers and the
+    flat triple go through :func:`tail_transpose_add`; the hybrid head
     reduces over rows as one dense matvec, then scatters just k_hot
     per-column sums (its zero pad columns are inert too)."""
     out = jnp.zeros((batch.dim,), dtype=batch.values.dtype)
     if batch.has_hybrid_view:
         hv = batch.hot_vals * batch.hot_vals if square_values else batch.hot_vals
         out = out.at[batch.hot_col_ids].add(row_weights @ hv)
-    if batch.has_ell_view:
-        ev = batch.ell_vals * batch.ell_vals if square_values else batch.ell_vals
-        contrib = row_weights[:, None] * ev  # dz broadcasts — no row gather
-        out = out.at[batch.ell_cols.ravel()].add(contrib.ravel())
-    if batch.values.shape[0]:
-        v = batch.values * batch.values if square_values else batch.values
-        contrib = row_weights[batch.row_ids] * v
-        out = out.at[batch.col_indices].add(contrib)
-    return out
+    return tail_transpose_add(out, batch, row_weights, square_values)
 
 
 def summarize_sparse(

@@ -31,6 +31,7 @@ from photon_ml_tpu.data.sparse_batch import (
     sparse_column_sum,
     sparse_margins,
     sparse_product,
+    tail_transpose_add,
 )
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import (
@@ -155,20 +156,13 @@ class SparseGLMObjective:
     def _tail_gradient_update(
         self, g_eff: Array, dzw: Array, batch: SparseLabeledPointBatch
     ) -> Array:
-        """Scatter the cold-tail contributions (ELL block + flat overflow)
-        into the effective gradient — the same transpose scatters autodiff
-        derives for the ELL path, written out so the hybrid value+gradient
-        shares ONE dz evaluation across head and tail (the r4 dense-kernel
-        single-pass discipline)."""
+        """Scatter the cold-tail contributions (the ELL view's tiers + flat
+        overflow) into the effective gradient — the same transpose scatters
+        autodiff derives for the ELL path (``tail_transpose_add``), written
+        out so the hybrid value+gradient shares ONE dz evaluation across
+        head and tail (the r4 dense-kernel single-pass discipline)."""
         with jax.named_scope("sparse/tail_gradient"):
-            if batch.has_ell_view:
-                contrib = dzw[:, None] * batch.ell_vals
-                g_eff = g_eff.at[batch.ell_cols.ravel()].add(contrib.ravel())
-            if batch.values.shape[0]:
-                g_eff = g_eff.at[batch.col_indices].add(
-                    dzw[batch.row_ids] * batch.values
-                )
-        return g_eff
+            return tail_transpose_add(g_eff, batch, dzw)
 
     def _head_gradient(
         self, row_terms: Array, batch: SparseLabeledPointBatch

@@ -227,10 +227,13 @@ def _data_pytree(dataset: GameDataset, re_specs: Sequence[RandomEffectStepSpec],
         # flat-COO FE batch: offsets filled per step (residual scores);
         # the static `dim` rides the pytree treedef, so sparse-vs-dense is
         # a compile-time branch in the step
+        # ONE [n, L] block (the agreed width, else the one-width auto rule):
+        # shard_inputs lays it over "data" as it lays a dense feature block
         labels = jnp.asarray(dataset.labels)
+        shard = dataset.feature_shards[fe_shard]
         data["fe_sparse_batch"] = SparseLabeledPointBatch.from_shard(
-            dataset.feature_shards[fe_shard], labels,
-            jnp.zeros_like(labels), jnp.asarray(dataset.weights),
+            shard, labels, jnp.zeros_like(labels),
+            jnp.asarray(dataset.weights), ell=shard.one_ell_width(),
         )
     return data
 
@@ -705,6 +708,7 @@ class GameTrainProgram:
                 weights=put(sb.weights, vec),
             )
             if sb.has_ell_view:
+                _refuse_ell_tiers(sb)
                 # [n, L] rides the sample axis like a dense feature block
                 sb = sb.replace(
                     ell_vals=put(sb.ell_vals, NamedSharding(mesh, P("data", None))),
@@ -2536,6 +2540,19 @@ def _partitioned_guards(program: GameTrainProgram, prepared: dict) -> None:
             )
 
 
+def _refuse_ell_tiers(sb) -> None:
+    """A mesh lays the ELL view out as ONE [n, L] block over "data"; a batch
+    whose view is in width tiers would lose them here, so it is refused."""
+    if sb.ell_tiers:
+        raise ValueError(
+            f"the sparse FE batch's ELL view has {1 + len(sb.ell_tiers)} "
+            "width tiers; a mesh takes one agreed width: build it with "
+            "from_shard(..., ell=shard.one_ell_width()) (prepare_inputs "
+            "does), or read through io/partitioned_reader.read_partitioned, "
+            "which sets SparseShard.ell_width"
+        )
+
+
 def _assemble_sparse_fe(prepared: dict, ranks, mesh: Mesh,
                         num_ranks: int, put) -> "SparseLabeledPointBatch":
     """Assemble per-rank local sparse-FE batches into ONE mesh-sharded
@@ -2566,6 +2583,7 @@ def _assemble_sparse_fe(prepared: dict, ranks, mesh: Mesh,
                 "partitioned sparse training rides the fixed-width ELL "
                 "layout (read through read_partitioned)"
             )
+        _refuse_ell_tiers(sb)
         if sb.dim != first.dim or (
             sb.ell_vals.shape != first.ell_vals.shape
         ) or sb.nnz != first.nnz:

@@ -18,6 +18,7 @@ from photon_ml_tpu.telemetry.journal import (
 from photon_ml_tpu.telemetry.layout import (
     LAYOUT_METRIC_PREFIX,
     record_hybrid_layout,
+    record_tail_layout,
     reset_layout_metrics,
 )
 from photon_ml_tpu.telemetry.probes import (
@@ -84,6 +85,7 @@ __all__ = [
     "read_journal",
     "LAYOUT_METRIC_PREFIX",
     "record_hybrid_layout",
+    "record_tail_layout",
     "reset_layout_metrics",
     "CompileMonitor",
     "compile_count",

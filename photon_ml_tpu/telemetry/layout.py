@@ -4,7 +4,7 @@ Reference parity: no reference analogue — the Spark reference never chooses
 a device layout (its sparse vectors stay Breeze CSR end to end); this is
 TPU-first observability for the hybrid dense-head / sparse-tail builder
 (data/sparse_batch.py, ISSUE 5). The hot-coverage fraction, head width
-k_hot, residual tail width L, and hybrid-vs-ELL byte estimate are exactly
+k_hot, and the cold tail's slots, width tiers and padding share are exactly
 the quantities that decide whether the layout wins (the expected win is
 index-op removal proportional to hot coverage, BASELINE.md r6), so they are
 recorded as registry gauges the run journal persists on success AND failure
@@ -40,18 +40,12 @@ def record_hybrid_layout(
     hot_coverage: float,
     hot_nnz: int,
     tail_nnz: int,
-    tail_width: int,
-    hybrid_bytes: int,
-    ell_bytes: int,
     registry=None,
 ) -> None:
-    """One hybrid build's layout decision, as gauges under
-    ``layout/<label>/*`` plus a ``layout/<label>/builds`` counter.
-
-    ``hybrid_bytes``/``ell_bytes`` are the builder's device-footprint
-    estimates for the chosen hybrid layout vs the counterfactual plain-ELL
-    layout of the same entries (auto width for both).
-    """
+    """One hybrid split's decision (``data/sparse_batch._hybrid_arrays``),
+    as gauges under ``layout/<label>/*`` plus a ``layout/<label>/builds``
+    counter: the head's columns and coverage and the entries left to the
+    cold tail (``tail_nnz``: entries, padding apart)."""
     from photon_ml_tpu.telemetry.registry import default_registry
 
     reg = registry or default_registry()
@@ -63,9 +57,35 @@ def record_hybrid_layout(
         ("hot_coverage", hot_coverage),
         ("hot_nnz", hot_nnz),
         ("tail_nnz", tail_nnz),
+    ))
+
+
+def record_tail_layout(
+    label: str,
+    *,
+    tail_slots: int,
+    tail_tiers: int,
+    tail_width: int,
+    tail_pad_share: float,
+    hybrid_bytes: int,
+    registry=None,
+) -> None:
+    """What a hybrid batch's builder made of the cold tail, counted on the
+    arrays that were BUILT: ``tail_slots`` = the slots an evaluation gathers
+    and scatters (every ELL tier's, pads included, plus the flat overflow's
+    length), ``tail_tiers`` = the ELL view's width tiers (0: a flat tail),
+    ``tail_width`` = the view's last width, ``tail_pad_share`` = the share of
+    the slots that holds no entry, ``hybrid_bytes`` = the bytes of the head
+    and the tail's arrays together."""
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    reg = registry or default_registry()
+    _set_gauges(reg, f"{LAYOUT_METRIC_PREFIX}{label}", (
+        ("tail_slots", tail_slots),
+        ("tail_tiers", tail_tiers),
         ("tail_width", tail_width),
+        ("tail_pad_share", tail_pad_share),
         ("hybrid_bytes", hybrid_bytes),
-        ("ell_bytes", ell_bytes),
     ))
 
 

@@ -288,19 +288,25 @@ def test_composed_overflow_layout_bitwise_training_close(tmp_path):
     mesh = make_hybrid_mesh(data=4, model=2)
     full, ref = _full_read_reference(path, configs, mesh=mesh)
 
+    # the full read's batch as a mesh takes it (prepare_inputs): ONE [n, L]
+    # block at the one-width auto rule on the rows' counts, the rule the
+    # ranks agree on (a one-process "auto" batch is tiered: another layout)
     full_shard = full.dataset.feature_shards["global"]
     full_batch = SparseLabeledPointBatch.from_shard(
         full_shard,
         np.asarray(full.dataset.host_array("labels")),
         np.asarray(full.dataset.host_array("offsets")),
         np.asarray(full.dataset.host_array("weights")),
+        ell=full_shard.one_ell_width(),
     )
     assert full_batch.nnz > 0  # the fixture really overflows
+    assert not full_batch.ell_tiers
 
     parts, exchanges, errors = _read_ranks(path, configs)
     assert not errors, errors
     shards = [p.result.dataset.feature_shards["global"] for p in parts]
-    # agreed width == the width the full read's auto rule picked
+    # agreed width == the width the one-width auto rule picks on the full
+    # read's counts
     assert shards[0].ell_width == full_batch.ell_vals.shape[1]
     assert shards[0].ell_width == shards[1].ell_width
     assert shards[0].flat_block_nnz == shards[1].flat_block_nnz > 0
@@ -367,12 +373,17 @@ def test_composed_width_mirrors_mesh_padding_on_non_multiple_n(tmp_path):
     assert n % data_axis != 0
 
     def batch_width(ds):
+        # the mesh's rule (one width from the counts), as prepare_inputs
+        # applies it to the padded data set
+        shard = ds.feature_shards["global"]
         b = SparseLabeledPointBatch.from_shard(
-            ds.feature_shards["global"],
+            shard,
             np.asarray(ds.host_array("labels")),
             np.asarray(ds.host_array("offsets")),
             np.asarray(ds.host_array("weights")),
+            ell=shard.one_ell_width(),
         )
+        assert not b.ell_tiers
         return b.ell_vals.shape[1]
 
     padded, _ = pad_game_dataset_to(

@@ -128,6 +128,64 @@ class TestSparseBatch:
                     rtol=1e-12,
                 )
 
+    @pytest.mark.parametrize("task", [
+        TaskType.LOGISTIC_REGRESSION, TaskType.POISSON_REGRESSION,
+    ])
+    def test_tiered_ell_view_matches_dense(self, task):
+        """An auto-built ELL view over rows of 1 to 60 entries (and a few of
+        200) is a list of width tiers; margins, value and gradient (autodiff
+        through the tiers' gathers and the adds at their row ids), the
+        Hessian-vector product and the Hessian diagonal match the DENSE
+        matrix of the same entries."""
+        rng = np.random.default_rng(17)
+        n, d = 3000, 256
+        counts = 1 + (rng.random(n) ** 2 * 60).astype(np.int64)
+        counts[:8] = 200
+        rows = np.repeat(np.arange(n), counts)
+        cols = np.concatenate(
+            [rng.choice(d, size=c, replace=False) for c in counts]
+        )
+        vals = rng.normal(size=len(rows))
+        labels = (rng.random(n) < 0.5).astype(np.float64)
+        x = np.zeros((n, d))
+        x[rows, cols] = vals
+        dense = LabeledPointBatch(
+            features=jnp.asarray(x), labels=jnp.asarray(labels),
+            offsets=jnp.zeros(n), weights=jnp.ones(n),
+        )
+        tiered = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=d, dtype=np.float64
+        )
+        assert len(tiered.ell_tiers) >= 2 and tiered.nnz > 0
+        slots = tiered.ell_vals.size + sum(t.vals.size for t in tiered.ell_tiers)
+        one_block = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=d, dtype=np.float64,
+            ell=int(counts.max()),
+        )
+        assert slots < one_block.ell_vals.size  # the slots follow the counts
+        loss = loss_for_task(task)
+        so = SparseGLMObjective(loss, l2_weight=0.2)
+        do = GLMObjective(loss, l2_weight=0.2)
+        w = jnp.asarray(rng.normal(scale=0.05, size=d))
+        v = jnp.asarray(rng.normal(size=d))
+        np.testing.assert_allclose(
+            np.asarray(sparse_margins(tiered, w)), x @ np.asarray(w),
+            rtol=1e-10, atol=1e-12,
+        )
+        sv, sg = so.value_and_gradient(w, tiered)
+        dv, dg = do.value_and_gradient(w, dense)
+        np.testing.assert_allclose(float(sv), float(dv), rtol=1e-10)
+        np.testing.assert_allclose(np.asarray(sg), np.asarray(dg),
+                                   rtol=1e-8, atol=1e-10)
+        np.testing.assert_allclose(
+            np.asarray(so.hessian_vector(w, v, tiered)),
+            np.asarray(do.hessian_vector(w, v, dense)), rtol=1e-8, atol=1e-10,
+        )
+        np.testing.assert_allclose(
+            np.asarray(so.hessian_diagonal(w, tiered)),
+            np.asarray(do.hessian_diagonal(w, dense)), rtol=1e-8, atol=1e-10,
+        )
+
     def test_out_of_range_indices_rejected(self):
         with pytest.raises(ValueError, match="dim"):
             SparseLabeledPointBatch.from_coo(

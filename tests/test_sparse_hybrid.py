@@ -12,6 +12,7 @@ the CLI grammar + partitioned-io guard behave.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -462,7 +463,451 @@ class TestColumnShardedHybrid:
         )
 
 
+def _tiered_coo(n=4000, d=300, seed=0):
+    """Rows that hold 1 to 60 entries (skewed low) and a few of 150 to 200:
+    counts spread enough that the width rule takes several tiers, with an
+    overflow beyond the last. Unique (row, col) pairs, row-major."""
+    rng = np.random.default_rng(seed)
+    counts = 1 + (rng.random(n) ** 2 * 60).astype(np.int64)
+    counts[rng.choice(n, size=12, replace=False)] = rng.integers(150, 201, 12)
+    rows = np.repeat(np.arange(n), counts)
+    cols = np.concatenate(
+        [np.sort(rng.choice(d, size=c, replace=False)) for c in counts]
+    )
+    # a column law with a hot head, so a hybrid policy has something to take
+    cols = (cols.astype(np.float64) ** 2 / d).astype(np.int64)
+    keep = np.ones(len(rows), bool)
+    keep[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1])
+    rows, cols = rows[keep], cols[keep]
+    vals = rng.normal(size=len(rows))
+    labels = (rng.random(n) < 0.5).astype(np.float64)
+    offsets = rng.normal(scale=0.1, size=n)
+    weights = rng.uniform(0.5, 2.0, size=n)
+    return rows, cols, vals, labels, offsets, weights
+
+
+@functools.lru_cache(maxsize=None)
+def _tiered_views():
+    """flat / tiered ELL / tiered hybrid views of ``_tiered_coo``, built once."""
+    rows, cols, vals, labels, offsets, weights = _tiered_coo()
+    build = lambda **kw: SparseLabeledPointBatch.from_coo(
+        rows, cols, vals, labels, dim=300, offsets=offsets,
+        weights=weights, dtype=np.float64, **kw
+    )
+    return dict(
+        flat=build(ell=False),
+        ell=build(),
+        hybrid=build(hybrid=HybridPolicy(hot_cols=8, pad_multiple=8,
+                                         label="t_tiers")),
+    )
+
+
+def _tiered_norm(kind, d=300):
+    rng = np.random.default_rng(77)
+    factors = jnp.asarray(rng.uniform(0.5, 2.0, size=d))
+    shifts = jnp.asarray(rng.normal(scale=0.2, size=d))
+    return {
+        "plain": None,
+        "factors": NormalizationContext(factors=factors, shifts=None),
+        "factors_shifts": NormalizationContext(factors=factors, shifts=shifts),
+    }[kind]
+
+
+def _view_entries(batch):
+    """Every (row, col, value) a batch's ELL tiers and flat triple hold,
+    pad slots (value 0) apart, row-major sorted."""
+    blocks = [(np.arange(batch.num_samples), batch.ell_vals, batch.ell_cols)]
+    # a further tier lies rows-minor: [width, n_k]
+    blocks += [(np.asarray(t.row_ids), t.vals.T, t.cols.T)
+               for t in batch.ell_tiers]
+    r, c, v = [], [], []
+    for row_ids, vals, cols in blocks:
+        vals, cols = np.asarray(vals), np.asarray(cols)
+        real = vals != 0.0
+        r.append(np.broadcast_to(row_ids[:, None], vals.shape)[real])
+        c.append(cols[real])
+        v.append(vals[real])
+    real = np.asarray(batch.values) != 0.0
+    r.append(np.asarray(batch.row_ids)[real])
+    c.append(np.asarray(batch.col_indices)[real])
+    v.append(np.asarray(batch.values)[real])
+    r, c, v = (np.concatenate(x) for x in (r, c, v))
+    order = np.lexsort((c, r))
+    return r[order], c[order], v[order]
+
+
+class TestEllTiers:
+    """The auto-built ELL view is a short list of width tiers read off the
+    rows' counts (ISSUE 44): same entries, same sums, fewer slots."""
+
+    def test_fixture_is_tiered_with_an_overflow(self):
+        views = _tiered_views()
+        for name in ("ell", "hybrid"):
+            batch = views[name]
+            assert len(batch.ell_tiers) >= 2, name
+            assert batch.nnz > 0, name  # entries beyond the last width
+            assert batch.ell_vals.shape[0] == batch.num_samples
+
+    @pytest.mark.parametrize("view", ["ell", "hybrid"])
+    @pytest.mark.parametrize("norm", ["plain", "factors", "factors_shifts"])
+    @pytest.mark.parametrize("quantity", [
+        "value_and_gradient", "hessian_vector", "hessian_diagonal",
+    ])
+    def test_objective_agrees_with_flat_coo(self, view, norm, quantity):
+        views = _tiered_views()
+        so = SparseGLMObjective(
+            loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=0.3,
+            normalization=_tiered_norm(norm),
+        )
+        rng = np.random.default_rng(5)
+        w = jnp.asarray(rng.normal(scale=0.1, size=300))
+        v = jnp.asarray(rng.normal(size=300))
+
+        def read(batch):
+            if quantity == "value_and_gradient":
+                # the flat view takes the autodiff path
+                val, grad = so.value_and_gradient(w, batch)
+                return np.concatenate([np.asarray(val)[None], np.asarray(grad)])
+            if quantity == "hessian_vector":
+                return np.asarray(so.hessian_vector(w, v, batch))
+            return np.asarray(so.hessian_diagonal(w, batch))
+
+        np.testing.assert_allclose(
+            read(views[view]), read(views["flat"]), rtol=1e-9, atol=1e-11
+        )
+
+    @pytest.mark.parametrize("view", ["ell", "hybrid"])
+    @pytest.mark.parametrize("square", [False, True])
+    def test_product_and_column_sums_agree_with_flat_coo(self, view, square):
+        from photon_ml_tpu.data.sparse_batch import sparse_product
+
+        views = _tiered_views()
+        rng = np.random.default_rng(6)
+        w = jnp.asarray(rng.normal(size=300))
+        rw = jnp.asarray(rng.uniform(0.5, 2.0, size=views["flat"].num_samples))
+        np.testing.assert_allclose(
+            np.asarray(sparse_product(views[view], w)),
+            np.asarray(sparse_product(views["flat"], w)),
+            rtol=1e-11, atol=1e-12,
+        )
+        np.testing.assert_allclose(
+            np.asarray(sparse_column_sum(views[view], rw, square)),
+            np.asarray(sparse_column_sum(views["flat"], rw, square)),
+            rtol=1e-10, atol=1e-12,
+        )
+
+    def test_float32_agrees_to_rounding(self):
+        """The cell's precision: float32 values and sums; only the order of
+        the additions differs from the flat path."""
+        rows, cols, vals, labels, offsets, weights = _tiered_coo(seed=3)
+        build = lambda **kw: SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=300, offsets=offsets,
+            weights=weights, dtype=np.float32, **kw
+        )
+        flat = build(ell=False)
+        tiered = build(hybrid=HybridPolicy(hot_cols=8, pad_multiple=8))
+        assert tiered.ell_tiers and tiered.ell_vals.dtype == jnp.float32
+        so = SparseGLMObjective(
+            loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=0.3
+        )
+        w = jnp.asarray(
+            np.random.default_rng(8).normal(scale=0.1, size=300), jnp.float32
+        )
+        want_v, want_g = so.value_and_gradient(w, flat)
+        got_v, got_g = so.value_and_gradient(w, tiered)
+        assert got_g.dtype == jnp.float32
+        np.testing.assert_allclose(float(got_v), float(want_v), rtol=2e-6)
+        scale = float(jnp.abs(want_g).max())
+        np.testing.assert_allclose(
+            np.asarray(got_g), np.asarray(want_g), atol=2e-5 * scale
+        )
+
+    @pytest.mark.parametrize("view", ["ell", "hybrid"])
+    def test_every_entry_stands_in_exactly_one_slot(self, view):
+        from photon_ml_tpu.data.sparse_batch import coalesce_coo
+
+        rows, cols, vals, *_ = _tiered_coo()
+        batch = _tiered_views()[view]
+        if view == "hybrid":
+            # the head took its columns: the tail is what is left
+            cold = ~np.isin(cols, np.asarray(batch.hot_col_ids))
+            rows, cols, vals = rows[cold], cols[cold], vals[cold]
+        want = coalesce_coo(rows, cols, vals)
+        got = _view_entries(batch)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        # and the slots are counted: pads are what is not an entry
+        slots = batch.ell_vals.size + sum(t.vals.size for t in batch.ell_tiers)
+        assert slots + batch.nnz >= len(want[0])
+
+    @pytest.mark.parametrize("view", ["ell", "hybrid"])
+    def test_tier_row_ids_ascend_and_nest(self, view):
+        batch = _tiered_views()[view]
+        holders = np.arange(batch.num_samples)
+        for tier in batch.ell_tiers:
+            ids = np.asarray(tier.row_ids)
+            assert ids.dtype == np.int32
+            assert (np.diff(ids) > 0).all()  # ascending and unique
+            assert np.isin(ids, holders).all()  # rows of the tier before
+            # rows along the minor axis
+            assert tier.vals.shape == tier.cols.shape == (tier.width, len(ids))
+            # a row is in a tier because it has an entry there
+            assert (np.asarray(tier.vals)[0] != 0.0).all()
+            holders = ids
+
+    def test_uniform_counts_build_one_tier_equal_to_the_explicit_width(self):
+        rng = np.random.default_rng(11)
+        n, d, width = 50, 64, 7
+        rows = np.repeat(np.arange(n), width)
+        cols = np.concatenate(
+            [np.sort(rng.choice(d, size=width, replace=False)) for _ in range(n)]
+        )
+        vals = rng.normal(size=n * width)
+        labels = np.zeros(n)
+        auto = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=d, dtype=np.float64
+        )
+        fixed = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=d, dtype=np.float64, ell=width
+        )
+        assert auto.ell_tiers == () and auto.ell_vals.shape == (n, width)
+        assert auto.nnz == 0
+        for a, b in zip(jax.tree_util.tree_leaves(auto),
+                        jax.tree_util.tree_leaves(fixed)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("how", ["ell_int", "shard_ell_width",
+                                     "shard_ell_int"])
+    def test_an_explicit_width_is_one_block_and_nothing_else(self, how):
+        rows, cols, vals, labels, offsets, weights = _tiered_coo()
+        n, width = len(labels), 9
+        if how == "ell_int":
+            batch = SparseLabeledPointBatch.from_coo(
+                rows, cols, vals, labels, dim=300, dtype=np.float64, ell=width
+            )
+        else:
+            shard = SparseShard(
+                rows=rows, cols=cols, vals=vals, num_samples=n,
+                feature_dim=300,
+                ell_width=width if how == "shard_ell_width" else None,
+            )
+            batch = SparseLabeledPointBatch.from_shard(
+                shard, labels, offsets, weights,
+                ell="auto" if how == "shard_ell_width" else width,
+            )
+        assert batch.ell_tiers == ()
+        assert batch.ell_vals.shape == batch.ell_cols.shape == (n, width)
+        counts = np.bincount(rows, minlength=n)
+        assert batch.nnz == int(np.maximum(counts - width, 0).sum())
+
+    def test_one_ell_width_is_the_agreed_width_or_the_one_width_rule(self):
+        from photon_ml_tpu.data.sparse_batch import _ell_auto_width
+
+        rows, cols, vals, labels, *_ = _tiered_coo()
+        n = len(labels)
+        shard = SparseShard(rows=rows, cols=cols, vals=vals, num_samples=n,
+                            feature_dim=300)
+        counts = np.bincount(rows, minlength=n)
+        assert shard.one_ell_width() == _ell_auto_width(counts, n, len(rows))
+        agreed = dataclasses.replace(shard, ell_width=5)
+        assert agreed.one_ell_width() == 5
+        # under a hybrid policy the rule reads the COLD tail's counts
+        policy = HybridPolicy(hot_cols=8, pad_multiple=8)
+        hyb = dataclasses.replace(shard, hybrid_policy=policy)
+        tail_rows = hyb.hybrid_split(policy)[2]
+        assert hyb.one_ell_width() == _ell_auto_width(
+            np.bincount(tail_rows, minlength=n), n, len(tail_rows)
+        )
+
+
+def _tier_cost(freq, widths):
+    """What ``_ell_tier_widths`` minimises, counted the slow way."""
+    from photon_ml_tpu.data import sparse_batch as sb
+
+    counts = np.repeat(np.arange(len(freq)), freq)
+    cost, lower = 0.0, 0
+    for upper in widths:
+        holders = len(counts) if lower == 0 else int((counts > lower).sum())
+        cost += holders * (upper - lower) + sb._TIER_LAUNCH_COST
+        if lower:
+            cost += sb._TIER_ROW_COST * holders
+        lower = upper
+    return cost + sb._OVERFLOW_ENTRY_COST * np.maximum(counts - lower, 0).sum()
+
+
+class TestTierWidthRule:
+    def test_same_histogram_same_widths(self):
+        from photon_ml_tpu.data.sparse_batch import _ell_tier_widths
+
+        rows, cols, vals, labels, *_ = _tiered_coo()
+        n = len(labels)
+        counts = np.bincount(rows, minlength=n)
+        widths = _ell_tier_widths(np.bincount(counts))
+        assert widths == _ell_tier_widths(np.bincount(counts))
+        # another order of the same rows is the same histogram
+        shuffled = np.random.default_rng(0).permutation(counts)
+        assert widths == _ell_tier_widths(np.bincount(shuffled))
+        # and the builder takes exactly these widths
+        batch = _tiered_views()["ell"]
+        built = np.cumsum(
+            [batch.ell_vals.shape[1]] + [t.width for t in batch.ell_tiers]
+        )
+        assert tuple(built) == widths
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_widths_are_the_cheapest_over_every_choice(self, seed, monkeypatch):
+        """Exhaustive over a small histogram: no choice of up to three
+        widths among 1..max costs less than the rule's."""
+        import itertools
+
+        from photon_ml_tpu.data import sparse_batch as sb
+
+        monkeypatch.setattr(sb, "_MAX_TIERS", 3)
+        monkeypatch.setattr(sb, "_TIER_LAUNCH_COST", 5.0)
+        rng = np.random.default_rng(seed)
+        freq = rng.integers(0, 30, size=11)  # counts 0..10
+        freq[-1] = max(freq[-1], 1)
+        widths = sb._ell_tier_widths(freq)
+        assert list(widths) == sorted(set(widths)) and 1 <= len(widths) <= 3
+        cheapest = min(
+            _tier_cost(freq, choice)
+            for k in (1, 2, 3)
+            for choice in itertools.combinations(range(1, 11), k)
+        )
+        assert _tier_cost(freq, widths) == pytest.approx(cheapest)
+
+    @pytest.mark.parametrize("freq,want", [
+        ([0, 0, 0, 9], (3,)),          # every row holds three entries
+        ([4], (1,)),                   # no entry at all
+        ([], (1,)),                    # no row
+        ([2, 0, 0, 0, 0, 5], (5,)),    # empty rows beside one count
+    ])
+    def test_degenerate_histograms_take_one_tier(self, freq, want):
+        from photon_ml_tpu.data.sparse_batch import _ell_tier_widths
+
+        assert _ell_tier_widths(np.asarray(freq, np.int64)) == want
+
+    def test_many_distinct_counts_stay_within_the_tier_cap(self):
+        from photon_ml_tpu.data import sparse_batch as sb
+
+        rng = np.random.default_rng(2)
+        counts = rng.integers(1, 3000, size=20000)
+        widths = sb._ell_tier_widths(np.bincount(counts))
+        assert 1 <= len(widths) <= sb._MAX_TIERS
+        assert list(widths) == sorted(set(widths))
+        assert widths[-1] <= counts.max()
+
+
+class TestMeshKeepsOneWidth:
+    def _tiered_shard_dataset(self):
+        from photon_ml_tpu.data.game_data import build_game_dataset
+
+        rows, cols, vals, labels, *_ = _tiered_coo(n=2048)
+        shard = SparseShard(rows=rows, cols=cols, vals=vals,
+                            num_samples=len(labels), feature_dim=300)
+        return shard, build_game_dataset(
+            labels=labels, feature_shards={"global": shard},
+            entity_keys={}, dtype=np.float64,
+        )
+
+    def _program(self):
+        from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType
+        from photon_ml_tpu.parallel.distributed import (
+            FixedEffectStepSpec,
+            GameTrainProgram,
+        )
+
+        opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS,
+                              max_iterations=2)
+        return GameTrainProgram(
+            TaskType.LOGISTIC_REGRESSION,
+            FixedEffectStepSpec("global", opt, l2_weight=0.1), (),
+        )
+
+    def test_prepare_inputs_builds_one_block(self):
+        shard, dataset = self._tiered_shard_dataset()
+        data, _ = self._program().prepare_inputs(dataset, {})
+        sb_ = data["fe_sparse_batch"]
+        assert sb_.ell_tiers == ()
+        assert sb_.ell_vals.shape == (2048, shard.one_ell_width())
+
+    def test_shard_inputs_refuses_a_tiered_batch(self):
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four CPU devices")
+        from photon_ml_tpu.parallel.mesh import make_mesh
+
+        shard, dataset = self._tiered_shard_dataset()
+        program = self._program()
+        data, buckets = program.prepare_inputs(dataset, {})
+        tiered = SparseLabeledPointBatch.from_shard(
+            shard, data["labels"], data["offsets"], data["weights"]
+        )
+        assert tiered.ell_tiers
+        data["fe_sparse_batch"] = tiered
+        mesh = make_mesh(data=4, model=1)
+        with pytest.raises(ValueError, match=r"one_ell_width.*ell_width"):
+            program._shard_data(mesh, data)
+
+    def test_partitioned_assembly_refuses_a_tiered_batch(self):
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four CPU devices")
+        from photon_ml_tpu.parallel.distributed import _assemble_sparse_fe
+        from photon_ml_tpu.parallel.mesh import make_mesh
+
+        shard, dataset = self._tiered_shard_dataset()
+        labels = np.asarray(dataset.host_array("labels"))
+        tiered = SparseLabeledPointBatch.from_shard(
+            shard, labels, np.zeros_like(labels), np.ones_like(labels)
+        )
+        prepared = {r: ({"fe_sparse_batch": tiered}, None) for r in (0, 1)}
+        with pytest.raises(ValueError, match=r"one_ell_width.*ell_width"):
+            _assemble_sparse_fe(
+                prepared, [0, 1], make_mesh(data=4, model=1), 2, jax.device_put
+            )
+
+
 class TestLayoutTelemetry:
+    def test_tail_gauges_equal_the_built_arrays(self):
+        from photon_ml_tpu.telemetry import default_registry
+        from photon_ml_tpu.telemetry.layout import reset_layout_metrics
+
+        reset_layout_metrics()
+        rows, cols, vals, labels, *_ = _tiered_coo(seed=9)
+        batch = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=300, dtype=np.float64,
+            hybrid=HybridPolicy(hot_cols=8, pad_multiple=8, label="t_built"),
+        )
+        g = {k.rsplit("/", 1)[1]: v
+             for k, v in default_registry().snapshot()["gauges"].items()
+             if k.startswith("layout/t_built/")}
+        blocks = [batch.ell_vals] + [t.vals for t in batch.ell_tiers]
+        slots = sum(b.size for b in blocks) + batch.nnz
+        assert len(blocks) >= 3 and batch.nnz > 0
+        assert g["tail_slots"] == slots
+        assert g["tail_tiers"] == len(blocks)
+        assert g["tail_width"] == batch.ell_vals.shape[1] + sum(
+            t.width for t in batch.ell_tiers)
+        # tail_nnz stays the tail's ENTRIES, padding apart
+        entries = len(_view_entries(batch)[0])
+        assert g["tail_nnz"] == entries
+        assert g["tail_pad_share"] == pytest.approx(1.0 - entries / slots)
+        arrays = [batch.hot_vals, batch.ell_vals, batch.ell_cols, batch.values,
+                  batch.col_indices, batch.row_ids]
+        for t in batch.ell_tiers:
+            arrays += [t.vals, t.cols, t.row_ids]
+        assert g["hybrid_bytes"] == sum(a.size * a.dtype.itemsize for a in arrays)
+        # a flat tail (ell=False) has no tier and pads nothing
+        SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=300, dtype=np.float64, ell=False,
+            hybrid=HybridPolicy(hot_cols=8, pad_multiple=8, label="t_flat"),
+        )
+        gauges = default_registry().snapshot()["gauges"]
+        assert gauges["layout/t_flat/tail_tiers"] == 0
+        assert gauges["layout/t_flat/tail_slots"] == gauges["layout/t_flat/tail_nnz"]
+        assert gauges["layout/t_flat/tail_pad_share"] == 0.0
+        reset_layout_metrics()
+
+
     def test_hybrid_build_records_gauges_and_resets(self):
         from photon_ml_tpu.telemetry import default_registry
         from photon_ml_tpu.telemetry.layout import reset_layout_metrics
@@ -476,7 +921,8 @@ class TestLayoutTelemetry:
         snap = default_registry().snapshot()
         gauges = snap["gauges"]
         for key in ("k_hot", "k_hot_padded", "hot_coverage", "hot_nnz",
-                    "tail_nnz", "tail_width", "hybrid_bytes", "ell_bytes"):
+                    "tail_nnz", "tail_width", "hybrid_bytes", "tail_slots",
+                    "tail_tiers", "tail_pad_share"):
             assert f"layout/t_shard/{key}" in gauges, key
         assert 0.0 < gauges["layout/t_shard/hot_coverage"] <= 1.0
         assert snap["counters"]["layout/t_shard/builds"] == 1

@@ -7,7 +7,10 @@ widths the auto rule admits (scoped VMEM, tiling), and ``glm/path_solve`` under
 TRON at the benchmark cell's size holds ONE custom call a product, inside the
 CG loop, under ``tron/hv``, named after its jitted wrapper by a name
 ``benchmark/trace_reduce.KERNEL`` does not match, with X relaid out twice a
-solve and never inside a loop.
+solve and never inside a loop; and (PR 44) ``glm/path_solve`` over the sparse
+cell's hybrid batch with its ELL view in width tiers compiles into a program
+of ordinary size whose tiers are gathered and scattered under the two
+``sparse/tail_*`` scopes.
 
 This is the ONE file that describes a topology: the TPU library is loaded by
 the process that runs these tests, inside a fixture, never at import.
@@ -115,3 +118,77 @@ def test_x_is_read_by_the_kernels_alone_and_relaid_out_twice_a_solve(tron_path_t
     copies = re.findall(rf"= {x}[^ ]* copy\(", tron_path_text)
     assert len(copies) == 2 and len(re.findall(rf"= {x}[^ ]* copy\(", entry)) == 2
     assert not re.search(rf"fusion\([^\n]*{x}", tron_path_text)
+
+
+# -- the sparse path program with its ELL view in width tiers (PR 44) ----------
+
+#: logistic-kdda-sparse.path: rows, features, hot columns; the tiers the width
+#: rule reads off the cell's counts (rows that hold a tier, its width) and the
+#: flat overflow's length
+SPARSE_ROWS, SPARSE_FEATURES, HOT_COLS = 525_484, 20_216_830, 2_048
+SPARSE_TIERS = ((525_484, 8), (378_425, 3), (266_081, 3), (172_123, 3),
+                (106_466, 3), (64_050, 3))
+SPARSE_OVERFLOW = 239_245
+
+
+@pytest.fixture(scope="module")
+def sparse_path_compiled(one_chip):
+    """``glm/path_solve`` over the cell's tiered hybrid batch, as shapes."""
+    from photon_ml_tpu import estimators
+    from photon_ml_tpu.data.sparse_batch import EllTier, SparseLabeledPointBatch
+    from photon_ml_tpu.ops.sparse_objective import SparseGLMObjective
+
+    def f32(*shape):
+        return _shape(one_chip, shape)
+
+    def i32(*shape):
+        return _shape(one_chip, shape, jnp.int32)
+
+    n, d = SPARSE_ROWS, SPARSE_FEATURES
+    (_, first), further = SPARSE_TIERS[0], SPARSE_TIERS[1:]
+    batch = SparseLabeledPointBatch(
+        values=f32(SPARSE_OVERFLOW), col_indices=i32(SPARSE_OVERFLOW),
+        row_ids=i32(SPARSE_OVERFLOW), labels=f32(n), offsets=f32(n),
+        weights=f32(n), dim=d, ell_vals=f32(n, first), ell_cols=i32(n, first),
+        ell_tiers=tuple(
+            EllTier(vals=f32(w, rows), cols=i32(w, rows), row_ids=i32(rows))
+            for rows, w in further),
+        hot_vals=f32(n, HOT_COLS), hot_col_ids=i32(HOT_COLS))
+    lbfgs = OptimizerConfig(OptimizerType.LBFGS, max_iterations=15,
+                            rel_function_tolerance=1e-6, history=10)
+    with jax.enable_x64(False):
+        return estimators._jitted_path_solve.lower(
+            SparseGLMObjective(LogisticLoss()), lbfgs, batch, f32(d), f32(),
+            None, None,
+        ).compile()
+
+
+def test_the_tiered_sparse_path_compiles_small_and_fits_the_chip(sparse_path_compiled):
+    """A further tier lies ``[width, n_k]``: kept ``[n_k, 4]`` this program
+    was 245 MB of code (PERF.md 6, PR 44). And it fits a 16 GB chip."""
+    memory = sparse_path_compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 150e6
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes) < 15.75e9
+
+
+def test_every_tier_is_gathered_and_scattered_under_the_tail_scopes(sparse_path_compiled):
+    """The line search's body gathers and scatter-adds every tier and the
+    overflow, each under ``sparse/tail_margins`` or ``sparse/tail_gradient``
+    (what ``benchmark/path_sparse_scopes.py`` files under the tail), and
+    nothing sparse stands outside a ``sparse/`` scope."""
+    text = sparse_path_compiled.as_text()
+    body = "line_search/while/body/"
+    ops = re.findall(r'op_name="([^"]*' + body + r'[^"]*/(?:gather|scatter-add))"', text)
+    blocks = len(SPARSE_TIERS) + 1  # and the flat overflow
+    margins = {op for op in ops if "sparse/tail_margins/" in op}
+    gradient = {op for op in ops if "sparse/tail_gradient/" in op}
+    assert any(op.endswith("/gather") for op in margins)
+    assert any(op.endswith("/scatter-add") for op in margins)  # at row ids
+    assert any(op.endswith("/scatter-add") for op in gradient)
+    assert any(op.endswith("/gather") for op in gradient)  # dzw[row_ids]
+    scatters = re.findall(
+        r"= f32\[" + str(SPARSE_FEATURES) + r"\][^\n]*" + body
+        + r"sparse/tail_gradient/scatter-add", text)
+    assert len(scatters) >= blocks
+    assert all("sparse/" in op for op in ops)
