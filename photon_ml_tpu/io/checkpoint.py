@@ -544,6 +544,15 @@ class SolverCheckpointer:
     Step ids encode (λ index, iteration) monotonically, so
     ``TrainingCheckpointer``'s newest-intact-step restore (with its
     corrupt-step fallback and prune protections) applies unchanged.
+
+    The state's arrays are saved in the SHAPE the solver keeps them in:
+    an L-BFGS / OWL-QN history slot is a row of ``[m, d]`` below
+    ``optim/lbfgs.SLAB_MIN_DIM`` and whole (8, 128) tiles, ``[m, R, 128]``
+    with d padded to ``R * 128``, from it on. A snapshot whose ``s_hist`` /
+    ``y_hist`` have the other form's shape for this d (the edge moved
+    between versions) is REFUSED by the solver on entry, the field and both
+    shapes named (``optim/lbfgs.require_history_form``): like a snapshot
+    with other fields, it is never folded or read as if it fitted.
     """
 
     #: step = lam_index * STRIDE + iteration + 1 — monotone across the
@@ -553,8 +562,10 @@ class SolverCheckpointer:
     def __init__(self, directory: str | os.PathLike, *, max_to_keep: int = 3,
                  save_every: int = 1):
         #: iteration cadence for mid-solve snapshots: the state is
-        #: model-sized (d·(2m+4) floats for LBFGS — ~0.5 GB at d=10⁷
-        #: m=10), so giant-d runs widen this instead of paying a blocking
+        #: model-sized (d·(2m+4) floats for LBFGS, d rounded up to whole
+        #: 1,024-float tiles in each of the 2m history slots from
+        #: optim/lbfgs.SLAB_MIN_DIM on: ~1 GB at d=10⁷ m=10 in float32),
+        #: so giant-d runs widen this instead of paying a blocking
         #: np.savez every iteration; λ-boundary snapshots always save
         self.save_every = max(1, int(save_every))
         self._inner = TrainingCheckpointer(directory, max_to_keep=max_to_keep)

@@ -11,12 +11,31 @@ program across coordinate-descent iterations and λ-grid points, and vmaps
 over entities for random-effect coordinates (replacing
 RandomEffectCoordinate.scala:104-153's per-entity breeze solves).
 
-The [m, d] pair history is kept IN ORDER, newest pair at slot 0: every slot
-the two-loop recursion reads or writes is its loop counter, the same for
-every lane of a vmapped solve, so a read is one slice of all the lanes'
-histories and never a gather with an index a lane (a circular buffer's
-newest slot differs lane by lane; PERF.md §6, PR 28). Keeping a pair costs
-one dense shift of the history: 2·m·d floats read and written an iteration.
+The pair history (m slots each of ``s_hist`` and ``y_hist``) is kept IN
+ORDER, newest pair at slot 0: every slot the two-loop recursion reads or
+writes is its loop counter, the same for every lane of a vmapped solve, so a
+read is one slice of all the lanes' histories and never a gather with an
+index a lane (a circular buffer's newest slot differs lane by lane; PERF.md
+§6, PR 28). Keeping a pair costs one dense shift of the history.
+
+A slot is STORED in one of two shapes, by one rule on the static d
+(``history_slot_shape``, ``SLAB_MIN_DIM``): below the edge the history is
+``[m, d]``, the lanes' and every small solve's program since PR 28; from the
+edge on it is ``[m, R, 128]`` with ``R = 8 * ceil(d / 1024)``, each slot
+whole (8, 128) tiles, d padded with zeros to them. The TPU tiles an array's
+last two dimensions: in ``[m, d]`` the m slots lie along the sublanes, ten
+slots are stored as sixteen and reading ONE of them moves the whole tiles it
+shares with seven others (2.0 ms a visit at d = 20 million where the slot's
+own bytes take 0.3; PERF.md §6, PR 45); in ``[m, R, 128]`` m is an untiled
+major dimension and slot k is one contiguous slab, so each of the recursion's
+2·m slot visits costs what the slot's bytes cost (4·m·d floats read an
+iteration). The shift, 2·m·d floats read and written by the count, is in
+both forms one out-of-place pass the compiler feeds from a copy of
+``hist[:-1]``: 2.4 times those bytes at d = 20 million, nothing that shows below
+the edge (PERF.md §7 row 4 h). The algorithm is one:
+``two_loop_direction`` and ``push_pair`` fold the flat vectors they are given
+to the slot's shape on the way in and unfold the direction on the way out;
+``w``, ``g`` and all the objective and the line search see stay flat ``[d]``.
 """
 
 from __future__ import annotations
@@ -35,6 +54,7 @@ from photon_ml_tpu.optim.common import (
     run_while,
     wolfe_line_search,
 )
+from photon_ml_tpu.telemetry.registry import default_registry
 
 Array = jax.Array
 
@@ -42,20 +62,97 @@ DEFAULT_MAX_ITER = 100
 DEFAULT_HISTORY = 10
 DEFAULT_TOLERANCE = 1e-7
 
+#: THE rule of the history's stored form: a slot of this many floats or more
+#: is whole (8, 128) tiles (``[m, R, 128]``), a shorter one a row of ``[m, d]``.
+#: 16 tiles: from here on the pad to whole tiles is under a sixteenth of a
+#: slot and falling, and the history stops being small enough for the compiler
+#: to keep in fast memory wherever its loop wants it (the lanes' d = 16 and
+#: 32, the fixed effects' 256 and 2,000: 0.26 MB at most, their compiled
+#: programs untouched) and becomes memory traffic that has to lie contiguous.
+SLAB_MIN_DIM = 16 * 1024
+
+_SUBLANES, _LANES = 8, 128
+
+
+def history_slot_shape(d: int) -> tuple[int, ...]:
+    """The shape in which one history slot of a d-vector is stored."""
+    if d < SLAB_MIN_DIM:
+        return (d,)
+    tile = _SUBLANES * _LANES
+    return (_SUBLANES * -(-d // tile), _LANES)
+
+
+def _record_history_form(slot: tuple[int, ...], dtype) -> None:
+    """Trace-time gauges of the last L-BFGS / OWL-QN solve traced: the slot's
+    stored shape (``slot_rows`` x ``slot_lanes``; one row of d for ``[m, d]``)
+    and the bytes of one slot as stored, the slab's pad included."""
+    rows, lanes = slot if len(slot) == 2 else (1,) + slot
+    reg = default_registry()
+    reg.gauge("solver/history/slot_rows").set(rows)
+    reg.gauge("solver/history/slot_lanes").set(lanes)
+    reg.gauge("solver/history/slot_bytes").set(rows * lanes * jnp.dtype(dtype).itemsize)
+
+
+def empty_history(m: int, d: int, dtype) -> tuple[Array, Array, Array, Array]:
+    """(s_hist, y_hist, rho, count) of a solve over d coefficients that has
+    kept no pair yet, in the form the rule gives d."""
+    slot = history_slot_shape(d)
+    _record_history_form(slot, dtype)
+    return (
+        jnp.zeros((m,) + slot, dtype),
+        jnp.zeros((m,) + slot, dtype),
+        jnp.zeros((m,), dtype),
+        jnp.int32(0),
+    )
+
+
+def require_history_form(state, m: int, d: int) -> None:
+    """Refuse a state to resume from whose history is not in the form the
+    rule gives d (a snapshot written under the other form, or another m): it
+    is never folded, and never read as if it fitted."""
+    want = (m,) + history_slot_shape(d)
+    for name in ("s_hist", "y_hist"):
+        got = tuple(getattr(state, name).shape)
+        if got != want:
+            raise ValueError(
+                f"resume_state.{name} has shape {got} where a history of "
+                f"{m} slots over d = {d} is stored as {want}: it was written "
+                "under another form of the L-BFGS history; use a fresh "
+                "checkpoint directory"
+            )
+    _record_history_form(want[1:], state.s_hist.dtype)
+
+
+def _fold(v: Array, slot: tuple[int, ...]) -> Array:
+    """A flat d-vector in a slot's stored shape (zeros in the slab's pad)."""
+    if v.shape == slot:
+        return v
+    rows, lanes = slot
+    return jnp.pad(v, (0, rows * lanes - v.shape[0])).reshape(slot)
+
+
+def _dot(a: Array, b: Array) -> Array:
+    """aᵀb. Over a slab one multiply-reduce of the tiles as they lie
+    (``vdot`` would flatten both operands first)."""
+    return jnp.vdot(a, b) if a.ndim == 1 else jnp.sum(a * b)
+
 
 def two_loop_direction(
     g: Array, s_hist: Array, y_hist: Array, rho: Array, count: Array
 ) -> Array:
     """L-BFGS two-loop recursion over an ordered history.
 
-    s_hist/y_hist: [m, d]; rho: [m] (1/sᵀy); slot 0 holds the newest pair,
-    slot k the pair k steps back; count: number of valid pairs. Slots from
-    ``count`` on are masked by zeroing their alpha/beta contributions, keeping
-    shapes static for jit. Every slot index is a loop counter: under ``vmap``
-    it is the same for all lanes, a slice of the lanes' histories.
+    g: [d]; s_hist/y_hist: [m, d] or [m, R, 128] (``history_slot_shape``);
+    rho: [m] (1/sᵀy); slot 0 holds the newest pair, slot k the pair k steps
+    back; count: number of valid pairs. Slots from ``count`` on are masked by
+    zeroing their alpha/beta contributions, keeping shapes static for jit.
+    Every slot index is a loop counter: under ``vmap`` it is the same for all
+    lanes, a slice of the lanes' histories. Returns the flat [d] direction.
     """
     with jax.named_scope("lbfgs/direction"):
         m = s_hist.shape[0]
+        d = g.shape[0]
+        g = _fold(g, s_hist.shape[1:])
 
         def slot(x, k):
             return lax.dynamic_index_in_dim(x, k, keepdims=False)
@@ -63,7 +160,7 @@ def two_loop_direction(
         def backward(k, carry):
             # newest to oldest
             q, alphas = carry
-            alpha = jnp.where(k < count, slot(rho, k) * jnp.vdot(slot(s_hist, k), q), 0.0)
+            alpha = jnp.where(k < count, slot(rho, k) * _dot(slot(s_hist, k), q), 0.0)
             q = q - alpha * slot(y_hist, k)
             return q, lax.dynamic_update_index_in_dim(alphas, alpha, k, 0)
 
@@ -71,8 +168,8 @@ def two_loop_direction(
 
         gamma = jnp.where(
             count > 0,
-            jnp.vdot(s_hist[0], y_hist[0])
-            / jnp.maximum(jnp.vdot(y_hist[0], y_hist[0]), 1e-30),
+            _dot(s_hist[0], y_hist[0])
+            / jnp.maximum(_dot(y_hist[0], y_hist[0]), 1e-30),
             1.0,
         )
         r = gamma * q
@@ -80,11 +177,12 @@ def two_loop_direction(
         def forward(i, r):
             # oldest to newest: the empty slots come first and add nothing
             k = m - 1 - i
-            beta = slot(rho, k) * jnp.vdot(slot(y_hist, k), r)
+            beta = slot(rho, k) * _dot(slot(y_hist, k), r)
             return r + jnp.where(k < count, slot(alphas, k) - beta, 0.0) * slot(s_hist, k)
 
         r = lax.fori_loop(0, m, forward, r)
-        return -r
+        # the slab's pad stayed zero throughout and is cut off here
+        return -r if r.ndim == 1 else (-r).reshape(-1)[:d]
 
 
 def push_pair(
@@ -96,9 +194,10 @@ def push_pair(
     y: Array,
     accepted: Array,
 ) -> tuple[Array, Array, Array, Array]:
-    """The history after a step (s, y): where the step was accepted and its
-    curvature sᵀy is positive, every pair moves one slot back (the oldest
-    falls off) and the new one takes slot 0; else the history stays."""
+    """The history after a step (s, y), both flat [d]: where the step was
+    accepted and its curvature sᵀy is positive, every pair moves one slot back
+    (the oldest falls off) and the new one takes slot 0; else the history
+    stays."""
     with jax.named_scope("lbfgs/history"):
         m = s_hist.shape[0]
         sy = jnp.vdot(s, y)
@@ -108,8 +207,8 @@ def push_pair(
             return jnp.where(keep_pair, jnp.concatenate([new[None], hist[:-1]]), hist)
 
         return (
-            pushed(s_hist, s),
-            pushed(y_hist, y),
+            pushed(s_hist, _fold(s, s_hist.shape[1:])),
+            pushed(y_hist, _fold(y, y_hist.shape[1:])),
             pushed(rho, 1.0 / jnp.maximum(sy, 1e-30)),
             jnp.where(keep_pair, jnp.minimum(count + 1, m), count),
         )
@@ -201,6 +300,7 @@ def minimize_lbfgs(
         # checkpointed re-entry: the saved state already holds f/g/history
         # for its iterate — re-evaluating w0 would cost an epoch for
         # numbers the checkpoint carries
+        require_history_form(resume_state, m, d)
         init = resume_state
     else:
         w0 = project(jnp.asarray(w0, dtype))
@@ -208,14 +308,15 @@ def minimize_lbfgs(
         g0_norm = projected_grad_norm(w0, g0)
 
         nan_hist = jnp.full((max_iter + 1,), jnp.nan, dtype)
+        s_hist, y_hist, rho, count = empty_history(m, d, dtype)
         init = _LBFGSState(
             w=w0,
             f=f0,
             g=g0,
-            s_hist=jnp.zeros((m, d), dtype),
-            y_hist=jnp.zeros((m, d), dtype),
-            rho=jnp.zeros((m,), dtype),
-            count=jnp.int32(0),
+            s_hist=s_hist,
+            y_hist=y_hist,
+            rho=rho,
+            count=count,
             iteration=jnp.int32(0),
             reason=jnp.int32(ConvergenceReason.NOT_CONVERGED),
             prev_f=jnp.asarray(jnp.inf, dtype),

@@ -25,7 +25,12 @@ from photon_ml_tpu.optim.common import (
     no_line_search_counts,
     run_while,
 )
-from photon_ml_tpu.optim.lbfgs import push_pair, two_loop_direction
+from photon_ml_tpu.optim.lbfgs import (
+    empty_history,
+    push_pair,
+    require_history_form,
+    two_loop_direction,
+)
 
 Array = jax.Array
 
@@ -102,6 +107,7 @@ def minimize_owlqn(
         return smooth_f + l1 * jnp.sum(jnp.abs(w))
 
     if resume_state is not None:
+        require_history_form(resume_state, m, d)
         init = resume_state
     else:
         w0 = jnp.asarray(w0, dtype)
@@ -111,14 +117,15 @@ def minimize_owlqn(
         g0_norm = jnp.linalg.norm(pg0)
 
         nan_hist = jnp.full((max_iter + 1,), jnp.nan, dtype)
+        s_hist, y_hist, rho, count = empty_history(m, d, dtype)
         init = _OWLQNState(
             w=w0,
             f=f0,
             g=g0,
-            s_hist=jnp.zeros((m, d), dtype),
-            y_hist=jnp.zeros((m, d), dtype),
-            rho=jnp.zeros((m,), dtype),
-            count=jnp.int32(0),
+            s_hist=s_hist,
+            y_hist=y_hist,
+            rho=rho,
+            count=count,
             iteration=jnp.int32(0),
             reason=jnp.where(
                 g0_norm <= tolerance,

@@ -477,3 +477,277 @@ def test_whole_solve_is_the_parent_commits(solver, vmapped):
     np.testing.assert_allclose(
         got["coefficients"], want["coefficients"], rtol=1e-6, atol=1e-12
     )
+
+
+# -- a slot stored as whole tiles where d is large (PR 45) --------------------
+#
+# ``history_slot_shape`` is the one rule: below ``SLAB_MIN_DIM`` a slot is a row
+# of ``[m, d]``, from it on a slab ``[R, 128]`` of ``[m, R, 128]``. The two
+# functions read the form off the history they are handed, so both forms of
+# one d can be fed the same pairs here.
+
+from photon_ml_tpu.optim import lbfgs as lbfgs_mod  # noqa: E402
+from photon_ml_tpu.optim.lbfgs import (  # noqa: E402
+    SLAB_MIN_DIM,
+    empty_history,
+    history_slot_shape,
+)
+from photon_ml_tpu.telemetry.registry import default_registry  # noqa: E402
+from photon_ml_tpu.telemetry.solver_trace import reset_solver_metrics  # noqa: E402
+
+SLAB_D = SLAB_MIN_DIM + 3_616  # 20,000: over the edge, no multiple of 1,024
+
+
+def _slab_shape(d):
+    return (8 * -(-d // 1024), 128)
+
+
+def test_the_rule_has_one_edge_and_whole_tiles_above_it():
+    for d in (16, 32, 256, 2_000, SLAB_MIN_DIM - 1):
+        assert history_slot_shape(d) == (d,)
+    assert history_slot_shape(SLAB_MIN_DIM) == (SLAB_MIN_DIM // 128, 128)
+    assert history_slot_shape(SLAB_D) == (160, 128)
+    assert history_slot_shape(20_216_830) == (157_944, 128)  # 20,216,832 floats
+    for d in (SLAB_MIN_DIM, SLAB_D, 20_216_830):
+        rows, lanes = history_slot_shape(d)
+        assert rows % 8 == 0 and 0 <= rows * lanes - d < 1024
+
+
+def _wide_stream(seed, n_pairs, d, dtype):
+    rng = np.random.default_rng(seed)
+    s = rng.normal(size=(n_pairs, d))
+    y = rng.uniform(0.5, 2.0, size=(n_pairs, 1)) * s + 0.1 * rng.normal(size=(n_pairs, d))
+    if n_pairs > 4:
+        y[2] = -y[2]  # negative and zero curvature: neither pair is kept
+        y[4] = 0.0
+    g = rng.normal(size=(n_pairs + 1, d))
+    return tuple(jnp.asarray(x, dtype) for x in (s, y, g))
+
+
+def _run_form(slot, s, y, g, accepted):
+    """The stream through a history whose slots have shape ``slot``: the
+    direction before the first step and after every one, and the final
+    history."""
+    m, dtype = M, s.dtype
+    init = (jnp.zeros((m,) + slot, dtype), jnp.zeros((m,) + slot, dtype),
+            jnp.zeros((m,), dtype), jnp.int32(0))
+
+    def step(history, xs):
+        s_t, y_t, g_t, ok = xs
+        history = push_pair(*history, s_t, y_t, ok)
+        return history, two_loop_direction(g_t, *history)
+
+    history, rest = lax.scan(step, init, (s, y, g[1:], accepted))
+    return two_loop_direction(g[0], *init), rest, history
+
+
+# pairs fed: none, part full, exactly full, wrapped (two of them dropped for
+# their curvature, two refused)
+FORM_CASES = {"count0": 0, "count3": 3, "full": M + 2, "wrapped": 2 * M + 5}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("name", FORM_CASES)
+@pytest.mark.parametrize("d", [1_000, SLAB_MIN_DIM, SLAB_D],
+                         ids=["below", "at_the_edge", "above"])
+def test_slab_form_gives_the_row_forms_direction_and_keeps_its_pairs(d, name, dtype):
+    n_pairs = FORM_CASES[name]
+    s, y, g = _wide_stream(45 + n_pairs, n_pairs, d, dtype)
+    accepted = np.ones((n_pairs,), bool)
+    if n_pairs > M:
+        accepted[[7, 9]] = False
+    accepted = jnp.asarray(accepted)
+    run = jax.jit(_run_form, static_argnums=0)
+    row_first, row_rest, row_hist = run((d,), s, y, g, accepted)
+    slab_first, slab_rest, slab_hist = run(_slab_shape(d), s, y, g, accepted)
+
+    assert slab_first.shape == (d,) and slab_rest.shape == (n_pairs, d)
+    assert np.array_equal(np.asarray(slab_first), np.asarray(-g[0]))
+    # another order of the same sums over d floats, 2 m of them a direction:
+    # a rounding of each sum (some sqrt(d) ulps) of the direction's norm
+    ulps = 4 * np.sqrt(d) * np.finfo(dtype).eps if dtype == "float32" else 1e-12
+    for slab, row in zip(np.asarray(slab_rest), np.asarray(row_rest)):
+        assert np.isfinite(slab).all()
+        assert np.linalg.norm(slab - row) <= ulps * np.linalg.norm(row)
+
+    kept = int(row_hist[3])
+    dropped = (2 if n_pairs > 4 else 0) + (2 if n_pairs > M else 0)
+    assert int(slab_hist[3]) == kept == min(n_pairs - dropped, M)
+    # the same pairs in the same slots (s.y is taken on the flat vectors in both)
+    assert np.array_equal(np.asarray(slab_hist[2]), np.asarray(row_hist[2]))
+    for slab, row in zip(slab_hist[:2], row_hist[:2]):
+        flat = np.asarray(slab).reshape(M, -1)
+        assert np.array_equal(flat[:, :d], np.asarray(row))
+        # the pad's floats stay zero through every push
+        assert not flat[:, d:].any()
+
+
+def test_the_slabs_pad_never_reaches_the_direction():
+    """d = 20,000 leaves 480 floats of pad a slot: zero after every push, and
+    the direction is the [d] floats alone, whatever the gradient holds."""
+    d = SLAB_D
+    s, y, g = _wide_stream(7, M + 3, d, "float32")
+    first, rest, history = jax.jit(_run_form, static_argnums=0)(
+        _slab_shape(d), s, y, g, jnp.ones((M + 3,), bool))
+    rows, lanes = _slab_shape(d)
+    assert rows * lanes - d == 480
+    for hist in history[:2]:
+        assert hist.shape == (M, rows, lanes)
+        assert not np.asarray(hist).reshape(M, -1)[:, d:].any()
+    assert first.shape == (d,) and rest.shape == (M + 3, d)
+    assert np.isfinite(np.asarray(rest)).all() and np.asarray(rest[-1]).all()
+
+
+# -- below the edge the traced program is the parent's ------------------------
+
+
+def _parent_two_loop_direction(g, s_hist, y_hist, rho, count):
+    """``two_loop_direction`` as commit 79e1951 had it."""
+    with jax.named_scope("lbfgs/direction"):
+        m = s_hist.shape[0]
+
+        def slot(x, k):
+            return lax.dynamic_index_in_dim(x, k, keepdims=False)
+
+        def backward(k, carry):
+            q, alphas = carry
+            alpha = jnp.where(k < count, slot(rho, k) * jnp.vdot(slot(s_hist, k), q), 0.0)
+            q = q - alpha * slot(y_hist, k)
+            return q, lax.dynamic_update_index_in_dim(alphas, alpha, k, 0)
+
+        q, alphas = lax.fori_loop(0, m, backward, (g, jnp.zeros((m,), dtype=g.dtype)))
+        gamma = jnp.where(
+            count > 0,
+            jnp.vdot(s_hist[0], y_hist[0])
+            / jnp.maximum(jnp.vdot(y_hist[0], y_hist[0]), 1e-30),
+            1.0,
+        )
+        r = gamma * q
+
+        def forward(i, r):
+            k = m - 1 - i
+            beta = slot(rho, k) * jnp.vdot(slot(y_hist, k), r)
+            return r + jnp.where(k < count, slot(alphas, k) - beta, 0.0) * slot(s_hist, k)
+
+        r = lax.fori_loop(0, m, forward, r)
+        return -r
+
+
+def _parent_push_pair(s_hist, y_hist, rho, count, s, y, accepted):
+    """``push_pair`` as commit 79e1951 had it."""
+    with jax.named_scope("lbfgs/history"):
+        m = s_hist.shape[0]
+        sy = jnp.vdot(s, y)
+        keep_pair = accepted & (sy > 1e-10)
+
+        def pushed(hist, new):
+            return jnp.where(keep_pair, jnp.concatenate([new[None], hist[:-1]]), hist)
+
+        return (
+            pushed(s_hist, s),
+            pushed(y_hist, y),
+            pushed(rho, 1.0 / jnp.maximum(sy, 1e-30)),
+            jnp.where(keep_pair, jnp.minimum(count + 1, m), count),
+        )
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["one_solve", "vmapped"])
+@pytest.mark.parametrize("d", [16, 32, 256])
+def test_below_the_edge_the_jaxprs_are_the_parents(d, vmapped):
+    """The lanes' d (16, 32) and the GLMix fixed effect's (256): the history
+    the rule gives them is ``[m, d]`` and both functions trace to the parent
+    commit's equations, variable for variable: no reshape, no pad."""
+    s_hist, y_hist, rho, count = empty_history(M, d, jnp.float32)
+    assert s_hist.shape == y_hist.shape == (M, d)
+    g = jnp.ones((d,), jnp.float32)
+    direction_args = (g, s_hist, y_hist, rho, count)
+    push_args = (s_hist, y_hist, rho, count, g, g, jnp.asarray(True))
+    wrap = (lambda f: f)
+    if vmapped:
+        tile = lambda x: jnp.broadcast_to(x, (LANES,) + x.shape)
+        direction_args = tuple(tile(x) for x in direction_args)
+        push_args = tuple(tile(x) for x in push_args)
+        wrap = jax.vmap
+    for ours, parents, args in (
+        (two_loop_direction, _parent_two_loop_direction, direction_args),
+        (push_pair, _parent_push_pair, push_args),
+    ):
+        got = jax.make_jaxpr(wrap(ours))(*args)
+        assert str(got) == str(jax.make_jaxpr(wrap(parents))(*args))
+        names = {eqn.primitive.name for eqn in _eqns(got.jaxpr)}
+        assert not names & {"reshape", "pad", "reduce_sum"}
+    # and above it the slab is what both are handed
+    assert empty_history(M, SLAB_D, jnp.float32)[0].shape == (M,) + _slab_shape(SLAB_D)
+
+
+# -- whole solves above the edge against the same solve forced below it -------
+
+
+def _wide_problem(n=48, d=SLAB_D):
+    rng = np.random.default_rng(4500)
+    x = rng.normal(size=(n, d)) / np.sqrt(d)
+    w_true = rng.normal(size=(d,)) * 4.0
+    p = 1.0 / (1.0 + np.exp(-(x @ w_true)))
+    labels = (rng.uniform(size=p.shape) < p).astype(np.float64)
+    return jnp.asarray(x), jnp.asarray(labels), jnp.asarray(0.05)
+
+
+def _wide_solve(solver):
+    """Traced anew at every call: the rule is read while tracing."""
+    x, labels, l2 = _wide_problem()
+    fn = _value_and_grad(x, labels, l2)
+    w0 = jnp.zeros((x.shape[-1],), x.dtype)
+    if solver == "lbfgs":
+        run = lambda: minimize_lbfgs(fn, w0, max_iter=30, history=4, tolerance=1e-9)
+    elif solver == "box":
+        run = lambda: minimize_lbfgs(
+            fn, w0, max_iter=30, history=4, tolerance=1e-9,
+            lower_bounds=jnp.full_like(w0, -0.02), upper_bounds=jnp.full_like(w0, 0.03))
+    else:
+        run = lambda: minimize_owlqn(
+            fn, w0, l1_weight=0.002, max_iter=30, history=4, tolerance=1e-9)
+    return jax.jit(run)()
+
+
+@pytest.mark.parametrize("solver", ["lbfgs", "box", "owlqn"])
+def test_whole_solve_above_the_edge_is_the_solve_forced_below_it(solver, monkeypatch):
+    reset_solver_metrics()
+    slab = _wide_solve(solver)
+    gauges = default_registry().snapshot()["gauges"]
+    assert gauges["solver/history/slot_rows"] == 160  # the slab engaged
+    monkeypatch.setattr(lbfgs_mod, "SLAB_MIN_DIM", 10 ** 9)
+    row = _wide_solve(solver)
+    assert default_registry().snapshot()["gauges"]["solver/history/slot_rows"] == 1
+    assert int(slab.iterations) == int(row.iterations) > 4  # the history wrapped
+    assert int(slab.reason) == int(row.reason)
+    assert np.array_equal(np.asarray(slab.line_search_trials),
+                          np.asarray(row.line_search_trials))
+    np.testing.assert_allclose(float(slab.value), float(row.value), rtol=1e-9)
+    gap = np.linalg.norm(np.asarray(slab.coefficients) - np.asarray(row.coefficients))
+    assert gap <= 1e-5 * np.linalg.norm(np.asarray(row.coefficients))
+    assert np.linalg.norm(np.asarray(row.coefficients)) > 0.0
+
+
+# -- the gauges that say which form the last traced solve stored --------------
+
+
+@pytest.mark.parametrize("solver", ["lbfgs", "owlqn"])
+@pytest.mark.parametrize("d,rows,lanes", [(SLAB_D, 160, 128), (16, 1, 16)],
+                         ids=["d20000_slab", "d16_row"])
+def test_a_traced_solve_records_its_historys_form(d, rows, lanes, solver):
+    def fn(w):
+        return 0.5 * jnp.vdot(w - 1.0, w - 1.0), w - 1.0
+
+    reset_solver_metrics()
+    assert not [k for k in default_registry().snapshot()["gauges"] if k.startswith("solver/")]
+    w0 = jnp.zeros((d,), jnp.float32)
+    if solver == "lbfgs":
+        jax.jit(lambda: minimize_lbfgs(fn, w0, max_iter=3))()
+    else:
+        jax.jit(lambda: minimize_owlqn(fn, w0, l1_weight=0.1, max_iter=3))()
+    gauges = default_registry().snapshot()["gauges"]
+    assert gauges["solver/history/slot_rows"] == rows
+    assert gauges["solver/history/slot_lanes"] == lanes
+    assert gauges["solver/history/slot_bytes"] == rows * lanes * 4  # the pad included
+    reset_solver_metrics()
+    assert "solver/history/slot_bytes" not in default_registry().snapshot()["gauges"]

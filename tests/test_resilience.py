@@ -1134,6 +1134,60 @@ class TestCrashSafeStreamingResume:
         own.save_progress(**mid_solve)
         assert set(self._train(checkpointer=own)) == set(self.LAMS)
 
+    @pytest.mark.parametrize("written_as", ["row", "slab"])
+    def test_solver_state_of_the_other_history_form_fails_fast_named(
+            self, tmp_path, monkeypatch, written_as):
+        """A mid-solve snapshot whose ``s_hist`` has the OTHER form's shape for
+        its d (a slot is a row of ``[m, d]`` below ``optim/lbfgs.SLAB_MIN_DIM``
+        and whole tiles ``[m, R, 128]`` from it on; the edge is moved here, as
+        a later version might move it) is refused with the field and both
+        shapes named: never folded, never read as if it fitted. Under the rule
+        it was written under it resumes to the uninterrupted run's models."""
+        import re
+
+        from photon_ml_tpu.io.checkpoint import SolverCheckpointer
+        from photon_ml_tpu.optim import lbfgs
+
+        edge = {"row": lbfgs.SLAB_MIN_DIM, "slab": 1}
+        other = {"row": "slab", "slab": "row"}[written_as]
+        monkeypatch.setattr(lbfgs, "SLAB_MIN_DIM", edge[written_as])
+
+        ck = SolverCheckpointer(tmp_path / "ck")
+        saves = []
+        save_progress = ck.save_progress
+
+        def recording_save(**kw):
+            saves.append(kw)
+            return save_progress(**kw)
+
+        ck.save_progress = recording_save
+        base = self._train(checkpointer=ck)
+        mid_solve = next(kw for kw in saves if kw["solver_state"] is not None)
+        saved = tuple(mid_solve["solver_state"].s_hist.shape)
+        m, d = saved[0], int(mid_solve["solver_state"].w.shape[0])
+        forms = {"row": (m, d), "slab": (m, 8, 128)}
+        assert saved == forms[written_as]
+
+        own = SolverCheckpointer(tmp_path / "own")
+        own.save_progress(**mid_solve)
+        resumed = self._train(checkpointer=own)
+        for lam in self.LAMS:
+            np.testing.assert_array_equal(
+                np.asarray(base[lam].coefficients.means),
+                np.asarray(resumed[lam].coefficients.means),
+            )
+
+        monkeypatch.setattr(lbfgs, "SLAB_MIN_DIM", edge[other])
+        moved = SolverCheckpointer(tmp_path / "moved")
+        moved.save_progress(**mid_solve)
+        with pytest.raises(
+            ValueError,
+            match=r"resume_state\.s_hist has shape " + re.escape(str(forms[written_as]))
+            + r".* is stored as " + re.escape(str(forms[other]))
+            + r".*fresh checkpoint directory",
+        ):
+            self._train(checkpointer=moved)
+
 
 def _partitioned_fixture(num_ranks=2, n=32, d=4, seed=1):
     """In-memory dense-FE partitioned GAME fixture: ``num_ranks`` equal

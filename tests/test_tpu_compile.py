@@ -10,7 +10,8 @@ CG loop, under ``tron/hv``, named after its jitted wrapper by a name
 solve and never inside a loop; and (PR 44) ``glm/path_solve`` over the sparse
 cell's hybrid batch with its ELL view in width tiers compiles into a program
 of ordinary size whose tiers are gathered and scattered under the two
-``sparse/tail_*`` scopes.
+``sparse/tail_*`` scopes, and (PR 45) whose L-BFGS history keeps every slot
+as whole tiles, ``f32[10,157944,128]``, m an untiled major dimension.
 
 This is the ONE file that describes a topology: the TPU library is loaded by
 the process that runs these tests, inside a fixture, never at import.
@@ -192,3 +193,49 @@ def test_every_tier_is_gathered_and_scattered_under_the_tail_scopes(sparse_path_
         + r"sparse/tail_gradient/scatter-add", text)
     assert len(scatters) >= blocks
     assert all("sparse/" in op for op in ops)
+
+
+# -- the same program's L-BFGS history: a slot is whole tiles (PR 45) ----------
+
+HISTORY, SLOT_ROWS = 10, 157_944  # 8 * ceil(20,216,830 / 1,024): 20,216,832 floats
+#: what commit 79e1951's program, its history ``f32[10,20216830]``, holds in
+#: temporaries by the same compile (the change: 4,111,265,280)
+PARENT_TEMPORARIES = 6_213_742_080
+
+
+def test_a_history_slot_is_whole_tiles_read_and_shifted_under_its_scopes(
+        sparse_path_compiled):
+    """At d = 20,216,830 the history is ``[m, R, 128]`` with the tiles over
+    its last two dimensions: slot k is one contiguous slab, where ``[m, d]``
+    had m along the sublanes, ten rows stored as sixteen and every read of
+    one row moving eight (PERF.md 6, PR 45). The recursion's fusions stand
+    under ``lbfgs/direction`` and the shift under ``lbfgs/history``, where
+    ``benchmark/path_sparse_scopes.py`` looks for them."""
+    text = sparse_path_compiled.as_text()
+    slot = rf"{SLOT_ROWS},128"
+    assert f"f32[{HISTORY},{SLOT_ROWS},128]{{2,1,0:T(8,128)}}" in text
+    assert f"f32[{HISTORY},{SPARSE_FEATURES}]" not in text
+    # a visit reads one whole slot at the loop's counter, inside the recursion
+    reads = re.findall(rf"= f32\[1,{slot}\]\{{2,1,0:T\(8,128\)\}} dynamic-slice\([^\n]*", text)
+    assert len(reads) >= 4  # s and y, the backward loop and the forward one
+    for line in reads:
+        assert f"dynamic_slice_sizes={{1,{SLOT_ROWS},128}}" in line
+        assert "lbfgs/direction/while/body" in line
+    # the loops' own fusions (a dot and an update each) carry the scope
+    fusions = dict(re.findall(
+        r"%([\w.-]+) = [^\n]* fusion\([^\n]*op_name=\"([^\"]*)\"", text))
+    in_loops = [op for op in fusions.values() if "lbfgs/direction/while/body" in op]
+    assert len(in_loops) >= 4
+    # the shift writes the whole history, under lbfgs/history, and nowhere else
+    shifts = re.findall(
+        rf"= f32\[{HISTORY},{slot}\][^ ]* fusion\([^\n]*op_name=\"([^\"]*)\"", text)
+    assert len(shifts) == 2 and all("/lbfgs/history/" in op for op in shifts)
+    # the folds: a pad of two floats behind a bitcast, never a [10, ...] block
+    pads = re.findall(r"= f32\[20216832\][^ ]* pad\([^\n]*op_name=\"([^\"]*)\"", text)
+    assert pads and all("/lbfgs/" in op for op in pads)
+
+
+def test_the_slab_historys_temporaries_are_no_larger_than_the_row_forms(
+        sparse_path_compiled):
+    memory = sparse_path_compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= PARENT_TEMPORARIES
