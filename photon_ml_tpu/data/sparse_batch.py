@@ -15,14 +15,14 @@ ValueAndGradientAggregator.scala:133-154, generated by XLA instead.
 Padding entries carry value 0.0 (and col/row 0), contributing nothing to
 either direction.
 
-Optional faster views of the same entries (all strictly opt-in, all
-agreeing to tight tolerance — tests/test_sparse_hybrid.py): column-sorted
-(scatter-free gradient via sorted-run prefix sums), ELL (padded rows in
-width tiers that follow the rows' counts, the single-chip default; one
-[n, L] block at an explicit or agreed width), and the r6 hybrid dense-head/sparse-tail
-split (:class:`HybridPolicy`) — the nnz-hottest columns of a power-law
-feature space train as one dense MXU block with zero per-entry index ops,
-the cold residual rides the ELL tail.
+Two faster views of the same entries, agreeing with the flat triple to
+tight tolerance (tests/test_sparse_hybrid.py): ELL (padded rows in width
+tiers that follow the rows' counts, the single-chip default; one [n, L]
+block at an explicit or agreed width), and the opt-in r6 hybrid
+dense-head/sparse-tail split (:class:`HybridPolicy`) — the nnz-hottest
+columns of a power-law feature space train as one dense MXU block with zero
+per-entry index ops, the cold residual rides the ELL tail. Which of them a
+batch gets is decided in one place, :func:`_build_batch`.
 """
 
 from __future__ import annotations
@@ -374,25 +374,11 @@ class SparseLabeledPointBatch:
     offsets: Array
     weights: Array
     dim: int = flax.struct.field(pytree_node=False)
-    #: optional column-sorted view of the same entries, for a gradient
-    #: computed as a SORTED segment-sum over columns instead of a
-    #: random-index scatter-add into [dim] (the scatter dominates giant-d
-    #: solves on TPU). Built by column_sorted_gradient=True.
-    vals_by_col: Array | None = None
-    rows_by_col: Array | None = None
-    cols_sorted: Array | None = None
-    #: [dim+1] int32 — entry-index boundaries of each column's run in the
-    #: column-sorted view (col j's entries live at [col_bounds[j],
-    #: col_bounds[j+1])). Lets the gradient be a chunked prefix-sum +
-    #: boundary gather — no scatter and no giant-num_segments segment-sum,
-    #: both of which fail to compile/perform at d ~ 10⁷ on TPU.
-    col_bounds: Array | None = None
     #: optional ELL (padded-row) view, tier 0: [n, W_1] values / column ids,
     #: every row's first W_1 entries, pad slots carrying value 0 / col 0.
     #: Margins become one dense row-sum (no per-row segment-sum) and the dz
-    #: term broadcasts (no per-entry row gather) — measured 1.9x the
-    #: flat-COO iteration at d=10⁷ on v5e (BASELINE.md r3). The gradient's
-    #: transpose scatter falls out of autodiff unchanged.
+    #: term broadcasts (no per-entry row gather). The gradient's transpose
+    #: scatter falls out of autodiff unchanged.
     ell_vals: Array | None = None
     ell_cols: Array | None = None
     #: the ELL view's further width tiers (:class:`EllTier`), narrowest rows
@@ -412,10 +398,6 @@ class SparseLabeledPointBatch:
     #: with the last hot id (inert both directions). Strictly opt-in.
     hot_vals: Array | None = None      # [n, k_hot]
     hot_col_ids: Array | None = None   # [k_hot] int32 global column ids
-
-    @property
-    def has_column_sorted_view(self) -> bool:
-        return self.cols_sorted is not None
 
     @property
     def has_ell_view(self) -> bool:
@@ -450,35 +432,27 @@ class SparseLabeledPointBatch:
         return self.replace(offsets=offsets)
 
     def pad_nnz(self, target: int) -> "SparseLabeledPointBatch":
-        """Pad the entry axis to ``target`` with inert entries — value 0 and
-        the LAST row/col id of each sorted view, so both segment-sums'
+        """Pad the flat triple to ``target`` with inert entries — value 0,
+        column 0 and the LAST row id, so the row segment-sum's
         ``indices_are_sorted=True`` promise survives. The ONE owner of the
-        padding contract for device-resident batches (the column-sorted
-        view, when present, pads in lockstep)."""
+        padding contract for device-resident batches (``_pad_flat`` is the
+        builder's, on the host)."""
         pad = target - self.nnz
         if pad < 0:
             raise ValueError(f"cannot pad {self.nnz} entries down to {target}")
         if pad == 0:
             return self
-        def tail(a, fallback=0):
-            return a[-1:] if self.nnz else jnp.full((1,), fallback, a.dtype)
-        out = self.replace(
+        last_row = (
+            self.row_ids[-1:] if self.nnz
+            else jnp.zeros((1,), self.row_ids.dtype)
+        )
+        return self.replace(
             values=jnp.pad(self.values, (0, pad)),
             col_indices=jnp.pad(self.col_indices, (0, pad)),
             row_ids=jnp.concatenate(
-                [self.row_ids, jnp.broadcast_to(tail(self.row_ids), (pad,))]
+                [self.row_ids, jnp.broadcast_to(last_row, (pad,))]
             ),
         )
-        if self.has_column_sorted_view:
-            out = out.replace(
-                vals_by_col=jnp.pad(self.vals_by_col, (0, pad)),
-                rows_by_col=jnp.pad(self.rows_by_col, (0, pad)),
-                cols_sorted=jnp.concatenate(
-                    [self.cols_sorted,
-                     jnp.broadcast_to(tail(self.cols_sorted), (pad,))]
-                ),
-            )
-        return out
 
     def add_scores_to_offsets(self, scores: Array) -> "SparseLabeledPointBatch":
         """Residual update used by coordinate descent (DataSet.addScoresToOffsets)."""
@@ -492,19 +466,16 @@ class SparseLabeledPointBatch:
         offsets: Array,
         weights: Array,
         *,
-        column_sorted_gradient: bool = False,
         ell: bool | int | str = "auto",
         hybrid: "HybridPolicy | bool | None" = None,
     ) -> "SparseLabeledPointBatch":
-        """Bind a shard's device COO to per-sample labels/offsets/weights.
+        """Bind a shard's entries to per-sample labels/offsets/weights.
 
-        ell: "auto" (default) builds the padded-row ELL view — the fast TPU
-        margin/gradient layout (BASELINE.md r3) — in width tiers read off
-        the rows' counts (one [n, L] block at ``shard.ell_width`` where the
-        shard carries an agreed width); an int fixes ONE width; False keeps
-        pure flat COO.
-        ``column_sorted_gradient`` selects the hand-fused column path
-        instead and disables ELL (it consumes the full flat triple).
+        ell: "auto" (default) builds the padded-row ELL view in width tiers
+        read off the rows' counts (one [n, L] block at ``shard.ell_width``
+        where the shard carries an agreed width); an int fixes ONE width;
+        False keeps pure flat COO. The overflow beyond the view pads to the
+        shard's agreed ``flat_block_nnz`` where it carries one.
         hybrid: None (default) inherits the shard's attached
         ``hybrid_policy`` (the CLI grammar's ``hybrid=true``); False forces
         the hybrid view off; a HybridPolicy/True enables it — the hot head
@@ -515,63 +486,18 @@ class SparseLabeledPointBatch:
             shard.hybrid_policy if hybrid is None
             else resolve_hybrid_policy(hybrid)
         )
-        if policy is not None and column_sorted_gradient:
-            raise ValueError(
-                "hybrid and column_sorted_gradient are mutually "
-                "exclusive layouts (the hybrid tail rides the ELL view)"
-            )
-        if policy is not None or (ell and not column_sorted_gradient):
-            # no shard.device() here: the batch is built from fresh
-            # host-side arrays, and caching the unused flat triple would pin
-            # ~12 bytes/nnz of dead HBM on exactly the giant-d path
-            extra = {}
-            if policy is not None:
-                hot_vals, hot_ids, rows, cols, vals = shard.hybrid_split(policy)
-                extra = dict(
-                    hot_vals=jnp.asarray(hot_vals),
-                    hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
-                )
-            else:
-                rows, cols, vals = (np.asarray(a) for a in shard.coalesced())
-            tail_nnz = len(vals)
-            if ell:
-                ell_fields, (rows, cols, vals) = _ell_view(
-                    rows, cols, vals, shard.num_samples,
-                    shard.ell_width if ell == "auto" or ell is True
-                    else int(ell),
-                )
-                rows, cols, vals = _pad_flat_tail(
-                    rows, cols, vals, shard.flat_block_nnz
-                )
-                extra.update(ell_fields)
-            batch = cls(
-                values=jnp.asarray(vals),
-                col_indices=jnp.asarray(cols, dtype=jnp.int32),
-                row_ids=jnp.asarray(rows, dtype=jnp.int32),
-                labels=jnp.asarray(labels),
-                offsets=jnp.asarray(offsets),
-                weights=jnp.asarray(weights),
-                dim=int(shard.feature_dim),
-                **extra,
-            )
-            if policy is not None:
-                _record_tail_layout(policy.label, batch, tail_nnz)
-            return batch
-        dev = shard.device()
-        extra = {}
-        if column_sorted_gradient:
-            rows, cols, vals = shard.coalesced()
-            extra = _column_sorted_arrays(rows, cols, vals, len(dev.values),
-                                          dim=dev.dim)
-        return cls(
-            values=dev.values,
-            col_indices=dev.col_indices,
-            row_ids=dev.row_ids,
-            labels=jnp.asarray(labels),
-            offsets=jnp.asarray(offsets),
-            weights=jnp.asarray(weights),
-            dim=dev.dim,
-            **extra,
+        # no shard.device() here: the batch is built from fresh host-side
+        # arrays, and caching a flat triple the layout does not use would pin
+        # ~12 bytes/nnz of dead HBM on exactly the giant-d path
+        return _build_batch(
+            *shard.coalesced(), shard.num_samples, shard.feature_dim,
+            labels, offsets, weights,
+            policy=policy,
+            split=None if policy is None else shard.hybrid_split(policy),
+            ell=ell, agreed_width=shard.ell_width,
+            # the agreed length is the ELL overflow's; a flat-only triple
+            # holds every entry and stays at its natural length
+            pad_to=shard.flat_block_nnz if ell else None, pad_agreed=True,
         )
 
     @classmethod
@@ -587,7 +513,6 @@ class SparseLabeledPointBatch:
         weights=None,
         dtype=np.float32,
         pad_nnz_to: int | None = None,
-        column_sorted_gradient: bool = False,
         ell: bool | int | str = "auto",
         hybrid: "HybridPolicy | bool | None" = None,
     ) -> "SparseLabeledPointBatch":
@@ -599,18 +524,12 @@ class SparseLabeledPointBatch:
         ell: "auto" (default) builds the padded-row ELL view (fast TPU
         layout) in width tiers read off the rows' counts; an int fixes ONE
         [n, L] block; overflow entries stay in the flat tail, which
-        pad_nnz_to then applies to; False keeps pure flat COO;
-        ``column_sorted_gradient`` disables ELL.
+        pad_nnz_to then applies to; False keeps pure flat COO.
         hybrid: opt-in hybrid dense-head view (HybridPolicy/True); the
         hot head becomes a dense [n, k_hot] block and ELL/flat keep only
         the cold tail. Off (None/False) is bitwise-identical to before.
         """
         policy = resolve_hybrid_policy(hybrid)
-        if policy is not None and column_sorted_gradient:
-            raise ValueError(
-                "hybrid and column_sorted_gradient are mutually exclusive "
-                "layouts (the hybrid tail rides the ELL view)"
-            )
         rows = np.asarray(rows, dtype=np.int64)
         cols = np.asarray(cols, dtype=np.int64)
         vals = np.asarray(vals, dtype=dtype)
@@ -619,32 +538,6 @@ class SparseLabeledPointBatch:
         _check_index_ranges(rows, cols, n, dim)
         # coalesced output is row-major sorted (segment_sum wants sorted rows)
         rows, cols, vals = coalesce_coo(rows, cols, vals)
-        hybrid_extra = {}
-        if policy is not None:
-            hot_vals, hot_ids, rows, cols, vals = _hybrid_arrays(
-                rows, cols, vals, n, dim, policy
-            )
-            hybrid_extra = dict(
-                hot_vals=jnp.asarray(hot_vals),
-                hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
-            )
-        tail_nnz = len(vals)
-        ell_extra = {}
-        if ell and not column_sorted_gradient:
-            ell_extra, (rows, cols, vals) = _ell_view(
-                rows, cols, vals, n,
-                None if ell == "auto" or ell is True else int(ell),
-            )
-        nnz = len(vals)
-        target = max(pad_nnz_to or 0, nnz)
-        if target > nnz:
-            # pad entries carry value 0 and the LAST row id, keeping the
-            # row_ids sorted (segment_sum is told indices_are_sorted=True —
-            # a correctness promise, not just a hint)
-            pad_row = int(rows[-1]) if nnz else 0
-            rows = np.pad(rows, (0, target - nnz), constant_values=pad_row)
-            cols = np.pad(cols, (0, target - nnz))
-            vals = np.pad(vals, (0, target - nnz))
         offsets = (
             np.zeros((n,), dtype=dtype) if offsets is None
             else np.asarray(offsets, dtype=dtype)
@@ -653,25 +546,71 @@ class SparseLabeledPointBatch:
             np.ones((n,), dtype=dtype) if weights is None
             else np.asarray(weights, dtype=dtype)
         )
-        extra = (
-            _column_sorted_arrays(rows, cols, vals, len(vals), dim=dim)
-            if column_sorted_gradient else {}
+        return _build_batch(
+            rows, cols, vals, n, dim, labels, offsets, weights,
+            policy=policy, ell=ell, pad_to=pad_nnz_to,
         )
-        batch = cls(
-            values=jnp.asarray(vals),
-            col_indices=jnp.asarray(cols, dtype=jnp.int32),
-            row_ids=jnp.asarray(rows, dtype=jnp.int32),
-            labels=jnp.asarray(labels),
-            offsets=jnp.asarray(offsets),
-            weights=jnp.asarray(weights),
-            dim=int(dim),
-            **extra,
-            **ell_extra,
-            **hybrid_extra,
+
+
+def _build_batch(
+    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, n: int, dim: int,
+    labels, offsets, weights, *,
+    policy: "HybridPolicy | None",
+    split: tuple | None = None,
+    ell: bool | int | str,
+    agreed_width: int | None = None,
+    pad_to: int | None = None,
+    pad_agreed: bool = False,
+) -> SparseLabeledPointBatch:
+    """The ONE place a batch's layout is decided: ``from_coo`` and
+    ``from_shard`` both end here, with row-major coalesced host triples.
+
+    In order: the hybrid head split under ``policy`` (``split``: a shard's
+    cached ``hybrid_split(policy)``, taken in place of a fresh one) -> the
+    ELL view of what is left (``ell``: "auto"/True takes width tiers off the
+    rows' counts, or ONE block at ``agreed_width`` where a shard carries
+    one; an int takes ONE block of that width; False takes none) -> the
+    flat triple (every entry, or only the view's overflow) padded to
+    ``pad_to`` under ``_pad_flat``'s contract -> the dataclass -> the tail's
+    gauges under the policy's label."""
+    fields = {}
+    if policy is not None:
+        if split is None:
+            split = _hybrid_arrays(rows, cols, vals, n, dim, policy)
+        hot_vals, hot_ids, rows, cols, vals = split
+        fields.update(
+            hot_vals=jnp.asarray(hot_vals),
+            hot_col_ids=jnp.asarray(hot_ids, dtype=jnp.int32),
         )
-        if policy is not None:
-            _record_tail_layout(policy.label, batch, tail_nnz)
-        return batch
+    tail_nnz = len(vals)
+    if ell:
+        ell_vals, ell_cols, tiers, rows, cols, vals = _ell_arrays(
+            rows, cols, vals, n,
+            agreed_width if ell == "auto" or ell is True else int(ell),
+        )
+        fields.update(
+            ell_vals=jnp.asarray(ell_vals),
+            ell_cols=jnp.asarray(ell_cols),
+            ell_tiers=tuple(
+                EllTier(vals=jnp.asarray(v), cols=jnp.asarray(c),
+                        row_ids=jnp.asarray(r))
+                for v, c, r in tiers
+            ),
+        )
+    rows, cols, vals = _pad_flat(rows, cols, vals, pad_to, agreed=pad_agreed)
+    batch = SparseLabeledPointBatch(
+        values=jnp.asarray(vals),
+        col_indices=jnp.asarray(cols, dtype=jnp.int32),
+        row_ids=jnp.asarray(rows, dtype=jnp.int32),
+        labels=jnp.asarray(labels),
+        offsets=jnp.asarray(offsets),
+        weights=jnp.asarray(weights),
+        dim=int(dim),
+        **fields,
+    )
+    if policy is not None:
+        _record_tail_layout(policy.label, batch, tail_nnz)
+    return batch
 
 
 #: one-width ELL rule (an agreed width: the partitioned reader's and the
@@ -794,24 +733,6 @@ def _ell_arrays(
     return ell_vals, ell_cols, tiers, rows[tail], cols[tail], vals[tail]
 
 
-def _ell_view(
-    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, n: int,
-    width: int | None,
-) -> tuple[dict, tuple[np.ndarray, np.ndarray, np.ndarray]]:
-    """``_ell_arrays`` as a batch's fields (placed) and the overflow triple."""
-    ev, ec, tiers, tr, tc, tv = _ell_arrays(rows, cols, vals, n, width)
-    fields = dict(
-        ell_vals=jnp.asarray(ev),
-        ell_cols=jnp.asarray(ec),
-        ell_tiers=tuple(
-            EllTier(vals=jnp.asarray(v), cols=jnp.asarray(c),
-                    row_ids=jnp.asarray(r))
-            for v, c, r in tiers
-        ),
-    )
-    return fields, (tr, tc, tv)
-
-
 def _ell_auto_width(counts: np.ndarray, n: int, nnz: int) -> int:
     """The ONE width of an agreed [n, L] block (a mesh's, the partitioned
     reader's): smallest L covering the 98th-percentile row while keeping n·L
@@ -860,25 +781,28 @@ def _ell_auto_width_from_hist(freq: np.ndarray, n: int, nnz: int) -> int:
     return width
 
 
-def _pad_flat_tail(
+def _pad_flat(
     rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
-    target: int | None,
+    target: int | None, *, agreed: bool,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Pad an ELL overflow triple to the agreed per-rank flat block length
-    (SparseShard.flat_block_nnz): value 0 / col 0 / the LAST real row id —
-    ``pad_nnz``'s inert-entry contract, which also keeps the concatenated
-    cross-rank row_ids nondecreasing (the next rank's rows start past this
-    rank's block)."""
+    """Pad a batch's flat triple to ``target`` entries under the one
+    inert-entry contract: value 0 / col 0 / the LAST real row id, so that
+    the row ids stay sorted (segment_sum is told ``indices_are_sorted=True``
+    — a correctness promise, not just a hint) and the concatenated
+    cross-rank row ids stay nondecreasing (the next rank's rows start past
+    this rank's block). ``agreed``: the target is the length every rank's
+    block must have (``SparseShard.flat_block_nnz``), so a triple longer
+    than it raises; a ``pad_nnz_to`` below the count pads nothing."""
     if target is None:
         return rows, cols, vals
     pad = int(target) - len(vals)
-    if pad < 0:
+    if pad < 0 and agreed:
         raise ValueError(
             f"flat overflow tail has {len(vals)} entries but the agreed "
             f"flat_block_nnz is {target} — the reader's ELL-width exchange "
             "must cover every rank's overflow"
         )
-    if pad == 0:
+    if pad <= 0:
         return rows, cols, vals
     last = rows[-1] if len(rows) else 0
     return (
@@ -1031,40 +955,6 @@ def _record_tail_layout(
     )
 
 
-def _column_sorted_arrays(
-    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, pad_to: int,
-    dim: int | None = None,
-) -> dict:
-    """The column-sorted view of the entries (host-side sort), padded with
-    inert entries (value 0) carrying the LAST column id so the sorted
-    promise holds for the column segment-sum too.
-
-    Also precomputes ``col_bounds`` ([dim+1] int32 run boundaries) when
-    ``dim`` is given — the device-side gradient then reduces each column's
-    contiguous run via prefix sums gathered at these static positions
-    (ops/sparse_objective.py), instead of a scatter over [dim]."""
-    order = np.argsort(cols, kind="stable")
-    vc, rc, cc = vals[order], rows[order], cols[order]
-    bounds = {}
-    if dim is not None:
-        counts = np.bincount(cc, minlength=dim)
-        cb = np.zeros(dim + 1, dtype=np.int64)
-        np.cumsum(counts, out=cb[1:])
-        bounds = dict(col_bounds=jnp.asarray(cb, dtype=jnp.int32))
-    pad = pad_to - len(vc)
-    if pad > 0:
-        pad_col = int(cc[-1]) if len(cc) else 0
-        vc = np.pad(vc, (0, pad))
-        rc = np.pad(rc, (0, pad))
-        cc = np.pad(cc, (0, pad), constant_values=pad_col)
-    return dict(
-        vals_by_col=jnp.asarray(vc),
-        rows_by_col=jnp.asarray(rc, dtype=jnp.int32),
-        cols_sorted=jnp.asarray(cc, dtype=jnp.int32),
-        **bounds,
-    )
-
-
 def hot_head_dot(a: Array, b: Array) -> Array:
     """A dot with the dense hot head as one operand, held to float32: a TPU's
     DEFAULT matmul precision lets the compiler round float32 operands to
@@ -1081,8 +971,7 @@ def sparse_product(batch: SparseLabeledPointBatch, coefficients: Array) -> Array
     model scores a sparse block with (models/coefficients.py).
 
     ELL view: one gather + a DENSE row-sum a width tier — no per-row
-    segment-sum at all (the segment-sum alone costs ~7.5 ns/entry on v5e,
-    BASELINE.md r3); a further tier's [n_k] sums are added into [n] at its
+    segment-sum at all; a further tier's [n_k] sums are added into [n] at its
     row ids (ascending and unique: no sort, no conflict); only overflow-tail
     entries, if any, take the segment-sum path. Flat COO: gather w at the
     nonzero columns, multiply, segment-sum by row. Either way autodiff

@@ -42,54 +42,6 @@ from photon_ml_tpu.ops.objective import BoundObjective
 
 Array = jax.Array
 
-#: chunk width of the sorted-run reduction: bounds the magnitude any prefix
-#: difference can cancel against (f32 error ~ eps·|within-chunk prefix|) and
-#: keeps the [C, B] cumsum VPU-friendly
-_RUN_CHUNK = 4096
-
-
-def _sorted_run_sums(contrib: Array, bounds: Array) -> Array:
-    """Sum each contiguous run of a (column-)sorted contribution vector.
-
-    ``bounds`` is the [dim+1] int32 run-boundary array (run j =
-    ``contrib[bounds[j]:bounds[j+1]]``, precomputed on host by
-    ``_column_sorted_arrays``). TPU-native replacement for
-    ``segment_sum(..., num_segments=dim)``: a two-level prefix sum over
-    [C, B] chunks plus one gather per boundary —
-        P(p) = chunk_prefix[p // B] + intra_chunk_cumsum[p]
-        run_sum[j] = P(bounds[j+1]-1) - P(bounds[j]-1)
-    Everything is cumsum/reshape/gather (bandwidth-bound, compiles in
-    seconds at any dim); no scatter appears anywhere. Empty runs subtract
-    identical gathers and come out exactly 0. Cross-chunk cancellation only
-    touches runs that span a chunk edge, whose sums are large relative to
-    the f32 error it introduces.
-    """
-    nnz = contrib.shape[0]
-    pad = (-nnz) % _RUN_CHUNK
-    if pad:
-        contrib = jnp.pad(contrib, (0, pad))
-    c2 = contrib.reshape(-1, _RUN_CHUNK)
-    intra = jnp.cumsum(c2, axis=1)
-    chunk_prefix = jnp.concatenate(
-        [jnp.zeros((1,), intra.dtype), jnp.cumsum(intra[:, -1])]
-    )
-    intra_flat = intra.reshape(-1)
-    end = bounds[1:] - 1
-    start = bounds[:-1] - 1
-
-    def parts(pos):
-        safe = jnp.maximum(pos, 0)
-        valid = pos >= 0
-        i = jnp.where(valid, intra_flat[safe], 0.0)
-        p = jnp.where(valid, chunk_prefix[safe // _RUN_CHUNK], 0.0)
-        return i, p
-
-    i_end, p_end = parts(end)
-    i_start, p_start = parts(start)
-    # grouped so same-chunk runs cancel the chunk prefix exactly
-    return (i_end - i_start) + (p_end - p_start)
-
-
 class SparseGLMObjective:
     """Sparse twin of GLMObjective: same interface, flat-COO batches.
 
@@ -149,8 +101,6 @@ class SparseGLMObjective:
     ) -> tuple[Array, Array]:
         if batch.has_hybrid_view:
             return self._value_and_gradient_hybrid(coefficients, batch)
-        if batch.has_column_sorted_view:
-            return self._value_and_gradient_column_sorted(coefficients, batch)
         return jax.value_and_grad(self.value)(coefficients, batch)
 
     def _tail_gradient_update(
@@ -191,8 +141,9 @@ class SparseGLMObjective:
                    covered nonzeros)
             tail:  the existing ELL/flat transpose scatters, now over the
                    cold residual only
-        with the full normalization algebra of the column-sorted path:
-            ∂/∂w = f ⊙ (Σ dz·x − (Σ dz)·shifts) + λw.
+        with the full normalization algebra (f = factors, dz = w_i·l'_i):
+            margin_i = Σ vals·eff[cols] − eff·shifts + offsets
+            ∂/∂w     = f ⊙ (Σ dz·x − (Σ dz)·shifts) + λw.
         Verified against the flat autodiff path in tests (the view-contract
         property test)."""
         margins = self.margins(coefficients, batch)
@@ -215,70 +166,20 @@ class SparseGLMObjective:
             grad = grad + self.l2_weight * coefficients
         return total, grad
 
-    def _value_and_gradient_column_sorted(
-        self, coefficients: Array, batch: SparseLabeledPointBatch
-    ) -> tuple[Array, Array]:
-        """Hand-fused value+gradient using the batch's column-sorted view.
-
-        The autodiff gradient transposes the margin gather into a
-        random-index scatter-add over [dim] — the dominant cost of giant-d
-        solves on TPU (733 ms/iter at d=10⁷, ~0.1 GB/s useful traffic:
-        measured before PR 21, not since). With the entries pre-sorted by
-        column, each column's contributions form one contiguous run, and the
-        whole reduction becomes chunked prefix sums + a boundary gather
-        (:func:`_sorted_run_sums`) — cumsum/gather only, no scatter and no
-        giant-``num_segments`` segment-sum (the latter failed to compile at
-        d=10⁷ on the TPU compile service, BASELINE.md r2). Full
-        normalization algebra:
-            margin_i = Σ vals·eff[cols] − eff·shifts + offsets
-            ∂/∂w     = f ⊙ (Σ_col dz·x  −  (Σ_i dz_i)·shifts) + λw
-        (f = factors; dz = w_i·l'_i). Verified against the autodiff path in
-        tests.
-        """
-        margins = self.margins(coefficients, batch)
-        losses, dz = self.loss.loss_and_dz(margins, batch.labels)
-        total = jnp.sum(batch.weights * losses)
-        dzw = batch.weights * dz
-        contrib = dzw[batch.rows_by_col] * batch.vals_by_col
-        if batch.col_bounds is not None:
-            g_eff = _sorted_run_sums(contrib, batch.col_bounds)
-        else:
-            g_eff = jax.ops.segment_sum(
-                contrib, batch.cols_sorted,
-                num_segments=batch.dim, indices_are_sorted=True,
-            )
-        norm = self.normalization
-        if norm.shifts is not None:
-            g_eff = g_eff - jnp.sum(dzw) * norm.shifts
-        grad = g_eff * norm.factors if norm.factors is not None else g_eff
-        if self.axis_name is not None:
-            total = jax.lax.psum(total, self.axis_name)
-            grad = jax.lax.psum(grad, self.axis_name)
-        if self.l2_weight > 0.0:
-            total = total + 0.5 * self.l2_weight * jnp.vdot(
-                coefficients, coefficients
-            )
-            grad = grad + self.l2_weight * coefficients
-        return total, grad
-
     def gradient(self, coefficients: Array, batch: SparseLabeledPointBatch) -> Array:
         return self.value_and_gradient(coefficients, batch)[1]
 
     def hessian_vector(
         self, coefficients: Array, vector: Array, batch: SparseLabeledPointBatch
     ) -> Array:
-        """H @ v. With a column-sorted view (and no margin shifts) this is
-        the scatter-free ladder TRON needs at giant d:
+        """H @ v. Hybrid view (and no margin shifts):
             H v = f ⊙ (Xᵀ D X (f ⊙ v)) + λ v,   D = diag(w_i·l''_i)
-        — a row gather/segment-sum forward, then the same sorted-run
-        reduction as the gradient. Otherwise forward-over-reverse jvp of
-        the gradient, same as the dense path (TRON calls this per CG step).
-
-        Hybrid view (and no margin shifts): the identical dense-head /
-        sparse-tail split as the gradient — forward X(f·v) rides the hot
-        MXU matmul + cold tail, and the transpose assembles as the head
-        matvec + k_hot scatter plus the tail scatters. This is TRON's CG
-        inner loop at giant d (d=10⁸).
+        with the identical dense-head / sparse-tail split as the gradient —
+        forward X(f·v) rides the hot MXU matmul + cold tail, and the
+        transpose assembles as the head matvec + k_hot scatter plus the
+        tail scatters. This is TRON's CG inner loop at giant d (d=10⁸).
+        Otherwise forward-over-reverse jvp of the gradient, same as the
+        dense path (TRON calls this per CG step).
         """
         norm = self.normalization
         if batch.has_hybrid_view and norm.shifts is None:
@@ -289,26 +190,6 @@ class SparseGLMObjective:
             t = d2w * mv
             hv_eff = self._head_gradient(t, batch)
             hv_eff = self._tail_gradient_update(hv_eff, t, batch)
-            hv = hv_eff * norm.factors if norm.factors is not None else hv_eff
-            if self.axis_name is not None:
-                hv = jax.lax.psum(hv, self.axis_name)
-            if self.l2_weight > 0.0:
-                hv = hv + self.l2_weight * vector
-            return hv
-        if batch.has_column_sorted_view and norm.shifts is None:
-            eff_v = norm.effective_coefficients(vector)
-            mv = sparse_product(batch, eff_v)  # pure X @ f·v, no offsets
-            margins = self.margins(coefficients, batch)
-            d2w = self.loss.d2z(margins, batch.labels) * batch.weights
-            t = d2w * mv
-            contrib = t[batch.rows_by_col] * batch.vals_by_col
-            if batch.col_bounds is not None:
-                hv_eff = _sorted_run_sums(contrib, batch.col_bounds)
-            else:
-                hv_eff = jax.ops.segment_sum(
-                    contrib, batch.cols_sorted,
-                    num_segments=batch.dim, indices_are_sorted=True,
-                )
             hv = hv_eff * norm.factors if norm.factors is not None else hv_eff
             if self.axis_name is not None:
                 hv = jax.lax.psum(hv, self.axis_name)

@@ -721,19 +721,6 @@ class GameTrainProgram:
                     hot_vals=put(sb.hot_vals, NamedSharding(mesh, P("data", None))),
                     hot_col_ids=put(sb.hot_col_ids, NamedSharding(mesh, P())),
                 )
-            if sb.has_column_sorted_view:
-                sb = sb.replace(
-                    vals_by_col=put(sb.vals_by_col, vec),
-                    rows_by_col=put(sb.rows_by_col, vec),
-                    cols_sorted=put(sb.cols_sorted, vec),
-                )
-                if sb.col_bounds is not None:
-                    # [dim+1] run boundaries ride with the coefficient
-                    # vector's layout (replicated; model-sharding of giant d
-                    # splits the batch by columns before it gets here)
-                    sb = sb.replace(
-                        col_bounds=put(sb.col_bounds, NamedSharding(mesh, P()))
-                    )
             data["fe_sparse_batch"] = sb
         if "re_sparse" in data:
             # compact RE entry mappings: nnz axis over "data"; pads carry

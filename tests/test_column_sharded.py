@@ -20,7 +20,9 @@ from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.sparse_objective import SparseGLMObjective
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType, solve
 from photon_ml_tpu.parallel.column_sharded import (
+    _RUN_CHUNK,
     ColumnShardedGLMObjective,
+    _sorted_run_sums,
     build_column_sharded_batch,
     init_column_sharded_coefficients,
     shard_column_batch,
@@ -44,6 +46,28 @@ def _problem(seed=0, n=120, d=37, nnz=600):
 
 def _put_model(mesh, x):
     return jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("model")))
+
+
+@pytest.mark.parametrize("nnz,runs", [
+    (300, 37),                    # inside one chunk
+    (3 * _RUN_CHUNK + 17, 50),    # runs that span chunk edges
+    (2 * _RUN_CHUNK, 5000),       # more runs than entries in places: empty runs
+    (1, 3),                       # one entry, two of three runs empty
+])
+def test_sorted_run_sums_equal_the_runs_own_sums(nnz, runs):
+    """The gradient's scatter-free reduction: every contiguous run of the
+    column-sorted contributions sums to what numpy adds up, empty runs to 0."""
+    rng = np.random.default_rng(nnz + runs)
+    contrib = rng.normal(size=nnz)
+    cuts = np.sort(rng.integers(0, nnz + 1, size=runs - 1))
+    bounds = np.concatenate([[0], cuts, [nnz]])
+    want = np.array([contrib[a:b].sum() for a, b in zip(bounds[:-1], bounds[1:])])
+    got = _sorted_run_sums(
+        jnp.asarray(contrib), jnp.asarray(bounds, dtype=jnp.int32)
+    )
+    assert got.shape == (runs,)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-9, atol=1e-11)
+    assert np.all(np.asarray(got)[np.diff(bounds) == 0] == 0.0)
 
 
 class TestColumnShardedObjective:

@@ -351,135 +351,6 @@ class TestSparseObjective:
         )
 
 
-class TestColumnSortedGradient:
-    def _batch(self, seed=30, sorted_grad=True, pad=0):
-        rng = np.random.default_rng(seed)
-        n, d, nnz = 80, 14, 400
-        rows, cols, vals = _random_coo(n, d, nnz, seed, duplicates=True)
-        labels = (rng.random(n) < 0.5).astype(np.float64)
-        return SparseLabeledPointBatch.from_coo(
-            rows, cols, vals, labels, dim=d,
-            offsets=rng.normal(scale=0.1, size=n),
-            weights=rng.uniform(0.5, 2.0, size=n),
-            dtype=np.float64,
-            pad_nnz_to=nnz + pad if pad else None,
-            column_sorted_gradient=sorted_grad,
-        )
-
-    @pytest.mark.parametrize("task", [
-        TaskType.LOGISTIC_REGRESSION, TaskType.POISSON_REGRESSION,
-    ])
-    def test_matches_autodiff(self, task):
-        sb = self._batch()
-        plain = sb.replace(vals_by_col=None, rows_by_col=None, cols_sorted=None)
-        so = SparseGLMObjective(loss_for_task(task), l2_weight=0.4)
-        w = jnp.asarray(np.random.default_rng(31).normal(scale=0.1, size=sb.dim))
-        v1, g1 = so.value_and_gradient(w, sb)       # column-sorted path
-        v2, g2 = so.value_and_gradient(w, plain)    # autodiff path
-        np.testing.assert_allclose(float(v1), float(v2), rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-9)
-
-    def test_matches_autodiff_with_normalization(self):
-        rng = np.random.default_rng(32)
-        sb = self._batch(seed=33)
-        norm = NormalizationContext(
-            factors=jnp.asarray(rng.uniform(0.5, 2.0, size=sb.dim)),
-            shifts=jnp.asarray(rng.normal(scale=0.2, size=sb.dim)),
-        )
-        so = SparseGLMObjective(
-            loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=0.2,
-            normalization=norm,
-        )
-        plain = sb.replace(vals_by_col=None, rows_by_col=None, cols_sorted=None)
-        w = jnp.asarray(rng.normal(scale=0.1, size=sb.dim))
-        v1, g1 = so.value_and_gradient(w, sb)
-        v2, g2 = so.value_and_gradient(w, plain)
-        np.testing.assert_allclose(float(v1), float(v2), rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-9)
-
-    def test_padding_is_inert_in_column_view(self):
-        sb = self._batch(seed=34, pad=33)
-        plain = self._batch(seed=34, pad=0)
-        so = SparseGLMObjective(loss_for_task(TaskType.LOGISTIC_REGRESSION))
-        w = jnp.asarray(np.random.default_rng(35).normal(size=sb.dim))
-        v1, g1 = so.value_and_gradient(w, sb)
-        v2, g2 = so.value_and_gradient(w, plain)
-        np.testing.assert_allclose(float(v1), float(v2), rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-10)
-
-    def test_segment_sum_fallback_matches(self):
-        """col_bounds=None falls back to the sorted segment-sum — both
-        reductions of the column-sorted view agree with autodiff."""
-        sb = self._batch(seed=37)
-        no_bounds = sb.replace(col_bounds=None)
-        plain = sb.replace(
-            vals_by_col=None, rows_by_col=None, cols_sorted=None,
-            col_bounds=None,
-        )
-        so = SparseGLMObjective(
-            loss_for_task(TaskType.LOGISTIC_REGRESSION), l2_weight=0.3
-        )
-        w = jnp.asarray(np.random.default_rng(38).normal(scale=0.1, size=sb.dim))
-        _, g_bounds = so.value_and_gradient(w, sb)
-        _, g_seg = so.value_and_gradient(w, no_bounds)
-        _, g_auto = so.value_and_gradient(w, plain)
-        np.testing.assert_allclose(np.asarray(g_bounds), np.asarray(g_auto), rtol=1e-9)
-        np.testing.assert_allclose(np.asarray(g_seg), np.asarray(g_auto), rtol=1e-9)
-
-    @pytest.mark.parametrize("with_factors", [False, True])
-    def test_hessian_vector_matches_autodiff(self, with_factors):
-        """The scatter-free Hv (TRON's CG ladder at giant d) equals the
-        forward-over-reverse jvp, with and without factor normalization."""
-        rng = np.random.default_rng(39)
-        sb = self._batch(seed=40)
-        norm = None
-        if with_factors:
-            norm = NormalizationContext(
-                factors=jnp.asarray(rng.uniform(0.5, 2.0, size=sb.dim)),
-                shifts=None,
-            )
-        so = SparseGLMObjective(
-            loss_for_task(TaskType.POISSON_REGRESSION), l2_weight=0.7,
-            normalization=norm,
-        )
-        plain = sb.replace(
-            vals_by_col=None, rows_by_col=None, cols_sorted=None,
-            col_bounds=None,
-        )
-        w = jnp.asarray(rng.normal(scale=0.1, size=sb.dim))
-        v = jnp.asarray(rng.normal(size=sb.dim))
-        hv_fast = so.hessian_vector(w, v, sb)
-        hv_auto = so.hessian_vector(w, v, plain)
-        np.testing.assert_allclose(
-            np.asarray(hv_fast), np.asarray(hv_auto), rtol=1e-8
-        )
-
-    def test_solver_equivalence(self):
-        from photon_ml_tpu.estimators import train_glm
-
-        rng = np.random.default_rng(36)
-        n, d = 300, 8
-        x = rng.normal(size=(n, d))
-        y = (x @ rng.normal(size=d) > 0).astype(np.float64)
-        rows, cols = np.nonzero(x)
-        common = dict(dim=d, dtype=np.float64)
-        sb_sorted = SparseLabeledPointBatch.from_coo(
-            rows, cols, x[rows, cols], y, column_sorted_gradient=True, **common
-        )
-        sb_plain = SparseLabeledPointBatch.from_coo(
-            rows, cols, x[rows, cols], y, **common
-        )
-        m1 = train_glm(sb_sorted, TaskType.LOGISTIC_REGRESSION,
-                       regularization_weights=[1.0])
-        m2 = train_glm(sb_plain, TaskType.LOGISTIC_REGRESSION,
-                       regularization_weights=[1.0])
-        np.testing.assert_allclose(
-            np.asarray(m1[1.0].coefficients.means),
-            np.asarray(m2[1.0].coefficients.means),
-            atol=1e-8,
-        )
-
-
 class TestSparseTraining:
     @pytest.mark.parametrize("opt_type", ["LBFGS", "TRON"])
     def test_train_glm_matches_dense(self, opt_type):

@@ -3,9 +3,8 @@
 The reference keeps name-term feature bags sparse end to end
 (AvroDataReader.scala:165-200); those bags are power-law distributed, so a
 small hot-column head carries most nonzeros. These tests pin the hybrid
-view's contract: every sparse view of the same shard (flat COO,
-column-sorted, ELL, hybrid) computes identical value/gradient/
-hessian_vector; hybrid OFF is bitwise-identical to the pre-existing
+view's contract: every sparse view of the same shard (flat COO, ELL,
+hybrid) computes identical value/gradient/hessian_vector; hybrid OFF is bitwise-identical to the pre-existing
 layouts; the pad/offsets lifecycle keeps all views in lockstep; the
 column-sharded hot head is sharding-invariant (1-device == 8-device); and
 the CLI grammar + partitioned-io guard behave.
@@ -56,7 +55,7 @@ def _data(n=80, d=40, nnz=600, seed=0):
 
 
 def _views(seed=0, n=80, d=40, nnz=600):
-    """All four views of the same shard, keyed by name."""
+    """Every view of the same shard, keyed by name."""
     rows, cols, vals, labels, offsets, weights = _data(n, d, nnz, seed)
     common = dict(dim=d, offsets=offsets, weights=weights, dtype=np.float64)
     build = lambda **kw: SparseLabeledPointBatch.from_coo(
@@ -64,7 +63,6 @@ def _views(seed=0, n=80, d=40, nnz=600):
     )
     return {
         "flat": build(ell=False),
-        "column_sorted": build(ell=False, column_sorted_gradient=True),
         "ell": build(),
         "ell_narrow": build(ell=2),  # forces a large overflow tail
         "hybrid": build(hybrid=HybridPolicy(coverage=0.6, pad_multiple=4)),
@@ -77,8 +75,19 @@ def _views(seed=0, n=80, d=40, nnz=600):
     }
 
 
+def _assert_same_leaves(got, want):
+    """Two batches equal leaf for leaf: same tree, dtypes, shapes, bits."""
+    got_leaves, got_tree = jax.tree_util.tree_flatten_with_path(got)
+    want_leaves, want_tree = jax.tree_util.tree_flatten_with_path(want)
+    assert got_tree == want_tree
+    for (path, a), (_, b) in zip(got_leaves, want_leaves):
+        name = jax.tree_util.keystr(path)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
 class TestViewContract:
-    """Flat-COO vs column-sorted vs ELL vs hybrid views of the same shard
+    """Flat-COO vs ELL vs hybrid views of the same shard
     agree on value/gradient/hessian_vector (ISSUE 5 property test)."""
 
     @pytest.mark.parametrize("seed", [0, 7, 23])
@@ -235,11 +244,7 @@ class TestHybridOffBitwise:
         )
         assert not off_batch.has_hybrid_view
         assert off_batch.hot_vals is None and off_batch.hot_col_ids is None
-        base_leaves = jax.tree_util.tree_leaves(base)
-        off_leaves = jax.tree_util.tree_leaves(off_batch)
-        assert len(base_leaves) == len(off_leaves)
-        for a, b in zip(base_leaves, off_leaves):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        _assert_same_leaves(off_batch, base)
 
     def test_objective_outputs_bitwise_identical(self):
         rows, cols, vals, labels, offsets, weights = _data(seed=12)
@@ -1068,14 +1073,6 @@ class TestCliGrammar:
         batch = result.dataset.fixed_effect_batch("g")
         assert batch.has_hybrid_view  # inherited through from_shard
 
-    def test_hybrid_incompatible_with_column_sorted(self):
-        rows, cols, vals, labels, _, _ = _data(seed=41)
-        with pytest.raises(ValueError, match="mutually exclusive"):
-            SparseLabeledPointBatch.from_coo(
-                rows, cols, vals, labels, dim=40,
-                column_sorted_gradient=True, hybrid=True,
-            )
-
 
 class TestHybridSplitCache:
     def test_from_shard_reuses_split_across_rebuilds(self):
@@ -1111,6 +1108,96 @@ class TestHybridSplitCache:
         counters = default_registry().snapshot()["counters"]
         assert counters["layout/cache/builds"] == 2
         reset_layout_metrics()
+
+
+_ONE_BUILDER_LAYOUTS = {
+    "flat": dict(ell=False),
+    "ell_auto": dict(),
+    "ell_width_overflow": dict(ell=4),
+    "hybrid_hot_cols": dict(
+        hybrid=HybridPolicy(hot_cols=8, pad_multiple=8, label="t_one")),
+    "hybrid_coverage": dict(
+        hybrid=HybridPolicy(coverage=0.4, pad_multiple=8, label="t_one")),
+    "hybrid_flat_tail": dict(
+        ell=False,
+        hybrid=HybridPolicy(hot_cols=8, pad_multiple=8, label="t_one")),
+}
+
+
+class TestOneBuilder:
+    """``from_shard`` and ``from_coo`` decide a batch's layout in one place
+    (``_build_batch``, ISSUE 46): the same triples give the same leaves."""
+
+    @staticmethod
+    def _both(dtype=np.float64, shard_kw=None, coo_kw=None, **kw):
+        rows, cols, vals, labels, offsets, weights = _tiered_coo()
+        from_coo = SparseLabeledPointBatch.from_coo(
+            rows, cols, vals, labels, dim=300, offsets=offsets,
+            weights=weights, dtype=dtype, **kw, **(coo_kw or {}),
+        )
+        shard = SparseShard(
+            rows, cols, vals.astype(dtype), 4000, 300, **(shard_kw or {})
+        )
+        from_shard = SparseLabeledPointBatch.from_shard(
+            shard, labels.astype(dtype), offsets.astype(dtype),
+            weights.astype(dtype), **kw,
+        )
+        return from_shard, from_coo
+
+    @pytest.mark.parametrize("dtype", [np.float32, np.float64],
+                             ids=["float32", "float64"])
+    @pytest.mark.parametrize("layout", sorted(_ONE_BUILDER_LAYOUTS))
+    def test_from_shard_and_from_coo_build_the_same_leaves(self, layout, dtype):
+        from_shard, from_coo = self._both(dtype, **_ONE_BUILDER_LAYOUTS[layout])
+        _assert_same_leaves(from_shard, from_coo)
+        assert from_coo.dtype == jnp.dtype(dtype)
+        # the case is the layout its name says
+        assert from_coo.has_hybrid_view == layout.startswith("hybrid")
+        assert from_coo.has_ell_view == (
+            layout not in ("flat", "hybrid_flat_tail")
+        )
+        if layout == "ell_auto":
+            assert len(from_coo.ell_tiers) >= 2
+        if layout == "ell_width_overflow":
+            assert from_coo.ell_vals.shape == (4000, 4)
+            assert not from_coo.ell_tiers and from_coo.nnz > 0
+
+    @pytest.mark.parametrize("case", [
+        "same_length_same_pad", "an_agreed_block_too_short_raises",
+        "pad_nnz_to_below_the_count_pads_nothing",
+    ])
+    def test_one_pad_rule_keeps_what_each_caller_sees(self, case):
+        """A shard's agreed ``flat_block_nnz`` and ``from_coo``'s
+        ``pad_nnz_to`` pad the flat triple under one contract (the last row
+        id, column 0, value 0); the agreed length is exact, the other a
+        floor."""
+        free = self._both(ell=4)[1]
+        assert free.nnz > 37
+        if case == "same_length_same_pad":
+            target = free.nnz + 37
+            from_shard, from_coo = self._both(
+                ell=4, coo_kw=dict(pad_nnz_to=target),
+                shard_kw=dict(flat_block_nnz=target),
+            )
+            _assert_same_leaves(from_shard, from_coo)
+            assert from_coo.nnz == target
+            np.testing.assert_array_equal(
+                np.asarray(from_coo.values)[: free.nnz], np.asarray(free.values)
+            )
+            assert np.all(np.asarray(from_coo.values)[free.nnz:] == 0.0)
+            assert np.all(np.asarray(from_coo.col_indices)[free.nnz:] == 0)
+            assert np.all(
+                np.asarray(from_coo.row_ids)[free.nnz:]
+                == np.asarray(free.row_ids)[-1]
+            )
+        elif case == "an_agreed_block_too_short_raises":
+            with pytest.raises(ValueError, match="agreed flat_block_nnz"):
+                self._both(ell=4, shard_kw=dict(flat_block_nnz=free.nnz - 37))
+        else:
+            from_coo = self._both(
+                ell=4, coo_kw=dict(pad_nnz_to=free.nnz - 37)
+            )[1]
+            _assert_same_leaves(from_coo, free)
 
 
 class TestPartitionedIoComposition:

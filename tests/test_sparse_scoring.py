@@ -28,7 +28,6 @@ LAYOUTS = {
     "flat": dict(ell=False),
     "ell": dict(),
     "ell_narrow": dict(ell=3),  # rows wider than 3 spill into the flat overflow
-    "column_sorted": dict(column_sorted_gradient=True),
     "hybrid": dict(hybrid=HybridPolicy(hot_cols=16, label="t")),
     "hybrid_narrow": dict(ell=2, hybrid=HybridPolicy(hot_cols=16, label="t")),
     "hybrid_no_ell": dict(ell=False, hybrid=HybridPolicy(hot_cols=16, label="t")),
