@@ -107,15 +107,15 @@ class GLMObjective:
         shift = self.normalization.margin_shift(eff)
         x = batch.features
         if x.dtype == jnp.bfloat16 and eff.dtype != jnp.bfloat16:
-            # bf16 feature blocks: keep X in bf16 across HBM (half the
-            # traffic of the upcast a mixed-dtype matmul would do) and let
-            # the MXU accumulate in f32. Coefficients stay f32; only the
-            # per-product operand is rounded — same arithmetic as the
-            # Pallas kernel's bf16 path.
-            m = jnp.matmul(x, eff.astype(jnp.bfloat16),
-                           preferred_element_type=eff.dtype)
+            # bf16 feature blocks: X stays bf16 across HBM, the product's
+            # operands are rounded and the MXU accumulates in f32 — the
+            # Pallas kernel's bf16 arithmetic. The float32 product's words
+            # are ``_feature_product``'s, at the end of this file.
+            with jax.named_scope("glm/margins"):
+                m = jnp.matmul(x, eff.astype(jnp.bfloat16),
+                               preferred_element_type=eff.dtype)
         else:
-            m = x @ eff
+            m = _feature_product(x, eff)
         return m - shift + batch.offsets
 
     def _data_value(self, coefficients: Array, batch: LabeledPointBatch) -> Array:
@@ -281,3 +281,17 @@ def _one_pass_hessian_vector(
         objective.loss, coefficients, vector, batch,
         l2_weight=objective.l2_weight, normalization=objective.normalization,
     )
+
+
+def _feature_product(x: Array, eff: Array) -> Array:
+    """``x @ eff`` of ``GLMObjective.margins``, under the scope ``glm/margins``
+    (the gradient's product carries the same scope inside ``transpose(jvp())``)
+    and at precision "highest". Un-vmapped, and over the random effects'
+    ``[e, cap, d]`` lanes, XLA lowers it to a float32 multiply-reduce on the
+    vector unit whatever the precision says; under a lane axis over ONE X (the
+    λ grid's lanes, ``estimators.train_glm_grid``) it is ``[n, d] x [d, L]`` and
+    its transpose, true matrix products, which a TPU at default precision feeds
+    to the MXU with float32 operands rounded to bfloat16 (PERF.md 6, PR 47).
+    Down here for ``_one_pass_hessian_vector``'s reason."""
+    with jax.named_scope("glm/margins"):
+        return jnp.matmul(x, eff, precision=jax.lax.Precision.HIGHEST)

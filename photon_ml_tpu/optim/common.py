@@ -335,8 +335,8 @@ def wolfe_line_search(
     (optimization/LBFGS.scala:97-107).
     """
     dg0 = jnp.vdot(g0, direction)
-    finfo = jnp.finfo(f0.dtype)
-    floor = LINE_SEARCH_FLOOR_K * finfo.eps * jnp.maximum(jnp.abs(f0), finfo.tiny)
+    # the floor and its test are OWL-QN's search's too: the end of this file
+    floor = line_search_floor(f0)
 
     def body(state):
         i, t, lo, hi, t_best, f_best, g_best, has_best, _done, _floored = state
@@ -345,7 +345,7 @@ def wolfe_line_search(
         armijo = (f_t <= f0 + c1 * t * dg0) & ~bad
         curv = jnp.vdot(g_t, direction) >= c2 * dg0
         done = armijo & curv
-        floored = ~armijo & (jnp.abs(t * dg0) <= floor)
+        floored = at_line_search_floor(~armijo, t * dg0, floor)
         # Remember the best Armijo-satisfying point seen so far: if curvature
         # never holds within max_steps, we still return a genuine decrease
         # step instead of reporting a spurious line-search failure.
@@ -396,3 +396,19 @@ def wolfe_line_search(
         step=t_best, value=f_best, gradient=g_best, success=success,
         trials=trials, floor_exit=floored,
     )
+
+
+def line_search_floor(f0: Array) -> Array:
+    """The smallest decrease ``f0``'s floating-point value resolves:
+    :data:`LINE_SEARCH_FLOOR_K` ulps of it. (Below ``wolfe_line_search``: a
+    line that moves above its call of the objective re-keys every compiled
+    program that holds the Pallas kernel, PERF.md 6, PR 24.)"""
+    finfo = jnp.finfo(f0.dtype)
+    return LINE_SEARCH_FLOOR_K * finfo.eps * jnp.maximum(jnp.abs(f0), finfo.tiny)
+
+
+def at_line_search_floor(failed: Array, claimable: Array, floor: Array) -> Array:
+    """True where a trial ``failed`` its decrease test and the decrease it
+    could still claim is within ``floor``: later trials only claim less, so
+    the search is over (``wolfe_line_search``, OWL-QN's backtracking)."""
+    return failed & (jnp.abs(claimable) <= floor)

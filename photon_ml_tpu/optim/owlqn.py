@@ -6,7 +6,12 @@ path). The L2 part of elastic net stays in the smooth objective; this solver
 adds λ₁‖w‖₁ via the pseudo-gradient and orthant projection (Andrew & Gao 2007).
 
 Jittable: one lax.while_loop, fixed-shape ordered L-BFGS history, masked
-projection — vmaps over entities like the plain L-BFGS solver.
+projection — vmaps over entities like the plain L-BFGS solver, and by
+``minimize_lbfgs``'s two rules for lock-step lanes: a lane whose solve has
+stopped adds no trial to the block's search loop, and a search ends at the
+float's floor (optim/common.LINE_SEARCH_FLOOR_K). ``SolverResult`` counts what
+L-BFGS counts: a search's trial points by iteration, the searches the floor
+ended.
 """
 
 from __future__ import annotations
@@ -21,8 +26,9 @@ from jax import lax
 from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     SolverResult,
+    at_line_search_floor,
     check_convergence,
-    no_line_search_counts,
+    line_search_floor,
     run_while,
 )
 from photon_ml_tpu.optim.lbfgs import (
@@ -64,6 +70,8 @@ class _OWLQNState:
     g0_norm: Array
     value_history: Array
     grad_norm_history: Array
+    line_search_trials: Array  # int32 [max_iter + 1]: trials of iteration i's search
+    floor_exits: Array  # int32: searches the float's floor ended
 
 
 def minimize_owlqn(
@@ -113,8 +121,9 @@ def minimize_owlqn(
         w0 = jnp.asarray(w0, dtype)
         sf0, g0 = value_and_grad_fn(w0)
         f0 = full_value(w0, sf0)
-        pg0 = pseudo_gradient(w0, g0, l1)
-        g0_norm = jnp.linalg.norm(pg0)
+        with jax.named_scope("owlqn/pseudo_gradient"):
+            pg0 = pseudo_gradient(w0, g0, l1)
+            g0_norm = jnp.linalg.norm(pg0)
 
         nan_hist = jnp.full((max_iter + 1,), jnp.nan, dtype)
         s_hist, y_hist, rho, count = empty_history(m, d, dtype)
@@ -135,6 +144,8 @@ def minimize_owlqn(
             g0_norm=g0_norm,
             value_history=nan_hist.at[0].set(f0),
             grad_norm_history=nan_hist.at[0].set(g0_norm),
+            line_search_trials=jnp.zeros((max_iter + 1,), jnp.int32),
+            floor_exits=jnp.int32(0),
         )
 
     def cond(state: _OWLQNState):
@@ -143,18 +154,24 @@ def minimize_owlqn(
         )
 
     def body(state: _OWLQNState):
-        pg = pseudo_gradient(state.w, state.g, l1)
+        # False only under vmap, where a stopped lane's body still runs (and
+        # is thrown away): its line search must not hold the block's
+        # lock-step loop open. Un-vmapped, ``cond`` guarantees it.
+        live = state.reason == ConvergenceReason.NOT_CONVERGED
+        with jax.named_scope("owlqn/pseudo_gradient"):
+            pg = pseudo_gradient(state.w, state.g, l1)
         direction = two_loop_direction(
             pg, state.s_hist, state.y_hist, state.rho, state.count
         )
-        # Constrain direction to the descent orthant of -pg.
-        direction = jnp.where(direction * (-pg) > 0.0, direction, 0.0)
-        # Fall back to steepest descent on the pseudo-gradient if degenerate.
-        degenerate = jnp.vdot(direction, pg) >= 0.0
-        direction = jnp.where(degenerate, -pg, direction)
+        with jax.named_scope("owlqn/pseudo_gradient"):
+            # Constrain direction to the descent orthant of -pg.
+            direction = jnp.where(direction * (-pg) > 0.0, direction, 0.0)
+            # Fall back to steepest descent on the pseudo-gradient if degenerate.
+            degenerate = jnp.vdot(direction, pg) >= 0.0
+            direction = jnp.where(degenerate, -pg, direction)
 
-        # Orthant of the search: sign(w), or sign(-pg) where w == 0.
-        xi = jnp.where(state.w != 0.0, jnp.sign(state.w), jnp.sign(-pg))
+            # Orthant of the search: sign(w), or sign(-pg) where w == 0.
+            xi = jnp.where(state.w != 0.0, jnp.sign(state.w), jnp.sign(-pg))
 
         t_init = jnp.where(
             state.count == 0,
@@ -166,9 +183,10 @@ def minimize_owlqn(
         # the orthant-projected trial point; Armijo decrease measured against
         # actual displacement dotted with the pseudo-gradient.
         c1 = 1e-4
+        floor = line_search_floor(state.f)
 
         def ls_body(ls_state):
-            i, t, w_best, f_best, g_best, done = ls_state
+            i, t, _w, _f, _g, _done, _floored = ls_state
             cand = state.w + t * direction
             cand = jnp.where(cand * xi > 0.0, cand, 0.0)  # orthant projection
             sf, sg = value_and_grad_fn(cand)
@@ -179,18 +197,24 @@ def minimize_owlqn(
                 & ~(jnp.isnan(f_t) | jnp.isinf(f_t))
                 & (f_t < state.f)
             )
-            return (i + 1, t * 0.5, cand, f_t, sg, ok)
+            # ``|decrease|`` only shrinks as t halves (a coordinate's move is
+            # t * direction, or its clip at zero): at the floor no later trial
+            # can show a decrease that ``state.f`` resolves
+            floored = at_line_search_floor(~ok, decrease, floor)
+            return (i + 1, t * 0.5, cand, f_t, sg, ok, floored)
 
         def ls_cond(ls_state):
-            i, _t, _w, _f, _g, done = ls_state
-            return (i < max_line_search_steps) & ~done
+            i, _t, _w, _f, _g, done, floored = ls_state
+            return (i < max_line_search_steps) & ~done & ~floored & live
 
-        _, _, w_new, f_new, g_new, ls_ok = run_while(
-            ls_cond,
-            ls_body,
-            (jnp.int32(0), t_init, state.w, state.f, state.g, jnp.asarray(False)),
-            host=host_loop,
-        )
+        with jax.named_scope("owlqn/line_search"):
+            ls_trials, _, w_new, f_new, g_new, ls_ok, ls_floored = run_while(
+                ls_cond,
+                ls_body,
+                (jnp.int32(0), t_init, state.w, state.f, state.g,
+                 jnp.asarray(False), jnp.asarray(False)),
+                host=host_loop,
+            )
 
         s_hist, y_hist, rho, count = push_pair(
             state.s_hist,
@@ -202,8 +226,9 @@ def minimize_owlqn(
             ls_ok,
         )
 
-        pg_new = pseudo_gradient(w_new, g_new, l1)
-        gnorm = jnp.linalg.norm(pg_new)
+        with jax.named_scope("owlqn/pseudo_gradient"):
+            pg_new = pseudo_gradient(w_new, g_new, l1)
+            gnorm = jnp.linalg.norm(pg_new)
         reason = jnp.where(
             ls_ok,
             check_convergence(
@@ -231,6 +256,8 @@ def minimize_owlqn(
             g0_norm=state.g0_norm,
             value_history=state.value_history.at[it].set(jnp.where(ls_ok, f_new, state.f)),
             grad_norm_history=state.grad_norm_history.at[it].set(gnorm),
+            line_search_trials=state.line_search_trials.at[it].set(ls_trials),
+            floor_exits=state.floor_exits + ls_floored.astype(jnp.int32),
         )
 
     final = run_while(cond, body, init, host=host_loop, observer=state_observer)
@@ -239,7 +266,8 @@ def minimize_owlqn(
         jnp.int32(ConvergenceReason.MAX_ITERATIONS),
         final.reason,
     )
-    pg_final = pseudo_gradient(final.w, final.g, l1)
+    with jax.named_scope("owlqn/pseudo_gradient"):
+        pg_final = pseudo_gradient(final.w, final.g, l1)
     return SolverResult(
         coefficients=final.w,
         value=final.f,
@@ -248,5 +276,6 @@ def minimize_owlqn(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
-        **no_line_search_counts(max_iter),
+        line_search_trials=final.line_search_trials,
+        floor_exits=final.floor_exits,
     )

@@ -239,3 +239,49 @@ def test_the_slab_historys_temporaries_are_no_larger_than_the_row_forms(
         sparse_path_compiled):
     memory = sparse_path_compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= PARENT_TEMPORARIES
+
+
+# -- the λ grid's lanes: vmapped OWL-QN over ONE X (PR 47) ----------------------
+
+GRID_LANES = 100  # logistic-epsilon-enet.grid
+
+
+@pytest.fixture(scope="module")
+def grid_solve_compiled(one_chip):
+    """``glm/grid_solve`` under OWL-QN at the cell's shapes, the objective as
+    ``train_glm_grid`` builds it (``use_pallas=False``: the lanes are vmapped)."""
+    from photon_ml_tpu import estimators
+
+    batch = LabeledPointBatch(
+        features=_shape(one_chip, (ROWS, FEATURES)), labels=_shape(one_chip, (ROWS,)),
+        offsets=_shape(one_chip, (ROWS,)), weights=_shape(one_chip, (ROWS,)))
+    with jax.enable_x64(False):
+        return estimators._jitted_grid_solve.lower(
+            GLMObjective(LogisticLoss(), use_pallas=False), True, 10, 50, 1e-7, 1e-6,
+            batch, _shape(one_chip, (GRID_LANES,)), _shape(one_chip, (GRID_LANES,)), None,
+        ).compile()
+
+
+def test_the_lanes_two_products_are_float32_matrix_products_under_glm_margins(
+        grid_solve_compiled):
+    """Under a lane axis over one X the margins and the gradient are true
+    matrix products: two convolutions in the search loop's body, operands at
+    precision ``highest`` (at the default a TPU rounds float32 operands to
+    bfloat16), told apart by the transpose's wrapper round the one scope."""
+    text = grid_solve_compiled.as_text()
+    products = re.findall(
+        r"= (f32\[[\d,]+\])[^\n]* convolution\([^\n]*operand_precision=\{(\w+),(\w+)\}"
+        r'[^\n]*op_name="([^"]*)"', text)
+    assert sorted(shape for shape, *_ in products) == [
+        f"f32[{GRID_LANES},{FEATURES}]", f"f32[{GRID_LANES},{ROWS}]"]
+    for shape, left, right, op_name in products:
+        assert (left, right) == ("highest", "highest")
+        assert "owlqn/line_search/while/body/" in op_name
+        wrapper = ("transpose(jvp(glm/margins))" if shape.endswith(f",{FEATURES}]")
+                   else "/jvp(glm/margins)")
+        assert wrapper in op_name
+    assert "tpu_custom_call" not in text
+    # the lanes' shared start is ONE un-vmapped evaluation: a multiply-reduce
+    assert 'vmap(jvp(glm/margins))/dot_general' in text
+    memory = grid_solve_compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 3.3e9 and memory.temp_size_in_bytes < 4.0e9
