@@ -77,6 +77,12 @@ logger = logging.getLogger(__name__)
 #: traced step on a mesh
 EXCHANGES_TRACED = "train/exchanges_traced"
 
+#: registry counter bumped each time the entry program runs: the one jitted
+#: ``GameTrainProgram._coordinate_scores`` that fills a state's empty
+#: ``scores`` before a sweep (``GameTrainProgram._carried``). A fit pays it
+#: once, whatever its number of sweeps; a resumed or warm-started fit once too
+ENTRY_SCORINGS = "train/entry_scorings"
+
 
 @flax.struct.dataclass
 class GameTrainState:
@@ -93,6 +99,17 @@ class GameTrainState:
         GameEstimator.scala:746-828 trains arbitrary coordinate sets; the
         fused step keeps one primary FE — the only one that may be sparse
         or feature-sharded — and any number of dense replicated extras).
+    scores: coordinate name -> [n] margin of that coordinate over the
+        TRAINING rows at this state's coefficients (rows shard over "data"):
+        what a sweep ends with and the next one starts from, so that a sweep
+        scores a coordinate only after it has solved it. Empty means "not
+        known": ``GameTrainProgram.step`` then fills it with one entry
+        scoring before it dispatches. It belongs to one data set and to the
+        training loop: ``score()`` drops it, and checkpoints, resumed states
+        and the states a fit returns hold none. ``step`` CONSUMES the carry
+        it is handed (the buffers are donated to the sweep, whose margins
+        take their place): to step twice from one state, hand it over as
+        ``state.replace(scores={})``.
     """
 
     fe_coefficients: Array
@@ -100,6 +117,7 @@ class GameTrainState:
     mf_rows: dict[str, Array] = flax.struct.field(default_factory=dict)
     mf_cols: dict[str, Array] = flax.struct.field(default_factory=dict)
     extra_fe: dict[str, Array] = flax.struct.field(default_factory=dict)
+    scores: dict[str, Array] = flax.struct.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,6 +331,43 @@ def _fe_solved(result: SolverResult) -> tuple[Array, dict[str, Array]]:
     }
 
 
+class _CarriedStep:
+    """``train/step`` as its callers hold it: ``(data, buckets, state)``,
+    called or lowered (the benchmark's traced runs read the compiled step's
+    text through ``program._step.lower``), the state's carry filled first
+    (:meth:`GameTrainProgram._carried`), so that the step is traced in ONE
+    form. The jitted program takes the margins APART from the rest of the
+    state, as its fourth argument, because they alone are donated: a sweep's
+    margins are written where the last sweep's stood, and a fit holds one set
+    of ``[n]`` vectors, not one coming in beside one going out. Every other
+    attribute is the ``ledger_jit`` object's own (``label``, ``jitted``,
+    ``clear_cache``; its ``trace`` and ``eval_shape`` take the four)."""
+
+    def __init__(self, program: "GameTrainProgram"):
+        def _step_impl(data, buckets, state, scores):  # the module's name
+            return program._step_impl(
+                data, buckets, state.replace(scores=scores))
+
+        self._program = program
+        self._jit = ledger_jit(_step_impl, label="train/step",
+                               donate_argnums=3)
+
+    def _arguments(self, data, buckets, state: GameTrainState):
+        state = self._program._carried(data, state)
+        return data, buckets, state.replace(scores={}), state.scores
+
+    def __call__(self, data, buckets, state: GameTrainState):
+        return self._jit(*self._arguments(data, buckets, state))
+
+    def lower(self, data, buckets, state: GameTrainState):
+        return self._jit.lower(*self._arguments(data, buckets, state))
+
+    def __getattr__(self, name):
+        if name.startswith("_"):  # ``_jit`` itself, of a half-made instance
+            raise AttributeError(name)
+        return getattr(self._jit, name)
+
+
 class GameTrainProgram:
     """A compiled full-GAME training step bound to static specs.
 
@@ -384,7 +439,10 @@ class GameTrainProgram:
         # sweep order inside one fused step (reference
         # CoordinateDescent.scala:198-255 trains coordinates in the
         # CONFIGURED order — order changes what residuals each solve sees).
-        # Default: primary FE, extra FEs, REs, MFs (the historical order).
+        # Default: primary FE, extra FEs, REs, MFs (the historical order),
+        # which is also the order every residual sum adds in, whatever the
+        # update order
+        self._canonical_order: tuple[str, ...] = tuple(names)
         if update_order is None:
             self.update_order: tuple[str, ...] = tuple(names)
         else:
@@ -528,8 +586,11 @@ class GameTrainProgram:
         }
         # ledger-labeled programs (telemetry/program_ledger.py): the whole
         # CD sweep and the validation score, the two hottest signatures of
-        # a training run
-        self._step = ledger_jit(self._step_impl, label="train/step")
+        # a training run, and the entry scoring that fills an empty carry
+        # ahead of a fit's first sweep (fused or scheduled)
+        self._entry_scores = ledger_jit(self._entry_scores_impl,
+                                        label="train/entry_scores")
+        self._step = _CarriedStep(self)
         self._solver_counts = None  # of the last fused sweep, on the device
         self._score = ledger_jit(self._score_impl, label="train/score")
 
@@ -844,7 +905,13 @@ class GameTrainProgram:
     # -- the fused step ------------------------------------------------------
 
     def step(self, data, buckets, state: GameTrainState):
-        """One full CD sweep. Returns (new_state, training_loss).
+        """One full CD sweep. Returns (new_state, training_loss); the new
+        state carries the sweep's margins (``GameTrainState.scores``), so the
+        next sweep over the SAME data scores nothing at its entry. A state
+        that carries none (a fresh, resumed or warm-started one) is given
+        them by one entry scoring first (:meth:`_carried`). The carry handed
+        in is donated (:class:`_CarriedStep`): ``state.scores`` is not to be
+        read after the call, the tables and coefficients are.
 
         The sweep's line-search counts (optim/common.SOLVER_COUNT_NAMES)
         stay on the program as one unread device array:
@@ -861,6 +928,53 @@ class GameTrainProgram:
         if counts is None:
             return None
         return dict(zip(SOLVER_COUNT_NAMES, np.asarray(counts).tolist()))
+
+    def _carried(self, data, state: GameTrainState) -> GameTrainState:
+        """``state`` with its margins over ``data``'s rows: as it came where
+        it carries them, else filled by the entry program, one jitted
+        :meth:`_coordinate_scores` (counter ``train/entry_scorings``). The
+        fill is the host's, ahead of the dispatch, so that ``train/step``
+        is traced in one form."""
+        if state.scores:
+            return state
+        default_registry().counter(ENTRY_SCORINGS).inc()
+        return state.replace(scores=self._entry_scores(data, state))
+
+    def _entry_scores_impl(self, data, state: GameTrainState):
+        """The entry program: every coordinate's margins at ``state``."""
+        return self._over_rows(self._coordinate_scores(data, state))
+
+    def _over_rows(self, scores: dict[str, Array]) -> dict[str, Array]:
+        """The margins a program hands on, held to the rows' sharding on a
+        mesh (``P("data")``): the entry program's and a sweep's then reach
+        the next sweep laid out alike, and it compiles once. As they came
+        in a program of one device."""
+        if self._exchange is None:
+            return scores
+        by_leading_axis, _ = self._exchange
+        return {k: jax.lax.with_sharding_constraint(v, by_leading_axis)
+                for k, v in scores.items()}
+
+    def _carried_scores(self, data, state: GameTrainState) -> dict[str, Array]:
+        """The state's carry as the recursion's dict, in canonical order: a
+        dict inside a pytree crosses ``jit`` with its keys SORTED, and
+        :meth:`_sum_scores` adds in the dict's order. A carry of other
+        coordinates or of another row count (a state carried over to
+        another data set) is refused, not broadcast."""
+        n = data["labels"].shape[0]
+        if set(state.scores) != set(self._canonical_order):
+            raise ValueError(
+                f"state.scores holds {sorted(state.scores)}, the program's "
+                f"coordinates are {list(self._canonical_order)}; step() "
+                "computes them for a state.replace(scores={})")
+        for name, v in state.scores.items():
+            if v.shape != (n,):
+                raise ValueError(
+                    f"state.scores['{name}'] has shape {tuple(v.shape)}, the "
+                    f"data has {n} rows: scores are margins over the rows "
+                    "the state was last stepped on; step() computes them "
+                    "anew for a state.replace(scores={})")
+        return {name: state.scores[name] for name in self._canonical_order}
 
     def _weighted_loss(self, labels, weights, total_margin):
         with jax.named_scope("loss"):
@@ -883,12 +997,12 @@ class GameTrainProgram:
         """Per-coordinate jitted pieces of the sweep, for step_scheduled:
         the scheduler needs host control between the probe and rescue
         solves, so the one-jit sweep is traded for a handful of cached
-        per-coordinate programs (compiled once, reused every sweep)."""
+        per-coordinate programs (compiled once, reused every sweep). The
+        entry scoring is not among them: it is the fused step's
+        (``_entry_scores``), run by :meth:`_carried` for an empty carry."""
         jits = getattr(self, "_sched_jits", None)
         if jits is None:
             jits = {
-                "scores": ledger_jit(self._coordinate_scores,
-                                     label="train/sched_scores"),
                 "fe_solve": ledger_jit(self._solve_primary_fe,
                                        label="train/sched_fe_solve"),
                 "fe_margin": ledger_jit(self._fe_margin_score,
@@ -927,7 +1041,8 @@ class GameTrainProgram:
         random-effect coordinates (algorithm/lane_scheduler.py).
 
         Same Gauss-Seidel recursion as :meth:`step` in the same
-        ``update_order``, but host-driven: each coordinate runs as its own
+        ``update_order`` and with the same carry of margins from sweep to
+        sweep, but host-driven: each coordinate runs as its own
         cached jitted program so the scheduler can read per-lane converged
         flags between the probe and rescue solves and compact only the
         unconverged lanes. Strictly opt-in — ``train_distributed`` uses it
@@ -941,7 +1056,7 @@ class GameTrainProgram:
         there). REs absent from the mapping solve unscheduled.
         """
         jits = self._scheduled_jits()
-        scores = dict(jits["scores"](data, state))
+        scores = self._carried_scores(data, self._carried(data, state))
         labels, weights = data["labels"], data["weights"]
         base = data["offsets"]
         fe_w = state.fe_coefficients
@@ -988,6 +1103,7 @@ class GameTrainProgram:
         new_state = GameTrainState(
             fe_coefficients=fe_w, re_tables=tables,
             mf_rows=mf_rows, mf_cols=mf_cols, extra_fe=extra_fe,
+            scores=scores,
         )
         return new_state, loss
 
@@ -1020,8 +1136,9 @@ class GameTrainProgram:
         ``state`` — the validation-scoring analogue of the reference's
         per-update ``GameModel.scoreAndValidate``
         (CoordinateDescent.scala:291-356), as one jitted SPMD program over
-        the same mesh shardings as the training step."""
-        return self._score(data, state)
+        the same mesh shardings as the training step. The state's carried
+        margins are of the training rows and stay behind."""
+        return self._score(data, state.replace(scores={}))
 
     def _score_impl(self, data, state: GameTrainState) -> Array:
         total = data["offsets"]
@@ -1134,8 +1251,12 @@ class GameTrainProgram:
         # holds each coordinate's score at its LATEST coefficients, so a
         # coordinate solved later in the sweep sees the residuals of the
         # ones already updated (reference CoordinateDescent.scala:198-255 —
-        # the configured order is semantic, not cosmetic).
-        scores = self._coordinate_scores(data, state)
+        # the configured order is semantic, not cosmetic). That holds across
+        # sweeps too: the dict a sweep ends with is the one the next starts
+        # from (state.scores), so a coordinate is scored once a sweep, after
+        # its solve. An empty carry is refused: filling it is step()'s, by
+        # the entry program, ahead of the dispatch.
+        scores = self._carried_scores(data, state)
 
         def offsets_excluding(skip=None):
             return self._sum_scores(base_offsets, scores, skip)
@@ -1184,6 +1305,7 @@ class GameTrainProgram:
         new_state = GameTrainState(
             fe_coefficients=fe_w, re_tables=tables,
             mf_rows=mf_rows, mf_cols=mf_cols, extra_fe=extra_fe,
+            scores=self._over_rows(scores),
         )
         # one small array: one device-to-host read a sweep, not one a count
         return new_state, train_loss, jnp.stack(
@@ -2130,6 +2252,7 @@ def train_distributed(
         start_sweep = 0
         prior_losses: list[float] = []
         best_state: GameTrainState | None = None
+        best_sweep: int | None = None  # of this call's sweeps
         best_metric = float("nan")
         history: list[dict] = []
         # An explicit caller-supplied state takes precedence over resume: passing
@@ -2451,7 +2574,11 @@ def train_distributed(
                         if i == 0 and (
                             best_state is None or ev.better_than(v, best_metric)
                         ):
-                            best_state, best_metric = state, v
+                            # without the margins: a best state kept
+                            # whole would pin a set of [n] vectors that
+                            # result_state only throws away
+                            best_state = state.replace(scores={})
+                            best_metric, best_sweep = v, sweep
                 if metrics:
                     history.append({"iteration": sweep,
                                     "coordinate": "fused_sweep", **metrics})
@@ -2490,7 +2617,8 @@ def train_distributed(
                 # best == final collapses to None ("treat final as best") so
                 # callers never convert/variance-compute the same state twice
                 best_state=(
-                    None if best_state is None or best_state is state
+                    None if best_state is None
+                    or best_sweep == num_iterations - 1
                     else result_state(best_state)
                 ),
                 best_metric=best_metric,

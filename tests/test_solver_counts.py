@@ -248,5 +248,6 @@ def test_without_an_mf_spec_the_mf_counts_are_zero_and_cost_nothing(float64_fit)
     assert [gained[name] for name in MF_COUNTS] == [0, 0, 0, 0]
     data, buckets = program.prepare_inputs(dataset, re_datasets, None)
     state = program.init_state(dataset, re_datasets, None)
-    jaxpr = jax.make_jaxpr(program._step_impl)(data, buckets, state)
+    jaxpr = jax.make_jaxpr(program._step_impl)(
+        data, buckets, program._carried(data, state))
     assert jaxpr.out_avals[-1].shape == (len(SOLVER_COUNT_NAMES),)

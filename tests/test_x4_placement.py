@@ -276,7 +276,7 @@ def test_the_step_under_data_4_moves_no_rows_by_width_array(host, four):
     data, buckets = program.prepare_inputs(dataset, four["packed"])
     data, buckets, state = program.shard_inputs(
         mesh, data, buckets, program.init_state(dataset, four["packed"]))
-    text = jax.jit(program._step_impl).lower(data, buckets, state).compile().as_text()
+    text = program._step.lower(data, buckets, state).compile().as_text()
     found = []
     for line in text.splitlines():
         m = COLLECTIVE.search(line)
@@ -339,7 +339,8 @@ def _traced_exchanges(program, placed) -> int:
     """What tracing the step once adds to the registry's counter."""
     counter = default_registry().counter(EXCHANGES_TRACED)
     before = counter.value
-    jax.eval_shape(program._step_impl, *placed)
+    data, buckets, state = placed
+    jax.eval_shape(program._step_impl, data, buckets, program._carried(data, state))
     return counter.value - before
 
 
@@ -347,7 +348,7 @@ def _compiled(program, placed) -> dict:
     """The optimized step's collectives as (operation, [(dtype, dims)], op_name)
     and its scalar gathers (one element a slot) as (slots written, op_name),
     from the program's own record of its compiled text."""
-    text = jax.jit(program._step_impl).lower(*placed).compile().as_text()
+    text = program._step.lower(*placed).compile().as_text()
     collectives, scalar_gathers = [], []
     for signature, name in scopes_of_text(text).instructions.values():
         opcode = re.search(r"[\w-]+$", signature).group()
@@ -488,7 +489,7 @@ def test_only_a_program_of_several_devices_lowers_the_step_with_a_sharding_const
     mesh = _mesh(devices)
     program = _program(mesh)
     placed = _placed(program, mesh, host, _packed(host, mesh))
-    text = jax.jit(program._step_impl).lower(*placed).as_text().lower()
+    text = program._step.lower(*placed).as_text().lower()
     assert ("sharding_constraint" in text or "@sharding" in text) == constrained
 
 
