@@ -481,6 +481,77 @@ def leg_kernel(c: Checks, sizes: Sizes, on_tpu: bool) -> None:
                     f"{rel(hv, hv_true):.2e}")
             c.check(f"{name}: product vs the jvp of the gradient",
                     rel(hv, rhv) < band, f"{rel(hv, rhv):.2e} (band {band:g})")
+    _check_placed_batch(c, sizes, loss, on_tpu)
+
+
+def _check_placed_batch(c: Checks, sizes: Sizes, loss, on_tpu: bool) -> None:
+    """PR 49: a block the chip keeps COLUMN-major (``kernel_ragged``'s width
+    over whole row tiles) is moved by ``LabeledPointBatch.create``, once, to
+    lie as the kernels read it; value, gradient and a whole ``glm/path_solve``
+    are the as-made block's bit for bit, and the program compiled for the
+    placed block copies no block of its shape where the as-made one's does.
+    Every program here goes to the persistent compile cache (floor 0 s, as
+    ``benchmark/run.py`` sets it), so that a SECOND run of this leg over one
+    cache loads them: that is where ``jax.device_put`` to a ``Format`` broke."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from photon_ml_tpu import estimators
+    from photon_ml_tpu.data.batch import DENSE_RELAYOUTS, LabeledPointBatch
+    from photon_ml_tpu.ops.objective import GLMObjective
+    from photon_ml_tpu.ops.pallas_glm import _round_up, _row_tile
+    from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType
+    from photon_ml_tpu.telemetry.registry import default_registry
+
+    d, _ = sizes.kernel_ragged
+    n = sizes.kernel_tiles * _row_tile(_round_up(d, 128), 4)
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32) / np.sqrt(d))
+    y = jnp.asarray((rng.random(n) < 0.5).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    name = f"placed batch d={d} n={n}"
+    relayouts = default_registry().counter(DENSE_RELAYOUTS)
+    floor = "jax_persistent_cache_min_compile_time_secs"
+    kept = getattr(jax.config, floor)
+    jax.config.update(floor, 0.0)
+    try:
+        before = relayouts.value
+        placed = LabeledPointBatch.create(x, y)
+        moved = relayouts.value - before
+        as_made = placed.replace(features=x)
+        lies = [tuple(a.format.layout.major_to_minor) for a in (x, placed.features)]
+        if on_tpu:
+            c.check(f"{name}: the chip keeps the block column-major, create moved it once",
+                    lies == [(1, 0), (0, 1)] and placed.features is not x and moved == 1,
+                    f"as made {lies[0]}, placed {lies[1]}, relayouts +{moved}")
+        else:
+            c.check(f"{name}: already row-major, create moved nothing",
+                    lies[1] == (0, 1) and placed.features is x and moved == 0,
+                    f"as made {lies[0]}, relayouts +{moved}")
+        objective = GLMObjective(loss)
+        evaluate = jax.jit(objective.value_and_gradient)
+        (v, g), (v_made, g_made) = evaluate(w, placed), evaluate(w, as_made)
+        c.check(f"{name}: value and gradient == the as-made block's",
+                bool(v == v_made) and bool(jnp.all(g == g_made)), f"value {float(v):.6g}")
+        opt = OptimizerConfig(OptimizerType.LBFGS, max_iterations=5)
+        args = (jnp.zeros_like(w), np.float32(1.0), None, None)
+        solved, copies = {}, {}
+        for side, batch in (("placed", placed), ("as made", as_made)):
+            solved[side] = estimators._jitted_path_solve(objective, opt, batch, *args)
+            copies[side] = len(re.findall(
+                rf"= f32\[{n},{d}\][^ ]* copy\(",
+                estimators._jitted_path_solve.lower(
+                    objective, opt, batch, *args).compile().as_text()))
+        c.check(f"{name}: glm/path_solve's coefficients == the as-made block's",
+                bool(jnp.all(solved["placed"].coefficients == solved["as made"].coefficients)),
+                f"iterations {int(solved['placed'].iterations)}")
+        c.check(f"{name}: glm/path_solve copies no block of its shape",
+                copies["placed"] == 0 and (copies["as made"] > 0 or not on_tpu),
+                f"copies of f32[{n},{d}]: {copies}")
+    finally:
+        jax.config.update(floor, kept)
 
 
 def glmix_argv(work: str, out: str, tel: str, mesh: "str | None") -> tuple:

@@ -185,12 +185,9 @@ def _read_batch(path: str, fmt: str, shard_cfg, index_maps=None,
         description=f"read {path}",
     ).result
     ds = result.dataset
-    batch = LabeledPointBatch(
-        features=ds.feature_shards["features"],
-        labels=ds.labels,
-        offsets=ds.offsets,
-        weights=ds.weights,
-    )
+    # ``create`` places the block as the kernels read it: no fit relayouts it
+    batch = LabeledPointBatch.create(
+        ds.feature_shards["features"], ds.labels, ds.offsets, ds.weights)
     return (batch, result.index_maps,
             result.intercept_indices.get("features"), result.decode_path)
 

@@ -18,8 +18,120 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.config import persistent_cache_min_compile_time_secs as _cache_write_floor
+from jax.experimental.layout import Format, Layout
+
+from photon_ml_tpu.telemetry.registry import default_registry
 
 Array = jax.Array
+
+_LANE = 128  # TPU lane width: the last dimension of every tile
+#: widest (lane-padded) feature block the Pallas GLM kernels take
+#: (ops/pallas_glm.py). Past it the resident w / grad blocks alone crowd the
+#: row tile down to a few sublanes; the auto rule (ops/objective.py) keeps
+#: wider dense blocks on the XLA path, and forcing the kernel there raises.
+#: Every width at or under it is compiled on the chip by chip_smoke.py's
+#: kernel leg. It stands here, under ops/, because the batch's placement
+#: (``in_kernel_layout``) asks the same question the auto rule does.
+MAX_KERNEL_DIM = 16384
+#: most a block may grow when its rows are stored whole lanes wide:
+#: ``round_up(d, 128) / d``. 2.4 % at d = 2,000, nothing at 256; a
+#: ``[n, 16]`` block would be stored eight times over and is left as it lies.
+MAX_ROW_MAJOR_GROWTH = 1.125
+#: the layout the kernels' X operand has: rows major, features along the lanes
+KERNEL_LAYOUT = Layout(major_to_minor=(0, 1))
+#: registry counter: dense blocks ``in_kernel_layout`` actually moved, and
+#: the bytes the last of them takes as it lies now
+DENSE_RELAYOUTS = "data/dense_relayouts"
+DENSE_RELAYOUT_BYTES = "data/dense_relayout_bytes"
+
+
+def _round_up(x: int, m: int) -> int:
+    return ((x + m - 1) // m) * m
+
+
+def kernel_supports(num_features: int) -> bool:
+    """Whether a dense block this wide is in the kernels' compiled range."""
+    return _round_up(num_features, _LANE) <= MAX_KERNEL_DIM
+
+
+def _major_to_minor(features) -> tuple[int, ...] | None:
+    """How a device array lies, as the runtime reports it; None where it
+    reports nothing."""
+    layout = features.format.layout
+    return None if layout is None else tuple(layout.major_to_minor)
+
+
+def _row_major(resident: Array) -> Array:
+    """``resident`` copied into ``KERNEL_LAYOUT`` on its own devices: a jitted
+    identity with the format as ``out_shardings``, which is what
+    ``jax.device_put`` to a ``Format`` runs, but compiled in THIS process and
+    never written to the persistent compile cache. An executable LOADED from
+    that cache hands back arrays that report the platform's default layout
+    whatever their buffers hold (chip run, PR 49: the second run of a cell
+    died of "expected parameter 0 of size 3200000000 ... but got buffer with
+    incompatible size 3276800000"), and ``jit`` compiles for what an array
+    reports. 0.1 s of compile a process; nothing else here is cache-shy.
+    The floor is raised for THIS thread and this compile alone (a jax config
+    state entered as a context): another thread's compiles write as before."""
+
+    def as_the_kernels_read_it(x):  # the program's name, and so its cache key
+        return x
+
+    with _cache_write_floor(float("inf")):
+        return jax.jit(
+            as_the_kernels_read_it,
+            out_shardings=Format(KERNEL_LAYOUT, resident.sharding),
+        )(resident)
+
+
+def in_kernel_layout(features):
+    """A dense feature block as the Pallas GLM kernels read it: row-major.
+
+    A TPU keeps a ``[400000, 2000]`` float32 array COLUMN-major (that pads
+    nothing; row-major pads 2,000 lanes to 2,048), the kernels' operand is
+    row-major, and XLA put a relayout of all of X in front of the kernel in
+    every program that took such an X as an argument: twice a
+    ``glm/path_solve``, eight copies of 9.95 ms a fit (PERF.md 6, PR 33 and
+    PR 49). Placed here ONCE, as a committed array in ``KERNEL_LAYOUT``
+    (tiling left to the platform), every later ``jit`` is compiled for the
+    layout the array has and reads it as it lies.
+
+    The rule reads what it can observe and nothing a caller sets: the
+    default backend is ``tpu``; the block is a concrete 2-D float32 or
+    bfloat16 array (host rows go to the default device); ``kernel_supports(d)``
+    (the auto rule's own predicate, ops/objective.py); whole lanes cost at
+    most ``MAX_ROW_MAJOR_GROWTH`` of the block; the array does not already
+    lie that way. In every other case the SAME object comes back: on a CPU,
+    under a trace, for an integer or float64 block, beyond
+    ``MAX_KERNEL_DIM``, for a narrow block, where the platform's default is
+    row-major already.
+    """
+    if jax.default_backend() != "tpu":
+        return features
+    if isinstance(features, jax.core.Tracer) or not isinstance(
+            features, (jax.Array, np.ndarray)):
+        return features
+    if features.ndim != 2 or features.dtype not in (jnp.float32, jnp.bfloat16):
+        return features
+    n, d = features.shape
+    lanes = _round_up(d, _LANE)
+    if not kernel_supports(d) or lanes > MAX_ROW_MAJOR_GROWTH * d:
+        return features
+    resident = jnp.asarray(features)  # host rows: to the default device first
+    if _major_to_minor(resident) == KERNEL_LAYOUT.major_to_minor:
+        return features
+    placed = _row_major(resident)
+    if _major_to_minor(placed) != KERNEL_LAYOUT.major_to_minor:
+        # a copy that reports another layout than it was compiled to give
+        # would be read transposed, or refused for its size, by the next jit
+        raise RuntimeError(
+            f"in_kernel_layout: a {features.shape} {features.dtype} block placed "
+            f"in {KERNEL_LAYOUT} reports {placed.format.layout}")
+    registry = default_registry()
+    registry.counter(DENSE_RELAYOUTS).inc()
+    registry.gauge(DENSE_RELAYOUT_BYTES).set(n * lanes * features.dtype.itemsize)
+    return placed
 
 
 @flax.struct.dataclass
@@ -79,8 +191,12 @@ class LabeledPointBatch:
         dtype=None,
     ) -> "LabeledPointBatch":
         """Build a batch. ``dtype=None`` preserves the input float dtype
-        (float64 in x64 test mode, float32 in production)."""
-        features = jnp.asarray(features, dtype=dtype)
+        (float64 in x64 test mode, float32 in production). A device array of
+        the asked dtype is taken as it is, with ONE exception: on a TPU a
+        block the GLM kernels will read is placed row-major here, once
+        (``in_kernel_layout``: a relayout copy of the block; the caller's
+        array is not touched), so that no fit relayouts it again."""
+        features = in_kernel_layout(jnp.asarray(features, dtype=dtype))
         if dtype is None:
             dtype = features.dtype
         if dtype == jnp.bfloat16:

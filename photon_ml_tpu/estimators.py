@@ -38,7 +38,7 @@ from photon_ml_tpu.algorithm.mf_coordinate import (
     MatrixFactorizationCoordinate,
     build_mf_dataset,
 )
-from photon_ml_tpu.data.batch import LabeledPointBatch, summarize
+from photon_ml_tpu.data.batch import LabeledPointBatch, in_kernel_layout, summarize
 from photon_ml_tpu.data.game_data import (
     GameDataset,
     build_random_effect_dataset,
@@ -1303,6 +1303,9 @@ def train_glm(
     refits a resident batch (same shapes, same optimizer, the same
     ``normalization`` object) pays a dispatch per λ — nothing is traced,
     lowered or loaded again. Nothing between two λ waits on the device.
+    On a TPU a dense block the kernels will read is put row-major before the
+    first λ unless it already lies so (``LabeledPointBatch.create`` places
+    it): the programs then read X as it lies and copy nothing.
 
     telemetry: optional ``telemetry.SolverTelemetry`` — one convergence row
     (iterations, reason, value history) per λ solve.
@@ -1321,6 +1324,10 @@ def train_glm(
             "(elastic_net_alpha must be 0)"
         )
     loss = loss_for_task(task)
+    if isinstance(batch, LabeledPointBatch):
+        # the same object for a batch ``create`` placed; else ONE relayout a
+        # fit, not two a solve (data/batch.in_kernel_layout)
+        batch = batch.replace(features=in_kernel_layout(batch.features))
     if lower_bounds is not None:
         lower_bounds = jnp.asarray(lower_bounds, batch.dtype)
     if upper_bounds is not None:

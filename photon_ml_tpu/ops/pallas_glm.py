@@ -75,7 +75,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from photon_ml_tpu.data.batch import LabeledPointBatch
+from photon_ml_tpu.data.batch import (  # the width rule lives with the batch
+    MAX_KERNEL_DIM, LabeledPointBatch, kernel_supports)
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.telemetry.registry import default_registry
 
@@ -91,12 +92,16 @@ _X_TILE_BYTES = 4 * 1024 * 1024  # target VMEM footprint for ONE X tile
 #: 16.00M"). 32 MiB covers every width up to MAX_KERNEL_DIM with room;
 #: v5e has 128 MiB of VMEM.
 _VMEM_LIMIT_BYTES = 32 * 1024 * 1024
-#: widest (lane-padded) feature block the kernel takes. Past it the
-#: resident w / grad blocks alone crowd the row tile down to a few
-#: sublanes; the auto rule (ops/objective.py) keeps wider dense blocks on
-#: the XLA path, and forcing the kernel there raises. Every width at or
-#: under it is compiled on the chip by chip_smoke.py's kernel leg.
-MAX_KERNEL_DIM = 16384
+#: ``MAX_KERNEL_DIM`` (the widest lane-padded block the kernels take) and
+#: ``kernel_supports`` (the predicate the auto rule of ops/objective.py
+#: reads) are written ONCE, in data/batch.py, and imported back above:
+#: since PR 49 the batch's placement asks the same question, and data/ lies
+#: under ops/ (tests/test_layering.py). The placement is the other half of
+#: "X as it lies": the kernels' X operand is ROW-major, a TPU keeps a
+#: ``[400000, 2000]`` float32 array column-major, and XLA put a relayout
+#: copy of all of X in front of the kernel in every program that took such
+#: an X as an argument. ``data/batch.in_kernel_layout`` places the block
+#: row-major once, where the batch is made; no program copies it again.
 #: registry counters bumped once per TRACE of the kernel into a program —
 #: how a run's journal shows that its FE solve held the Mosaic-compiled
 #: kernel (and never the interpreter) without anyone reading HLO
@@ -109,11 +114,6 @@ TRACES_RAGGED = "ops/pallas_glm/traces_ragged"
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
-
-
-def kernel_supports(num_features: int) -> bool:
-    """Whether a dense block this wide is in the kernel's compiled range."""
-    return _round_up(num_features, _LANE) <= MAX_KERNEL_DIM
 
 
 def _row_tile(d_pad: int, itemsize: int) -> int:
@@ -248,10 +248,10 @@ def fused_value_and_gradient(
 
     bf16 feature blocks stream as bf16 (half the HBM traffic) with all
     accumulation in f32; coefficients/value/gradient stay f32 throughout.
-    Inputs of any shape go to the kernel as they are: no copy of
-    ``batch.features`` or of the aux block is made to reach whole tiles
-    (the kernel masks its last row tile and its last lanes itself, see the
-    module header); only the coefficients are zero-padded to 128m lanes.
+    Inputs of any shape go to the kernel as they are: nothing of size n is
+    copied to reach whole tiles (the kernel masks its last row tile and lanes
+    itself; only the coefficients are padded to 128m lanes), nor to reach the
+    kernel's row-major layout where ``data/batch.in_kernel_layout`` placed X.
     """
     if interpret is None:
         interpret = _should_interpret()

@@ -706,17 +706,23 @@ _TRACED: "dict[str, _TracedCall]" = {}
 
 
 def _abstract(leaf):
-    """An array leaf as its ``jax.ShapeDtypeStruct`` (with the sharding of a
-    committed ``jax.Array`` and the weak type of a Python scalar's array:
-    what jit lowers by); anything else as it is."""
+    """An array leaf as its ``jax.ShapeDtypeStruct`` (with the sharding AND
+    the layout of a committed ``jax.Array`` and the weak type of a Python
+    scalar's array: what jit lowers by; a dense batch placed row-major,
+    ``data/batch.in_kernel_layout``, is compiled for as it lies, and the
+    record has to be of THAT program); anything else as it is."""
     import jax
 
     shape, dtype = getattr(leaf, "shape", None), getattr(leaf, "dtype", None)
     if shape is None or dtype is None:
         return leaf
-    committed = isinstance(leaf, jax.Array) and leaf.committed
+    placement = None
+    if isinstance(leaf, jax.Array) and leaf.committed:
+        # a donated (deleted) array reports no layout: its sharding alone
+        lies = leaf.format
+        placement = leaf.sharding if lies.layout is None else lies
     return jax.ShapeDtypeStruct(
-        shape, dtype, sharding=leaf.sharding if committed else None,
+        shape, dtype, sharding=placement,
         weak_type=getattr(leaf, "weak_type", False))
 
 

@@ -359,6 +359,34 @@ def test_a_weak_typed_leaf_is_remembered_as_one():
     assert traces == [1]  # jit's own cached lowering answered: no retrace
 
 
+def test_a_committed_leaf_is_remembered_in_the_layout_it_lies_in():
+    """``jit`` compiles for the layout a committed array has (a dense batch
+    placed by ``data/batch.in_kernel_layout``): remembered by its sharding
+    alone it would lower as the DEFAULT-layout program, another than the one
+    that ran, and every partition by its record would be nothing (the TRON
+    cell's ``path_hv_roofline`` fell silent so; chip run, PR 49). Column-major
+    stands in, on a CPU, for a layout the platform does not default to."""
+    from jax.experimental.layout import Format, Layout
+
+    traces = []
+    f = ledger_jit(lambda x, w: traces.append(1) or (x @ w, w + 1),
+                   label="test/compiled_scopes/layout", donate_argnums=(1,))
+    x = jax.device_put(jnp.ones((8, 4), jnp.float32), jax.devices()[0])
+    lies = Format(Layout(major_to_minor=(1, 0)), x.sharding)
+    x = jax.jit(lambda a: a, out_shardings=lies)(x)
+    w = jax.device_put(jnp.ones((4, 4), jnp.float32), jax.devices()[0])
+    f(x, w)
+    kept = program_ledger._TRACED["test/compiled_scopes/layout"]
+    assert kept.args[0].format.layout.major_to_minor == (1, 0)
+    # donated and deleted by the call: its sharding, no layout to report
+    assert w.is_deleted() and kept.args[1].sharding == x.sharding
+    assert compiled_scopes("test/compiled_scopes/layout") is not None
+    assert traces == [1]  # jit's own cached lowering answered: the SAME program
+    compiled = f.lower(*kept.args).compile()
+    x_format = jax.tree_util.tree_leaves(compiled.input_formats)[0]
+    assert x_format.layout.major_to_minor == (1, 0)
+
+
 def test_only_a_trace_inside_the_call_is_remembered():
     @ledger_jit(label="test/compiled_scopes/own")
     def f(x):

@@ -242,10 +242,10 @@ def test_row_tile_fits_budget_and_sublane_packing():
 def test_kernel_width_limit(monkeypatch):
     """Past MAX_KERNEL_DIM the auto rule keeps the XLA path and a forced
     kernel raises — never a silent fallback."""
+    import photon_ml_tpu.data.batch as batch_mod
     import photon_ml_tpu.ops.objective as objective_mod
-    import photon_ml_tpu.ops.pallas_glm as kernel_mod
 
-    monkeypatch.setattr(kernel_mod, "MAX_KERNEL_DIM", 128)
+    monkeypatch.setattr(batch_mod, "MAX_KERNEL_DIM", 128)  # where the rule reads it
     monkeypatch.setattr(objective_mod.jax, "default_backend", lambda: "tpu")
     batch = _batch(32, 200)  # pads to 256 lanes > 128
     w = jnp.zeros(200, jnp.float32)

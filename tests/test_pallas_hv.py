@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import photon_ml_tpu.data.batch as batch_mod
 import photon_ml_tpu.ops.objective as objective_mod
 import photon_ml_tpu.ops.pallas_glm as kernel_mod
 from photon_ml_tpu.data.batch import LabeledPointBatch
@@ -265,7 +266,7 @@ def test_the_product_takes_the_kernel_where_the_gradient_does(
 def test_the_product_follows_the_kernels_width_limit(monkeypatch, kernel_calls):
     """Past MAX_KERNEL_DIM the auto rule keeps the jvp and a forced kernel
     raises: ``value_and_gradient``'s rule, through the same ``_pallas_enabled``."""
-    monkeypatch.setattr(kernel_mod, "MAX_KERNEL_DIM", 128)
+    monkeypatch.setattr(batch_mod, "MAX_KERNEL_DIM", 128)  # where the rule reads it
     monkeypatch.setattr(objective_mod.jax, "default_backend", lambda: "tpu")
     batch, w, v = _problem(32, 200)  # pads to 256 lanes > 128
     hv = GLMObjective(SquaredLoss()).hessian_vector(w, v, batch)

@@ -7,7 +7,10 @@ widths the auto rule admits (scoped VMEM, tiling), and ``glm/path_solve`` under
 TRON at the benchmark cell's size holds ONE custom call a product, inside the
 CG loop, under ``tron/hv``, named after its jitted wrapper by a name
 ``benchmark/trace_reduce.KERNEL`` does not match, with X relaid out twice a
-solve and never inside a loop; and (PR 44) ``glm/path_solve`` over the sparse
+solve and never inside a loop WHERE IT ARRIVES AS THE PLATFORM LAYS IT, and
+(PR 49) not at all where it arrives row-major, as ``data/batch.
+in_kernel_layout`` places it: under L-BFGS, under TRON and in the λ grid's
+program; and (PR 44) ``glm/path_solve`` over the sparse
 cell's hybrid batch with its ELL view in width tiers compiles into a program
 of ordinary size whose tiers are gathered and scattered under the two
 ``sparse/tail_*`` scopes, and (PR 45) whose L-BFGS history keeps every slot
@@ -23,11 +26,12 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format
 from jax.sharding import SingleDeviceSharding
 
 import photon_ml_tpu.ops.pallas_glm as kernel_mod
 from benchmark import trace_reduce
-from photon_ml_tpu.data.batch import LabeledPointBatch
+from photon_ml_tpu.data.batch import KERNEL_LAYOUT, LabeledPointBatch
 from photon_ml_tpu.ops.losses import LogisticLoss
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType
@@ -68,24 +72,53 @@ def test_the_product_kernel_compiles_for_a_v5e(one_chip, d, rows_short, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+#: how X arrives: as the platform lays a [400000, 2000] block (column-major:
+#: that pads nothing), or row-major, as ``data/batch.in_kernel_layout`` places it
+AS_THE_PLATFORM_LAYS_IT, ROW_MAJOR = "platform", "row-major"
+OPTIMIZERS = {
+    "TRON": OptimizerConfig(OptimizerType.TRON, max_iterations=15, tolerance=1e-5,
+                            max_cg_iterations=20),  # logistic-epsilon-tron.path
+    "LBFGS": OptimizerConfig(OptimizerType.LBFGS, max_iterations=50,
+                             rel_function_tolerance=1e-6),  # logistic-epsilon.path
+}
+
+
+def _dense_batch(one_chip, layout):
+    """The dense cells' batch as shapes, X in the given layout."""
+    placement = Format(KERNEL_LAYOUT, one_chip) if layout == ROW_MAJOR else one_chip
+    return LabeledPointBatch(
+        features=_shape(placement, (ROWS, FEATURES)), labels=_shape(one_chip, (ROWS,)),
+        offsets=_shape(one_chip, (ROWS,)), weights=_shape(one_chip, (ROWS,)))
+
+
 @pytest.fixture(scope="module")
-def tron_path_text(one_chip):
-    """The optimized text of ``glm/path_solve`` under the cell's optimizer at
-    the cell's size, the objective as ``train_glm`` builds it on a TPU."""
+def path_compiled(one_chip):
+    """``glm/path_solve`` at the dense cells' size under one of their
+    optimizers, the objective as ``train_glm`` builds it on a TPU; compiled
+    once for each (optimizer, layout of X) a test asks for."""
     from photon_ml_tpu import estimators
 
-    batch = LabeledPointBatch(
-        features=_shape(one_chip, (ROWS, FEATURES)), labels=_shape(one_chip, (ROWS,)),
-        offsets=_shape(one_chip, (ROWS,)), weights=_shape(one_chip, (ROWS,)))
-    tron = OptimizerConfig(OptimizerType.TRON, max_iterations=15, tolerance=1e-5,
-                           max_cg_iterations=20)
-    with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
-        # what the auto rule and the interpreter's rule ask
-        patch.setattr(jax, "default_backend", lambda: "tpu")
-        return estimators._jitted_path_solve.lower(
-            GLMObjective(LogisticLoss()), tron, batch,
-            _shape(one_chip, (FEATURES,)), _shape(one_chip, ()), None, None,
-        ).compile().as_text()
+    programs = {}
+
+    def compiled(optimizer, layout):
+        if (optimizer, layout) not in programs:
+            with pytest.MonkeyPatch.context() as patch, jax.enable_x64(False):
+                # what the auto rule and the interpreter's rule ask
+                patch.setattr(jax, "default_backend", lambda: "tpu")
+                programs[optimizer, layout] = estimators._jitted_path_solve.lower(
+                    GLMObjective(LogisticLoss()), OPTIMIZERS[optimizer],
+                    _dense_batch(one_chip, layout),
+                    _shape(one_chip, (FEATURES,)), _shape(one_chip, ()), None, None,
+                ).compile()
+        return programs[optimizer, layout]
+
+    return compiled
+
+
+@pytest.fixture(scope="module")
+def tron_path_text(path_compiled):
+    """The optimized text of the TRON cell's program, X as the platform lays it."""
+    return path_compiled("TRON", AS_THE_PLATFORM_LAYS_IT).as_text()
 
 
 def _custom_calls(text):
@@ -109,16 +142,41 @@ def test_a_product_is_one_custom_call_under_tron_hv_by_its_own_name(tron_path_te
     assert not any("tron/" in calls[n] for n in calls if n not in products)
 
 
-def test_x_is_read_by_the_kernels_alone_and_relaid_out_twice_a_solve(tron_path_text):
+X_BLOCK = rf"f32\[{ROWS},{FEATURES}\]"
+#: temporaries of a program that keeps no second X (one that relayouts it: 3.48 GB)
+NO_SECOND_X = 0.5e9
+
+
+def _copies_of_x(text):
+    return re.findall(rf"= {X_BLOCK}[^ ]* copy\(", text)
+
+
+def _x_format(compiled):
+    """The format the compiled program takes its batch's features in."""
+    return jax.tree_util.tree_leaves(compiled.input_formats)[0]
+
+
+@pytest.mark.parametrize("optimizer", ["TRON", "LBFGS"])
+@pytest.mark.parametrize("layout,copies", [(AS_THE_PLATFORM_LAYS_IT, 2), (ROW_MAJOR, 0)])
+def test_x_is_read_by_the_kernels_alone_and_relaid_out_twice_a_solve(
+        path_compiled, optimizer, layout, copies):
     """No XLA fusion reads the [rows, features] block (the jvp's two
-    multiply-reduce passes and the hoisted third are gone), and the relayout
-    copy of X stands in ENTRY, before the first evaluation and before the
-    rounds' loop: once a solve each, never once a product."""
-    x = rf"f32\[{ROWS},{FEATURES}\]"
-    entry = tron_path_text[tron_path_text.index("\nENTRY "):]
-    copies = re.findall(rf"= {x}[^ ]* copy\(", tron_path_text)
-    assert len(copies) == 2 and len(re.findall(rf"= {x}[^ ]* copy\(", entry)) == 2
-    assert not re.search(rf"fusion\([^\n]*{x}", tron_path_text)
+    multiply-reduce passes and the hoisted third are gone). Where X arrives
+    as the platform lays it, the relayout copy of X stands in ENTRY, before
+    the first evaluation and before the rounds' loop: once a solve each,
+    never once a product. Where it arrives row-major (PR 49) the program
+    takes it in that layout, copies nothing and keeps no second X."""
+    compiled = path_compiled(optimizer, layout)
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert len(_copies_of_x(text)) == copies and len(_copies_of_x(entry)) == copies
+    assert not re.search(rf"fusion\([^\n]*{X_BLOCK}", text)
+    major_to_minor = _x_format(compiled).layout.major_to_minor
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    if layout == ROW_MAJOR:
+        assert major_to_minor == (0, 1) and temporaries < NO_SECOND_X
+    else:
+        assert major_to_minor == (1, 0) and temporaries > 3.2e9
 
 
 # -- the sparse path program with its ELL view in width tiers (PR 44) ----------
@@ -246,20 +304,33 @@ def test_the_slab_historys_temporaries_are_no_larger_than_the_row_forms(
 GRID_LANES = 100  # logistic-epsilon-enet.grid
 
 
+def _grid_solve(one_chip, layout):
+    from photon_ml_tpu import estimators
+
+    with jax.enable_x64(False):
+        return estimators._jitted_grid_solve.lower(
+            GLMObjective(LogisticLoss(), use_pallas=False), True, 10, 50, 1e-7, 1e-6,
+            _dense_batch(one_chip, layout),
+            _shape(one_chip, (GRID_LANES,)), _shape(one_chip, (GRID_LANES,)), None,
+        ).compile()
+
+
 @pytest.fixture(scope="module")
 def grid_solve_compiled(one_chip):
     """``glm/grid_solve`` under OWL-QN at the cell's shapes, the objective as
     ``train_glm_grid`` builds it (``use_pallas=False``: the lanes are vmapped)."""
-    from photon_ml_tpu import estimators
+    return _grid_solve(one_chip, AS_THE_PLATFORM_LAYS_IT)
 
-    batch = LabeledPointBatch(
-        features=_shape(one_chip, (ROWS, FEATURES)), labels=_shape(one_chip, (ROWS,)),
-        offsets=_shape(one_chip, (ROWS,)), weights=_shape(one_chip, (ROWS,)))
-    with jax.enable_x64(False):
-        return estimators._jitted_grid_solve.lower(
-            GLMObjective(LogisticLoss(), use_pallas=False), True, 10, 50, 1e-7, 1e-6,
-            batch, _shape(one_chip, (GRID_LANES,)), _shape(one_chip, (GRID_LANES,)), None,
-        ).compile()
+
+def test_the_grid_program_takes_a_row_major_x_as_it_lies(one_chip, grid_solve_compiled):
+    """XLA reads any layout, and lays a relayout of its own in front of the
+    lanes' products where X arrives column-major (one copy a solve); handed
+    the block as ``LabeledPointBatch.create`` places it, it copies nothing."""
+    assert len(_copies_of_x(grid_solve_compiled.as_text())) == 1
+    placed = _grid_solve(one_chip, ROW_MAJOR)
+    assert not _copies_of_x(placed.as_text())
+    assert _x_format(placed).layout.major_to_minor == (0, 1)
+    assert placed.memory_analysis().temp_size_in_bytes < NO_SECOND_X
 
 
 def test_the_lanes_two_products_are_float32_matrix_products_under_glm_margins(
@@ -285,3 +356,47 @@ def test_the_lanes_two_products_are_float32_matrix_products_under_glm_margins(
     assert 'vmap(jvp(glm/margins))/dot_general' in text
     memory = grid_solve_compiled.memory_analysis()
     assert memory.argument_size_in_bytes < 3.3e9 and memory.temp_size_in_bytes < 4.0e9
+
+
+def test_a_signature_remembered_with_its_default_layouts_lowers_the_same_program(one_chip):
+    """``program_ledger._abstract`` remembers a committed array with its layout
+    (PR 49), so that ``compiled_scopes`` describes the program that RAN where a
+    batch was placed row-major; for every other array that layout is the
+    platform's default, and on a 2x2 v5e mesh a signature that names the
+    default layouts has to lower the program a signature without them does:
+    a ``[65536, 16]`` block the chip keeps column-major, a vector, a scalar."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from photon_ml_tpu.telemetry.program_ledger import _abstract
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    placed = [((65536, 16), PartitionSpec("data", None)), ((65536,), PartitionSpec("data")),
+              ((16,), PartitionSpec()), ((), PartitionSpec())]
+
+    def step(x, y, w, k):
+        margins = x @ w + y
+        return (margins * margins).sum() * k, x.T @ margins
+
+    jitted = jax.jit(step)
+    by_sharding = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=NamedSharding(mesh, spec))
+                   for shape, spec in placed]
+    compiled = jitted.lower(*by_sharding).compile()
+    formats = jax.tree_util.tree_leaves(compiled.input_formats)
+    assert formats[0].layout.major_to_minor == (1, 0)  # the default is NOT row-major
+
+    class Committed:  # what ``_abstract`` reads of a committed ``jax.Array``
+        committed, weak_type = True, False
+
+        def __init__(self, like, lies):
+            self.shape, self.dtype, self.sharding, self.format = (
+                like.shape, like.dtype, like.sharding, lies)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "Array", Committed)
+        remembered = [_abstract(Committed(like, lies))
+                      for like, lies in zip(by_sharding, formats)]
+    assert [r.format for r in remembered] == formats
+    assert jitted.lower(*remembered).compile().as_text() == compiled.as_text()
