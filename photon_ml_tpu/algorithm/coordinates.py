@@ -411,7 +411,7 @@ class RandomEffectCoordinate(Coordinate):
         )
         # AUTO resolves to NEWTON here: the per-entity bucket solve is
         # exactly the small-d dense vmapped shape the batched-Newton
-        # solver was measured on (BASELINE.md r5)
+        # solver is written for (optim/newton.py)
         opt = _solve_config(self.config, loss=objective.loss, small_dense=True)
 
         traces: list[LaneTrace] = []

@@ -196,7 +196,7 @@ class GLMObjective:
                 factors if factors is not None else 1.0
             )
             x = x - shift_row
-        h = x.T @ (d2[:, None] * x)
+        h = _weighted_gram(x, d2)
         if self.axis_name is not None:
             h = jax.lax.psum(h, self.axis_name)
         if self.l2_weight > 0.0:
@@ -295,3 +295,15 @@ def _feature_product(x: Array, eff: Array) -> Array:
     Down here for ``_one_pass_hessian_vector``'s reason."""
     with jax.named_scope("glm/margins"):
         return jnp.matmul(x, eff, precision=jax.lax.Precision.HIGHEST)
+
+
+def _weighted_gram(x: Array, d2: Array) -> Array:
+    """``x' diag(d2) x`` of ``GLMObjective.hessian_matrix``, at precision
+    "highest". Under the random effects' lanes (``vmap``) it is a batched
+    ``[e, d, cap] x [e, cap, d]`` contraction, a true matrix product, which a
+    TPU at default precision feeds to the MXU with float32 operands rounded to
+    bfloat16 (PERF.md 6, PR 47, of ``_feature_product``; PR 50 for this one):
+    for a squared loss the Newton step built on it IS the ridge solution, so a
+    rounded ``X'X`` is a rounded model. Down here for
+    ``_one_pass_hessian_vector``'s reason."""
+    return jnp.matmul(x.T, d2[:, None] * x, precision=jax.lax.Precision.HIGHEST)

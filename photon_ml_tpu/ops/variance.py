@@ -6,7 +6,9 @@ SingleNodeOptimizationProblem.computeVariances (:58-69) — both build the
 full Hessian at the optimum and return diag(H⁻¹) via Cholesky inverse
 (photon-lib util/Linalg.scala choleskyInverse).
 
-TPU-native: H is one X'ᵀDX' matmul on the MXU (GLMObjective.hessian_matrix);
+TPU-native: H is one X'ᵀDX' matmul (GLMObjective.hessian_matrix, since PR 50 at
+precision "highest": float32 operands stay float32 on the MXU, in several
+bfloat16 passes, where the platform's default rounds them to bfloat16 once);
 diag(H⁻¹) = column sums of squares of L⁻¹ where H = LLᵀ, i.e. one triangular
 solve against I. O(d³) compute / O(d²) memory, so FULL is gated to small d;
 above FULL_VARIANCE_MAX_DIM the AUTO mode falls back to the diagonal

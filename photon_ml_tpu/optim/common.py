@@ -91,7 +91,10 @@ class SolverResult:
     iteration ``i`` (int32 [max_iter + 1]; slot 0 and slots past ``iterations``
     hold 0): a line search's trial points, TRON's Hessian-vector products;
     ``floor_exits`` the searches, or TRON's rounds, the float's floor ended
-    (:data:`LINE_SEARCH_FLOOR_K`). Others: :func:`no_line_search_counts`.
+    (:data:`LINE_SEARCH_FLOOR_K`).
+    Newton (optim/newton.py) counts a round's step-shrink candidates as its
+    trials, 1 in ``floor_exits`` where the floor ended the solve, and its
+    rounds that accepted no candidate in ``rejected_rounds`` (None elsewhere).
     """
 
     coefficients: Array
@@ -103,6 +106,7 @@ class SolverResult:
     grad_norm_history: Array
     line_search_trials: Array  # int32 [max_iter + 1]
     floor_exits: Array  # int32 scalar
+    rejected_rounds: Array | None = None  # int32 scalar; Newton only
 
     @property
     def converged(self) -> Array:
@@ -126,15 +130,6 @@ class SolverResult:
         reason = ConvergenceReason(int(self.reason)).name
         lines.append(f"converged after {n} iterations: {reason}")
         return "\n".join(lines)
-
-
-def no_line_search_counts(max_iter: int) -> dict[str, Array]:
-    """The ``line_search_trials`` / ``floor_exits`` fields of a
-    :class:`SolverResult` whose solver runs no counted line search."""
-    return {
-        "line_search_trials": jnp.zeros((max_iter + 1,), jnp.int32),
-        "floor_exits": jnp.int32(0),
-    }
 
 
 @flax.struct.dataclass
@@ -170,6 +165,9 @@ class LaneTrace:
     #: int32 scalar: sum over iterations of the max over ALL lanes (padding
     #: lanes too) — the trips the vmapped search loop actually ran
     lockstep_trials: Array | None = None
+    #: [lanes] int32, a Newton lane's rounds that accepted no candidate; None
+    #: for every other solver (read by :func:`newton_lane_counts`)
+    rejected_rounds: Array | None = None
 
 
 class LaneTraces:
@@ -208,6 +206,8 @@ def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace
         # iteration by iteration the vmapped search loop runs until its
         # slowest lane is done: the sum of those maxima is what the device ran
         lockstep_trials=jnp.sum(jnp.max(trials, axis=0), dtype=jnp.int32),
+        rejected_rounds=(None if result.rejected_rounds is None
+                         else jnp.atleast_1d(result.rejected_rounds)),
     )
 
 
@@ -215,11 +215,13 @@ def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace
 #: four of :func:`lane_solver_counts`, random-effect coordinates summed, the
 #: fixed-effect solves' own trials and floor exits, and the same four of the
 #: matrix-factorization half-steps' lanes (``mf_*``; zero without such a
-#: coordinate)
+#: coordinate), and the three of :func:`newton_lane_counts` (zero where no
+#: random effect is solved by Newton)
 SOLVER_COUNT_NAMES = (
     "lockstep_trials", "lane_trials", "floor_exits", "line_searches",
     "fe_trials", "fe_floor_exits",
     "mf_lockstep_trials", "mf_lane_trials", "mf_floor_exits", "mf_line_searches",
+    "newton_lockstep_rounds", "newton_lane_rounds", "newton_rejected_rounds",
 )
 
 
@@ -229,16 +231,17 @@ def lane_solver_counts(trace: LaneTrace) -> dict[str, Array]:
     slowest search of each iteration), ``lane_trials`` (what the valid lanes
     needed, each by itself), ``floor_exits`` and ``line_searches`` (valid
     lanes; one search an iteration)."""
-
-    def over_valid(per_lane):
-        return jnp.sum(jnp.where(trace.valid, per_lane, 0), dtype=jnp.int32)
-
     return {
         "lockstep_trials": trace.lockstep_trials,
-        "lane_trials": over_valid(trace.line_search_trials),
-        "floor_exits": over_valid(trace.floor_exits),
-        "line_searches": over_valid(trace.iterations),
+        "lane_trials": _over_valid(trace, trace.line_search_trials),
+        "floor_exits": _over_valid(trace, trace.floor_exits),
+        "line_searches": _over_valid(trace, trace.iterations),
     }
+
+
+def _over_valid(trace: LaneTrace, per_lane: Array) -> Array:
+    """A per-lane count summed over the trace's valid lanes (int32)."""
+    return jnp.sum(jnp.where(trace.valid, per_lane, 0), dtype=jnp.int32)
 
 
 def check_convergence(
@@ -412,3 +415,17 @@ def at_line_search_floor(failed: Array, claimable: Array, floor: Array) -> Array
     could still claim is within ``floor``: later trials only claim less, so
     the search is over (``wolfe_line_search``, OWL-QN's backtracking)."""
     return failed & (jnp.abs(claimable) <= floor)
+
+
+def newton_lane_counts(trace: LaneTrace) -> dict[str, Array]:
+    """A bucket's Newton rounds as int32 device scalars:
+    ``newton_lockstep_rounds`` (what the device ran: the bucket's loop turns
+    until its slowest lane, padding or not, has stopped),
+    ``newton_lane_rounds`` (what the valid lanes needed, each by itself) and
+    ``newton_rejected_rounds`` (valid lanes' rounds that accepted no
+    candidate). For a trace that carries ``rejected_rounds``: Newton's."""
+    return {
+        "newton_lockstep_rounds": jnp.max(trace.iterations).astype(jnp.int32),
+        "newton_lane_rounds": _over_valid(trace, trace.iterations),
+        "newton_rejected_rounds": _over_valid(trace, trace.rejected_rounds),
+    }

@@ -1,36 +1,50 @@
-"""Damped Newton (IRLS) with an explicit Cholesky solve — the small-d solver.
+"""Damped Newton (IRLS) with an explicit elimination solve — the small-d solver.
 
 No reference analogue: the reference solves every per-entity random-effect
 subproblem with the iterative LBFGS/TRON family (RandomEffectOptimizationProblem
 + Optimizer.scala template loop), which is the right call on a JVM executor.
-On TPU those vmapped iterative solves are OP-COUNT-bound, not
-bandwidth-bound (an r5 decomposition of the fused sweep put ~2 ms per RE
-coordinate per L-BFGS iteration on a [2000, 128, 16] bucket whose data could
-stream in ~50 µs; r5 numbers throughout this file predate this round's
-toolchain and have not been re-measured on it — PERF.md) — the two-loop
-recursion plus a Wolfe line search whose batched while_loop runs every lane
-until the WORST lane satisfies the conditions, tens of tiny [e, d] ops per
-iteration.
+On TPU those vmapped iterative solves are bound by their op COUNT, not by
+bandwidth: the two-loop recursion plus a Wolfe line search whose batched
+while_loop runs every lane until the WORST lane satisfies the conditions
+(``glmix-ml20m.sweeps``: the lanes' searches alone 30 % of the device's busy
+seconds, 838 lock-step trials a sweep; PERF.md 5).
 
 For the small dense dimensions where per-entity solves live (d ≲ a few
-hundred), Newton's method is the op-minimal shape: one Hessian pass
-(a batched [e, cap, d]ᵀ[e, cap, d] MXU contraction), one d-step
-Gauss-Jordan solve (NOT an XLA cholesky — batched small decompositions
-serialize per matrix on TPU, 3.4 ms vs 0.09 ms hand-rolled at
-[2000, 16, 16] in r5), one fixed 4-point step-shrink
-(a vmapped value evaluation that shares the feature read across the 4
-candidates — no divergent line-search loop), one gradient pass. ~15 fused
-ops per iteration regardless of entity count. For the squared loss one
-full step is EXACT (ridge normal equations), so warm-started sweeps
-converge in one accepted step plus one convergence check.
+hundred), Newton's method is the op-minimal shape, a round under four scopes:
+``newton/hessian`` (a batched ``[e, d, cap] x [e, cap, d]`` contraction, on a
+TPU an MXU convolution, at precision "highest":
+ops/objective._weighted_gram), ``newton/solve`` (one d-step Gauss-Jordan
+elimination, NOT an XLA cholesky: batched small decompositions serialize per
+matrix on TPU), ``newton/shrink`` (one fixed 4-point step-shrink: a vmapped
+value evaluation that shares the feature read across the candidates, no
+divergent line-search loop), ``newton/gradient`` (one value-and-gradient pass
+at an accepted point). For the squared loss one full step is EXACT (ridge
+normal equations), and a lane that can gain no more than the float resolves
+stops (the floor in ``body``), so a ridge lane costs the exact step and one
+check: two rounds. On the chip (a TPU v5e, the cell ``game-ymusic-r2.sweeps``:
+155,674 ridge lanes in 14 buckets; PERF.md 5, PR 50) a sweep runs 30.67
+lock-step rounds, 2.19 a bucket solve, where the same lanes without the
+floor run 140, and of an episode's 1.27 busy seconds the elimination takes
+0.35 (the lanes lie on the MAJOR axis of its ``[e, 16, 17]`` system: tiles
+seven eighths padding), the Hessian pass 0.10 at 7.5 % of its HBM roofline,
+the candidates' pass 0.04 and the gradient pass 0.04. This
+file's earlier timings (a hand-rolled elimination at 0.09 ms against 3.4 ms
+for cholesky at [2000, 16, 16], "~2 ms per RE coordinate per L-BFGS
+iteration") were taken before the chip's benchmark on a plug-in that no
+longer exists (BASELINE.md) and are NOT re-measured: Newton against L-BFGS
+on the same logistic lanes has no cell yet (PERF.md 7 row 3).
 
 GLM Hessians are PSD and every RE coordinate carries l2 > 0, so H + l2·I is
 PD; a trace-scaled Levenberg jitter plus a gradient-direction fallback guard
 the elimination against degenerate all-padding entities (their H is l2·I,
 which eliminates cleanly — the fallback only fires on non-finite input).
 
-Opt-in via ``OptimizerType.NEWTON``; LBFGS stays the default everywhere, so
-reference-parity solver behavior is unchanged unless asked for.
+Opt-in via ``OptimizerType.NEWTON``, or chosen by ``OptimizerType.AUTO`` for
+the small dense vmapped solves (optim/optimizer.resolve_auto_optimizer); LBFGS
+stays the default everywhere, so reference-parity solver behavior is unchanged
+unless asked for. A result counts its work (SolverResult): a round's trials
+are its five candidates, ``floor_exits`` 1 where the floor ended the solve,
+``rejected_rounds`` the rounds that accepted no candidate.
 """
 
 from __future__ import annotations
@@ -45,7 +59,7 @@ from jax import lax
 from photon_ml_tpu.optim.common import (
     ConvergenceReason,
     SolverResult,
-    no_line_search_counts,
+    line_search_floor,
 )
 
 Array = jax.Array
@@ -64,11 +78,11 @@ def _solve_pd(h: Array, g: Array) -> Array:
     vectorized over any batch dims with a fori over columns.
 
     XLA's native decompositions are the wrong tool for BATCHED small
-    systems on TPU: on [2000, 16, 16] this measured 0.088 ms vs 3.39 ms
-    for cholesky+cho_solve and 8.97 ms for jnp.linalg.solve in r5 (their
-    row-sequential inner loops serialize per matrix). PD systems need no pivoting (every pivot
-    is a positive Schur complement diagonal; the caller's Levenberg jitter
-    keeps them away from zero under f32)."""
+    systems on TPU (their row-sequential inner loops serialize per matrix;
+    the timings that first said so predate the chip's benchmark and are not
+    re-measured: the module's docstring). PD systems need no pivoting (every
+    pivot is a positive Schur complement diagonal; the caller's Levenberg
+    jitter keeps them away from zero under f32)."""
     d = h.shape[-1]
     a = jnp.concatenate([h, g[..., None]], axis=-1)  # [..., d, d+1]
 
@@ -97,6 +111,9 @@ class _NewtonState:
     reason: Array
     value_history: Array
     grad_norm_history: Array
+    #: int32: rounds that accepted no candidate; 1 where the floor ended the solve
+    rejected: Array
+    floor_exit: Array
 
 
 def minimize_newton(
@@ -133,7 +150,8 @@ def minimize_newton(
     d = w0.shape[-1]
     if value_fn is None:
         value_fn = lambda w: value_and_grad_fn(w)[0]
-    f0, g0 = value_and_grad_fn(w0)
+    with jax.named_scope("newton/gradient"):
+        f0, g0 = value_and_grad_fn(w0)
     g0_norm = jnp.linalg.norm(g0)
     alphas = jnp.asarray(_ALPHAS, dtype)
     ftol = tolerance if rel_function_tolerance is None else rel_function_tolerance
@@ -153,6 +171,8 @@ def minimize_newton(
         ),
         value_history=nan_hist.at[0].set(f0),
         grad_norm_history=nan_hist.at[0].set(g0_norm),
+        rejected=jnp.int32(0),
+        floor_exit=jnp.int32(0),
     )
 
     def cond(state: _NewtonState):
@@ -161,28 +181,32 @@ def minimize_newton(
         )
 
     def body(state: _NewtonState):
-        h = hessian_matrix_fn(state.w)
-        # trace-scaled Levenberg jitter (f32 PD safety) + the adaptive LM
-        # damping carried in the state. The scale is floored so the damping
-        # still regularizes a zero-trace Hessian (all-zero H with l2=0,
-        # reachable outside the RE path): without the floor the jitter
-        # collapses to 1e-30 and damping growth multiplies zero, leaving
-        # the gradient fallback's 1e-12 divisor to produce huge steps.
-        scale = jnp.maximum(jnp.trace(h) / d, 1e-12)
-        jitter = (1e-7 + state.damping) * scale + 1e-30
-        p = -_solve_pd(h + jitter * jnp.eye(d, dtype=h.dtype), state.g)
-        # degenerate Hessian (non-finite solve): steepest descent scaled
-        # by the largest curvature — only reachable on non-finite input
-        ok = jnp.all(jnp.isfinite(p))
-        p_fallback = -state.g / jnp.maximum(jnp.max(jnp.diag(h)), 1e-12)
-        p = jnp.where(ok, p, p_fallback)
+        with jax.named_scope("newton/hessian"):
+            h = hessian_matrix_fn(state.w)
+        with jax.named_scope("newton/solve"):
+            # trace-scaled Levenberg jitter (f32 PD safety) + the adaptive LM
+            # damping carried in the state. The scale is floored so the
+            # damping still regularizes a zero-trace Hessian (all-zero H with
+            # l2=0, reachable outside the RE path): without the floor the
+            # jitter collapses to 1e-30 and damping growth multiplies zero,
+            # leaving the gradient fallback's 1e-12 divisor to produce huge
+            # steps.
+            scale = jnp.maximum(jnp.trace(h) / d, 1e-12)
+            jitter = (1e-7 + state.damping) * scale + 1e-30
+            p = -_solve_pd(h + jitter * jnp.eye(d, dtype=h.dtype), state.g)
+            # degenerate Hessian (non-finite solve): steepest descent scaled
+            # by the largest curvature — only reachable on non-finite input
+            ok = jnp.all(jnp.isfinite(p))
+            p_fallback = -state.g / jnp.maximum(jnp.max(jnp.diag(h)), 1e-12)
+            p = jnp.where(ok, p, p_fallback)
 
         # fixed step-shrink: ONE vmapped value pass over all candidates,
         # alpha=0 included so every accept/convergence comparison below is
         # between evaluations of the SAME value path (value_fn) — state.f
         # may come from the Pallas kernel, whose ~5e-6 relative delta vs
         # the autodiff value would otherwise decide accepts near optimum
-        vals = jax.vmap(lambda a: value_fn(state.w + a * p))(alphas)
+        with jax.named_scope("newton/shrink"):
+            vals = jax.vmap(lambda a: value_fn(state.w + a * p))(alphas)
         vals = jnp.where(jnp.isfinite(vals), vals, jnp.inf)
         best = jnp.argmin(vals[1:]) + 1  # best NONZERO step
         improved = vals[best] < vals[0]
@@ -191,21 +215,37 @@ def minimize_newton(
         # Newton step leaves ‖g‖ at rounding scale, which warm-started RE
         # solves' large g0 never map below the relative gradient
         # tolerance, and without a live stop every vmapped lane pays
-        # max_iter full iterations — the 81 ms sweep in
-        # newton_sweep_probe_r5.log)
+        # max_iter full iterations)
         f_delta_small = jnp.abs(vals[0] - vals[best]) <= ftol * (
             jnp.abs(vals[0]) + 1e-30
+        )
+        # the float's floor (optim/common.line_search_floor, L-BFGS's): the
+        # objective is convex, so no candidate can lie further below vals[0]
+        # than the slope claims for the full step, |g . p|. Where that is
+        # within an ulp of the value no candidate's value can SHOW a
+        # decrease: whether one reads lower is rounding, in this round and in
+        # every later one (same g, same H). After the one exact step of a
+        # ridge lane g is rounding and the claim some 1e-9 of an ulp, while
+        # the candidates' values scatter by an ulp or two, strictly below
+        # vals[0] for some lanes and not for others: the measured values
+        # cannot carry the rule (PERF.md 6, PR 50; TRON's floor reads the
+        # predicted decrease for the same reason, PR 40). Under heavy damping
+        # the step, and so the claim, is artificially small: no stop there,
+        # as for a flat round.
+        at_floor = (jnp.abs(jnp.vdot(state.g, p)) <= line_search_floor(vals[0])) & (
+            state.damping <= 1e-3
         )
         w_new = jnp.where(improved, state.w + alphas[best] * p, state.w)
         # rejected round: w_new == state.w, so the value+grad it carries is
         # already exact — reuse it. lax.cond skips the pass entirely on
         # un-vmapped solves; vmapped lanes lower to a select-both-branches
         # (no worse than the unconditional recompute this replaces).
-        f_new, g_new = lax.cond(
-            improved,
-            lambda: value_and_grad_fn(w_new),
-            lambda: (state.f, state.g),
-        )
+        with jax.named_scope("newton/gradient"):
+            f_new, g_new = lax.cond(
+                improved,
+                lambda: value_and_grad_fn(w_new),
+                lambda: (state.f, state.g),
+            )
 
         # LM damping: a rejected round means the step overshot past the
         # alphas' 16x range — damp hard and retry; acceptance decays the
@@ -229,7 +269,7 @@ def minimize_newton(
             gnorm <= tolerance * jnp.maximum(g0n, 1.0),
             jnp.int32(ConvergenceReason.GRADIENT_WITHIN_TOLERANCE),
             jnp.where(
-                flat_round,
+                flat_round | at_floor,
                 jnp.int32(ConvergenceReason.FUNCTION_VALUES_WITHIN_TOLERANCE),
                 jnp.int32(ConvergenceReason.NOT_CONVERGED),
             ),
@@ -244,6 +284,10 @@ def minimize_newton(
             reason=reason,
             value_history=state.value_history.at[it].set(f_new),
             grad_norm_history=state.grad_norm_history.at[it].set(gnorm),
+            rejected=state.rejected + (~improved).astype(jnp.int32),
+            floor_exit=(
+                at_floor & (reason == ConvergenceReason.FUNCTION_VALUES_WITHIN_TOLERANCE)
+            ).astype(jnp.int32),
         )
 
     final = lax.while_loop(cond, body, init)
@@ -252,6 +296,7 @@ def minimize_newton(
         jnp.int32(ConvergenceReason.MAX_ITERATIONS),
         final.reason,
     )
+    rounds = jnp.arange(max_iter + 1, dtype=jnp.int32)
     return SolverResult(
         coefficients=final.w,
         value=final.f,
@@ -260,5 +305,10 @@ def minimize_newton(
         reason=reason,
         value_history=final.value_history,
         grad_norm_history=final.grad_norm_history,
-        **no_line_search_counts(max_iter),
+        # a round's trials are its step-shrink candidates, one value pass
+        line_search_trials=jnp.where(
+            (rounds >= 1) & (rounds <= final.iteration), jnp.int32(len(_ALPHAS)), 0
+        ),
+        floor_exits=final.floor_exit,
+        rejected_rounds=final.rejected,
     )

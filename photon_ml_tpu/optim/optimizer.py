@@ -31,8 +31,8 @@ class OptimizerType(enum.Enum):
     TPU-first extension with no reference analogue (optim/newton.py): the
     op-minimal solver for small-d vmapped per-entity solves. AUTO picks
     the fastest safe solver per coordinate KIND (resolve_auto_optimizer):
-    NEWTON on eligible small-d dense vmapped solves (RE/MF buckets —
-    the measured 18 vs 48 ms fused-sweep win), LBFGS everywhere else.
+    NEWTON on eligible small-d dense vmapped solves (RE/MF buckets; on
+    the chip in the cell game-ymusic-r2.sweeps, PERF.md 5), LBFGS everywhere else.
     Explicit LBFGS stays the reference-parity default."""
 
     LBFGS = "LBFGS"
@@ -261,8 +261,11 @@ def resolve_auto_optimizer(
 
     ``small_dense=True`` marks the vmapped small-d dense per-entity solve
     shape (RE/MF buckets): there AUTO promotes to NEWTON — the op-minimal
-    solver for that shape (fused_game_sweep_newton_ms = 18 vs 48 ms,
-    BASELINE.md r5) — exactly when the dispatch guards in :func:`solve`
+    solver for that shape (on the chip: the ridge lanes of the cell
+    game-ymusic-r2.sweeps, PERF.md 5, PR 50; against L-BFGS on the same
+    logistic lanes it is not measured, PERF.md 7 row 3; the "18 vs 48 ms" once
+    quoted here predates the chip's benchmark, BASELINE.md) — exactly when
+    the dispatch guards in :func:`solve`
     would accept it (twice-differentiable ``loss``, no L1 term; box
     constraints are an LBFGS-family feature and AUTO never carries them
     here). Everything else (big-d FE solves, streamed host-loop

@@ -58,6 +58,7 @@ from photon_ml_tpu.optim.common import (
     SOLVER_COUNT_NAMES,
     SolverResult,
     lane_solver_counts,
+    newton_lane_counts,
 )
 from photon_ml_tpu.optim.optimizer import OptimizerConfig, solve
 from photon_ml_tpu.parallel.mesh import place
@@ -323,6 +324,16 @@ def _add_counts(total: dict, counts: Mapping) -> None:
         total[name] = total.get(name, 0) + value
 
 
+def _re_lane_counts(trace) -> dict[str, Array]:
+    """A random-effect bucket's counts: its line-search work and, where the
+    lanes were solved by Newton (the trace then carries the rejected rounds),
+    its rounds."""
+    counts = lane_solver_counts(trace)
+    if trace.rejected_rounds is not None:
+        counts.update(newton_lane_counts(trace))
+    return counts
+
+
 def _fe_solved(result: SolverResult) -> tuple[Array, dict[str, Array]]:
     """A fixed-effect solve's (coefficients, ``fe_*`` line-search counts)."""
     return result.coefficients, {
@@ -397,9 +408,9 @@ class GameTrainProgram:
         # AUTO resolution happens ONCE, at program build: FE coordinates
         # (big-d, possibly sharded/streamed) take LBFGS; RE/MF coordinates
         # (small-d dense vmapped buckets) take NEWTON when the loss is
-        # eligible (optim/optimizer.resolve_auto_optimizer) — the measured
-        # 18 vs 48 ms fused-sweep win, now reachable without naming the
-        # solver. Explicit configs pass through untouched.
+        # eligible (optim/optimizer.resolve_auto_optimizer), without naming
+        # the solver (the cell game-ymusic-r2.sweeps runs it: PERF.md 5).
+        # Explicit configs pass through untouched.
         from photon_ml_tpu.optim.optimizer import resolve_auto_optimizer
 
         _loss_for_auto = loss_for_task(task)
@@ -1431,7 +1442,7 @@ class GameTrainProgram:
                         b["sample_rows"], b["entity_rows"], b["col_index"],
                         full_offsets, table_ext,
                     )
-                    _add_counts(counts, lane_solver_counts(trace))
+                    _add_counts(counts, _re_lane_counts(trace))
                 return table_ext[:, :-1], counts
             if spec.projector == ProjectorType.RANDOM:
                 matrix = buckets["__projections__"][k]
@@ -1442,7 +1453,7 @@ class GameTrainProgram:
                         b["sample_rows"], b["entity_rows"], matrix,
                         full_offsets, table,
                     )
-                    _add_counts(counts, lane_solver_counts(trace))
+                    _add_counts(counts, _re_lane_counts(trace))
                 return table, counts
             for b in buckets[k]:
                 table, trace = solve_entity_bucket_traced(
@@ -1456,7 +1467,7 @@ class GameTrainProgram:
                     full_offsets,
                     table,
                 )
-                _add_counts(counts, lane_solver_counts(trace))
+                _add_counts(counts, _re_lane_counts(trace))
             return table, counts
 
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):

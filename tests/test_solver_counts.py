@@ -152,9 +152,10 @@ def test_float32_fit_counts_searches_the_floor_ended():
     """Float32, four sweeps (the later ones start near their optimum): all
     six counters of a GLMix program are positive, and they hang together (a
     floor exit is a search, a search has a trial); with no factorization
-    coordinate the ``mf_*`` four stay zero."""
+    coordinate the ``mf_*`` four stay zero, and with no lane solved by
+    Newton the ``newton_*`` three."""
     gained, _events = counted_fit(*glmix(np.float32), sweeps=4)
-    assert all((gained[name] > 0) != name.startswith("mf_")
+    assert all((gained[name] > 0) != name.startswith(("mf_", "newton_"))
                for name in SOLVER_COUNT_NAMES), gained
     assert gained["floor_exits"] <= gained["line_searches"] <= gained["lane_trials"]
     assert gained["lockstep_trials"] <= gained["lane_trials"]

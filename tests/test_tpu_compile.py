@@ -14,7 +14,9 @@ program; and (PR 44) ``glm/path_solve`` over the sparse
 cell's hybrid batch with its ELL view in width tiers compiles into a program
 of ordinary size whose tiers are gathered and scattered under the two
 ``sparse/tail_*`` scopes, and (PR 45) whose L-BFGS history keeps every slot
-as whole tiles, ``f32[10,157944,128]``, m an untiled major dimension.
+as whole tiles, ``f32[10,157944,128]``, m an untiled major dimension; and
+(PR 50) a random-effect bucket's Newton solve, whose batched Hessian is an MXU
+contraction at precision ``highest``.
 
 This is the ONE file that describes a topology: the TPU library is loaded by
 the process that runs these tests, inside a fixture, never at import.
@@ -356,6 +358,46 @@ def test_the_lanes_two_products_are_float32_matrix_products_under_glm_margins(
     assert 'vmap(jvp(glm/margins))/dot_general' in text
     memory = grid_solve_compiled.memory_analysis()
     assert memory.argument_size_in_bytes < 3.3e9 and memory.temp_size_in_bytes < 4.0e9
+
+
+# -- the random effects' lanes under Newton: a batched Hessian (PR 50) --------------
+
+
+def test_the_newton_lanes_hessian_is_an_mxu_contraction_at_the_highest_precision(one_chip):
+    """A bucket of ``game-ymusic-r2.sweeps`` (the users' 4,576 lanes of 128
+    rows) solved by Newton: the Hessian ``X_e' D X_e`` of every lane at once is
+    a batched ``[e, 16, cap] x [e, cap, 16]`` contraction that the TPU's
+    compiler makes an MXU ``convolution`` (the lanes a dilated spatial axis),
+    under ``newton/hessian``; a TPU at default precision would round its
+    float32 operands to bfloat16, and for a squared loss a rounded ``X'X`` is a
+    rounded model, so ``ops/objective._weighted_gram`` says ``highest``. The
+    candidates' margins (``newton/shrink``) are such a product too."""
+    from photon_ml_tpu.algorithm.coordinates import solve_entity_bucket_traced
+    from photon_ml_tpu.ops.losses import SquaredLoss
+
+    lanes, cap, d, rows, entities = 4576, 128, 16, 3_643_392, 9_496
+    newton = OptimizerConfig(OptimizerType.NEWTON, max_iterations=10,
+                             rel_function_tolerance=1e-6)
+    objective = GLMObjective(SquaredLoss(), l2_weight=1.0)
+    with jax.enable_x64(False):
+        text = jax.jit(
+            lambda *a: solve_entity_bucket_traced(objective, newton, *a)
+        ).lower(_shape(one_chip, (lanes, cap, d)), _shape(one_chip, (lanes, cap)),
+                _shape(one_chip, (lanes, cap)), _shape(one_chip, (lanes, cap), jnp.int32),
+                _shape(one_chip, (lanes,), jnp.int32), _shape(one_chip, (rows,)),
+                _shape(one_chip, (entities, d))).compile().as_text()
+    products = re.findall(
+        r"= (f32\[[\d,]+\])[^\n]* convolution\(([^\n]*)op_name=\"([^\"]*)\"", text)
+    hessians = [(shape, rest) for shape, rest, op_name in products
+                if "newton/hessian/" in op_name]
+    assert hessians and all(shape == f"f32[{lanes},{d},{d}]" for shape, _ in hessians)
+    assert all("operand_precision={highest,highest}" in rest for _, rest in hessians)
+    shrinks = [rest for shape, rest, op_name in products if "newton/shrink/" in op_name]
+    assert shrinks and all("operand_precision={highest,highest}" in rest for rest in shrinks)
+    # no contraction of the solve is left at the platform's default
+    assert all("operand_precision={highest,highest}" in rest for _, rest, _ in products)
+    for scope in ("newton/hessian/", "newton/solve/", "newton/shrink/", "newton/gradient/"):
+        assert scope in text
 
 
 def test_a_signature_remembered_with_its_default_layouts_lowers_the_same_program(one_chip):
