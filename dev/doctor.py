@@ -16,6 +16,11 @@ files) and per-rank ``trace-*.json`` files, and emits ONE report:
   flops, peak bytes, with each label's LAST recompile attribution (the
   exact signature leaves that changed), plus heartbeat staleness and
   hbm/compile drift so a wedged run is distinguishable from a slow one;
+- the lanes' accounts of a fused fit (``lane_counts`` rows, one a sweep,
+  parallel/distributed.py): per coordinate the LAST sweep's buckets, lanes
+  and valid ones, lock-step trials and outer trips, the rows the searches
+  paid for against the rows a live lane wanted, the lanes by why they
+  stopped, and the trials and that share sweep by sweep;
 - the straggler table from the per-rank trace files (dev/trace_summary.py
   machinery — online and offline reports share one implementation);
 - the cross-rank coordinated-recovery table (ISSUE 15): per-rank
@@ -106,6 +111,7 @@ def _journal_section(path: str, live: bool) -> tuple[list, list[str], list]:
                 if drift:
                     lines.append(f"   heartbeat drift: {drift}")
     lines.extend(_ledger_table(records))
+    lines.extend(_lane_table(records))
     return findings, lines, records
 
 
@@ -188,6 +194,58 @@ def _ledger_table(records: list) -> list[str]:
         )
         if ent["attribution"]:
             lines.append(f"      last recompile: {ent['attribution']}")
+    return lines
+
+
+#: the columns of a ``lane_counts`` bucket that :func:`_lane_table` sums
+_LANE_COUNTS = (
+    "lane_solves", "lockstep_trials", "lockstep_iterations",
+    "lanes_max_iterations", "lanes_function_tolerance",
+    "lanes_gradient_tolerance", "lanes_search_failed")
+
+
+def _lane_table(records: list) -> list[str]:
+    """The lanes' accounts by coordinate, from the journal's ``lane_counts``
+    rows (one a fused sweep: every bucket solve's counts,
+    optim/common.BUCKET_COUNT_NAMES, with its lanes and cap): the newest
+    sweep in full and, from every row, the lock-step trials and the share
+    wanted of paid by sweep (the newest dozen). Rows paid = lock-step trials
+    x lanes x cap, wanted = the valid lanes' own trials x cap: the share
+    says how much of a search's work any lane asked for."""
+    rows = [r for r in records if r.get("kind") == "lane_counts"]
+    if not rows:
+        return []
+
+    def by_coordinate(row) -> dict[str, dict]:
+        totals: dict[str, dict] = {}
+        for b in row.get("buckets") or []:
+            t = totals.setdefault(str(b.get("coordinate")), dict.fromkeys(
+                ("buckets", "paid", "wanted", "lanes", *_LANE_COUNTS), 0))
+            t["buckets"] += 1
+            t["lanes"] += int(b["lanes"])
+            t["paid"] += int(b["lockstep_trials"]) * int(b["lanes"]) * int(b["cap"])
+            t["wanted"] += int(b["lane_trials"]) * int(b["cap"])
+            for key in _LANE_COUNTS:
+                t[key] += int(b.get(key) or 0)
+        return totals
+
+    def share(t) -> str:
+        return f"{100.0 * t['wanted'] / t['paid']:.2f}%" if t["paid"] else "-"
+
+    sweeps = [by_coordinate(r) for r in rows]
+    lines = [f"   lanes, sweep {rows[-1].get('sweep')} of {len(rows)} journaled "
+             "(trials and trips in lock-step; stops: cap/function/gradient/failed):"]
+    for name, t in sweeps[-1].items():
+        lines.append(
+            f"   {name:<24} buckets {t['buckets']:>3} lanes {t['lanes']:>8} "
+            f"valid {t['lane_solves']:>8} trials {t['lockstep_trials']:>6} "
+            f"trips {t['lockstep_iterations']:>5} wanted/paid {share(t)} "
+            "stops " + "/".join(str(t[k]) for k in _LANE_COUNTS[3:]))
+        past = [s[name] for s in sweeps[-12:] if name in s]
+        lines.append(
+            f"   {'':<24} by sweep: trials "
+            + " ".join(str(p["lockstep_trials"]) for p in past)
+            + "; wanted/paid " + " ".join(share(p) for p in past))
     return lines
 
 

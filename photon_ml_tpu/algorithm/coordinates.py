@@ -721,9 +721,9 @@ def _solve_bucket_entities(
     """vmapped per-entity solves: ([e, k] solved coefficients, [e] trace).
 
     The trace carries each lane's final iteration count / convergence reason
-    / value and its line-search work (optim/common.lane_solver_counts) —
-    tiny extra outputs XLA computes anyway; consumers that only want the
-    table drop it (DCE removes the cost)."""
+    / value and its line-search work (optim/common.lane_trace_of) — small
+    extra outputs XLA computes anyway; consumers that only want the table
+    drop it (DCE removes the cost)."""
 
     def solve_one(f, l, o, w, w0):
         batch = LabeledPointBatch(features=f, labels=l, offsets=o, weights=w)
@@ -754,7 +754,7 @@ def solve_entity_bucket_traced(
     """Solve every entity in a bucket and scatter results into the table;
     also returns the per-lane convergence trace (padding lanes masked
     invalid): the CD path hands it to telemetry, the fused step reduces it
-    to its line-search counts.
+    to the bucket's row of counts (optim/common.bucket_count_parts).
 
     Pure/traceable: reused by the single-chip jit wrapper below and by the
     mesh-sharded full-GAME train step (parallel/distributed.py), where the

@@ -140,12 +140,12 @@ class LaneTrace:
     The jittable skeleton of the reference's per-problem
     OptimizationStatesTracker reporting (OptimizationStatesTracker.scala:
     82-101): vmapped solves cannot keep per-iteration host-side state, but
-    XLA computes each lane's final iteration count / reason / value anyway —
-    these are those scalars surfaced as tiny extra outputs. ``valid`` masks
-    padding lanes (OOB-sentinel entity rows solve all-zero-weight batches
-    and must not pollute convergence tallies). Consumed by
-    telemetry/solver_trace.py for reason tallies across lanes — the
-    "every lane pays max_iter" pathology (CLAUDE.md) made visible.
+    XLA computes each lane's final iteration count / reason / value anyway.
+    ``valid`` masks padding lanes (OOB-sentinel entity rows solve
+    all-zero-weight batches and must not pollute convergence tallies). Read
+    by telemetry/solver_trace.py (reason tallies across lanes) and by the
+    fused sweep's counts (:func:`bucket_count_parts`): the "every lane pays
+    max_iter" pathology (CLAUDE.md) made visible.
     """
 
     iterations: Array  # [lanes] int32
@@ -153,20 +153,20 @@ class LaneTrace:
     value: Array  # [lanes] final objective values
     gradient_norm: Array  # [lanes]
     valid: Array  # [lanes] bool; False = padding lane
-    #: True when the lane scheduler (algorithm/lane_scheduler.py) produced
-    #: this trace — it has already observed these lanes into the
-    #: solver/lane_iters histogram, so telemetry consumers must not count
-    #: them again (static metadata, not a pytree leaf)
+    #: True when the lane scheduler (algorithm/lane_scheduler.py) produced this
+    #: trace: it has already observed these lanes into the solver/lane_iters
+    #: histogram, so telemetry must not count them again (static, not a leaf)
     scheduled: bool = flax.struct.field(pytree_node=False, default=False)
-    #: line-search work, filled by :func:`lane_trace_of` and read by
-    #: :func:`lane_solver_counts`; None on traces built elsewhere
+    #: line-search work, filled by :func:`lane_trace_of`; None elsewhere
     line_search_trials: Array | None = None  # [lanes] int32, a lane's own trials
     floor_exits: Array | None = None  # [lanes] int32
     #: int32 scalar: sum over iterations of the max over ALL lanes (padding
     #: lanes too) — the trips the vmapped search loop actually ran
     lockstep_trials: Array | None = None
-    #: [lanes] int32, a Newton lane's rounds that accepted no candidate; None
-    #: for every other solver (read by :func:`newton_lane_counts`)
+    #: [lanes, max_iter + 1] int32, the solve's own history: a lane's trials
+    #: by iteration (what :func:`bucket_count_parts` takes its maxima from)
+    search_trials: Array | None = None
+    #: [lanes] int32, a Newton lane's rejected rounds; None for other solvers
     rejected_rounds: Array | None = None
 
 
@@ -187,13 +187,12 @@ class LaneTraces:
 
 
 def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace:
-    """Build a LaneTrace from a (vmapped) SolverResult, dropping the
-    per-iteration histories that padding lanes would make meaningless (the
-    line-search history is reduced to each lane's total and the bucket's
-    lock-step total)."""
+    """Build a LaneTrace from a (vmapped) SolverResult, dropping the value
+    and gradient histories that padding lanes would make meaningless (the
+    line-search history stays, with each lane's total and the bucket's
+    lock-step total beside it)."""
     iterations = jnp.atleast_1d(result.iterations)
-    if valid is None:
-        valid = jnp.ones(iterations.shape, dtype=bool)
+    valid = jnp.ones(iterations.shape, bool) if valid is None else valid
     trials = jnp.atleast_2d(result.line_search_trials)  # [lanes, max_iter + 1]
     return LaneTrace(
         iterations=iterations,
@@ -206,17 +205,18 @@ def lane_trace_of(result: SolverResult, valid: Array | None = None) -> LaneTrace
         # iteration by iteration the vmapped search loop runs until its
         # slowest lane is done: the sum of those maxima is what the device ran
         lockstep_trials=jnp.sum(jnp.max(trials, axis=0), dtype=jnp.int32),
+        search_trials=trials,
         rejected_rounds=(None if result.rejected_rounds is None
                          else jnp.atleast_1d(result.rejected_rounds)),
     )
 
 
-#: the registry counters (``solver/<name>``) a fused sweep reports: the
-#: four of :func:`lane_solver_counts`, random-effect coordinates summed, the
-#: fixed-effect solves' own trials and floor exits, and the same four of the
-#: matrix-factorization half-steps' lanes (``mf_*``; zero without such a
-#: coordinate), and the three of :func:`newton_lane_counts` (zero where no
-#: random effect is solved by Newton)
+#: the thirteen registry counters (``solver/<name>``) a fused sweep has
+#: reported since PR 50, each a sum over the rows of the step's count array
+#: (:data:`BUCKET_COUNT_NAMES`, end of this file): four of the random-effect
+#: buckets, the fixed-effect solves' trials and floor exits, the same four of
+#: the matrix-factorization half-steps' buckets (``mf_*``) and three of the
+#: buckets Newton solves (``newton_*``); zero where a program has no such solve
 SOLVER_COUNT_NAMES = (
     "lockstep_trials", "lane_trials", "floor_exits", "line_searches",
     "fe_trials", "fe_floor_exits",
@@ -225,23 +225,23 @@ SOLVER_COUNT_NAMES = (
 )
 
 
-def lane_solver_counts(trace: LaneTrace) -> dict[str, Array]:
-    """A bucket's line-search work as int32 device scalars:
-    ``lockstep_trials`` (what the device ran: every lane waits for the
-    slowest search of each iteration), ``lane_trials`` (what the valid lanes
-    needed, each by itself), ``floor_exits`` and ``line_searches`` (valid
-    lanes; one search an iteration)."""
-    return {
-        "lockstep_trials": trace.lockstep_trials,
-        "lane_trials": _over_valid(trace, trace.line_search_trials),
-        "floor_exits": _over_valid(trace, trace.floor_exits),
-        "line_searches": _over_valid(trace, trace.iterations),
-    }
-
-
-def _over_valid(trace: LaneTrace, per_lane: Array) -> Array:
-    """A per-lane count summed over the trace's valid lanes (int32)."""
-    return jnp.sum(jnp.where(trace.valid, per_lane, 0), dtype=jnp.int32)
+#: a row of the fused step's count array, one row a solve (a bucket of lanes,
+#: or a fixed-effect solve as a bucket of one lane), built by
+#: :func:`bucket_count_parts` and :func:`bucket_counts` at the end of this
+#: file: what the device ran (``lockstep_trials``: iteration by iteration
+#: the slowest lane's trials, summed; ``lockstep_iterations``: the slowest
+#: lane's outer trips; both over ALL lanes, padding too: every lane waits),
+#: what the VALID lanes needed each by itself (``lane_trials``;
+#: ``line_searches``: one search, or Newton round, an iteration), the valid
+#: lanes (``lane_solves``) and how many of them each reason stopped (the four
+#: sum to it), the searches the float's floor ended, and the Newton lanes'
+#: rounds that accepted no candidate (zero under every other solver)
+BUCKET_COUNT_NAMES = (
+    "lockstep_trials", "lockstep_iterations",
+    "lane_trials", "floor_exits", "line_searches", "lane_solves",
+    "lanes_max_iterations", "lanes_function_tolerance",
+    "lanes_gradient_tolerance", "lanes_search_failed", "rejected_rounds",
+)
 
 
 def check_convergence(
@@ -417,15 +417,63 @@ def at_line_search_floor(failed: Array, claimable: Array, floor: Array) -> Array
     return failed & (jnp.abs(claimable) <= floor)
 
 
-def newton_lane_counts(trace: LaneTrace) -> dict[str, Array]:
-    """A bucket's Newton rounds as int32 device scalars:
-    ``newton_lockstep_rounds`` (what the device ran: the bucket's loop turns
-    until its slowest lane, padding or not, has stopped),
-    ``newton_lane_rounds`` (what the valid lanes needed, each by itself) and
-    ``newton_rejected_rounds`` (valid lanes' rounds that accepted no
-    candidate). For a trace that carries ``rejected_rounds``: Newton's."""
-    return {
-        "newton_lockstep_rounds": jnp.max(trace.iterations).astype(jnp.int32),
-        "newton_lane_rounds": _over_valid(trace, trace.iterations),
-        "newton_rejected_rounds": _over_valid(trace, trace.rejected_rounds),
-    }
+#: the reasons that can end a lane, in the order of ``lanes_max_iterations``
+#: ... ``lanes_search_failed`` (a solve never returns NOT_CONVERGED)
+_STOP_REASONS = (
+    ConvergenceReason.MAX_ITERATIONS,
+    ConvergenceReason.FUNCTION_VALUES_WITHIN_TOLERANCE,
+    ConvergenceReason.GRADIENT_WITHIN_TOLERANCE,
+    ConvergenceReason.LINE_SEARCH_FAILED,
+)
+
+
+def bucket_count_parts(trace: LaneTrace, parts: int = 1) -> tuple[Array, Array]:
+    """A solve's counts over each of ``parts`` runs of neighbouring lanes,
+    before anything crosses a part: (maxima ``[parts, max_iter + 2]`` over
+    ALL lanes of a part, padding too: its largest ``iterations``, then by
+    iteration its slowest search's trials; sums ``[parts, 9]`` over a part's
+    VALID lanes, in the order of :data:`BUCKET_COUNT_NAMES` from
+    ``lane_trials`` on). int32. Where the lane axis lies over the chips of a
+    mesh and ``parts`` is their number, a part is one chip's own lanes and
+    nothing here crosses a chip; :func:`bucket_counts` brings the parts
+    together, for one solve or for a stack of them at once (the fused step:
+    one reduction across the chips a sweep, not one a bucket). ``parts`` has
+    to divide the lanes."""
+    lanes = trace.iterations.shape[0]
+    if lanes % parts:
+        raise ValueError(f"{parts} parts do not divide a bucket of {lanes} lanes")
+
+    def by_part(per_lane):  # [lanes, ...] -> [parts, lanes / parts, ...]
+        return per_lane.reshape((parts, lanes // parts) + per_lane.shape[1:])
+
+    per_lane = [
+        trace.line_search_trials, trace.floor_exits, trace.iterations,
+        jnp.ones_like(trace.iterations),
+        *(trace.reason == reason for reason in _STOP_REASONS),
+        (jnp.zeros_like(trace.iterations) if trace.rejected_rounds is None
+         else trace.rejected_rounds),
+    ]
+    # each [lanes] count reduced by itself, the results stacked: stacked first
+    # and reduced once, the four-chip step's offsets gather of the widest
+    # coordinate ran a quarter slower (PERF.md 6, PR 52, call 6)
+    sums = jnp.stack([
+        jnp.sum(by_part(jnp.where(trace.valid, count, 0)), axis=1, dtype=jnp.int32)
+        for count in per_lane], axis=1)
+    maxima = jnp.concatenate([
+        jnp.max(by_part(trace.iterations), axis=1)[:, None],
+        jnp.max(by_part(trace.search_trials), axis=1)], axis=1)
+    return maxima.astype(jnp.int32), sums
+
+
+def bucket_counts(maxima: Array, sums: Array) -> Array:
+    """``[..., len(BUCKET_COUNT_NAMES)]`` int32 from :func:`bucket_count_parts`'
+    pair (``[parts, ..., max_iter + 2]``, ``[parts, ..., 9]``; the solves of a
+    stack between the two axes, their trips zero-padded to one width): the
+    parts' largest and their sums, and the search trips summed over the
+    iterations into ``lockstep_trials``."""
+    largest = jnp.max(maxima, axis=0)
+    return jnp.concatenate([
+        jnp.sum(largest[..., 1:], axis=-1, keepdims=True, dtype=jnp.int32),
+        largest[..., :1],
+        jnp.sum(sums, axis=0, dtype=jnp.int32),
+    ], axis=-1)

@@ -29,8 +29,8 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from functools import partial
-from typing import Mapping, Sequence
+from functools import lru_cache, partial
+from typing import Mapping, NamedTuple, Sequence
 
 import flax.struct
 import jax
@@ -55,14 +55,16 @@ from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.objective import GLMObjective
 from photon_ml_tpu.optim.common import (
+    BUCKET_COUNT_NAMES,
     SOLVER_COUNT_NAMES,
     SolverResult,
-    lane_solver_counts,
-    newton_lane_counts,
+    bucket_count_parts,
+    bucket_counts,
+    lane_trace_of,
 )
-from photon_ml_tpu.optim.optimizer import OptimizerConfig, solve
+from photon_ml_tpu.optim.optimizer import OptimizerConfig, OptimizerType, solve
 from photon_ml_tpu.parallel.mesh import place
-from photon_ml_tpu.telemetry.program_ledger import ledger_jit
+from photon_ml_tpu.telemetry.program_ledger import current_ledger, ledger_jit
 from photon_ml_tpu.telemetry.registry import default_registry
 from photon_ml_tpu.telemetry.tracing import span
 from photon_ml_tpu.types import TaskType
@@ -318,28 +320,106 @@ def _buckets_pytree(
     return out
 
 
-def _add_counts(total: dict, counts: Mapping) -> None:
-    """``total[name] += counts[name]`` over the solver counts of one solve."""
-    for name, value in counts.items():
-        total[name] = total.get(name, 0) + value
+class SolveRow(NamedTuple):
+    """What the host knows of one row of the step's count array without
+    reading it, from the program's specs and the packed buckets' static
+    shapes."""
+
+    family: str  # "re", "mf" or "fe"
+    coordinate: str  # the solve's scope: re/<type>, mf/<name>/<side>, fe/<shard>
+    lanes: int  # e, padding lanes included; 1 for a fixed effect
+    cap: int  # rows a lane; 0 for a fixed effect (its rows are the data's)
+    newton: bool  # a random effect's bucket whose lanes Newton solves
 
 
-def _re_lane_counts(trace) -> dict[str, Array]:
-    """A random-effect bucket's counts: its line-search work and, where the
-    lanes were solved by Newton (the trace then carries the rejected rounds),
-    its rounds."""
-    counts = lane_solver_counts(trace)
-    if trace.rejected_rounds is not None:
-        counts.update(newton_lane_counts(trace))
-    return counts
+#: the run journal's row kind for a sweep's counts by bucket (dev/doctor.py)
+LANE_COUNTS_ROW = "lane_counts"
+
+#: a family's totals beside the thirteen of SOLVER_COUNT_NAMES: sums of the
+#: rows' columns of those names, and the rows a search passed over against
+#: the rows a live lane asked for (Python integers: a sweep's may pass int32)
+_FAMILY_TOTALS = (
+    "lockstep_iterations", "lane_solves", "lanes_max_iterations",
+    "lanes_function_tolerance", "lanes_gradient_tolerance",
+    "lanes_search_failed",
+)
 
 
-def _fe_solved(result: SolverResult) -> tuple[Array, dict[str, Array]]:
-    """A fixed-effect solve's (coefficients, ``fe_*`` line-search counts)."""
-    return result.coefficients, {
-        "fe_trials": jnp.sum(result.line_search_trials, dtype=jnp.int32),
-        "fe_floor_exits": result.floor_exits,
-    }
+@lru_cache(maxsize=32)
+def _counter_plan(rows: "tuple[SolveRow, ...]") -> "tuple[tuple[str, ...], np.ndarray]":
+    """How a sweep's count array becomes ``solver/<key>`` increments, worked
+    out once for a program's rows: (the keys, an int64 matrix that takes the
+    flattened ``[solves, columns]`` array to their values). The keys: the
+    thirteen of SOLVER_COUNT_NAMES (always, zero where the program has no
+    such solve), then for every family the rows hold (random effects bare,
+    ``mf_``) its ``_FAMILY_TOTALS`` and ``row_trials_paid`` /
+    ``row_trials_wanted``, then every coordinate's own four,
+    ``<scope>/lockstep_trials|lockstep_iterations|row_trials_paid|
+    row_trials_wanted``. A trial of a bucket passes over ``lanes x cap``
+    rows and a lane wants ``cap`` of them. A bucket Newton solves is a
+    random effect's like any other and fills the three ``newton_`` names
+    besides; further ``newton_`` totals wait for a reader in the cell that
+    has such lanes (ROADMAP R7 i): the journal's row has them by bucket."""
+    column = {name: j for j, name in enumerate(BUCKET_COUNT_NAMES)}
+    terms: dict[str, list] = {name: [] for name in SOLVER_COUNT_NAMES}
+
+    def add(key, i, name, factor=1):
+        terms.setdefault(key, []).append((i * len(column) + column[name], factor))
+
+    for i, row in enumerate(rows):
+        if row.family == "fe":
+            add("fe_trials", i, "lane_trials")
+            add("fe_floor_exits", i, "floor_exits")
+            continue
+        prefix = "" if row.family == "re" else "mf_"
+        slots = row.lanes * row.cap
+        for name in ("lockstep_trials", "lane_trials", "floor_exits",
+                     "line_searches", *_FAMILY_TOTALS):
+            add(prefix + name, i, name)
+        for name in ("lockstep_trials", "lockstep_iterations"):
+            add(f"{row.coordinate}/{name}", i, name)
+        for key in (prefix, row.coordinate + "/"):
+            add(key + "row_trials_paid", i, "lockstep_trials", slots)
+            add(key + "row_trials_wanted", i, "lane_trials", row.cap)
+        if row.newton:
+            add("newton_lockstep_rounds", i, "lockstep_iterations")
+            add("newton_lane_rounds", i, "line_searches")
+            add("newton_rejected_rounds", i, "rejected_rounds")
+    # coordinates after the families, each coordinate's four together
+    keys = sorted(terms, key=lambda k: "/" in k)
+    matrix = np.zeros((len(keys), len(rows) * len(column)), np.int64)
+    for k, key in enumerate(keys):
+        for j, factor in terms[key]:
+            matrix[k, j] += factor
+    return tuple(keys), matrix
+
+
+class SweepCounts:
+    """A fused sweep's solver counts on the host: the step's count array as
+    it was read (``array[i]`` = the counts of ``rows[i]``, columns in
+    optim/common.BUCKET_COUNT_NAMES' order). :meth:`counters` is what the
+    registry is bumped by (Python integers: a sweep's rows paid may pass
+    int32), :meth:`table` what a run journal keeps."""
+
+    def __init__(self, rows: "tuple[SolveRow, ...]", array):
+        self.rows, self.array = rows, np.asarray(array, np.int64)
+
+    def table(self) -> list[dict]:
+        """One dict a solve: scope, lanes, cap and the row's counts."""
+        return [{"coordinate": row.coordinate, "lanes": row.lanes,
+                 "cap": row.cap, **dict(zip(BUCKET_COUNT_NAMES, counts))}
+                for row, counts in zip(self.rows, self.array.tolist())]
+
+    def counters(self) -> dict[str, int]:
+        """``solver/<key>`` increments of the sweep (:func:`_counter_plan`)."""
+        keys, matrix = _counter_plan(self.rows)
+        return dict(zip(keys, (matrix @ self.array.ravel()).tolist()))
+
+
+def _fe_solved(result: SolverResult) -> tuple[Array, tuple[Array, Array]]:
+    """A fixed-effect solve's (coefficients, its counts as a bucket of one
+    lane: optim/common.bucket_count_parts)."""
+    return result.coefficients, bucket_count_parts(lane_trace_of(result))
 
 
 class _CarriedStep:
@@ -503,6 +583,9 @@ class GameTrainProgram:
             (NamedSharding(mesh, P("data")), NamedSharding(mesh, P()))
             if multi_device else None
         )
+        # the chips a bucket's lanes lie over: its counts are taken chip by
+        # chip and brought together once a sweep (_stacked_counts)
+        self._lane_parts = int(mesh.shape["data"]) if multi_device else 1
         if mesh is None and use_pallas_fe is None:
             use_pallas_fe = False  # topology unknown: keep the kernel out
         self._fe_objective = GLMObjective(
@@ -603,6 +686,7 @@ class GameTrainProgram:
                                         label="train/entry_scores")
         self._step = _CarriedStep(self)
         self._solver_counts = None  # of the last fused sweep, on the device
+        self._rows_of = None  # (the buckets last stepped over, their solve_rows)
         self._score = ledger_jit(self._score_impl, label="train/score")
 
     def fe_coefficients_model_space(self, state: GameTrainState,
@@ -924,21 +1008,56 @@ class GameTrainProgram:
         in is donated (:class:`_CarriedStep`): ``state.scores`` is not to be
         read after the call, the tables and coefficients are.
 
-        The sweep's line-search counts (optim/common.SOLVER_COUNT_NAMES)
-        stay on the program as one unread device array:
-        :meth:`take_solver_counts` reads it."""
-        state, loss, self._solver_counts = self._step(data, buckets, state)
+        The sweep's solver counts (a row of optim/common.BUCKET_COUNT_NAMES
+        for every solve, :meth:`solve_rows`) stay on the program as one
+        unread device array, its copy to the host started here, behind the
+        sweep's work: :meth:`take_solver_counts` reads it (a read that
+        starts only once the loss has arrived waits 0.4 ms more for some
+        hundred bytes, PERF.md 6, PR 52)."""
+        state, loss, counts = self._step(data, buckets, state)
+        counts.copy_to_host_async()
+        self._solver_counts = (self.solve_rows(buckets), counts)
         return state, loss
 
-    def take_solver_counts(self) -> "dict[str, int] | None":
-        """The line-search counts of the sweep :meth:`step` last ran, as host
-        ints (a device-to-host read: call it once the loss has been waited
-        for), or None when no fused sweep left any; each sweep's counts are
-        handed out once."""
-        counts, self._solver_counts = self._solver_counts, None
-        if counts is None:
+    def take_solver_counts(self) -> "SweepCounts | None":
+        """The solver counts of the sweep :meth:`step` last ran, on the host
+        (ONE device-to-host read, which :meth:`step` started: call it once
+        the loss has been waited for), or None when no fused sweep left any;
+        each sweep's counts are handed out once."""
+        held, self._solver_counts = self._solver_counts, None
+        if held is None:
             return None
-        return dict(zip(SOLVER_COUNT_NAMES, np.asarray(counts).tolist()))
+        rows, counts = held
+        return SweepCounts(rows, counts)
+
+    def solve_rows(self, buckets) -> "tuple[SolveRow, ...]":
+        """The rows of the step's count array, in its order: the bucket
+        solves (coordinates in update order, a random effect's buckets in
+        the ladder's order, a factorization's alternations with the row
+        side's buckets ahead of the column side's), then the fixed-effect
+        solves in update order. From static shapes: nothing is read, and a
+        fit's sweeps, which hand in one packed-buckets object, work them out
+        once (the program keeps that object with its rows)."""
+        if self._rows_of is not None and self._rows_of[0] is buckets:
+            return self._rows_of[1]
+        lanes, fixed = [], []
+        for name in self.update_order:
+            kind = self._kind[name]
+            if kind == "re":
+                newton = (self._re_by_name[name].optimizer.optimizer_type
+                          == OptimizerType.NEWTON)
+                lanes += [SolveRow("re", f"re/{name}", *b["labels"].shape, newton)
+                          for b in buckets[name]]
+            elif kind == "mf":
+                sides = buckets["__mf__"][name]
+                lanes += [
+                    SolveRow("mf", f"mf/{name}/{side}", *b["labels"].shape, False)
+                    for _ in range(self._mf_by_name[name].num_alternations)
+                    for side in ("row", "col") for b in sides[side]]
+            else:
+                fixed.append(SolveRow("fe", f"fe/{name}", 1, 0, False))
+        self._rows_of = (buckets, tuple(lanes + fixed))
+        return self._rows_of[1]
 
     def _carried(self, data, state: GameTrainState) -> GameTrainState:
         """``state`` with its margins over ``data``'s rows: as it came where
@@ -1277,7 +1396,10 @@ class GameTrainProgram:
         tables = dict(state.re_tables)
         mf_rows = dict(state.mf_rows)
         mf_cols = dict(state.mf_cols)
-        solver_counts = dict.fromkeys(SOLVER_COUNT_NAMES, jnp.int32(0))
+        # every solve's counts by parts (optim/common.bucket_count_parts),
+        # in solve_rows' order: the lanes' buckets, the fixed effects
+        lane_counts: list = []
+        fe_counts: list = []
 
         for name in self.update_order:
             kind = self._kind[name]
@@ -1285,20 +1407,20 @@ class GameTrainProgram:
                 fe_w, counts = self._solve_primary_fe(
                     data, offsets_excluding(name), weights, fe_w
                 )
-                _add_counts(solver_counts, counts)
+                fe_counts.append(counts)
                 scores[name] = self._fe_margin_score(data, fe_w)
             elif kind == "extra_fe":
                 extra_fe[name], counts = self._solve_extra_fe(
                     data, name, offsets_excluding(name), labels, weights,
                     extra_fe[name],
                 )
-                _add_counts(solver_counts, counts)
+                fe_counts.append(counts)
                 scores[name] = self._extra_fe_margin(data, name, extra_fe[name])
             elif kind == "re":
                 tables[name], counts = self._solve_re(
                     data, buckets, name, offsets_excluding(name), tables[name]
                 )
-                _add_counts(solver_counts, counts)
+                lane_counts += counts
                 scores[name] = self._re_coordinate_score(
                     data, name, tables[name],
                     self._re_by_name[name].feature_shard_id,
@@ -1309,7 +1431,7 @@ class GameTrainProgram:
                         data, buckets, name, offsets_excluding(name),
                         mf_rows[name], mf_cols[name],
                     ))
-                _add_counts(solver_counts, counts)
+                lane_counts += counts
 
         total_margin = offsets_excluding()
         train_loss = self._weighted_loss(labels, weights, total_margin)
@@ -1319,8 +1441,34 @@ class GameTrainProgram:
             scores=self._over_rows(scores),
         )
         # one small array: one device-to-host read a sweep, not one a count
-        return new_state, train_loss, jnp.stack(
-            [solver_counts[name] for name in SOLVER_COUNT_NAMES])
+        return new_state, train_loss, self._stacked_counts(
+            lane_counts, fe_counts)
+
+    def _stacked_counts(self, lane_counts, fe_counts) -> Array:
+        """``int32[solves, len(BUCKET_COUNT_NAMES)]`` from every solve's
+        counts by parts: the lanes' buckets stacked and their parts (on a
+        mesh a chip's own lanes each) brought together ONCE, one ``max`` and
+        one ``sum`` across the chips a sweep and not a handful a bucket; the
+        fixed effects' rows, whose counts every chip holds whole, after
+        them. Trips are zero-padded to the widest solve's (a count is never
+        negative)."""
+        def stacked(counts):  # [parts, solves, ...] each
+            width = max(maxima.shape[1] for maxima, _ in counts)
+            return (jnp.stack([jnp.pad(maxima, ((0, 0), (0, width - maxima.shape[1])))
+                               for maxima, _ in counts], axis=1),
+                    jnp.stack([sums for _, sums in counts], axis=1))
+
+        rows = []
+        if lane_counts:
+            by_part = stacked(lane_counts)
+            if self._exchange is not None:  # a mesh: a part is a chip's own
+                by_leading_axis, _ = self._exchange
+                by_part = tuple(jax.lax.with_sharding_constraint(a, by_leading_axis)
+                                for a in by_part)
+            rows.append(bucket_counts(*by_part))
+        if fe_counts:
+            rows.append(bucket_counts(*stacked(fe_counts)))
+        return jnp.concatenate(rows, axis=0)
 
     def _solve_primary_fe(self, data, fe_offsets, weights, fe_w0):
         """Primary fixed-effect solve (samples sharded; grads psum over the
@@ -1332,7 +1480,7 @@ class GameTrainProgram:
         The returned vector lives in normalized space (warm starts stay
         there across steps); callers score through the same effective-
         coefficient algebra the objective uses, so residuals stay in data
-        space. Returns (coefficients, the solve's ``fe_*`` counts).
+        space. Returns (coefficients, the solve's counts: ``_fe_solved``).
         """
         with jax.named_scope("fe/solve"):
             fe_sparse = data.get("fe_sparse_batch")
@@ -1419,12 +1567,13 @@ class GameTrainProgram:
     def _solve_re(self, data, buckets, k, full_offsets, table):
         """One random-effect coordinate (entities sharded, vmapped solves),
         under the scope ``re/<k>``; on a mesh the offsets are brought whole
-        to every chip once, ahead of the buckets. Returns (table, the
-        coordinate's line-search counts: its buckets'
-        optim/common.lane_solver_counts summed)."""
+        to every chip once, ahead of the buckets. Returns (table, its
+        buckets' counts by parts, one optim/common.bucket_count_parts pair
+        a bucket)."""
         spec = self._re_by_name[k]
         objective = self._re_solve_objectives[k]
-        counts: dict = {}
+        counts: list = []
+        parts = self._lane_parts
         with jax.named_scope(f"re/{k}"):
             (full_offsets,) = self._whole_on_every_chip(full_offsets)
             if spec.projector == ProjectorType.INDEX_MAP:
@@ -1442,7 +1591,7 @@ class GameTrainProgram:
                         b["sample_rows"], b["entity_rows"], b["col_index"],
                         full_offsets, table_ext,
                     )
-                    _add_counts(counts, _re_lane_counts(trace))
+                    counts.append(bucket_count_parts(trace, parts))
                 return table_ext[:, :-1], counts
             if spec.projector == ProjectorType.RANDOM:
                 matrix = buckets["__projections__"][k]
@@ -1453,7 +1602,7 @@ class GameTrainProgram:
                         b["sample_rows"], b["entity_rows"], matrix,
                         full_offsets, table,
                     )
-                    _add_counts(counts, _re_lane_counts(trace))
+                    counts.append(bucket_count_parts(trace, parts))
                 return table, counts
             for b in buckets[k]:
                 table, trace = solve_entity_bucket_traced(
@@ -1467,7 +1616,7 @@ class GameTrainProgram:
                     full_offsets,
                     table,
                 )
-                _add_counts(counts, _re_lane_counts(trace))
+                counts.append(bucket_count_parts(trace, parts))
             return table, counts
 
     def _solve_mf(self, data, buckets, name, full_offsets, rows, cols):
@@ -1476,14 +1625,14 @@ class GameTrainProgram:
         device instructions carry the coordinate's name; on a mesh what its
         buckets index (the offsets, the fixed side's entity index and
         factors) is brought whole to every chip once a half-step. Returns
-        (rows, cols, score, the half-steps' line-search counts: their
-        buckets' optim/common.lane_solver_counts summed, as ``mf_*``)."""
+        (rows, cols, score, the half-steps' buckets' counts by parts, in
+        the order they were solved)."""
         m = self._mf_by_name[name]
         row_idx = data["entity_idx"][m.row_effect_type]
         col_idx = data["entity_idx"][m.col_effect_type]
         objective = self._mf_objectives[name]
         mf_buckets = buckets["__mf__"][name]
-        counts: dict = {}
+        counts: list = []
 
         def half_step(side, table, other_idx, other_factors):
             with jax.named_scope(f"mf/{name}/{side}"):
@@ -1495,9 +1644,7 @@ class GameTrainProgram:
                         b["entity_rows"], b["sample_rows"], other_idx,
                         other_factors, offsets, table,
                     )
-                    _add_counts(counts, {
-                        "mf_" + k: v
-                        for k, v in lane_solver_counts(trace).items()})
+                    counts.append(bucket_count_parts(trace, self._lane_parts))
             return table
 
         for _ in range(m.num_alternations):
@@ -2149,9 +2296,13 @@ def _step_and_wait(program: GameTrainProgram, data, buckets,
     loss: ``train/step`` ends when the work is ENQUEUED, ``train/loss_wait``
     is the host blocked on the device. Bumps ``train/sweeps`` and
     ``train/rows`` (rows trained, the work as a count), and the
-    ``solver/<name>`` counters from the fused sweep's line-search counts,
-    read under ``train/solver_counts`` once the loss has arrived (the
-    device is done by then: no second wait). Returns
+    ``solver/<name>`` counters from the fused sweep's solver counts
+    (:meth:`SweepCounts.counters`: trials, outer trips, rows paid and
+    wanted, the lanes by stop reason; by family and by coordinate), read
+    under ``train/solver_counts`` once the loss has arrived (the device is
+    done by then: no second wait); a run journal, where the installed
+    program ledger has one (``--telemetry-dir``), gets the table by bucket
+    as one ``lane_counts`` row a sweep, and nothing else does. Returns
     (state, loss as a float); a non-finite loss raises before any
     checkpoint could overwrite the last finite state with NaNs (CD-path
     DivergenceError contract, coordinate_descent.py)."""
@@ -2181,9 +2332,14 @@ def _step_and_wait(program: GameTrainProgram, data, buckets,
     registry.counter("train/sweeps").inc()
     registry.counter("train/rows").inc(rows)
     with span("train/solver_counts"):
-        counts = program.take_solver_counts() or {}
-    for name, value in counts.items():
-        registry.counter(f"solver/{name}").inc(value)
+        counts = program.take_solver_counts()
+    if counts is not None:
+        for name, value in counts.counters().items():
+            registry.counter(f"solver/{name}").inc(value)
+        ledger = current_ledger()
+        if ledger is not None and ledger.journal is not None:
+            ledger.journal.record(LANE_COUNTS_ROW, sweep=sweep + 1,
+                                  buckets=counts.table())
     return state, loss
 
 

@@ -1,6 +1,8 @@
-"""The fused sweep's line-search counts: a third output of the step program,
-kept on the program as device scalars, read after the loss under
+"""The fused sweep's solver counts: a third output of the step program, one
+small array with a row for every solve (optim/common.BUCKET_COUNT_NAMES),
+kept on the program unread, read once after the loss under
 ``train/solver_counts`` and summed into the registry's ``solver/*`` counters
+by family and by coordinate; a run journal gets the rows themselves
 (PERF.md §3)."""
 
 import functools
@@ -20,7 +22,15 @@ from photon_ml_tpu.data.game_data import (
     build_game_dataset,
     build_random_effect_dataset,
 )
-from photon_ml_tpu.optim.common import SOLVER_COUNT_NAMES
+from photon_ml_tpu.optim.common import (
+    BUCKET_COUNT_NAMES,
+    SOLVER_COUNT_NAMES,
+    ConvergenceReason,
+    SolverResult,
+    bucket_count_parts,
+    bucket_counts,
+    lane_trace_of,
+)
 from photon_ml_tpu.optim.optimizer import (
     OptimizerConfig,
     OptimizerType,
@@ -32,7 +42,15 @@ from photon_ml_tpu.parallel.distributed import (
     GameTrainState,
     MatrixFactorizationStepSpec,
     RandomEffectStepSpec,
+    SolveRow,
+    SweepCounts,
     train_distributed,
+)
+from photon_ml_tpu.telemetry.journal import RunJournal, read_journal
+from photon_ml_tpu.telemetry.program_ledger import (
+    ProgramLedger,
+    install_ledger,
+    uninstall_ledger,
 )
 from photon_ml_tpu.telemetry.registry import default_registry
 from photon_ml_tpu.telemetry.tracing import (
@@ -46,7 +64,15 @@ SWEEPS = 2
 RE_TYPES = ("user", "item")
 
 
-def glmix(dtype):
+REASONS = ("lanes_max_iterations", "lanes_function_tolerance",
+           "lanes_gradient_tolerance", "lanes_search_failed")
+FAMILY_TOTALS = ("lockstep_iterations", "lane_solves", *REASONS,
+                 "row_trials_paid", "row_trials_wanted")
+COORDINATE_COUNTS = ("lockstep_trials", "lockstep_iterations",
+                     "row_trials_paid", "row_trials_wanted")
+
+
+def glmix(dtype, **optimizer):
     """A GLMix problem with several buckets a coordinate, and its program."""
     rng = np.random.default_rng(7)
     n = 240
@@ -61,8 +87,9 @@ def glmix(dtype):
     )
     re_datasets = {t: build_random_effect_dataset(
         dataset, t, "re", bucket_sizes=(8, 32, 128)) for t in RE_TYPES}
-    opt = OptimizerConfig(optimizer_type=OptimizerType.LBFGS, max_iterations=6,
-                          rel_function_tolerance=1e-6)
+    opt = OptimizerConfig(**{
+        "optimizer_type": OptimizerType.LBFGS, "max_iterations": 6,
+        "rel_function_tolerance": 1e-6, **optimizer})
     program = GameTrainProgram(
         TaskType.LOGISTIC_REGRESSION,
         FixedEffectStepSpec("global", opt, l2_weight=0.5),
@@ -171,8 +198,15 @@ def test_step_still_returns_a_pair_and_keeps_the_counts_on_the_program(
     out = program.step(data, buckets, state)
     assert len(out) == 2 and isinstance(out[0], GameTrainState)
     counts = program.take_solver_counts()
-    assert sorted(counts) == sorted(SOLVER_COUNT_NAMES)
-    assert all(isinstance(v, int) for v in counts.values())
+    assert counts.rows == program.solve_rows(buckets)
+    assert np.shape(counts.array) == (len(counts.rows), len(BUCKET_COUNT_NAMES))
+    counters = counts.counters()
+    # the thirteen first and whole, then the random effects' new totals and
+    # four counters a coordinate: no other family's, and nothing a bucket
+    assert list(counters) == [
+        *SOLVER_COUNT_NAMES, *FAMILY_TOTALS,
+        *(f"re/{t}/{name}" for t in RE_TYPES for name in COORDINATE_COUNTS)]
+    assert all(isinstance(v, int) for v in counters.values())
     assert program.take_solver_counts() is None  # handed out once
 
 
@@ -208,8 +242,15 @@ def test_mf_counts_are_the_half_steps_lane_traces_summed():
     data, buckets = program.prepare_inputs(dataset, re_datasets, mf_datasets)
     state = program.init_state(dataset, re_datasets, mf_datasets)
     new_state, _loss = program.step(data, buckets, state)
-    counts = program.take_solver_counts()
-    assert sorted(counts) == sorted(SOLVER_COUNT_NAMES)
+    taken = program.take_solver_counts()
+    counts = taken.counters()
+    # a row a bucket of every half-step, the row side's ahead of the column
+    # side's, after the random effects' and ahead of the fixed effect's
+    sides = buckets["__mf__"]["mf"]
+    assert [row.coordinate for row in taken.rows if row.family == "mf"] == [
+        f"mf/mf/{side}" for side in ("row", "col") for _ in sides[side]]
+    assert [row.family for row in taken.rows] == sorted(
+        (row.family for row in taken.rows), key=("re", "mf", "fe").index)
 
     # the half-steps again, outside the step, at the offsets the step gave them
     scores = program._coordinate_scores(data, GameTrainState(
@@ -236,6 +277,19 @@ def test_mf_counts_are_the_half_steps_lane_traces_summed():
             expect["mf_line_searches"] += int(np.sum(trace.iterations))
     assert {name: counts[name] for name in MF_COUNTS} == expect
     assert 0 < counts["mf_lockstep_trials"] < counts["mf_lane_trials"]
+    # the factorization's sides fill their own family and their own
+    # coordinates, and nothing of Newton's
+    lanes = sum(b["labels"].shape[0] for side in sides.values() for b in side)
+    assert counts["mf_lane_solves"] == lanes == sum(
+        counts["mf_" + reason] for reason in REASONS)
+    assert 0 < counts["mf_row_trials_wanted"] < counts["mf_row_trials_paid"]
+    for name in COORDINATE_COUNTS:
+        assert counts["mf_" + name] == (
+            counts[f"mf/mf/row/{name}"] + counts[f"mf/mf/col/{name}"])
+        assert counts[name] == sum(counts[f"re/{t}/{name}"] for t in RE_TYPES)
+    assert not any(key.startswith("newton_") and value
+                   for key, value in counts.items())
+    assert not any(key.startswith("newton_lane_solves") for key in counts)
     np.testing.assert_allclose(np.asarray(new_state.mf_rows["mf"]), np.asarray(rows))
     np.testing.assert_allclose(np.asarray(new_state.mf_cols["mf"]), np.asarray(cols))
     # the random effects' lanes are counted beside them, under their own names
@@ -251,4 +305,166 @@ def test_without_an_mf_spec_the_mf_counts_are_zero_and_cost_nothing(float64_fit)
     state = program.init_state(dataset, re_datasets, None)
     jaxpr = jax.make_jaxpr(program._step_impl)(
         data, buckets, program._carried(data, state))
-    assert jaxpr.out_avals[-1].shape == (len(SOLVER_COUNT_NAMES),)
+    assert jaxpr.out_avals[-1].shape == (
+        len(program.solve_rows(buckets)), len(BUCKET_COUNT_NAMES))
+    counters = SweepCounts(program.solve_rows(buckets), np.zeros(
+        jaxpr.out_avals[-1].shape, int).tolist()).counters()
+    assert not any(key.startswith(("mf_row", "mf_lane_solves", "mf/"))
+                   for key in counters)
+    # of Newton's family the three names the step has had since PR 50, no more
+    assert [key for key in counters if key.startswith("newton_")] == [
+        name for name in SOLVER_COUNT_NAMES if name.startswith("newton_")]
+
+
+def _lanes_result(lanes, rng, iterations_at_most=6):
+    """A vmapped SolverResult as a solver fills it: a lane's trials stand in
+    slots 1 .. iterations, zeros elsewhere."""
+    slots = iterations_at_most + 1
+    iterations = rng.integers(0, slots, lanes)
+    trials = rng.integers(1, 5, (lanes, slots)) * (
+        (np.arange(slots) >= 1) & (np.arange(slots) <= iterations[:, None]))
+    zeros = np.zeros(lanes)
+    return SolverResult(
+        coefficients=jnp.zeros((lanes, 3)), value=jnp.asarray(zeros),
+        gradient_norm=jnp.asarray(zeros),
+        iterations=jnp.asarray(iterations, jnp.int32),
+        reason=jnp.asarray(rng.integers(1, 5, lanes), jnp.int32),
+        value_history=jnp.zeros((lanes, slots)),
+        grad_norm_history=jnp.zeros((lanes, slots)),
+        line_search_trials=jnp.asarray(trials, jnp.int32),
+        floor_exits=jnp.asarray(rng.integers(0, 2, lanes), jnp.int32),
+        rejected_rounds=None)
+
+
+@pytest.mark.parametrize("lanes, parts", [
+    (1, 1), (12, 1), (12, 2), (12, 4), (12, 6), (64, 4), (64, 8)])
+def test_a_buckets_row_is_numpys_own_count_of_its_trace(lanes, parts):
+    """Whatever the parts the lanes are counted in (on a mesh: a chip's own
+    each), a bucket's row is: the slowest lane's outer trips and, iteration by
+    iteration, the slowest lane's trials, over ALL lanes; everything else over
+    the valid lanes alone: padding lanes are in no reason and in no sum."""
+    rng = np.random.default_rng(100 * lanes + parts)
+    result = _lanes_result(lanes, rng)
+    valid = np.ones(1, bool) if lanes == 1 else rng.random(lanes) < 0.7
+    trace = lane_trace_of(result, jnp.asarray(valid))
+    maxima, sums = bucket_count_parts(trace, parts)
+    assert maxima.shape[0] == sums.shape[0] == parts
+    row = dict(zip(BUCKET_COUNT_NAMES, np.asarray(bucket_counts(maxima, sums)).tolist()))
+    iterations, trials = np.asarray(result.iterations), np.asarray(result.line_search_trials)
+    reason = np.asarray(result.reason)
+    assert row["lockstep_iterations"] == iterations.max()
+    assert row["lockstep_trials"] == trials.max(axis=0).sum() == int(trace.lockstep_trials)
+    assert row["lane_trials"] == trials[valid].sum()
+    assert row["line_searches"] == iterations[valid].sum()
+    assert row["floor_exits"] == np.asarray(result.floor_exits)[valid].sum()
+    assert row["lane_solves"] == valid.sum() == sum(row[name] for name in REASONS)
+    for name, code in zip(REASONS, (
+            ConvergenceReason.MAX_ITERATIONS,
+            ConvergenceReason.FUNCTION_VALUES_WITHIN_TOLERANCE,
+            ConvergenceReason.GRADIENT_WITHIN_TOLERANCE,
+            ConvergenceReason.LINE_SEARCH_FAILED)):
+        assert row[name] == (reason[valid] == code).sum()
+    assert row["rejected_rounds"] == 0  # no Newton lane
+    # what the device paid against what a live lane asked for, in rows
+    cap = 32
+    counters = SweepCounts((SolveRow("re", "re/t", lanes, cap, False),),
+                           [list(row.values())]).counters()
+    assert counters["row_trials_paid"] == row["lockstep_trials"] * lanes * cap
+    assert counters["row_trials_wanted"] == row["lane_trials"] * cap
+    assert counters["row_trials_wanted"] <= counters["row_trials_paid"]
+    if lanes == 1:  # a bucket of one lane pays what the lane wants
+        assert counters["row_trials_wanted"] == counters["row_trials_paid"] > 0
+    with pytest.raises(ValueError, match="do not divide"):
+        bucket_count_parts(trace, lanes + 1)
+
+
+def test_a_sweeps_rows_are_its_whole_solver_results_own(float64_fit):
+    """One sweep from the start, its rows against the same sweep's WHOLE
+    solver results: a bucket's ``lockstep_iterations`` is the largest
+    ``iterations`` of its lanes, its lanes are counted once each by why they
+    stopped, and the fixed effect's solve is a row of one lane."""
+    dataset, re_datasets, program, _gained, _events = float64_fit
+    data, buckets = program.prepare_inputs(dataset, re_datasets, None)
+    state = program.init_state(dataset, re_datasets, None)
+    fe, lanes, _state = jax.jit(functools.partial(_whole_results, program))(
+        data, buckets, state)
+    program.step(data, buckets, state)
+    taken = program.take_solver_counts()
+    table = taken.table()
+    shapes = [b["labels"].shape for t in RE_TYPES for b in buckets[t]]
+    assert [(row["coordinate"], row["lanes"], row["cap"]) for row in table] == [
+        *((f"re/{t}", *b["labels"].shape) for t in RE_TYPES for b in buckets[t]),
+        ("fe/global", 1, 0)]
+    for row, result, (e, cap) in zip(table, lanes, shapes):
+        iterations = np.asarray(result.iterations)
+        assert row["lockstep_iterations"] == iterations.max()
+        assert row["line_searches"] == iterations.sum()
+        assert row["lane_solves"] == e == sum(row[name] for name in REASONS)
+        assert row["lanes_max_iterations"] == (
+            np.asarray(result.reason) == ConvergenceReason.MAX_ITERATIONS).sum()
+        assert row["lane_trials"] * cap <= row["lockstep_trials"] * e * cap
+    assert table[-1]["lane_solves"] == 1 == sum(table[-1][name] for name in REASONS)
+    assert table[-1]["lockstep_iterations"] == int(fe.iterations)
+    assert table[-1]["lockstep_trials"] == table[-1]["lane_trials"] == int(
+        jnp.sum(fe.line_search_trials))
+    counters = taken.counters()
+    assert counters["lockstep_iterations"] == sum(
+        int(np.asarray(r.iterations).max()) for r in lanes)
+    assert 0 < counters["row_trials_wanted"] < counters["row_trials_paid"]
+
+
+def test_at_a_cap_of_one_iteration_every_lane_stops_at_the_cap():
+    """``max_iterations=1`` from zero: no lane can meet a tolerance after one
+    step, so every valid lane, and the fixed effect's one, is counted under
+    ``lanes_max_iterations``: the pathology this count exists to show."""
+    dataset, re_datasets, program = glmix(
+        np.float64, max_iterations=1, tolerance=1e-14, rel_function_tolerance=1e-14)
+    data, buckets = program.prepare_inputs(dataset, re_datasets, None)
+    program.step(data, buckets, program.init_state(dataset, re_datasets, None))
+    table = program.take_solver_counts().table()
+    assert all(row["lanes_max_iterations"] == row["lane_solves"] > 0 for row in table)
+    assert all(row["lockstep_iterations"] == 1 for row in table)
+
+
+def test_a_run_journal_gets_a_row_a_sweep_and_nothing_else_gets_the_table(tmp_path):
+    """With a program ledger installed that has a journal (``--telemetry-dir``)
+    every sweep leaves one ``lane_counts`` row holding the table by bucket;
+    the registry holds totals and a coordinate's four, never a bucket's."""
+    dataset, re_datasets, program = glmix(np.float64)
+    registry = default_registry()
+    names = ("lockstep_trials", "lockstep_iterations", "lane_solves")
+    before = {n: registry.counter("solver/" + n).value for n in names}
+    journal = RunJournal(str(tmp_path))
+    install_ledger(ProgramLedger(journal=journal))
+    try:
+        train_distributed(program, dataset, re_datasets, num_iterations=SWEEPS)
+    finally:
+        uninstall_ledger()
+        journal.close()
+    rows = [r for r in read_journal(journal.path) if r["kind"] == "lane_counts"]
+    assert [r["sweep"] for r in rows] == list(range(1, SWEEPS + 1))
+    buckets = sum(len(ds.buckets) for ds in re_datasets.values())
+    for r in rows:
+        assert len(r["buckets"]) == buckets + 1  # and the fixed effect's solve
+        assert set(r["buckets"][0]) == {"coordinate", "lanes", "cap", *BUCKET_COUNT_NAMES}
+    for name in names:
+        assert registry.counter("solver/" + name).value - before[name] == sum(
+            b[name] for r in rows for b in r["buckets"] if b["coordinate"] != "fe/global")
+    counters = registry.snapshot()["counters"]
+    assert not any(key.startswith("solver/") and any(ch.isdigit() for ch in key)
+                   for key in counters)
+    # and the run doctor renders the newest row by coordinate, every row by sweep
+    from dev.doctor import run_doctor
+
+    code, _findings, report = run_doctor(str(tmp_path))
+    assert code == 0 and f"lanes, sweep {SWEEPS} of {SWEEPS} journaled" in report
+    last = {b["coordinate"]: 0 for b in rows[-1]["buckets"]}
+    for b in rows[-1]["buckets"]:
+        last[b["coordinate"]] += b["lockstep_iterations"]
+    for coordinate, trips in last.items():
+        line = next(l for l in report.splitlines() if l.strip().startswith(coordinate + " "))
+        assert f"trips {trips:>5}" in line and "stops " in line
+        trend = report.splitlines()[report.splitlines().index(line) + 1]
+        assert trend.split("by sweep: trials ")[1].split(";")[0].split() == [
+            str(sum(b["lockstep_trials"] for b in r["buckets"]
+                    if b["coordinate"] == coordinate)) for r in rows]

@@ -504,7 +504,8 @@ def test_the_exchange_changes_no_bit_of_a_sweep(host, four, mesh4):
 
     state, loss, counts = sweep(True)
     plain_state, plain_loss, plain_counts = sweep(False)
-    assert counts == plain_counts and counts["line_searches"] > 0
+    assert np.array_equal(counts.array, plain_counts.array) and counts.rows == plain_counts.rows
+    assert counts.counters()["line_searches"] > 0
     assert loss.tobytes() == plain_loss.tobytes()
     np.testing.assert_array_equal(np.asarray(state.fe_coefficients),
                                   np.asarray(plain_state.fe_coefficients))
@@ -523,13 +524,64 @@ def test_four_devices_count_the_line_searches_one_device_counts(host, four):
         program = _program(mesh)
         packed = four["packed"] if devices == 4 else _packed(host, mesh)
         _, loss = program.step(*_placed(program, mesh, host, packed))
-        return program.take_solver_counts(), float(loss)
+        return program.take_solver_counts().counters(), float(loss)
 
     (on_four, loss_four), (on_one, loss_one) = counts(4), counts(1)
-    for name in ("line_searches", "lane_trials", "lockstep_trials", "fe_trials"):
+    for name in ("line_searches", "lane_trials", "lockstep_trials", "fe_trials",
+                 "lockstep_iterations", "row_trials_wanted"):
         assert 0 < on_one[name]
         assert abs(on_four[name] - on_one[name]) <= max(1, 0.01 * on_one[name]), name
+    # four devices pay for the lanes that fill a bucket up to a multiple of
+    # four; the lanes that are there are the same lanes, counted once each
+    assert on_one["row_trials_paid"] <= on_four["row_trials_paid"] <= (
+        1.3 * on_one["row_trials_paid"])
+    for t, _ in RE:
+        assert on_four[f"re/{t}/lockstep_iterations"] > 0
+    assert on_four["lane_solves"] == on_one["lane_solves"] == (
+        len(np.unique(host["user"])) + len(np.unique(host["item"])))
     assert abs(loss_four - loss_one) < 1.3e-5 * loss_one
+
+
+def test_on_four_devices_the_counts_are_taken_chip_by_chip_and_read_the_same(
+        host, four, mesh4):
+    """A bucket's counts taken over each chip's own lanes and brought together
+    once (``_lane_parts`` = the mesh's four) against the same program counting
+    every bucket over all its lanes at once: the same array to the digit, the
+    padding lanes that fill a bucket up to a multiple of four in no sum."""
+    def sweep(parts):
+        program = _program(mesh4)
+        assert program._lane_parts == 4
+        program._lane_parts = parts
+        program.step(*_placed(program, mesh4, host, four["packed"]))
+        return program.take_solver_counts()
+
+    by_chip, at_once = sweep(4), sweep(1)
+    assert np.array_equal(by_chip.array, at_once.array) and by_chip.rows == at_once.rows
+    lanes = {t: sum(row.lanes for row in by_chip.rows if row.coordinate == f"re/{t}")
+             for t, _ in RE}
+    solved = {t: sum(r["lane_solves"] for r in by_chip.table()
+                     if r["coordinate"] == f"re/{t}") for t, _ in RE}
+    assert solved == {t: len(np.unique(host[t])) for t, _ in RE}
+    assert all(row.lanes % 4 == 0 for row in by_chip.rows if row.family == "re")
+    assert any(lanes[t] > solved[t] for t, _ in RE)  # there ARE padding lanes
+
+
+@pytest.mark.parametrize("compiled", ["glmix4", "mf4"])
+def test_the_counts_cross_the_chips_once_a_sweep(compiled, request):
+    """The compiled step holds ONE ``max`` and ONE ``sum`` of int32 counts
+    across the chips, over all the buckets stacked (outside every scope, the
+    step's own), where one a count a bucket stood: no collective under a
+    coordinate's ``solve`` carries an int32."""
+    compiled = request.getfixturevalue(compiled)
+    buckets = sum(len(shapes) for shapes in compiled["lanes"].values()) * (
+        MF_ALTERNATIONS if "row" in compiled["lanes"] else 1)
+    counts = sorted((name.rsplit("/", 1)[-1], op, shapes)
+                    for op, shapes, name in compiled["collectives"]
+                    if any(dtype == "s32" for dtype, _ in shapes)
+                    and "/gather/" not in name and "/scatter/" not in name)
+    # max_iterations 10: a trip count and eleven slots of search trips; 9 sums
+    assert counts == [("reduce_max", "all-reduce", [("s32", (buckets, 12))]),
+                      ("reduce_sum", "all-reduce", [("s32", (buckets, 9))])]
 
 
 def test_the_variances_of_a_fit_on_the_mesh_go_through_the_same_exchange(host, four, mesh4):
