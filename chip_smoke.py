@@ -4,9 +4,13 @@
 One process drives, in order, through the entry points a user would call:
 
   kernel   ops.pallas_glm.fused_value_and_gradient vs the autodiff objective
-           at d in {256, 512, 2048, 4096} x {f32, bf16} on whole tiles and at
-           d = 2000, 617 rows short of them (the masked body): Mosaic custom call
-           present, value/gradient agree with an f64 numpy recomputation; then
+           at d in {256, 512, 2048, 4096, 16384} x {f32, bf16} on whole tiles
+           and at d = 2000, 617 rows short of them (the masked body; since
+           PR 53 the per-row columns reach both kernels as one [3, n] block,
+           whose last (3, tile) piece is then ragged along the lanes, and
+           16384 is the width whose float32 tile is the rule's floor of 128
+           rows, 8 MiB): Mosaic custom call present, value/gradient agree
+           with an f64 numpy recomputation; then
            ops.pallas_glm.fused_hessian_vector at each: its custom call, the
            product against f64 numpy and against the jvp of the gradient
   glmix    cli.game_training_driver.main  (TrainingExampleAvro on disk ->
@@ -65,7 +69,7 @@ class Sizes:
     n_items: int = 1500
     glm_n: int = 262144
     glm_n_val: int = 16384
-    kernel_widths: tuple = (256, 512, 2048, 4096)
+    kernel_widths: tuple = (256, 512, 2048, 4096, 16384)
     kernel_tiles: int = 8
     #: (width, rows short of ``kernel_tiles`` whole tiles): neither a whole
     #: number of lanes nor of row tiles, so Mosaic compiles the masked body

@@ -27,11 +27,12 @@ Array = jax.Array
 
 _LANE = 128  # TPU lane width: the last dimension of every tile
 #: widest (lane-padded) feature block the Pallas GLM kernels take
-#: (ops/pallas_glm.py). Past it the resident w / grad blocks alone crowd the
-#: row tile down to a few sublanes; the auto rule (ops/objective.py) keeps
-#: wider dense blocks on the XLA path, and forcing the kernel there raises.
-#: Every width at or under it is compiled on the chip by chip_smoke.py's
-#: kernel leg. It stands here, under ops/, because the batch's placement
+#: (ops/pallas_glm.py). At it the row tile stands at its floor of 128 rows
+#: (8 MiB of float32, 23.9 MiB of the 32 MiB of scoped VMEM the kernels ask
+#: for); the auto rule (ops/objective.py) keeps wider dense blocks on the XLA
+#: path, and forcing the kernel there raises. chip_smoke.py's kernel leg
+#: compiles and runs it on the chip, and tests/test_tpu_compile.py compiles
+#: it for a described v5e. It stands here, under ops/, because the batch's placement
 #: (``in_kernel_layout``) asks the same question the auto rule does.
 MAX_KERNEL_DIM = 16384
 #: most a block may grow when its rows are stored whole lanes wide:

@@ -223,20 +223,26 @@ def test_vmap_detection_canary():
 
 
 def test_row_tile_fits_budget_and_sublane_packing():
-    """Every width the auto rule routes to the kernel gets a row tile that
-    is a multiple of the dtype's sublane packing (8 f32 / 16 bf16 — Mosaic
-    refuses others) and keeps ONE X tile within the 4 MiB budget whose
-    double buffer fits the explicit VMEM limit (the widths from 2048 up did
-    not compile on the v5e before)."""
+    """Every width the auto rule routes to the kernel gets a row tile in whole
+    128s: whole sublane packings (8 f32 / 16 bf16; Mosaic refuses others) and,
+    since PR 53, a ``(3, tile)`` aux block that ends on a lane boundary (the
+    Pallas lowering refuses any other). ONE X tile stays within the 4 MiB
+    budget wherever 128 rows do, and its double buffer fits the explicit VMEM
+    limit with the widest float32 tile's 8 MiB (tests/test_tpu_compile.py
+    compiles those for a described v5e)."""
     import photon_ml_tpu.ops.pallas_glm as kernel_mod
 
-    for d_pad in (128, 256, 512, 2048, 4096, 12800, kernel_mod.MAX_KERNEL_DIM):
+    for d_pad in (128, 256, 512, 1280, 2048, 4096, 8192, 12800, kernel_mod.MAX_KERNEL_DIM):
         for itemsize, sublane in ((4, 8), (2, 16)):
             tile = kernel_mod._row_tile(d_pad, itemsize)
-            assert tile % sublane == 0 and tile >= sublane
-            assert tile * d_pad * itemsize <= kernel_mod._X_TILE_BYTES
+            assert tile % 128 == 0 and tile % sublane == 0 and tile >= 128
+            assert tile == 128 or tile * d_pad * itemsize <= kernel_mod._X_TILE_BYTES
+            assert 3 * tile * d_pad * itemsize <= kernel_mod._VMEM_LIMIT_BYTES
     assert kernel_mod._row_tile(512, 4) == 1024
     assert kernel_mod._row_tile(512, 2) == 2048
+    assert kernel_mod._row_tile(2048, 4) == 512  # the dense cells' tile
+    assert kernel_mod._row_tile(12800, 2) == 128  # 163 rows fit: whole 128s
+    assert kernel_mod._row_tile(kernel_mod.MAX_KERNEL_DIM, 4) == 128  # 64 fit
 
 
 def test_kernel_width_limit(monkeypatch):
@@ -366,7 +372,7 @@ def _kernel_body(n, d):
     closed = jax.make_jaxpr(
         lambda x, aux, w: kernel_mod._fused_padded(SquaredLoss(), x, aux, True, w))(
         jax.ShapeDtypeStruct((n, d), jnp.float32),
-        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+        jax.ShapeDtypeStruct((3, n), jnp.float32),
         jax.ShapeDtypeStruct((kernel_mod._round_up(d, 128),), jnp.float32))
     (call,) = [e for e in closed.jaxpr.eqns[0].params["jaxpr"].eqns
                if e.primitive.name == "pallas_call"]
@@ -376,8 +382,9 @@ def _kernel_body(n, d):
 @pytest.mark.parametrize("n,d,bodies,iotas", [
     pytest.param(2048, 256, 1, 0, id="whole-tiles"),  # the GLMix cells' kind
     pytest.param(2048, 200, 1, 1, id="lanes"),  # a lane mask, on every step
-    pytest.param(2000, 256, 2, 1, id="rows"),  # a row mask, in the last step's body
-    pytest.param(2000, 200, 2, 3, id="rows+lanes"),  # lanes in both, rows in the last
+    # rows, in the last step's body: of X along the sublanes, of the per-row values along the lanes
+    pytest.param(2000, 256, 2, 2, id="rows"),
+    pytest.param(2000, 200, 2, 4, id="rows+lanes"),  # lanes in both, rows in the last
 ])
 def test_masks_follow_from_the_static_shape(n, d, bodies, iotas):
     """No option decides what is masked: ``n % tile`` and ``d % 128`` do, at
@@ -411,3 +418,104 @@ def test_an_empty_batch_is_zero():
     batch = _batch(0, 8)
     v, g = fused_value_and_gradient(SquaredLoss(), jnp.ones(8), batch, interpret=True)
     assert float(v) == 0.0 and not np.any(np.asarray(g))
+
+
+# -- the aux block lies [3, n]: the same body fed the parent's [tile, 3] block
+
+
+class _Turned:
+    """A ``[tile, 3]`` block read as the ``[3, tile]`` one the body takes its
+    three rows from."""
+
+    def __init__(self, ref):
+        self._ref = ref
+
+    def __getitem__(self, index):
+        return self._ref[index].T
+
+
+def _fed_row_wise(loss, x, aux_rows, w):
+    """The parent's form, kept here alone: ``aux_rows`` [n, 3] blocked
+    ``(tile, 3)`` along the rows beside X, and ``_kernel``'s own body."""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+    from jax.experimental import pallas as pl
+
+    (n, d), d_pad = x.shape, w.shape[0]
+    tile = kernel_mod._row_tile(d_pad, x.dtype.itemsize)
+
+    def body(x_ref, aux_ref, *refs):
+        kernel_mod._kernel(loss, n, d, x_ref, _Turned(aux_ref), *refs)
+
+    row, scalar = pl.BlockSpec((1, d_pad), lambda i: (0, 0)), pl.BlockSpec((1, 1), lambda i: (0, 0))
+    value, grad, rsum = pl.pallas_call(
+        body, grid=(pl.cdiv(n, tile),),
+        in_specs=[pl.BlockSpec((tile, d_pad), lambda i: (i, 0)),
+                  pl.BlockSpec((tile, 3), lambda i: (i, 0)), row],
+        out_specs=[scalar, row, scalar],
+        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        interpret=True)(x, aux_rows, w.reshape(1, d_pad))
+    return value[0, 0], grad[0], rsum[0, 0]
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    pytest.param(4096, 256, "float32", id="whole-tiles"),
+    pytest.param(3000, 200, "float32", id="rows+lanes"),
+    pytest.param(300, 20, "float32", id="n<tile"),
+    pytest.param(1025, 130, "float32", id="n=tile+1"),
+    pytest.param(4096, 256, "bfloat16", id="whole-tiles-bfloat16"),
+    pytest.param(3000, 200, "bfloat16", id="rows+lanes-bfloat16"),
+    pytest.param(520, 12800, "float32", id="d12800-tile-128"),
+])
+def test_the_lane_wise_block_gives_the_row_wise_blocks_sums_bit_for_bit(n, d, dtype):
+    """Value, gradient and Σr of ``_fused_padded`` on the ``[3, n]`` block
+    against the same body fed the ``[n, 3]`` block in ``(tile, 3)`` pieces:
+    the orientation moves bytes, no arithmetic. A ragged last block is NaN
+    past the array's edge along the lanes now, along the rows then. (That the
+    pointwise work on ``[1, tile]`` rows gives the parent's bits, whose body
+    worked on ``[tile, 1]`` columns, is the chip's to show: PERF.md 6, PR 53.)"""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+
+    batch = _batch(n, d, seed=n + d, binary=True)
+    x = batch.features.astype(jnp.dtype(dtype))
+    w = jnp.pad(jnp.asarray(np.random.default_rng(d).normal(size=d).astype(np.float32)) * 0.3,
+                (0, kernel_mod._round_up(d, 128) - d))
+    aux_rows = jnp.stack([batch.labels, batch.offsets, batch.weights], axis=1)
+    new = kernel_mod._fused_padded(LogisticLoss(), x, aux_rows.T, True, w)
+    old = _fed_row_wise(LogisticLoss(), x, aux_rows, w)
+    assert np.all(np.isfinite(np.asarray(new[1])))
+    for a, b in zip(new, old):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_operands_are_prepared_in_one_place_and_hold_no_row_wise_block():
+    """Both public functions go through ``_operands``: the aux block of either
+    is ``[3, n]`` float32 with the offsets moved by the shift term, and no
+    ``[n, 3]`` array is built on the way to either kernel."""
+    import photon_ml_tpu.ops.pallas_glm as kernel_mod
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+    from photon_ml_tpu.ops.pallas_glm import fused_hessian_vector
+
+    n, d = 300, 20
+    batch = _batch(n, d, binary=True)
+    rng = np.random.default_rng(9)
+    context = NormalizationContext(
+        factors=jnp.asarray(rng.uniform(0.5, 2.0, size=d).astype(np.float32)),
+        shifts=jnp.asarray(rng.normal(size=d).astype(np.float32)))
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    x, aux, (eff,), factors, shifts = kernel_mod._operands(batch, context, w)
+    assert x is batch.features and aux.shape == (3, n) and aux.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(aux[0]), np.asarray(batch.labels))
+    np.testing.assert_array_equal(np.asarray(aux[2]), np.asarray(batch.weights))
+    np.testing.assert_array_equal(
+        np.asarray(aux[1]), np.asarray(batch.offsets - jnp.dot(w * factors, shifts)))
+    np.testing.assert_array_equal(np.asarray(eff), np.asarray(w * factors))
+    programs = [
+        jax.make_jaxpr(lambda w_: fused_value_and_gradient(
+            LogisticLoss(), w_, batch, normalization=context, interpret=True))(w),
+        jax.make_jaxpr(lambda w_: fused_hessian_vector(
+            LogisticLoss(), w_, w_, batch, normalization=context, interpret=True))(w)]
+    for program in programs:
+        shapes = {tuple(v.aval.shape) for eqn in program.jaxpr.eqns for v in eqn.outvars}
+        assert (3, n) in shapes and (n, 3) not in shapes

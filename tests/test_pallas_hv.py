@@ -162,7 +162,7 @@ def _kernel_body(n, d):
         lambda x, aux, w, v, z: kernel_mod._hv_one_pass(
             SquaredLoss(), x, aux, True, w, v, z))(
         jax.ShapeDtypeStruct((n, d), jnp.float32),
-        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+        jax.ShapeDtypeStruct((3, n), jnp.float32),
         jax.ShapeDtypeStruct((d_pad,), jnp.float32),
         jax.ShapeDtypeStruct((d_pad,), jnp.float32),
         jax.ShapeDtypeStruct((), jnp.float32))
@@ -174,8 +174,9 @@ def _kernel_body(n, d):
 @pytest.mark.parametrize("n,d,bodies,iotas", [
     pytest.param(2048, 256, 1, 0, id="whole-tiles"),
     pytest.param(2048, 200, 1, 1, id="lanes"),  # a lane mask, on every step
-    pytest.param(2000, 256, 2, 1, id="rows"),  # a row mask, in the last step's body
-    pytest.param(2000, 200, 2, 3, id="rows+lanes"),  # lanes in both, rows in the last
+    # rows, in the last step's body: of X along the sublanes, of the per-row values along the lanes
+    pytest.param(2000, 256, 2, 2, id="rows"),
+    pytest.param(2000, 200, 2, 4, id="rows+lanes"),  # lanes in both, rows in the last
 ])
 def test_the_products_masks_follow_from_the_static_shape(n, d, bodies, iotas):
     """The gradient kernel's rule: ``n % tile`` and ``d % 128`` decide at trace
@@ -316,3 +317,65 @@ def test_a_compiled_trace_is_counted_as_compiled(monkeypatch):
     batch, w, v = _problem(64, 8)
     jax.make_jaxpr(lambda w_, v_: fused_hessian_vector(LogisticLoss(), w_, v_, batch))(w, v)
     assert counter.value == before + 1
+
+
+# -- the aux block lies [3, n]: the same body fed the parent's [tile, 3] block
+
+
+class _Turned:
+    """A ``[tile, 3]`` block read as the ``[3, tile]`` one the body takes its
+    three rows from."""
+
+    def __init__(self, ref):
+        self._ref = ref
+
+    def __getitem__(self, index):
+        return self._ref[index].T
+
+
+def _fed_row_wise(loss, x, aux_rows, w, v, zshift):
+    """The parent's form, kept here alone: ``aux_rows`` [n, 3] blocked
+    ``(tile, 3)`` along the rows beside X, and ``_hv_kernel``'s own body."""
+    from jax.experimental import pallas as pl
+
+    (n, d), d_pad = x.shape, w.shape[0]
+    tile = kernel_mod._row_tile(d_pad, x.dtype.itemsize)
+
+    def body(zshift_ref, x_ref, aux_ref, *refs):
+        kernel_mod._hv_kernel(loss, n, d, zshift_ref, x_ref, _Turned(aux_ref), *refs)
+
+    row, scalar = pl.BlockSpec((1, d_pad), lambda i: (0, 0)), pl.BlockSpec((1, 1), lambda i: (0, 0))
+    acc, usum = pl.pallas_call(
+        body, grid=(pl.cdiv(n, tile),),
+        in_specs=[scalar, pl.BlockSpec((tile, d_pad), lambda i: (i, 0)),
+                  pl.BlockSpec((tile, 3), lambda i: (i, 0)), row, row],
+        out_specs=[row, scalar],
+        out_shape=[jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        interpret=True)(zshift.reshape(1, 1), x, aux_rows, w.reshape(1, d_pad),
+                        v.reshape(1, d_pad))
+    return acc[0], usum[0, 0]
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    pytest.param(4096, 256, "float32", id="whole-tiles"),
+    pytest.param(3000, 200, "float32", id="rows+lanes"),
+    pytest.param(300, 20, "float32", id="n<tile"),
+    pytest.param(4096, 256, "bfloat16", id="whole-tiles-bfloat16"),
+    pytest.param(3000, 200, "bfloat16", id="rows+lanes-bfloat16"),
+    pytest.param(520, 12800, "float32", id="d12800-tile-128"),
+])
+def test_the_lane_wise_block_gives_the_row_wise_blocks_product_bit_for_bit(n, d, dtype):
+    """``X'u`` and Σu of ``_hv_one_pass`` on the ``[3, n]`` block against the
+    same body fed the ``[n, 3]`` block in ``(tile, 3)`` pieces."""
+    batch, w, v = _problem(n, d, dtype)
+    lanes = (0, kernel_mod._round_up(d, 128) - d)
+    w, v = jnp.pad(w, lanes), jnp.pad(v, lanes)
+    zshift = jnp.float32(0.25)
+    aux_rows = jnp.stack([batch.labels, batch.offsets, batch.weights], axis=1)
+    new = kernel_mod._hv_one_pass(
+        LogisticLoss(), batch.features, aux_rows.T, True, w, v, zshift)
+    old = _fed_row_wise(LogisticLoss(), batch.features, aux_rows, w, v, zshift)
+    assert np.all(np.isfinite(np.asarray(new[0])))
+    for a, b in zip(new, old):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

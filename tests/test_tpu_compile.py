@@ -2,8 +2,9 @@
 interpreter cannot show of the Pallas kernels, and what the benchmark's trace
 reduction will find in the TRON path program.
 
-Held here: the one-pass Hessian-vector kernel compiles through Mosaic at the
-widths the auto rule admits (scoped VMEM, tiling), and ``glm/path_solve`` under
+Held here: both kernels compile through Mosaic at the widths the auto rule
+admits (scoped VMEM, tiling) with their per-row columns a ``[3, n]`` block
+(PR 53), and ``glm/path_solve`` under
 TRON at the benchmark cell's size holds ONE custom call a product, inside the
 CG loop, under ``tron/hv``, named after its jitted wrapper by a name
 ``benchmark/trace_reduce.KERNEL`` does not match, with X relaid out twice a
@@ -57,21 +58,39 @@ def _shape(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _gradient_call(one_chip, n, d, d_pad, dtype):
+    return jax.jit(
+        lambda x, aux, w: kernel_mod._fused_padded(LogisticLoss(), x, aux, False, w)
+    ).lower(_shape(one_chip, (n, d), dtype), _shape(one_chip, (3, n)),
+            _shape(one_chip, (d_pad,)))
+
+
+def _product_call(one_chip, n, d, d_pad, dtype):
+    return jax.jit(
+        lambda x, aux, w, v, z: kernel_mod._hv_one_pass(
+            LogisticLoss(), x, aux, False, w, v, z)
+    ).lower(_shape(one_chip, (n, d), dtype), _shape(one_chip, (3, n)),
+            _shape(one_chip, (d_pad,)), _shape(one_chip, (d_pad,)),
+            _shape(one_chip, ()))
+
+
 @pytest.mark.parametrize("d,rows_short", [(512, 0), (2000, 617), (4096, 0), (16384, 0)],
                          ids=["d512", "d2000-ragged", "d4096", "d16384"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_product_kernel_compiles_for_a_v5e(one_chip, d, rows_short, dtype):
+@pytest.mark.parametrize("lower", [_gradient_call, _product_call],
+                         ids=["gradient", "product"])
+def test_the_kernels_compile_for_a_v5e(one_chip, lower, d, rows_short, dtype):
+    """Both kernels through Mosaic with the aux block ``[3, n]`` in
+    ``(3, tile)`` pieces, turned on the tile: scoped VMEM (the widest float32
+    tile is 128 rows of 16,384, 8 MiB, twice buffered), tiling, the ragged
+    last block along the lanes."""
     dtype = jnp.dtype(dtype)
     d_pad = kernel_mod._round_up(d, 128)
     n = 8 * kernel_mod._row_tile(d_pad, dtype.itemsize) - rows_short
     with jax.enable_x64(False):  # the suite's x64 makes the grid's index maps int64
-        compiled = jax.jit(
-            lambda x, aux, w, v, z: kernel_mod._hv_one_pass(
-                LogisticLoss(), x, aux, False, w, v, z)
-        ).lower(_shape(one_chip, (n, d), dtype), _shape(one_chip, (n, 3)),
-                _shape(one_chip, (d_pad,)), _shape(one_chip, (d_pad,)),
-                _shape(one_chip, ())).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        compiled = lower(one_chip, n, d, d_pad, dtype).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"f32[3,{n}]" in text
 
 
 #: how X arrives: as the platform lays a [400000, 2000] block (column-major:
@@ -142,6 +161,28 @@ def test_a_product_is_one_custom_call_under_tron_hv_by_its_own_name(tron_path_te
     others = {trace_reduce.instruction(f"%{n} = ") for n in calls if n not in products}
     assert others == {"_fused_padded"}
     assert not any("tron/" in calls[n] for n in calls if n not in products)
+
+
+@pytest.mark.parametrize("optimizer", ["TRON", "LBFGS"])
+def test_the_per_row_columns_reach_the_kernels_along_the_lanes(path_compiled, optimizer):
+    """PR 53: a ``[rows, 3]`` float32 array is stored 128 lanes wide, 512 bytes
+    a row in HBM and in every DMA of a launch. No instruction of a path
+    program makes one; every kernel call takes ``f32[3,rows]`` beside X, X
+    first, which is where ``benchmark/trace_reduce.kernel_operand`` reads the
+    bytes ``sweeps_glm_kernel_roofline`` counts."""
+    text = path_compiled(optimizer, ROW_MAJOR).as_text()
+    assert not re.search(rf"= f32\[{ROWS},(3|128)\]", text)
+    calls = re.findall(
+        r"%([\w.-]+) = ([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*?\}\}),", text)
+    # at the start and in the search (L-BFGS) or a round (TRON), and TRON's product
+    assert len(calls) == (3 if optimizer == "TRON" else 2)
+    for name, call in calls:
+        operands = call.split("operand_layout_constraints=", 1)[1]
+        assert f"f32[3,{ROWS}]" in operands and f"f32[{ROWS},3]" not in operands
+        if trace_reduce.KERNEL.search(name):
+            event = f"%{name} = {call}"[:trace_reduce.NAME_CHARS]  # as the trace names it
+            assert trace_reduce.kernel_operand(event) == (ROWS, FEATURES, 4)
+    assert any(trace_reduce.KERNEL.search(name) for name, _ in calls)
 
 
 X_BLOCK = rf"f32\[{ROWS},{FEATURES}\]"
